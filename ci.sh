@@ -72,7 +72,7 @@ set -euo pipefail
 cd "$(dirname "$0")"
 
 echo "== [1/11] syntax + format gate"
-python -m compileall -q cleisthenes_tpu tests bench.py __graft_entry__.py
+python -m compileall -q cleisthenes_tpu tests bench.py chip_smoke.py __graft_entry__.py
 python tools/format_gate.py
 
 echo "== [2/11] staticcheck gate: whole-program registry + determinism plane"

@@ -116,6 +116,20 @@ def main(argv=None) -> int:
         f"batch={args.batch_size} crypto={args.crypto} mode={args.mode}"
         + (" keys=dkg" if args.dkg else " keys=dealer")
     )
+    if args.crypto == "tpu":
+        # 'tpu' means "the XLA kernels on whatever JAX found": say what
+        # that is, so an XLA-on-host run is never mistaken for a chip
+        import jax
+
+        from cleisthenes_tpu.utils.compile_cache import enable_compile_cache
+
+        cache_dir = enable_compile_cache()
+        devs = jax.devices()
+        print(
+            f"== crypto=tpu runs on JAX platform {devs[0].platform!r} "
+            f"({devs[0].device_kind} x{len(devs)}); compile cache "
+            f"{cache_dir}"
+        )
     if args.mode == "lockstep":
         if args.trace:
             print(

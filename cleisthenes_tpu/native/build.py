@@ -3,13 +3,16 @@
 Each kernel source compiles to a shared library cached by source hash
 (rebuilds on change, races benignly via atomic rename); loading is
 attempted once per process and failure degrades to the pure-python /
-XLA paths, never to an exception.
+XLA paths, never to an exception — but the reason is logged once,
+because the degradation moves the host floors (ModEngine falls to
+HOST_FLOOR_NO_NATIVE) and the CPU reference to python pow().
 """
 
 from __future__ import annotations
 
 import ctypes
 import hashlib
+import logging
 import os
 import subprocess
 import tempfile
@@ -18,6 +21,12 @@ from typing import Callable, Dict, Optional
 
 _DIR = Path(__file__).parent
 _LIBS: Dict[str, Optional[ctypes.CDLL]] = {}
+_LOG = logging.getLogger("cleisthenes_tpu.native")
+
+
+def source_path(name: str) -> Path:
+    """The committed C++ source of kernel ``name``."""
+    return _DIR / f"{name}.cpp"
 
 
 def _cache_path(src: Path) -> Path:
@@ -45,18 +54,34 @@ def _compile(src: Path, out: Path) -> None:
 
 def _load(name: str, configure: Callable[[ctypes.CDLL], None]):
     """Compile-if-needed + load + configure + selftest, once per
-    process; returns None forever after the first failure."""
+    process; returns None forever after the first failure, whose
+    reason is logged that one time."""
     if name in _LIBS:
         return _LIBS[name]
     try:
-        src = _DIR / f"{name}.cpp"
+        src = source_path(name)
         path = _cache_path(src)
         if not path.exists():
             _compile(src, path)
         lib = ctypes.CDLL(str(path))
         configure(lib)
         _LIBS[name] = lib
-    except Exception:
+    except (
+        OSError,  # no g++, unwritable cache dir, dlopen failure
+        subprocess.SubprocessError,  # compile error or timeout
+        AttributeError,  # library lacks an expected symbol
+        RuntimeError,  # selftest mismatch
+    ) as exc:
+        detail = getattr(exc, "stderr", None) or exc
+        if isinstance(detail, bytes):
+            detail = detail.decode("utf-8", "replace")
+        _LOG.warning(
+            "native kernel %r unavailable, using the python/XLA path "
+            "(%s: %s)",
+            name,
+            type(exc).__name__,
+            str(detail).strip()[-400:],
+        )
         _LIBS[name] = None
     return _LIBS[name]
 

@@ -1,0 +1,48 @@
+"""Placement tally: which side of the ops/ seam ran each batch.
+
+Under ``crypto_backend="tpu"`` every batched entry point in ops/
+decides per call whether the XLA kernels or the native host kernels
+run the batch (``ModEngine._host_floor``, the ``XlaMerkle`` and
+``XlaErasureCoder`` floors).  ``CryptoHub.stats()`` counts dispatches
+without saying where they ran, so a run that "used the tpu backend"
+could have left the device idle and nobody would know.  This module
+counts the decision where it is made: calls and items per kernel
+family, ``device`` or ``host``.  ``chip_smoke.py`` reads it.
+
+Only the 'tpu' backend's entry points report here: the 'cpu'/'cpp'
+backends have no decision to make.
+"""
+
+from __future__ import annotations
+
+import threading
+from typing import Dict
+
+_FIELDS = ("device_calls", "device_items", "host_calls", "host_items")
+_lock = threading.Lock()
+_counts: Dict[str, Dict[str, int]] = {}
+
+
+def note(family: str, on_device: bool, items: int) -> None:
+    """One batch of ``items`` in ``family`` ran on the device (or, with
+    ``on_device`` false, was routed to the host kernels)."""
+    side = "device" if on_device else "host"
+    with _lock:
+        row = _counts.setdefault(family, dict.fromkeys(_FIELDS, 0))
+        row[side + "_calls"] += 1
+        row[side + "_items"] += int(items)
+
+
+def snapshot() -> Dict[str, Dict[str, int]]:
+    """{family: {device_calls, device_items, host_calls, host_items}}
+    since the last ``reset()``, families in name order."""
+    with _lock:
+        return {fam: dict(_counts[fam]) for fam in sorted(_counts)}
+
+
+def reset() -> None:
+    with _lock:
+        _counts.clear()
+
+
+__all__ = ["note", "snapshot", "reset"]

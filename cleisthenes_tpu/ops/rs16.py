@@ -20,6 +20,7 @@ import functools
 import numpy as np
 
 from cleisthenes_tpu.ops import gf65536 as gf
+from cleisthenes_tpu.ops import placement
 from cleisthenes_tpu.ops.backend import ErasureCoder
 
 
@@ -91,9 +92,11 @@ class Xla16ErasureCoder(ErasureCoder):
     # -- single-instance ops (tiny: host path keeps dispatch count
     # down, same policy as the 8-bit XLA coder's host floor) ----------
     def encode(self, data: np.ndarray) -> np.ndarray:
+        placement.note("rs_gf65536.encode", False, 1)
         return self._cpu.encode(data)
 
     def _decode_impl(self, indices: tuple, shards: np.ndarray) -> np.ndarray:
+        placement.note("rs_gf65536.decode", False, 1)
         return self._cpu._decode_impl(indices, shards)
 
     # -- batched ops: one lifted matmul for all instances -------------
@@ -108,6 +111,7 @@ class Xla16ErasureCoder(ErasureCoder):
         if self.n == self.k:
             return data.copy()
         syms = data.view("<u2").reshape(b, k, L // 2)
+        placement.note("rs_gf65536.encode_batch", True, b)
         out = encode_kernel_batch(
             jnp.asarray(self._g_parity), jnp.asarray(syms)
         )
@@ -136,6 +140,7 @@ class Xla16ErasureCoder(ErasureCoder):
                 return shards.copy()
             g = self._g_decode(pat)
             syms = shards.view("<u2").reshape(b, k, L // 2)
+            placement.note("rs_gf65536.decode_batch", True, b)
             out = np.asarray(
                 decode_kernel_shared(jnp.asarray(g), jnp.asarray(syms))
             )

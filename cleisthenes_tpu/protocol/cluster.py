@@ -14,8 +14,8 @@ ChannelNetwork (optionally seeded = adversarial scheduler), pairwise
 MAC authenticators, and — by default — a cluster-SHARED CryptoHub, so
 every wave flush executes the whole roster's crypto in single batched
 device dispatches (the north star's "vmaps them across all N
-validators' shards at once"; essential under a remote TPU attachment
-where per-dispatch round-trips dominate).  ``shared_hub=False``
+validators' shards at once": each device dispatch is paid once for
+the roster, not once per node).  ``shared_hub=False``
 reverts to per-node hubs, the shape of a real multi-host deployment.
 
 Fault injection passes straight through to the network: ``crash``,

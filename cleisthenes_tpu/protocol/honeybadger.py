@@ -583,9 +583,9 @@ class HoneyBadger:
         # ``hub`` may be SHARED by every in-proc validator of a
         # simulated cluster: one wave-deferred flush then executes the
         # WHOLE roster's crypto in single cluster-wide dispatches — the
-        # north star's "vmap across all N validators" framing, and the
-        # only sane shape under a remote TPU attachment where dispatch
-        # round-trips dominate.  Scopes are node-qualified so one
+        # north star's "vmap across all N validators" framing, which
+        # pays each device dispatch once for the roster instead of
+        # once per node.  Scopes are node-qualified so one
         # node's epoch GC never drops a peer's clients.  Real
         # deployments (one validator per host) keep per-node hubs.
         self.hub = CryptoHub(self.crypto) if hub is None else hub

@@ -139,7 +139,7 @@ def test_load_snapshot_shape():
 def test_nonblocking_busy_probe_exits_cleanly(tmp_path, monkeypatch):
     """A busy block=False probe must yield False and EXIT without
     error: the double-close (EBADF in the outer finally) killed the
-    armed relay watcher the first time a capture held the lock."""
+    prober the first time a capture held the lock."""
     from tools import benchlock
 
     monkeypatch.setattr(

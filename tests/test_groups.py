@@ -172,10 +172,10 @@ def test_2048bit_modp14_xla_engine_matches_pow(
 
 
 def test_wide_floors_route_by_measured_crossover(jax_cpu_devices):
-    """Round-4 verdict weak #4: engine defaults must follow measured
-    device-vs-host crossovers per limb family (TPU_QUICK_r05
-    modexp_wide).  384-bit wins on device above ~160 exps (floor 256);
-    2048-bit measured 0.97x host — it must ALWAYS delegate."""
+    """Engine defaults route per limb family by WIDE_FLOORS (values
+    carried over from an earlier attachment of the chip, unmeasured on
+    the present one): 384-bit goes to the device at >= 256 exps;
+    2048-bit must ALWAYS delegate to the host."""
     eng384 = get_engine("tpu", group=GROUP384)
     assert eng384._host_floor(255) is not None  # below floor -> host
     assert eng384._host_floor(256) is None  # above -> device

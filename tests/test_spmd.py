@@ -107,8 +107,8 @@ def test_lockstep_roster_past_gf256_ceiling():
 
 
 def test_lockstep_serial_coin_blocks_match_doubling():
-    """The coin_block_doubling knob (the on-chip A/B comparator,
-    AB_COIN_BLOCKS_r05) changes dispatch batching only: committed
+    """The coin_block_doubling knob (the on-chip A/B comparator)
+    changes dispatch batching only: committed
     transactions, coin values, and round counts are identical because
     the shares are deterministic VUFs of (epoch, proposer, round)."""
     a = LockstepCluster(n=5, batch_size=40, key_seed=9)

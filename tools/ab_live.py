@@ -1,9 +1,8 @@
 """Quick live-path A/B: measure protocol_n64 before/after a change.
 
 Runs bench.measure_protocol on the cpu backend under the benchlock
-(pausing the background sweep so the one core is ours) and prints the
-section dict.  Used to attribute each columnar-delivery-plane stage's
-win honestly (16.6 s r4 baseline; target <= 5 s, r4 verdict item 3).
+(pausing the background sweep so the cores are ours) and prints the
+section dict.
 
 Usage:  python tools/ab_live.py [n] [batch] [epochs]
 """
@@ -17,6 +16,7 @@ import sys
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
+# host-path measurement: never takes the chip
 os.environ["JAX_PLATFORMS"] = "cpu"
 import jax  # noqa: E402
 

@@ -1,19 +1,18 @@
 """Measurement mutual exclusion + host-load provenance.
 
-Round-4 post-mortem (VERDICT weak #2): the armed bench_watcher's 5-min
-jax-import probes ran concurrently with the driver's end-of-round
-capture on this ONE-core box and inflated every CPU section ~2x
-(protocol_n16 994 ms vs the builder's committed 462 ms).  The artifacts
-could not prove the contamination because provenance recorded only
-relay drift, not host contention.  This module fixes both halves:
+A second python process sharing the host's cores with a capture
+inflated every CPU section ~2x once (protocol_n16 994 ms vs 462 ms
+on a quiet box), and the artifact could not prove the contamination
+because its provenance recorded nothing about host contention.  This
+module fixes both halves:
 
 1. MUTUAL EXCLUSION — one flock'd lockfile shared by every measuring
-   driver (bench.py, tools/bench_watcher.py, tools/quick_tpu.py).
-   While a holder measures, no other driver probes or measures.
+   driver (bench.py, tools/ab_live.py, tools/profile_*.py).  While a
+   holder measures, no other driver measures.
 2. PAUSABLE LOW-PRIORITY JOBS — hours-long background work
    (tools/sweep_roster.py) registers its pid; acquiring the lock
    SIGSTOPs registered jobs for the duration and SIGCONTs them on
-   release, so a TPU window can be seized without the sweep
+   release, so a measurement can start without the sweep
    contaminating the timing (and without losing the sweep's progress).
    A detached guardian subprocess resumes the jobs even if the holder
    is SIGKILLed mid-capture.
@@ -22,8 +21,9 @@ relay drift, not host contention.  This module fixes both halves:
    self-incriminating instead of silently wrong.
 
 Reentrancy: a holder exports CLEISTHENES_BENCH_LOCK=<pid> so child
-processes it spawns (bench.py --child, watcher -> bench.py) see the
-lock as already held and no-op instead of deadlocking on the flock.
+processes it spawns (bench.py --ab -> tools/abench.py samples) see
+the lock as already held and no-op instead of deadlocking on the
+flock.
 """
 
 from __future__ import annotations
@@ -176,10 +176,9 @@ def hold(name: str, block: bool = True, timeout_s: float = 7200.0):
             try:
                 fcntl.flock(fd, fcntl.LOCK_EX | fcntl.LOCK_NB)
             except BlockingIOError:
-                # the outer finally closes fd — closing here too made
-                # every busy non-blocking probe die with EBADF on
-                # exit, killing the armed relay watcher the first
-                # time a capture held the lock (round-5 regression)
+                # the outer finally closes fd — closing here too
+                # makes every busy non-blocking probe die with EBADF
+                # on exit
                 yield False
                 return
         os.ftruncate(fd, 0)

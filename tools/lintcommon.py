@@ -35,7 +35,7 @@ def gate_targets(root: pathlib.Path = REPO_ROOT) -> List[pathlib.Path]:
     out: List[pathlib.Path] = []
     for rel in ("cleisthenes_tpu", "tests", "tools"):
         out.extend(walk_python_files(root / rel))
-    for rel in ("bench.py", "__graft_entry__.py", "demo.py"):
+    for rel in ("bench.py", "chip_smoke.py", "__graft_entry__.py", "demo.py"):
         out.extend(walk_python_files(root / rel))
     return out
 

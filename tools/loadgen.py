@@ -130,10 +130,13 @@ def run_arm(
     max_drain_rounds: int = 400,
     wan_profile: Optional[str] = None,
     progress=None,
+    crypto_backend: str = "cpu",
 ) -> Dict:
     """One measured arm: drive the shared schedule through per-node
     ingress twins at pipeline depth ``depth``, drain to quiescence,
     audit the invariants, and report both latency distributions.
+    ``crypto_backend`` is Config.crypto_backend for the arm (the
+    ledger digest must not depend on it — chip_smoke.py's check).
 
     Raises AssertionError on any invariant breach — a loadgen number
     from a run that lost a tx is not a number."""
@@ -145,7 +148,7 @@ def run_arm(
         n=n,
         batch_size=batch,
         seed=seed,
-        crypto_backend="cpu",
+        crypto_backend=crypto_backend,
         # lanes > 1 shards the schedule across S consensus lanes: the
         # mempool's admit() routes each tx by seeded digest hash, so
         # loadgen exercises the production partitioner, not its own

@@ -1,11 +1,12 @@
 """cProfile of one lockstep N=128 epoch — where does bba_s go?
 
-The chip A/B (AB_COIN_BLOCKS_r05) put the N=128 epoch at ~3.1-3.5 s
-with bba_s ~2.4-3.2 s; the north star wants the whole epoch under
+bba_s was ~3/4 of the N=128 lockstep epoch at the last look (PERF.md,
+"History, unverified"); the north star wants the whole epoch under
 1 s.  This attributes the gap: device wait (XLA dispatch/transfer
 frames) vs host-side marshalling (item assembly, limb packing, CP
 hashing, nonce draws) — so the next optimization targets the real
-cost, not the assumed one.
+cost, not the assumed one.  One process, no children: with backend
+'tpu' on a chip machine this process owns the chip.
 
 Usage:  python tools/profile_spmd.py [n] [batch] [backend]
 """
