@@ -1,0 +1,437 @@
+"""The two ways the harness drives the system under test.
+
+``served``    SimulatedCluster through ``cluster.ingress(node)`` (the
+              in-process twin of the client gRPC surface, i.e.
+              ``IngressPlane.submit_frame``), driven round by round as
+              tools/loadgen.py::run_arm's ``one_round`` drives it: every
+              node's ``start_epoch()``, then ``net.step()`` /
+              ``net.idle_phase()`` to quiescence.  Loops: ``open`` (due
+              times on the wall clock) and ``backlog`` (held backlog).
+``lockstep``  LockstepCluster: submit one epoch's transactions,
+              ``run_epoch()``, repeat.  Loop: ``epoch``.
+
+From the program these take only the system under test and its
+counters.  Clocks, stamps, spans and what is handed to the reference
+are the harness's own.  A configuration's file picks the executor by
+name; a traffic file picks the loop.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import time
+from typing import Callable, Dict, List, Optional, Sequence
+
+from benchmarks.traffic import KIND_WARM, Arrival, TxSource
+
+DRAIN_LIMIT_S = 60.0  # an answer may come a minute late, not never
+# warm-up rounds of the served path, as shares of a full batch: full
+# ones, then a ramp down, since the device's RS decode compiles one
+# program per shard length and only nearly full batches reach it
+WARMUP_FILLS = (1.0, 1.0, 0.97, 0.94, 0.91)
+LOCKSTEP_WARMUP_MAX = 12
+LOCKSTEP_WARMUP_CLEAN = 2
+COMB_FILLER = (8, 512)  # groups, exponents a group
+
+
+def _no_tick(_now: float) -> None:
+    return None
+
+
+class Spans:
+    """Names the host's phases on the profiler's clock while a trace is
+    on (jax.profiler.TraceAnnotation), and is free while it is off."""
+
+    def __init__(self) -> None:
+        self.on = False
+
+    def __call__(self, name: str):
+        if not self.on:
+            return contextlib.nullcontext()
+        import jax.profiler
+
+        return jax.profiler.TraceAnnotation(name)
+
+
+def _config(cell_config: Dict, seed: Optional[int]):
+    from cleisthenes_tpu.config import Config
+
+    fields = dict(cell_config["config"])
+    if fields.get("mesh_shape") is not None:
+        fields["mesh_shape"] = tuple(fields["mesh_shape"])
+    if seed is not None:
+        fields["seed"] = seed
+    return Config(**fields)
+
+
+class Served:
+    """SimulatedCluster behind its ingress planes."""
+
+    kind = "served"
+
+    def __init__(self, cell, seed: int, spans: Spans, meter) -> None:
+        from cleisthenes_tpu.protocol.cluster import SimulatedCluster
+        from cleisthenes_tpu.transport.message import IngressStatus
+
+        self.cell = cell
+        self.seed = seed
+        self.spans = spans
+        self.meter = meter
+        self.cfg = _config(cell.config, seed)
+        extra = dict(cell.config.get("cluster", {}))
+        self.cluster = SimulatedCluster(
+            config=self.cfg, seed=seed, key_seed=seed, auto_propose=False,
+            **extra,
+        )
+        self.ids: List[str] = list(self.cluster.ids)
+        self._nodes = [self.cluster.nodes[nid] for nid in self.ids]
+        self._ingress = [self.cluster.ingress(nid) for nid in self.ids]
+        self._ok = int(IngressStatus.OK)
+        self.t_ordered: List[float] = []
+        self.t_settled: List[float] = []
+        # (tx, node_id, ok) of every submission, warm-up and drain too
+        self.submissions: List[tuple] = []
+        # of the window's submissions: when due, how late, how long
+        self.due: List[float] = []
+        self.late: List[float] = []
+        self.submit_s: List[float] = []
+        self.timed: List[bytes] = []
+        self.timed_ok: List[bool] = []
+        self.rounds = 0
+
+    # -- driving -------------------------------------------------------
+
+    def _frontiers(self) -> tuple:
+        ordered = min(hb.merged_ordered_frontier for hb in self._nodes)
+        settled = min(hb.merged_settled_frontier for hb in self._nodes)
+        return ordered, settled
+
+    def _stamp(self) -> None:
+        """An epoch is ordered, or settled, once EVERY validator's
+        frontier has crossed it."""
+        ordered, settled = self._frontiers()
+        now = time.perf_counter()
+        while len(self.t_ordered) < ordered:
+            self.t_ordered.append(now)
+        while len(self.t_settled) < settled:
+            self.t_settled.append(now)
+
+    def _submit(self, a: Arrival, due: Optional[float]) -> None:
+        node = a.nonce % len(self.ids)
+        t1 = time.perf_counter()
+        ack = self._ingress[node].submit(a.client, a.nonce, a.fee, a.tx)
+        t2 = time.perf_counter()
+        ok = int(ack.status) == self._ok
+        self.submissions.append((a.tx, self.ids[node], ok))
+        if due is not None:
+            self.due.append(due)
+            self.late.append(t1 - due)
+            self.submit_s.append(t2 - t1)
+            self.timed.append(a.tx)
+            self.timed_ok.append(ok)
+
+    def _round(self, between: Callable[[], None]) -> None:
+        spans = self.spans
+        net = self.cluster.net
+        with spans("start_epoch"):
+            for hb in self._nodes:
+                hb.start_epoch()
+        while True:
+            with spans("step"):
+                stepped = net.step()
+            if not stepped:
+                # the manual-driving contract (ChannelNetwork.step): a
+                # drained queue needs the idle phase, and another pass
+                # if that produced traffic
+                with spans("idle_phase"):
+                    net.idle_phase()
+            self._stamp()
+            between()
+            if not stepped and net.pending_count() == 0:
+                break
+        self.rounds += 1
+
+    def _quiet(self) -> bool:
+        ordered, settled = self._frontiers()
+        return self.cluster.pending() == 0 and ordered == settled
+
+    def _drain(self) -> None:
+        limit = time.perf_counter() + DRAIN_LIMIT_S
+        while not self._quiet() and time.perf_counter() < limit:
+            self._round(lambda: None)
+
+    def warm_up(self) -> None:
+        source = TxSource(
+            self.cell.traffic, self.cell.config["tx_bytes"], self.seed,
+            kind=KIND_WARM,
+        )
+        for fill in WARMUP_FILLS:
+            for a in source.take(int(fill * self.cfg.batch_size)):
+                self._submit(a, None)
+            self._round(lambda: None)
+        self._drain()
+
+    # -- loops ---------------------------------------------------------
+
+    def run_open(
+        self,
+        due: Sequence[float],
+        arrivals: Sequence[Arrival],
+        seconds: float,
+        tick: Callable[[float], None] = _no_tick,
+    ) -> Dict:
+        """Open loop: submit whatever is due between delivery waves,
+        never waiting for the service.  Returns the window's ends."""
+        count = len(arrivals)
+        nxt = 0
+        spans = self.spans
+        t0 = time.perf_counter()
+
+        def pump() -> None:
+            nonlocal nxt
+            now = time.perf_counter() - t0
+            if nxt < count and due[nxt] <= now:
+                with spans("submit"):
+                    while nxt < count and due[nxt] <= now:
+                        self._submit(arrivals[nxt], t0 + due[nxt])
+                        nxt += 1
+
+        while True:
+            pump()
+            now = time.perf_counter() - t0
+            if nxt >= count and now >= seconds:
+                break
+            tick(now)
+            if self._quiet():
+                # nothing to order: wait for the next arrival
+                wake = due[nxt] if nxt < count else seconds
+                with spans("wait_arrival"):
+                    time.sleep(min(0.001, max(0.0, wake - now)))
+                continue
+            self._round(pump)
+        t_end = time.perf_counter()
+        with spans("drain"):
+            self._drain()
+        return {"t0": t0, "t_end": t_end}
+
+    def run_backlog(
+        self,
+        seconds: float,
+        tick: Callable[[float], None] = _no_tick,
+    ) -> Dict:
+        """Backlog held at ``backlog_batches`` full batches, topped up
+        after every round.  The window closes with the round in which
+        the first settle at or after ``seconds`` falls: rounds run to
+        quiescence, so no epoch is in flight at either end."""
+        source = TxSource(
+            self.cell.traffic, self.cell.config["tx_bytes"], self.seed
+        )
+        target = int(self.cell.traffic["backlog_batches"]) * self.cfg.batch_size
+        spans = self.spans
+        t0 = time.perf_counter()
+        first = len(self.t_settled)
+        while True:
+            need = target - self.cluster.pending()
+            if need > 0:
+                with spans("submit"):
+                    for a in source.take(need):
+                        self._submit(a, t0)
+            tick(time.perf_counter() - t0)
+            self._round(lambda: None)
+            if self.t_settled[first:] and self.t_settled[-1] - t0 >= seconds:
+                break
+        t_end = time.perf_counter()
+        with spans("drain"):
+            self._drain()
+        return {"t0": t0, "t_end": t_end, "first_epoch": first}
+
+    # -- what the harness reads ------------------------------------------
+
+    def counters(self) -> Dict:
+        from cleisthenes_tpu.ops import placement
+
+        hb0 = self._nodes[0]
+        return {
+            "hub": dict(hb0.hub.stats()),
+            "delivery": dict(self.cluster.net.delivery_stats()),
+            "placement": placement.snapshot(),
+            "ingress": dict(hb0.metrics.snapshot()["ingress"]),
+            "compiles": self.meter.count,
+            "epochs": len(self.t_settled),
+            "rounds": self.rounds,
+        }
+
+    def observe(self) -> Dict:
+        """Plain data for the reference: nothing of the program's
+        objects but the transactions' bytes and the ledgers' shape."""
+        return {
+            "node_ids": list(self.ids),
+            "submissions": self.submissions,
+            "ledgers": {
+                nid: [b.contributions for b in hb.merged_batches]
+                for nid, hb in zip(self.ids, self._nodes)
+            },
+            "evicted": sum(hb.mempool.evicted for hb in self._nodes),
+            "ordered": {
+                nid: hb.merged_ordered_frontier
+                for nid, hb in zip(self.ids, self._nodes)
+            },
+            "settled": {
+                nid: hb.merged_settled_frontier
+                for nid, hb in zip(self.ids, self._nodes)
+            },
+            "batch_size": max(self.cfg.batch_size, self.cfg.n),
+        }
+
+    def close(self) -> None:
+        self.cluster.stop()
+        self.cluster = None
+        self._nodes = []
+        self._ingress = []
+
+
+class Lockstep:
+    """LockstepCluster, one epoch at a time."""
+
+    kind = "lockstep"
+
+    def __init__(self, cell, seed: int, spans: Spans, meter) -> None:
+        from cleisthenes_tpu.protocol.spmd import LockstepCluster
+
+        self.cell = cell
+        self.seed = seed
+        self.spans = spans
+        self.meter = meter
+        self.cfg = _config(cell.config, None)
+        self.cluster = LockstepCluster(
+            config=self.cfg, key_seed=seed,
+            **dict(cell.config.get("cluster", {})),
+        )
+        self.ids: List[str] = list(self.cluster.ids)
+        n = self.cfg.n
+        self.per_epoch = (max(self.cfg.batch_size, n) // n) * n
+        self._source = TxSource(
+            cell.traffic, cell.config["tx_bytes"], seed
+        )
+        self.epochs: List[Dict] = []  # one row per epoch, warm-up too
+
+    def _epoch(self) -> Dict:
+        n = len(self.ids)
+        submitted: Dict[str, List[bytes]] = {nid: [] for nid in self.ids}
+        with self.spans("submit"):
+            for j, a in enumerate(self._source.take(self.per_epoch)):
+                nid = self.ids[j % n]
+                self.cluster.submit(a.tx, nid)
+                submitted[nid].append(a.tx)
+        before = len(self.cluster.committed_batches)
+        with self.spans("run_epoch"):
+            stats = dict(self.cluster.run_epoch())
+        row = {
+            "epoch": before,
+            "submitted": submitted,
+            "stats": stats,
+            "t_end": time.perf_counter(),
+        }
+        self.epochs.append(row)
+        return row
+
+    def _warm_shapes(self, shapes: Dict) -> None:
+        """Run each exponentiation program the cell can meet once, at
+        the sizes the configuration's file lists (``warm_shapes``: the
+        coin waves' sizes move with the round count, each size bucket
+        is a program, and the rare ones would otherwise be met first
+        inside a window).  Through the engine's own entry points."""
+        from cleisthenes_tpu.ops import modmath
+
+        crypto = self.cluster.crypto
+        group = self.cluster.tpke.group
+        eng = modmath.get_engine(crypto.engine_backend, crypto.mesh, group)
+        # a grouped call is split by group size, so a small shape rides
+        # with a filler of COMB_FILLER that lifts the call over the
+        # comb's host floor
+        filler = [(group.g, [3] * COMB_FILLER[1])] * COMB_FILLER[0]
+        for groups, exps in shapes.get("comb", ()):
+            call = [(group.g, [3] * exps)] * groups
+            if exps != COMB_FILLER[1]:
+                call = call + filler
+            eng.pow_batch_grouped(call)
+        for rows in shapes.get("dual_pow", ()):
+            eng.dual_pow_batch(
+                [group.g] * rows, [3] * rows, [group.g] * rows, [5] * rows
+            )
+
+    def warm_up(self) -> None:
+        """The listed shapes, then epochs until LOCKSTEP_WARMUP_CLEAN
+        in a row compile nothing."""
+        self._warm_shapes(self.cell.config.get("warm_shapes", {}))
+        clean = 0
+        for _ in range(LOCKSTEP_WARMUP_MAX):
+            before = self.meter.count
+            self._epoch()
+            clean = clean + 1 if self.meter.count == before else 0
+            if clean >= LOCKSTEP_WARMUP_CLEAN:
+                break
+
+    def run_epochs(
+        self,
+        seconds: float,
+        tick: Callable[[float], None] = _no_tick,
+    ) -> Dict:
+        """Closed loop; the window closes at the first epoch boundary
+        at or after ``seconds``."""
+        first = len(self.epochs)
+        t0 = time.perf_counter()
+        while True:
+            tick(time.perf_counter() - t0)
+            row = self._epoch()
+            if row["t_end"] - t0 >= seconds:
+                break
+        return {"t0": t0, "t_end": self.epochs[-1]["t_end"],
+                "first_epoch": first}
+
+    def counters(self) -> Dict:
+        from cleisthenes_tpu.ops import placement
+
+        return {
+            "placement": placement.snapshot(),
+            "compiles": self.meter.count,
+            "epochs": len(self.epochs),
+        }
+
+    def observe(self, first_epoch: int = 0) -> Dict:
+        committed = self.cluster.committed_batches
+        rows = []
+        for row in self.epochs[first_epoch:]:
+            i = row["epoch"]
+            rows.append({
+                "epoch": row["epoch"],
+                "submitted": row["submitted"],
+                "committed": (
+                    committed[i].contributions if i < len(committed) else None
+                ),
+                "bba_rounds": int(row["stats"].get("bba_rounds", -1)),
+            })
+        keys = self.cluster.keys
+        pub = keys[self.ids[0]].coin_pub
+        group = pub.group
+        return {
+            "node_ids": list(self.ids),
+            "epochs": rows,
+            "coin": {
+                "group": {"p": group.p, "q": group.q, "g": group.g},
+                "threshold": pub.threshold,
+                "shares": [
+                    (keys[nid].coin_share.index, keys[nid].coin_share.value)
+                    for nid in self.ids[: pub.threshold]
+                ],
+                "master_pub": pub.master,
+            },
+        }
+
+    def close(self) -> None:
+        self.cluster = None
+
+
+EXECUTORS = {"served": Served, "lockstep": Lockstep}
+
+__all__ = ["Served", "Lockstep", "Spans", "EXECUTORS"]

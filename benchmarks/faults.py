@@ -1,0 +1,129 @@
+"""The timed path, broken underneath: what ``correct`` has to catch.
+
+Used by benchmarks/tests and benchmarks/control.py only; the
+benchmark's own runs never import this file.  Each fault takes the
+warmed-up executor (benchmarks/executors.py) and breaks one guarantee
+the configuration's file states, in the program's own objects, so that
+the window drives the broken path.  They are the contract's faults as
+far as these cells can have them (one process, one chip: there is no
+exchange between chips to leave out):
+
+  state_unchanged   a step returns its state unchanged
+  half_batch        half of the batch is left out
+  answer_altered    an answer is altered where it is produced
+  coin_flipped      (lockstep) the common coin's bit is inverted: the
+                    control for the BBA + coin layer, whose outcome
+                    the committed batch alone does not show
+"""
+
+from __future__ import annotations
+
+import copy
+from typing import Callable, Dict
+
+
+def _flip(tx: bytes) -> bytes:
+    return tx[:-1] + bytes([tx[-1] ^ 1])
+
+
+# -- served -------------------------------------------------------------------
+
+
+class _Ledger(list):
+    """A validator's committed_batches with its commit step broken."""
+
+    def __init__(self, items, mode: str) -> None:
+        super().__init__(items)
+        self._mode = mode
+        self._seen = 0
+
+    def append(self, batch) -> None:
+        self._seen += 1
+        if self._mode == "state_unchanged" and self._seen % 2 == 0:
+            return  # the commit returns its state unchanged
+        if self._mode == "answer_altered":
+            # this validator must not share the batch with the others
+            batch = copy.deepcopy(batch)
+            for txs in batch.contributions.values():
+                if txs:
+                    txs[0] = _flip(txs[0])
+                    break
+        super().append(batch)
+
+
+def _served_ledger(mode: str) -> Callable:
+    def fault(executor) -> None:
+        hb = executor._nodes[1]
+        hb.committed_batches = _Ledger(hb.committed_batches, mode)
+
+    return fault
+
+
+def _served_half_batch(executor) -> None:
+    for hb in executor._nodes:
+        orig = hb._create_batch
+
+        def half(orig=orig):
+            txs = orig()
+            return txs[: len(txs) // 2]
+
+        hb._create_batch = half
+
+
+# -- lockstep -----------------------------------------------------------------
+
+
+def _lockstep_state_unchanged(executor) -> None:
+    cluster = executor.cluster
+    stats = dict(cluster.last_stats)
+    cluster.run_epoch = lambda: dict(stats)
+
+
+def _lockstep_half_batch(executor) -> None:
+    cluster = executor.cluster
+    keep = set(cluster.ids[::2])
+    orig = cluster.submit
+
+    def submit(tx, node_id=None):
+        if node_id in keep:
+            orig(tx, node_id)
+
+    cluster.submit = submit
+
+
+def _lockstep_answer_altered(executor) -> None:
+    tpke = executor.cluster.tpke
+    orig = tpke.combine
+    state = {"calls": 0}
+
+    def combine(ct, shares):
+        plain = orig(ct, shares)
+        state["calls"] += 1
+        if state["calls"] % 97 == 1:
+            plain = _flip(plain)
+        return plain
+
+    tpke.combine = combine
+
+
+def _lockstep_coin_flipped(executor) -> None:
+    coin = executor.cluster.coin
+    orig = coin.toss
+    coin.toss = lambda coin_id, shares: not orig(coin_id, shares)
+
+
+FAULTS: Dict[str, Dict[str, Callable]] = {
+    "served": {
+        "state_unchanged": _served_ledger("state_unchanged"),
+        "half_batch": _served_half_batch,
+        "answer_altered": _served_ledger("answer_altered"),
+    },
+    "lockstep": {
+        "state_unchanged": _lockstep_state_unchanged,
+        "half_batch": _lockstep_half_batch,
+        "answer_altered": _lockstep_answer_altered,
+        "coin_flipped": _lockstep_coin_flipped,
+    },
+}
+
+__all__ = ["FAULTS"]

@@ -1,0 +1,75 @@
+"""Compilation and dispatch meters, copied from chip_smoke.py
+(CompileMeter, dispatch_round_trip_us) so that the yardstick lives
+where a later PR cannot change it."""
+
+from __future__ import annotations
+
+import functools
+import statistics
+import time
+
+import numpy as np
+
+# jax.monitoring's name for one jit-cache miss going through
+# compile_or_get_cached (jax._src.dispatch.BACKEND_COMPILE_EVENT, 0.9.0)
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+
+class CompileMeter:
+    """Counts XLA compilations and the seconds they took.  With a warm
+    persistent cache a compilation is a cache read: same count, far
+    fewer seconds, and ``cache_hits`` says how many were reads."""
+
+    def __init__(self) -> None:
+        import jax.monitoring
+
+        self.count = 0
+        self.seconds = 0.0
+        self.cache_hits = 0
+        jax.monitoring.register_event_duration_secs_listener(self._on_secs)
+        jax.monitoring.register_event_listener(self._on_event)
+
+    def _on_secs(self, event: str, duration: float, **_kw) -> None:
+        if event == _COMPILE_EVENT:
+            self.count += 1
+            self.seconds += duration
+
+    def _on_event(self, event: str, **_kw) -> None:
+        if event == _CACHE_HIT_EVENT:
+            self.cache_hits += 1
+
+
+@functools.cache
+def _bump():
+    import jax
+
+    def benchmark_probe_bump(a):
+        return a + 1
+
+    return jax.jit(benchmark_probe_bump)
+
+
+def probe_round_trip() -> float:
+    """One tiny forced round trip, in seconds: a 4 KiB numpy array to
+    the device, one jitted add, the result back as numpy.  It is the
+    harness's own proof that the device answers; the served N=16 cells
+    send nothing else there (PERF.md section 3)."""
+    import jax.numpy as jnp
+
+    x = np.zeros((8, 128), dtype=np.int32)
+    t0 = time.perf_counter()
+    out = np.asarray(_bump()(jnp.asarray(x)))
+    wall = time.perf_counter() - t0
+    if int(out[0, 0]) != 1:
+        raise RuntimeError("the device returned a wrong probe result")
+    return wall
+
+
+def dispatch_round_trip_us(reps: int = 20) -> float:
+    """Median of ``reps`` round trips in us, compiled beforehand."""
+    probe_round_trip()
+    return statistics.median(probe_round_trip() for _ in range(reps)) * 1e6
+
+
+__all__ = ["CompileMeter", "probe_round_trip", "dispatch_round_trip_us"]
