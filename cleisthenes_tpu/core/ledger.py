@@ -57,6 +57,7 @@ from typing import Dict, Iterator, List, Optional, Sequence, Set, Tuple
 from cleisthenes_tpu.core.batch import Batch
 from cleisthenes_tpu.utils.determinism import guarded_by
 from cleisthenes_tpu.utils.lockcheck import new_lock
+from cleisthenes_tpu.utils.trace import span
 
 _MAGIC = b"CLOG"
 _MAGIC_CKPT = b"CCKP"
@@ -433,15 +434,12 @@ class BatchLog:
 
     def append(self, epoch: int, batch: Batch) -> None:
         rec = _encode_record(epoch, batch)
-        tr = self.trace
-        t0 = 0.0 if tr is None else tr.now()
-        with self._lock:
+        with span(
+            "ledger", "wal_append", recorder=self.trace,
+            epoch=epoch, bytes=len(rec),
+        ), self._lock:
             self._append_record_locked(rec)
             self._last_epoch = epoch
-        if tr is not None:
-            tr.complete(
-                "ledger", "wal_append", t0, epoch=epoch, bytes=len(rec)
-            )
 
     def append_ordered(self, epoch: int, output: Dict[str, bytes]) -> bytes:
         """Durably record ``epoch``'s ciphertext-ordered commit (the
@@ -460,15 +458,12 @@ class BatchLog:
         store, and the fuzzer's byte-identity witness can never
         diverge."""
         rec = _frame_record(_MAGIC_ORD, body)
-        tr = self.trace
-        t0 = 0.0 if tr is None else tr.now()
-        with self._lock:
+        with span(
+            "ledger", "wal_ordered", recorder=self.trace,
+            epoch=epoch, bytes=len(rec),
+        ), self._lock:
             self._append_record_locked(rec)
             self._last_ordered_epoch = epoch
-        if tr is not None:
-            tr.complete(
-                "ledger", "wal_ordered", t0, epoch=epoch, bytes=len(rec)
-            )
 
     def append_checkpoint(
         self, epoch: int, history: Sequence[Set[bytes]]
@@ -479,15 +474,12 @@ class BatchLog:
         rec = _frame_record(
             _MAGIC_CKPT, _encode_checkpoint_body(epoch, history)
         )
-        tr = self.trace
-        t0 = 0.0 if tr is None else tr.now()
-        with self._lock:
+        with span(
+            "ledger", "wal_checkpoint", recorder=self.trace,
+            epoch=epoch, bytes=len(rec),
+        ), self._lock:
             self._append_record_locked(rec)
             self._last_checkpoint = (epoch, [set(s) for s in history])
-        if tr is not None:
-            tr.complete(
-                "ledger", "wal_checkpoint", t0, epoch=epoch, bytes=len(rec)
-            )
 
     def append_reconfig(
         self,
@@ -505,18 +497,11 @@ class BatchLog:
                 version, activation_epoch, members, key_digest
             ),
         )
-        tr = self.trace
-        t0 = 0.0 if tr is None else tr.now()
-        with self._lock:
+        with span(
+            "ledger", "wal_reconfig", recorder=self.trace,
+            version=version, activation_epoch=activation_epoch,
+        ), self._lock:
             self._append_record_locked(rec)
-        if tr is not None:
-            tr.complete(
-                "ledger",
-                "wal_reconfig",
-                t0,
-                version=version,
-                activation_epoch=activation_epoch,
-            )
 
     def replay_reconfigs(
         self,
@@ -625,16 +610,12 @@ class _LaneLog:
         rec = _frame_record(
             _MAGIC_LANE, _lane_body(self.lane, _encode_body(epoch, batch))
         )
-        tr = log.trace
-        t0 = 0.0 if tr is None else tr.now()
-        with log._lock:
+        with span(
+            "ledger", "wal_append", recorder=log.trace,
+            epoch=epoch, bytes=len(rec), lane=self.lane,
+        ), log._lock:
             log._append_record_locked(rec)
             log._lane_last_epoch[self.lane] = epoch
-        if tr is not None:
-            tr.complete(
-                "ledger", "wal_append", t0, epoch=epoch, bytes=len(rec),
-                lane=self.lane,
-            )
 
     def append_ordered(self, epoch: int, output: Dict[str, bytes]) -> bytes:
         body = encode_ordered_body(epoch, output)
@@ -644,16 +625,12 @@ class _LaneLog:
     def append_ordered_body(self, epoch: int, body: bytes) -> None:
         log = self._log
         rec = _frame_record(_MAGIC_LANE_ORD, _lane_body(self.lane, body))
-        tr = log.trace
-        t0 = 0.0 if tr is None else tr.now()
-        with log._lock:
+        with span(
+            "ledger", "wal_ordered", recorder=log.trace,
+            epoch=epoch, bytes=len(rec), lane=self.lane,
+        ), log._lock:
             log._append_record_locked(rec)
             log._lane_last_ordered[self.lane] = epoch
-        if tr is not None:
-            tr.complete(
-                "ledger", "wal_ordered", t0, epoch=epoch, bytes=len(rec),
-                lane=self.lane,
-            )
 
     def append_checkpoint(
         self, epoch: int, history: Sequence[Set[bytes]]
@@ -663,18 +640,14 @@ class _LaneLog:
             _MAGIC_LANE_CKPT,
             _lane_body(self.lane, _encode_checkpoint_body(epoch, history)),
         )
-        tr = log.trace
-        t0 = 0.0 if tr is None else tr.now()
-        with log._lock:
+        with span(
+            "ledger", "wal_checkpoint", recorder=log.trace,
+            epoch=epoch, bytes=len(rec), lane=self.lane,
+        ), log._lock:
             log._append_record_locked(rec)
             log._lane_last_checkpoint[self.lane] = (
                 epoch,
                 [set(s) for s in history],
-            )
-        if tr is not None:
-            tr.complete(
-                "ledger", "wal_checkpoint", t0, epoch=epoch,
-                bytes=len(rec), lane=self.lane,
             )
 
     def append_reconfig(self, *args, **kwargs) -> None:

@@ -24,6 +24,7 @@ from typing import List, Sequence
 import numpy as np
 
 from cleisthenes_tpu.ops import placement
+from cleisthenes_tpu.utils import trace
 
 _LEAF_PREFIX = b"\x00"
 _NODE_PREFIX = b"\x01"
@@ -232,42 +233,57 @@ class XlaMerkle(MerkleBackend):
         if b < self.HOST_FLOOR_VERIFY:
             # also covers the base-class single-tree build(): a
             # 16-leaf tree would be ~5 per-level device dispatches
-            placement.note("sha256.hash_batch", False, b)
-            return self._host._hash_batch(msgs)
-        placement.note("sha256.hash_batch", True, b)
-        bucket = self._bucket(b)
-        if bucket != b:
-            msgs = np.concatenate(
-                [msgs, np.zeros((bucket - b, msgs.shape[1]), dtype=np.uint8)]
-            )
-        return np.asarray(sha256_batch(self._put(msgs)))[:b]
+            with placement.batch("sha256.hash_batch", False, b), trace.span(
+                "ops", "host"
+            ):
+                return self._host._hash_batch(msgs)
+        with placement.batch("sha256.hash_batch", True, b):
+            with trace.span("ops", "pack"):
+                bucket = self._bucket(b)
+                if bucket != b:
+                    msgs = np.concatenate([
+                        msgs,
+                        np.zeros(
+                            (bucket - b, msgs.shape[1]), dtype=np.uint8
+                        ),
+                    ])
+            with trace.span("ops", "device", program="sha256_batch"):
+                return np.asarray(sha256_batch(self._put(msgs)))[:b]
 
     def build_batch(self, shards: np.ndarray) -> List[MerkleTree]:
         from cleisthenes_tpu.ops.sha256_xla import build_forest
 
         b, n, _ = shards.shape
         if b * n < self.HOST_FLOOR_BUILD_LEAVES:
-            placement.note("merkle.build_forest", False, b * n)
-            return self._host.build_batch(shards)
-        placement.note("merkle.build_forest", True, b * n)
-        bucket = self._bucket(b)
-        if bucket != b:
-            shards = np.concatenate(
-                [shards, np.zeros((bucket - b,) + shards.shape[1:], np.uint8)]
-            )
-        # (bucket, 2p-1, 32): the whole forest in one transfer
-        forest = np.asarray(build_forest(self._put(shards)))
-        p = _next_pow2(n)
-        levels = []
-        off, width = 0, p
-        while width >= 1:
-            levels.append(forest[:, off : off + width])
-            off += width
-            width //= 2
-        return [
-            MerkleTree([lvl[i] for lvl in levels], n_leaves=n)
-            for i in range(b)
-        ]
+            with placement.batch(
+                "merkle.build_forest", False, b * n
+            ), trace.span("ops", "host"):
+                return self._host.build_batch(shards)
+        with placement.batch("merkle.build_forest", True, b * n):
+            with trace.span("ops", "pack"):
+                bucket = self._bucket(b)
+                if bucket != b:
+                    shards = np.concatenate([
+                        shards,
+                        np.zeros(
+                            (bucket - b,) + shards.shape[1:], np.uint8
+                        ),
+                    ])
+            with trace.span("ops", "device", program="build_forest"):
+                # (bucket, 2p-1, 32): the whole forest in one transfer
+                forest = np.asarray(build_forest(self._put(shards)))
+            with trace.span("ops", "unpack"):
+                p = _next_pow2(n)
+                levels = []
+                off, width = 0, p
+                while width >= 1:
+                    levels.append(forest[:, off : off + width])
+                    off += width
+                    width //= 2
+                return [
+                    MerkleTree([lvl[i] for lvl in levels], n_leaves=n)
+                    for i in range(b)
+                ]
 
     def verify_batch(
         self,
@@ -280,9 +296,12 @@ class XlaMerkle(MerkleBackend):
 
         b = leaves.shape[0]
         if b < self.HOST_FLOOR_VERIFY:
-            placement.note("merkle.verify_branches", False, b)
-            return self._host.verify_batch(roots, leaves, branches, indices)
-        placement.note("merkle.verify_branches", True, b)
+            with placement.batch(
+                "merkle.verify_branches", False, b
+            ), trace.span("ops", "host"):
+                return self._host.verify_batch(
+                    roots, leaves, branches, indices
+                )
         bucket = self._bucket(b)
 
         def pad(a):
@@ -291,13 +310,17 @@ class XlaMerkle(MerkleBackend):
             reps = np.repeat(a[:1], bucket - b, axis=0)
             return np.concatenate([a, reps])
 
-        ok = verify_branches(
-            self._put(pad(np.ascontiguousarray(roots, dtype=np.uint8))),
-            self._put(pad(np.ascontiguousarray(leaves, dtype=np.uint8))),
-            self._put(pad(np.ascontiguousarray(branches, dtype=np.uint8))),
-            self._put(pad(np.asarray(indices, dtype=np.uint32))),
-        )
-        return np.asarray(ok)[:b]
+        with placement.batch("merkle.verify_branches", True, b):
+            with trace.span("ops", "pack"):
+                columns = (
+                    pad(np.ascontiguousarray(roots, dtype=np.uint8)),
+                    pad(np.ascontiguousarray(leaves, dtype=np.uint8)),
+                    pad(np.ascontiguousarray(branches, dtype=np.uint8)),
+                    pad(np.asarray(indices, dtype=np.uint32)),
+                )
+            with trace.span("ops", "device", program="verify_branches"):
+                ok = verify_branches(*(self._put(c) for c in columns))
+                return np.asarray(ok)[:b]
 
 
 def make_merkle(backend: str, mesh=None) -> MerkleBackend:

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from cleisthenes_tpu.ops import placement
+from cleisthenes_tpu.utils import trace
 
 # 256-bit safe prime p = 2q + 1 (generated deterministically, seed
 # 20260729, 64-round Miller-Rabin) and the prime order q of the QR
@@ -970,61 +971,76 @@ class ModEngine:
         # ~2 generic-exp equivalents per extra row, noise at G >= 64.
         G_ROW = 512
         split: List[tuple] = []  # (orig_idx, base, exps_slice)
-        for gi, (base, exps) in enumerate(groups):
-            if len(exps) > G_ROW:
-                for off in range(0, len(exps), G_ROW):
-                    split.append((gi, base, exps[off : off + G_ROW]))
-            else:
-                split.append((gi, base, exps))
         # one comb dispatch per G-bucket: groups of similar size pad
         # to a shared (n_bases_bucket, G_bucket) compile shape
         chunks: Dict[int, List[List[int]]] = {
             gi: [] for gi in range(len(groups))
         }
         by_bucket: Dict[int, List[int]] = {}
-        for si, (_gi, _base, exps) in enumerate(split):
-            gb = self._bucket(max(1, len(exps)))
-            by_bucket.setdefault(gb, []).append(si)
+        with trace.span("ops", "pack"):
+            for gi, (base, exps) in enumerate(groups):
+                if len(exps) > G_ROW:
+                    for off in range(0, len(exps), G_ROW):
+                        split.append((gi, base, exps[off : off + G_ROW]))
+                else:
+                    split.append((gi, base, exps))
+            for si, (_gi, _base, exps) in enumerate(split):
+                gb = self._bucket(max(1, len(exps)))
+                by_bucket.setdefault(gb, []).append(si)
         gp = self.group
         for g_bucket, sidxs in by_bucket.items():
-            nb = self._bucket(len(sidxs))
-            bases_arr = np.ones((nb, 33), dtype=np.uint8)
-            bases_arr[:, 1:] = 0  # padding bases = 1
-            exps_arr = np.zeros((nb, g_bucket, 32), dtype=np.uint8)
-            placement.note(
+            with placement.batch(
                 self._family + ".comb",
                 True,
                 sum(len(split[si][2]) for si in sidxs),
-            )
-            for row, si in enumerate(sidxs):
-                _gi, base, exps = split[si]
-                bases_arr[row] = ints_to_bytes33([base % gp.p])[0]
-                # same exponent domain as pow_batch: values must fit
-                # 256 bits (to_bytes raises loudly otherwise); no mod-q
-                # reduction here — that is only sound for subgroup
-                # elements and is the CALLER's knowledge, not ours
-                exps_arr[row, : len(exps)] = exps_to_bytes(list(exps))
-            out = np.asarray(
-                _pow_fused_grouped(
-                    jnp.asarray(bases_arr),
-                    jnp.asarray(exps_arr),
-                    *self._xla_spec,
-                )
-            )
-            for row, si in enumerate(sidxs):
-                gi, _base, exps = split[si]
-                n_e = len(exps)
-                chunks[gi].append(
-                    (si, bytes33_to_ints(out[row, :n_e].reshape(n_e, 33)))
-                )
+            ):
+                with trace.span("ops", "pack"):
+                    nb = self._bucket(len(sidxs))
+                    bases_arr = np.ones((nb, 33), dtype=np.uint8)
+                    bases_arr[:, 1:] = 0  # padding bases = 1
+                    exps_arr = np.zeros(
+                        (nb, g_bucket, 32), dtype=np.uint8
+                    )
+                    for row, si in enumerate(sidxs):
+                        _gi, base, exps = split[si]
+                        bases_arr[row] = ints_to_bytes33([base % gp.p])[0]
+                        # same exponent domain as pow_batch: values
+                        # must fit 256 bits (to_bytes raises loudly
+                        # otherwise); no mod-q reduction here — that
+                        # is only sound for subgroup elements and is
+                        # the CALLER's knowledge, not ours
+                        exps_arr[row, : len(exps)] = exps_to_bytes(
+                            list(exps)
+                        )
+                with trace.span(
+                    "ops", "device", program="_pow_fused_grouped"
+                ):
+                    out = np.asarray(
+                        _pow_fused_grouped(
+                            jnp.asarray(bases_arr),
+                            jnp.asarray(exps_arr),
+                            *self._xla_spec,
+                        )
+                    )
+                with trace.span("ops", "unpack"):
+                    for row, si in enumerate(sidxs):
+                        gi, _base, exps = split[si]
+                        n_e = len(exps)
+                        chunks[gi].append((
+                            si,
+                            bytes33_to_ints(
+                                out[row, :n_e].reshape(n_e, 33)
+                            ),
+                        ))
         # a group's tail slice lands in a different size bucket than
         # its full slices, and buckets run in dict order — reassemble
         # strictly by split index
-        results: List[List[int]] = [[] for _ in groups]
-        for gi in range(len(groups)):
-            for _si, part in sorted(chunks[gi]):
-                results[gi].extend(part)
-        return results
+        with trace.span("ops", "unpack"):
+            results: List[List[int]] = [[] for _ in groups]
+            for gi in range(len(groups)):
+                for _si, part in sorted(chunks[gi]):
+                    results[gi].extend(part)
+            return results
 
     def pow_batch(self, bases: Sequence[int], exps: Sequence[int]) -> List[int]:
         """[b_i^e_i mod p] for all i in one dispatch."""
@@ -1060,23 +1076,35 @@ class ModEngine:
             return [pow(b, e, gp) for b, e in zip(bases, exps)]
         host = self._host_floor(len(bases))
         if host is not None:
-            placement.note(f"{self._family}.{host_op}", False, len(bases))
-            return host.pow_batch(bases, exps)
-        placement.note(self._family + ".pow", True, len(bases))
-        if self._wide is not None:
-            lay = self._layout
-            gp = self.group.p
-            base_b, exp_b, b = self._place(
-                _ints_to_val_bytes([x % gp for x in bases], lay),
-                _exps_to_bytes_w(exps, lay),
-            )
-            out = self._wide[0](base_b, exp_b, *self._xla_spec)
-            return _val_bytes_to_ints(np.asarray(out)[:b])
-        base_b, exp_b, b = self._place(
-            ints_to_bytes33(bases), exps_to_bytes(exps)
-        )
-        out = _pow_fused(base_b, exp_b, *self._xla_spec)
-        return bytes33_to_ints(np.asarray(out)[:b])
+            with placement.batch(
+                f"{self._family}.{host_op}", False, len(bases)
+            ), trace.span("ops", "host"):
+                return host.pow_batch(bases, exps)
+        with placement.batch(self._family + ".pow", True, len(bases)):
+            if self._wide is not None:
+                lay = self._layout
+                gp = self.group.p
+                with trace.span("ops", "pack"):
+                    cols = (
+                        _ints_to_val_bytes([x % gp for x in bases], lay),
+                        _exps_to_bytes_w(exps, lay),
+                    )
+                with trace.span("ops", "device", program="_pow_wide"):
+                    base_b, exp_b, b = self._place(*cols)
+                    out = np.asarray(
+                        self._wide[0](base_b, exp_b, *self._xla_spec)
+                    )[:b]
+                with trace.span("ops", "unpack"):
+                    return _val_bytes_to_ints(out)
+            with trace.span("ops", "pack"):
+                cols = (ints_to_bytes33(bases), exps_to_bytes(exps))
+            with trace.span("ops", "device", program="_pow_fused"):
+                base_b, exp_b, b = self._place(*cols)
+                out = np.asarray(
+                    _pow_fused(base_b, exp_b, *self._xla_spec)
+                )[:b]
+            with trace.span("ops", "unpack"):
+                return bytes33_to_ints(out)
 
     def dual_pow_batch(
         self,
@@ -1114,28 +1142,46 @@ class ModEngine:
             ]
         host = self._host_floor(len(u1))
         if host is not None:
-            placement.note(self._family + ".dual_pow", False, len(u1))
-            return host.dual_pow_batch(u1, e1, u2, e2)
-        placement.note(self._family + ".dual_pow", True, len(u1))
-        if self._wide is not None:
-            lay = self._layout
-            gp = self.group.p
-            u1_b, e1_b, u2_b, e2_b, b = self._place(
-                _ints_to_val_bytes([x % gp for x in u1], lay),
-                _exps_to_bytes_w(e1, lay),
-                _ints_to_val_bytes([x % gp for x in u2], lay),
-                _exps_to_bytes_w(e2, lay),
-            )
-            out = self._wide[1](u1_b, e1_b, u2_b, e2_b, *self._xla_spec)
-            return _val_bytes_to_ints(np.asarray(out)[:b])
-        u1_b, e1_b, u2_b, e2_b, b = self._place(
-            ints_to_bytes33(u1),
-            exps_to_bytes(e1),
-            ints_to_bytes33(u2),
-            exps_to_bytes(e2),
-        )
-        out = _dual_pow_fused(u1_b, e1_b, u2_b, e2_b, *self._xla_spec)
-        return bytes33_to_ints(np.asarray(out)[:b])
+            with placement.batch(
+                self._family + ".dual_pow", False, len(u1)
+            ), trace.span("ops", "host"):
+                return host.dual_pow_batch(u1, e1, u2, e2)
+        with placement.batch(self._family + ".dual_pow", True, len(u1)):
+            if self._wide is not None:
+                lay = self._layout
+                gp = self.group.p
+                with trace.span("ops", "pack"):
+                    cols = (
+                        _ints_to_val_bytes([x % gp for x in u1], lay),
+                        _exps_to_bytes_w(e1, lay),
+                        _ints_to_val_bytes([x % gp for x in u2], lay),
+                        _exps_to_bytes_w(e2, lay),
+                    )
+                with trace.span("ops", "device", program="_dual_pow_wide"):
+                    u1_b, e1_b, u2_b, e2_b, b = self._place(*cols)
+                    out = np.asarray(
+                        self._wide[1](
+                            u1_b, e1_b, u2_b, e2_b, *self._xla_spec
+                        )
+                    )[:b]
+                with trace.span("ops", "unpack"):
+                    return _val_bytes_to_ints(out)
+            with trace.span("ops", "pack"):
+                cols = (
+                    ints_to_bytes33(u1),
+                    exps_to_bytes(e1),
+                    ints_to_bytes33(u2),
+                    exps_to_bytes(e2),
+                )
+            with trace.span("ops", "device", program="_dual_pow_fused"):
+                u1_b, e1_b, u2_b, e2_b, b = self._place(*cols)
+                out = np.asarray(
+                    _dual_pow_fused(
+                        u1_b, e1_b, u2_b, e2_b, *self._xla_spec
+                    )
+                )[:b]
+            with trace.span("ops", "unpack"):
+                return bytes33_to_ints(out)
 
 
 @functools.cache

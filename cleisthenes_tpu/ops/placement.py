@@ -8,6 +8,8 @@ without saying where they ran, so a run that "used the tpu backend"
 could have left the device idle and nobody would know.  This module
 counts the decision where it is made: calls and items per kernel
 family, ``device`` or ``host``.  ``chip_smoke.py`` reads it.
+``batch`` takes the same count and opens the batch's span
+(``utils.trace.span``): the count says which side, the span how long.
 
 Only the 'tpu' backend's entry points report here: the 'cpu'/'cpp'
 backends have no decision to make.
@@ -17,6 +19,8 @@ from __future__ import annotations
 
 import threading
 from typing import Dict
+
+from cleisthenes_tpu.utils import trace
 
 _FIELDS = ("device_calls", "device_items", "host_calls", "host_items")
 _lock = threading.Lock()
@@ -33,6 +37,14 @@ def note(family: str, on_device: bool, items: int) -> None:
         row[side + "_items"] += int(items)
 
 
+def batch(family: str, on_device: bool, items: int):
+    """``note`` plus the ``ops/<family>`` span around the batch:
+    ``with placement.batch(...):`` holds its pack / device / unpack
+    (or host) children."""
+    note(family, on_device, items)
+    return trace.span("ops", family, items=items, on_device=on_device)
+
+
 def snapshot() -> Dict[str, Dict[str, int]]:
     """{family: {device_calls, device_items, host_calls, host_items}}
     since the last ``reset()``, families in name order."""
@@ -45,4 +57,4 @@ def reset() -> None:
         _counts.clear()
 
 
-__all__ = ["note", "snapshot", "reset"]
+__all__ = ["note", "batch", "snapshot", "reset"]

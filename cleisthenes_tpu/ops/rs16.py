@@ -22,6 +22,7 @@ import numpy as np
 from cleisthenes_tpu.ops import gf65536 as gf
 from cleisthenes_tpu.ops import placement
 from cleisthenes_tpu.ops.backend import ErasureCoder
+from cleisthenes_tpu.utils import trace
 
 
 def _to_symbols(x: np.ndarray) -> np.ndarray:
@@ -92,12 +93,16 @@ class Xla16ErasureCoder(ErasureCoder):
     # -- single-instance ops (tiny: host path keeps dispatch count
     # down, same policy as the 8-bit XLA coder's host floor) ----------
     def encode(self, data: np.ndarray) -> np.ndarray:
-        placement.note("rs_gf65536.encode", False, 1)
-        return self._cpu.encode(data)
+        with placement.batch("rs_gf65536.encode", False, 1), trace.span(
+            "ops", "host"
+        ):
+            return self._cpu.encode(data)
 
     def _decode_impl(self, indices: tuple, shards: np.ndarray) -> np.ndarray:
-        placement.note("rs_gf65536.decode", False, 1)
-        return self._cpu._decode_impl(indices, shards)
+        with placement.batch("rs_gf65536.decode", False, 1), trace.span(
+            "ops", "host"
+        ):
+            return self._cpu._decode_impl(indices, shards)
 
     # -- batched ops: one lifted matmul for all instances -------------
     def encode_batch(self, data: np.ndarray) -> np.ndarray:
@@ -110,15 +115,18 @@ class Xla16ErasureCoder(ErasureCoder):
         assert k == self.k
         if self.n == self.k:
             return data.copy()
-        syms = data.view("<u2").reshape(b, k, L // 2)
-        placement.note("rs_gf65536.encode_batch", True, b)
-        out = encode_kernel_batch(
-            jnp.asarray(self._g_parity), jnp.asarray(syms)
-        )
-        full = np.asarray(out)  # (b, n, L/2) uint16
-        return np.ascontiguousarray(full.astype("<u2")).view(
-            np.uint8
-        ).reshape(b, self.n, L)
+        with placement.batch("rs_gf65536.encode_batch", True, b):
+            with trace.span("ops", "pack"):
+                syms = data.view("<u2").reshape(b, k, L // 2)
+            with trace.span("ops", "device", program="encode_kernel_batch"):
+                out = encode_kernel_batch(
+                    jnp.asarray(self._g_parity), jnp.asarray(syms)
+                )
+                full = np.asarray(out)  # (b, n, L/2) uint16
+            with trace.span("ops", "unpack"):
+                return np.ascontiguousarray(full.astype("<u2")).view(
+                    np.uint8
+                ).reshape(b, self.n, L)
 
     def decode_batch(
         self, indices: np.ndarray, shards: np.ndarray
@@ -138,15 +146,22 @@ class Xla16ErasureCoder(ErasureCoder):
             self._normalize_indices(pat)
             if pat == tuple(range(self.k)):
                 return shards.copy()
-            g = self._g_decode(pat)
-            syms = shards.view("<u2").reshape(b, k, L // 2)
-            placement.note("rs_gf65536.decode_batch", True, b)
-            out = np.asarray(
-                decode_kernel_shared(jnp.asarray(g), jnp.asarray(syms))
-            )
-            return np.ascontiguousarray(out.astype("<u2")).view(
-                np.uint8
-            ).reshape(b, k, L)
+            with placement.batch("rs_gf65536.decode_batch", True, b):
+                with trace.span("ops", "pack"):
+                    g = self._g_decode(pat)
+                    syms = shards.view("<u2").reshape(b, k, L // 2)
+                with trace.span(
+                    "ops", "device", program="decode_kernel_shared"
+                ):
+                    out = np.asarray(
+                        decode_kernel_shared(
+                            jnp.asarray(g), jnp.asarray(syms)
+                        )
+                    )
+                with trace.span("ops", "unpack"):
+                    return np.ascontiguousarray(out.astype("<u2")).view(
+                        np.uint8
+                    ).reshape(b, k, L)
         return super().decode_batch(indices, shards)
 
 

@@ -29,6 +29,7 @@ import numpy as np
 
 from cleisthenes_tpu.ops import gf256, placement
 from cleisthenes_tpu.ops.backend import ErasureCoder
+from cleisthenes_tpu.utils import trace
 
 
 def _unpack_bits(x: jnp.ndarray) -> jnp.ndarray:
@@ -137,10 +138,14 @@ class XlaErasureCoder(ErasureCoder):
         if self.n == self.k:
             return data.copy()
         if data.nbytes < self.HOST_FLOOR_BYTES:
-            placement.note("rs_gf256.encode", False, 1)
-            return self._host.encode(data)
-        placement.note("rs_gf256.encode", True, 1)
-        return np.asarray(_encode_kernel(self._g_enc, jnp.asarray(data)))
+            with placement.batch("rs_gf256.encode", False, 1), trace.span(
+                "ops", "host"
+            ):
+                return self._host.encode(data)
+        with placement.batch("rs_gf256.encode", True, 1), trace.span(
+            "ops", "device", program="_encode_kernel"
+        ):
+            return np.asarray(_encode_kernel(self._g_enc, jnp.asarray(data)))
 
     def _decode_bits_impl(self, indices: tuple) -> jnp.ndarray:
         inv = gf256.gf_mat_inv(self.matrix[list(indices)])
@@ -148,12 +153,15 @@ class XlaErasureCoder(ErasureCoder):
 
     def _decode_impl(self, indices: tuple, shards: np.ndarray) -> np.ndarray:
         if shards.nbytes < self.HOST_FLOOR_BYTES:
-            placement.note("rs_gf256.decode", False, 1)
-            return self._host._decode_impl(indices, shards)
-        placement.note("rs_gf256.decode", True, 1)
-        return np.asarray(
-            _decode_kernel(self._decode_bits(indices), jnp.asarray(shards))
-        )
+            with placement.batch("rs_gf256.decode", False, 1), trace.span(
+                "ops", "host"
+            ):
+                return self._host._decode_impl(indices, shards)
+        with placement.batch("rs_gf256.decode", True, 1):
+            with trace.span("ops", "pack"):
+                g = self._decode_bits(indices)
+            with trace.span("ops", "device", program="_decode_kernel"):
+                return np.asarray(_decode_kernel(g, jnp.asarray(shards)))
 
     def encode_batch(self, data: np.ndarray) -> np.ndarray:
         data = np.ascontiguousarray(data, dtype=np.uint8)
@@ -161,16 +169,20 @@ class XlaErasureCoder(ErasureCoder):
         if self.n == self.k:
             return data.copy()
         if self._mesh is None and data.nbytes < 4 * self.HOST_FLOOR_BYTES:
-            placement.note("rs_gf256.encode_batch", False, len(data))
-            return self._host.encode_batch(data)
-        placement.note("rs_gf256.encode_batch", True, len(data))
-        if self._mesh is None:
-            return np.asarray(
-                _encode_kernel_batch(self._g_enc, jnp.asarray(data))
-            )
-        dev, b, l = self._put_vl(data)
-        out = _encode_kernel_batch(self._g_enc, dev)
-        return np.asarray(out)[:b, :, :l]
+            with placement.batch(
+                "rs_gf256.encode_batch", False, len(data)
+            ), trace.span("ops", "host"):
+                return self._host.encode_batch(data)
+        with placement.batch(
+            "rs_gf256.encode_batch", True, len(data)
+        ), trace.span("ops", "device", program="_encode_kernel_batch"):
+            if self._mesh is None:
+                return np.asarray(
+                    _encode_kernel_batch(self._g_enc, jnp.asarray(data))
+                )
+            dev, b, l = self._put_vl(data)
+            out = _encode_kernel_batch(self._g_enc, dev)
+            return np.asarray(out)[:b, :, :l]
 
     def decode_recheck_batch(self, indices: np.ndarray, shards: np.ndarray):
         """Fused decode + re-encode + Merkle roots, or None when the
@@ -185,36 +197,50 @@ class XlaErasureCoder(ErasureCoder):
         shards = np.ascontiguousarray(shards, dtype=np.uint8)
         if shards.nbytes < 4 * self.HOST_FLOOR_BYTES:
             # tiny job: the 3-step path, whose own floors then tally
-            # each step; this row counts the fusion's refusals
-            placement.note("rs_gf256.decode_recheck", False, len(shards))
-            return None
+            # (and span) each step; this row counts the fusion's
+            # refusals, and its span is empty
+            with placement.batch(
+                "rs_gf256.decode_recheck", False, len(shards)
+            ):
+                return None
         patterns = [self._normalize_indices(ix) for ix in indices]
         if len(set(patterns)) != 1:
             return None
-        placement.note("rs_gf256.decode_recheck", True, len(shards))
-        g = self._decode_bits(patterns[0])
-        b = shards.shape[0]
-        bucket = 8
-        while bucket < b:
-            bucket <<= 1
-        if bucket != b:
-            shards = np.concatenate(
-                [shards, np.repeat(shards[:1], bucket - b, axis=0)]
-            )
-        data, roots = _decode_recheck_kernel(
-            g, self._g_enc, jnp.asarray(shards)
-        )
-        return np.asarray(data)[:b], np.asarray(roots)[:b]
+        with placement.batch("rs_gf256.decode_recheck", True, len(shards)):
+            with trace.span("ops", "pack"):
+                g = self._decode_bits(patterns[0])
+                b = shards.shape[0]
+                bucket = 8
+                while bucket < b:
+                    bucket <<= 1
+                if bucket != b:
+                    shards = np.concatenate(
+                        [shards, np.repeat(shards[:1], bucket - b, axis=0)]
+                    )
+            with trace.span(
+                "ops", "device", program="_decode_recheck_kernel"
+            ):
+                data, roots = _decode_recheck_kernel(
+                    g, self._g_enc, jnp.asarray(shards)
+                )
+                return np.asarray(data)[:b], np.asarray(roots)[:b]
 
     def decode_batch(
         self, indices: np.ndarray, shards: np.ndarray
     ) -> np.ndarray:
         shards = np.ascontiguousarray(shards, dtype=np.uint8)
         if self._mesh is None and shards.nbytes < 4 * self.HOST_FLOOR_BYTES:
-            placement.note("rs_gf256.decode_batch", False, len(shards))
-            return self._host.decode_batch(indices, shards)
-        placement.note("rs_gf256.decode_batch", True, len(shards))
-        patterns = [self._normalize_indices(ix) for ix in indices]
+            with placement.batch(
+                "rs_gf256.decode_batch", False, len(shards)
+            ), trace.span("ops", "host"):
+                return self._host.decode_batch(indices, shards)
+        with placement.batch("rs_gf256.decode_batch", True, len(shards)):
+            with trace.span("ops", "pack"):
+                patterns = [self._normalize_indices(ix) for ix in indices]
+            with trace.span("ops", "device", program="_decode_kernel_batch"):
+                return self._decode_batch_device(patterns, shards)
+
+    def _decode_batch_device(self, patterns, shards: np.ndarray):
         if len(set(patterns)) == 1:
             g = self._decode_bits(patterns[0])
             if self._mesh is None:

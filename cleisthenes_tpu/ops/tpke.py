@@ -48,6 +48,7 @@ from cleisthenes_tpu.ops.modmath import (
     host_pow,
     host_pow_batch,
 )
+from cleisthenes_tpu.utils import trace
 
 
 def _hash_to_int(*parts: bytes) -> int:
@@ -85,48 +86,49 @@ def _cp_challenge_batch(
     m = len(contexts)
     if m == 0:
         return []
-    nb, q = group.nbytes, group.q
-    if m < 64:
-        # matrix assembly costs more than it saves on the live path's
-        # small hub flushes; identical bytes either way
-        return [
-            _hash_to_int(
-                b"cp", contexts[i], _ibytes(bases[i], nb),
-                _ibytes(his[i], nb), _ibytes(ds[i], nb),
-                _ibytes(a1s[i], nb), _ibytes(a2s[i], nb),
-            )
-            % q
-            for i in range(m)
+    with trace.span("tpke", "cp_challenge", items=m):
+        nb, q = group.nbytes, group.q
+        if m < 64:
+            # matrix assembly costs more than it saves on the live path's
+            # small hub flushes; identical bytes either way
+            return [
+                _hash_to_int(
+                    b"cp", contexts[i], _ibytes(bases[i], nb),
+                    _ibytes(his[i], nb), _ibytes(ds[i], nb),
+                    _ibytes(a1s[i], nb), _ibytes(a2s[i], nb),
+                )
+                % q
+                for i in range(m)
+            ]
+        cols = [
+            ints_to_be_rows(vals, nb)
+            for vals in (bases, his, ds, a1s, a2s)
         ]
-    cols = [
-        ints_to_be_rows(vals, nb)
-        for vals in (bases, his, ds, a1s, a2s)
-    ]
-    head_pfx = (2).to_bytes(4, "big") + b"cp"
-    heads = [
-        head_pfx + len(c).to_bytes(4, "big") + c for c in contexts
-    ]
-    by_hl: Dict[int, List[int]] = {}
-    for i, h in enumerate(heads):
-        by_hl.setdefault(len(h), []).append(i)
-    field_pfx = np.frombuffer(nb.to_bytes(4, "big"), dtype=np.uint8)
-    out: List[int] = [0] * m
-    for hl, idxs in by_hl.items():
-        k = len(idxs)
-        rows = np.empty((k, hl + 5 * (4 + nb)), dtype=np.uint8)
-        rows[:, :hl] = np.frombuffer(
-            b"".join(heads[i] for i in idxs), dtype=np.uint8
-        ).reshape(k, hl)
-        off = hl
-        sel = np.asarray(idxs, dtype=np.intp)
-        for col in cols:
-            rows[:, off : off + 4] = field_pfx
-            rows[:, off + 4 : off + 4 + nb] = col[sel]
-            off += 4 + nb
-        digs = sha256_rows(rows)
-        for row, i in zip(digs, idxs):
-            out[i] = int.from_bytes(row.tobytes(), "big") % q
-    return out
+        head_pfx = (2).to_bytes(4, "big") + b"cp"
+        heads = [
+            head_pfx + len(c).to_bytes(4, "big") + c for c in contexts
+        ]
+        by_hl: Dict[int, List[int]] = {}
+        for i, h in enumerate(heads):
+            by_hl.setdefault(len(h), []).append(i)
+        field_pfx = np.frombuffer(nb.to_bytes(4, "big"), dtype=np.uint8)
+        out: List[int] = [0] * m
+        for hl, idxs in by_hl.items():
+            k = len(idxs)
+            rows = np.empty((k, hl + 5 * (4 + nb)), dtype=np.uint8)
+            rows[:, :hl] = np.frombuffer(
+                b"".join(heads[i] for i in idxs), dtype=np.uint8
+            ).reshape(k, hl)
+            off = hl
+            sel = np.asarray(idxs, dtype=np.intp)
+            for col in cols:
+                rows[:, off : off + 4] = field_pfx
+                rows[:, off + 4 : off + 4 + nb] = col[sel]
+                off += 4 + nb
+            digs = sha256_rows(rows)
+            for row, i in zip(digs, idxs):
+                out[i] = int.from_bytes(row.tobytes(), "big") % q
+        return out
 
 
 def _ibytes(x: int, nbytes: int = 32) -> bytes:
@@ -329,75 +331,76 @@ def issue_shares_batch(
     """
     if not items:
         return []
-    eng = get_engine_degraded(backend, mesh, group)
-    q, g = group.q, group.g
-    nbytes = group.nbytes
-    # Exponentiations grouped by base — a wave shares a handful of
-    # bases (the generator g plus one coin base / ciphertext c1 per
-    # instance), which is exactly the fixed-base comb kernel's shape
-    # (ModEngine.pow_batch_grouped).
-    ws = []
-    g_exps: List[int] = []
-    by_base: Dict[int, List[int]] = {}
-    # ONE urandom draw for the whole wave (a lockstep wave issues
-    # ~N^2 shares; per-item token_bytes was one syscall each), sliced
-    # per item — same unbiased nonce rule (and reason) as issue_share
-    stride = nbytes + 8
-    nonce_pool = secrets.token_bytes(  # staticcheck: allow[DET001] CP-proof nonces
-        stride * len(items)
-    )
-    off = 0
-    for share, base, _context, vk in items:
-        w = int.from_bytes(nonce_pool[off : off + stride], "big") % q
-        off += stride
-        ws.append(w)
-        g_exps.append(w)  # a1 = g^w
-        if vk is None:
-            g_exps.append(share.value)  # h_i = g^{s_i}
-        be = by_base.setdefault(base, [])
-        be.append(w)  # a2 = base^w
-        be.append(share.value)  # d = base^{s_i}
-    base_order = list(by_base)
-    groups = [(g, g_exps)] + [(b, by_base[b]) for b in base_order]
-    pows = eng.pow_batch_grouped(groups)
-    g_res = pows[0]
-    base_res = {b: res for b, res in zip(base_order, pows[1:])}
-    base_off = {b: 0 for b in base_order}
-    g_off = 0
-    a1s: List[int] = []
-    his: List[int] = []
-    a2s: List[int] = []
-    ds: List[int] = []
-    for share, base, _context, vk in items:
-        a1s.append(g_res[g_off])
-        g_off += 1
-        if vk is None:
-            his.append(g_res[g_off])
-            g_off += 1
-        else:
-            his.append(vk)
-        bo = base_off[base]
-        a2s.append(base_res[base][bo])
-        ds.append(base_res[base][bo + 1])
-        base_off[base] = bo + 2
-    es = _cp_challenge_batch(
-        [it[2] for it in items],
-        [it[1] for it in items],
-        his,
-        ds,
-        a1s,
-        a2s,
-        group,
-    )
-    return [
-        DhShare(
-            index=share.index,
-            d=d,
-            e=e,
-            z=(w + e * share.value) % q,
+    with trace.span("tpke", "issue_batch", items=len(items)):
+        eng = get_engine_degraded(backend, mesh, group)
+        q, g = group.q, group.g
+        nbytes = group.nbytes
+        # Exponentiations grouped by base — a wave shares a handful of
+        # bases (the generator g plus one coin base / ciphertext c1 per
+        # instance), which is exactly the fixed-base comb kernel's shape
+        # (ModEngine.pow_batch_grouped).
+        ws = []
+        g_exps: List[int] = []
+        by_base: Dict[int, List[int]] = {}
+        # ONE urandom draw for the whole wave (a lockstep wave issues
+        # ~N^2 shares; per-item token_bytes was one syscall each), sliced
+        # per item — same unbiased nonce rule (and reason) as issue_share
+        stride = nbytes + 8
+        nonce_pool = secrets.token_bytes(  # staticcheck: allow[DET001] CP-proof nonces
+            stride * len(items)
         )
-        for (share, _b, _c, _vk), w, d, e in zip(items, ws, ds, es)
-    ]
+        off = 0
+        for share, base, _context, vk in items:
+            w = int.from_bytes(nonce_pool[off : off + stride], "big") % q
+            off += stride
+            ws.append(w)
+            g_exps.append(w)  # a1 = g^w
+            if vk is None:
+                g_exps.append(share.value)  # h_i = g^{s_i}
+            be = by_base.setdefault(base, [])
+            be.append(w)  # a2 = base^w
+            be.append(share.value)  # d = base^{s_i}
+        base_order = list(by_base)
+        groups = [(g, g_exps)] + [(b, by_base[b]) for b in base_order]
+        pows = eng.pow_batch_grouped(groups)
+        g_res = pows[0]
+        base_res = {b: res for b, res in zip(base_order, pows[1:])}
+        base_off = {b: 0 for b in base_order}
+        g_off = 0
+        a1s: List[int] = []
+        his: List[int] = []
+        a2s: List[int] = []
+        ds: List[int] = []
+        for share, base, _context, vk in items:
+            a1s.append(g_res[g_off])
+            g_off += 1
+            if vk is None:
+                his.append(g_res[g_off])
+                g_off += 1
+            else:
+                his.append(vk)
+            bo = base_off[base]
+            a2s.append(base_res[base][bo])
+            ds.append(base_res[base][bo + 1])
+            base_off[base] = bo + 2
+        es = _cp_challenge_batch(
+            [it[2] for it in items],
+            [it[1] for it in items],
+            his,
+            ds,
+            a1s,
+            a2s,
+            group,
+        )
+        return [
+            DhShare(
+                index=share.index,
+                d=d,
+                e=e,
+                z=(w + e * share.value) % q,
+            )
+            for (share, _b, _c, _vk), w, d, e in zip(items, ws, ds, es)
+        ]
 
 
 def combine_shares_batch(
@@ -413,42 +416,43 @@ def combine_shares_batch(
     ``combine_shares``, and shares its memo."""
     if not share_sets:
         return []
-    eng = get_engine_degraded(backend, mesh, group)
-    results: List[Optional[int]] = [None] * len(share_sets)
-    bases_flat: List[int] = []
-    exps_flat: List[int] = []
-    spans: List[tuple] = []  # (set_idx, memo_key, n_terms)
-    for si, shares in enumerate(share_sets):
-        if len(shares) < threshold:
-            raise ValueError(
-                f"need >= {threshold} shares to combine, got {len(shares)}"
-            )
-        use = sorted(shares, key=lambda s: s.index)[:threshold]
-        xs = [s.index for s in use]
-        if len(set(xs)) != len(xs):
-            raise ValueError("duplicate share indices")
-        key = (group, threshold, tuple((s.index, s.d) for s in use))
-        hit = _COMBINE_MEMO.get(key)
-        if hit is not None:
-            results[si] = hit
-            continue
-        lams = lagrange_coeff_at_zero(xs, group.q)
-        bases_flat.extend(sh.d % group.p for sh in use)
-        exps_flat.extend(lams)
-        spans.append((si, key, threshold))
-    if bases_flat:
-        pows = eng.pow_batch(bases_flat, exps_flat)
-        off = 0
-        for si, key, n_terms in spans:
-            acc = 1
-            for term in pows[off : off + n_terms]:
-                acc = acc * term % group.p
-            off += n_terms
-            if len(_COMBINE_MEMO) >= _COMBINE_MEMO_CAP:
-                _COMBINE_MEMO.clear()
-            _COMBINE_MEMO[key] = acc
-            results[si] = acc
-    return results  # type: ignore[return-value]
+    with trace.span("tpke", "combine_batch", groups=len(share_sets)):
+        eng = get_engine_degraded(backend, mesh, group)
+        results: List[Optional[int]] = [None] * len(share_sets)
+        bases_flat: List[int] = []
+        exps_flat: List[int] = []
+        spans: List[tuple] = []  # (set_idx, memo_key, n_terms)
+        for si, shares in enumerate(share_sets):
+            if len(shares) < threshold:
+                raise ValueError(
+                    f"need >= {threshold} shares to combine, got {len(shares)}"
+                )
+            use = sorted(shares, key=lambda s: s.index)[:threshold]
+            xs = [s.index for s in use]
+            if len(set(xs)) != len(xs):
+                raise ValueError("duplicate share indices")
+            key = (group, threshold, tuple((s.index, s.d) for s in use))
+            hit = _COMBINE_MEMO.get(key)
+            if hit is not None:
+                results[si] = hit
+                continue
+            lams = lagrange_coeff_at_zero(xs, group.q)
+            bases_flat.extend(sh.d % group.p for sh in use)
+            exps_flat.extend(lams)
+            spans.append((si, key, threshold))
+        if bases_flat:
+            pows = eng.pow_batch(bases_flat, exps_flat)
+            off = 0
+            for si, key, n_terms in spans:
+                acc = 1
+                for term in pows[off : off + n_terms]:
+                    acc = acc * term % group.p
+                off += n_terms
+                if len(_COMBINE_MEMO) >= _COMBINE_MEMO_CAP:
+                    _COMBINE_MEMO.clear()
+                _COMBINE_MEMO[key] = acc
+                results[si] = acc
+        return results  # type: ignore[return-value]
 
 
 def verify_share_groups(
@@ -470,25 +474,26 @@ def verify_share_groups(
     """
     if not groups:
         return []
-    # one engine (and one batched dispatch) per distinct GroupParams;
-    # in practice a node's TPKE and coin keys share one group, so this
-    # stays a single dispatch
-    by_gp: Dict[GroupParams, List[int]] = {}
-    for gi, (pub, _base, _shares, _context) in enumerate(groups):
-        by_gp.setdefault(pub.group, []).append(gi)
-    results: Dict[int, List[bool]] = {}
-    for gp, idx_list in by_gp.items():
-        eng = get_engine_degraded(backend, mesh, gp)
-        # NOTE: a comb-decomposed variant (g^z, h^{-e}, base^z grouped
-        # fixed-base; d^{-e} generic; host recombination) was once
-        # measured SLOWER than this fused path at 4k checks (on an
-        # earlier attachment of the chip): Shamir's trick already
-        # shares the square chain between both factors of each dual,
-        # so the decomposition saves fewer multiplies than it spends
-        # on extra dispatches and host marshalling.
-        a = _verify_pows_dual(gp, eng, groups, idx_list)
-        results.update(_cp_verdicts(gp, groups, idx_list, a))
-    return [results[gi] for gi in range(len(groups))]
+    with trace.span("tpke", "verify_batch", groups=len(groups)):
+        # one engine (and one batched dispatch) per distinct GroupParams;
+        # in practice a node's TPKE and coin keys share one group, so this
+        # stays a single dispatch
+        by_gp: Dict[GroupParams, List[int]] = {}
+        for gi, (pub, _base, _shares, _context) in enumerate(groups):
+            by_gp.setdefault(pub.group, []).append(gi)
+        results: Dict[int, List[bool]] = {}
+        for gp, idx_list in by_gp.items():
+            eng = get_engine_degraded(backend, mesh, gp)
+            # NOTE: a comb-decomposed variant (g^z, h^{-e}, base^z grouped
+            # fixed-base; d^{-e} generic; host recombination) was once
+            # measured SLOWER than this fused path at 4k checks (on an
+            # earlier attachment of the chip): Shamir's trick already
+            # shares the square chain between both factors of each dual,
+            # so the decomposition saves fewer multiplies than it spends
+            # on extra dispatches and host marshalling.
+            a = _verify_pows_dual(gp, eng, groups, idx_list)
+            results.update(_cp_verdicts(gp, groups, idx_list, a))
+        return [results[gi] for gi in range(len(groups))]
 
 
 def _verify_dual_items(gp, groups, idx_list):
@@ -595,89 +600,95 @@ def verify_and_combine_share_groups(
     returned list."""
     if not groups and not combine_only_sets:
         return [], [], []
-    by_gp: Dict[GroupParams, List[int]] = {}
-    for gi, (pub, _base, _shares, _context) in enumerate(groups):
-        by_gp.setdefault(pub.group, []).append(gi)
-    co_gp: Optional[GroupParams] = None
-    if combine_only_sets:
-        if combine_only_group is not None:
-            co_gp = combine_only_group
-        elif groups:
-            co_gp = groups[0][0].group
-        else:
-            # guessing a group here would produce a well-formed but
-            # cryptographically WRONG combination (and memoize it)
-            raise ValueError(
-                "combine_only_sets without groups requires an "
-                "explicit combine_only_group"
-            )
-        by_gp.setdefault(co_gp, [])
-    verdicts: Dict[int, List[bool]] = {}
-    values: Dict[int, Optional[int]] = {}
-    co_values: List[int] = [0] * len(combine_only_sets)
-    for gp, idx_list in by_gp.items():
-        eng = get_engine_degraded(backend, mesh, gp)
-        # verification duals first (2 per share), then combine terms
-        # (threshold per set) ride the same dispatch as u2^0 = 1
-        # dummy-factor duals
-        u1, e1, u2, e2 = _verify_dual_items(gp, groups, idx_list)
-        n_dual = len(u1)
-        comb_spans: List[tuple] = []  # (store(value), memo_key)
-
-        def queue_combine(shares, store) -> None:
-            """Memo-hit now or queue threshold Lagrange terms; the
-            post-dispatch loop below routes the product to ``store``.
-            One body for both the verified groups and the
-            combine-only sets — they cannot drift."""
-            use = sorted(shares, key=lambda s: s.index)[:threshold]
-            xs = [s.index for s in use]
-            if len(set(xs)) != len(xs):
-                raise ValueError("duplicate share indices")
-            key = (gp, threshold, tuple((s.index, s.d) for s in use))
-            hit = _COMBINE_MEMO.get(key)
-            if hit is not None:
-                store(hit)
-                return
-            lams = lagrange_coeff_at_zero(xs, gp.q)
-            for sh, lam in zip(use, lams):
-                u1.append(sh.d % gp.p); e1.append(lam)
-                u2.append(1); e2.append(0)
-            comb_spans.append((store, key))
-
-        for gi in idx_list:
-            pub, _base, shares, _context = groups[gi]
-            if len(shares) < threshold:
-                values[gi] = None
-                continue
-            queue_combine(
-                shares, lambda v, gi=gi: values.__setitem__(gi, v)
-            )
-        if gp == co_gp:  # equality, not identity: by_gp keys by value
-            for ci, shares in enumerate(combine_only_sets):
-                if len(shares) < threshold:
-                    raise ValueError(
-                        f"need >= {threshold} shares, got {len(shares)}"
-                    )
-                queue_combine(
-                    shares, lambda v, ci=ci: co_values.__setitem__(ci, v)
+    with trace.span(
+        "tpke",
+        "verify_combine_batch",
+        groups=len(groups),
+        combine_only=len(combine_only_sets),
+    ):
+        by_gp: Dict[GroupParams, List[int]] = {}
+        for gi, (pub, _base, _shares, _context) in enumerate(groups):
+            by_gp.setdefault(pub.group, []).append(gi)
+        co_gp: Optional[GroupParams] = None
+        if combine_only_sets:
+            if combine_only_group is not None:
+                co_gp = combine_only_group
+            elif groups:
+                co_gp = groups[0][0].group
+            else:
+                # guessing a group here would produce a well-formed but
+                # cryptographically WRONG combination (and memoize it)
+                raise ValueError(
+                    "combine_only_sets without groups requires an "
+                    "explicit combine_only_group"
                 )
-        a = eng.dual_pow_batch(u1, e1, u2, e2)
-        verdicts.update(_cp_verdicts(gp, groups, idx_list, a))
-        off = n_dual
-        for store, key in comb_spans:
-            acc = 1
-            for term in a[off : off + threshold]:
-                acc = acc * term % gp.p
-            off += threshold
-            if len(_COMBINE_MEMO) >= _COMBINE_MEMO_CAP:
-                _COMBINE_MEMO.clear()
-            _COMBINE_MEMO[key] = acc
-            store(acc)
-    return (
-        [verdicts[gi] for gi in range(len(groups))],
-        [values[gi] for gi in range(len(groups))],
-        co_values,
-    )
+            by_gp.setdefault(co_gp, [])
+        verdicts: Dict[int, List[bool]] = {}
+        values: Dict[int, Optional[int]] = {}
+        co_values: List[int] = [0] * len(combine_only_sets)
+        for gp, idx_list in by_gp.items():
+            eng = get_engine_degraded(backend, mesh, gp)
+            # verification duals first (2 per share), then combine terms
+            # (threshold per set) ride the same dispatch as u2^0 = 1
+            # dummy-factor duals
+            u1, e1, u2, e2 = _verify_dual_items(gp, groups, idx_list)
+            n_dual = len(u1)
+            comb_spans: List[tuple] = []  # (store(value), memo_key)
+
+            def queue_combine(shares, store) -> None:
+                """Memo-hit now or queue threshold Lagrange terms; the
+                post-dispatch loop below routes the product to ``store``.
+                One body for both the verified groups and the
+                combine-only sets — they cannot drift."""
+                use = sorted(shares, key=lambda s: s.index)[:threshold]
+                xs = [s.index for s in use]
+                if len(set(xs)) != len(xs):
+                    raise ValueError("duplicate share indices")
+                key = (gp, threshold, tuple((s.index, s.d) for s in use))
+                hit = _COMBINE_MEMO.get(key)
+                if hit is not None:
+                    store(hit)
+                    return
+                lams = lagrange_coeff_at_zero(xs, gp.q)
+                for sh, lam in zip(use, lams):
+                    u1.append(sh.d % gp.p); e1.append(lam)
+                    u2.append(1); e2.append(0)
+                comb_spans.append((store, key))
+
+            for gi in idx_list:
+                pub, _base, shares, _context = groups[gi]
+                if len(shares) < threshold:
+                    values[gi] = None
+                    continue
+                queue_combine(
+                    shares, lambda v, gi=gi: values.__setitem__(gi, v)
+                )
+            if gp == co_gp:  # equality, not identity: by_gp keys by value
+                for ci, shares in enumerate(combine_only_sets):
+                    if len(shares) < threshold:
+                        raise ValueError(
+                            f"need >= {threshold} shares, got {len(shares)}"
+                        )
+                    queue_combine(
+                        shares, lambda v, ci=ci: co_values.__setitem__(ci, v)
+                    )
+            a = eng.dual_pow_batch(u1, e1, u2, e2)
+            verdicts.update(_cp_verdicts(gp, groups, idx_list, a))
+            off = n_dual
+            for store, key in comb_spans:
+                acc = 1
+                for term in a[off : off + threshold]:
+                    acc = acc * term % gp.p
+                off += threshold
+                if len(_COMBINE_MEMO) >= _COMBINE_MEMO_CAP:
+                    _COMBINE_MEMO.clear()
+                _COMBINE_MEMO[key] = acc
+                store(acc)
+        return (
+            [verdicts[gi] for gi in range(len(groups))],
+            [values[gi] for gi in range(len(groups))],
+            co_values,
+        )
 
 
 def verify_shares(

@@ -58,6 +58,7 @@ from cleisthenes_tpu.protocol.acs import ACS
 from cleisthenes_tpu.utils.determinism import proposal_rng
 from cleisthenes_tpu.utils.log import NodeLogger
 from cleisthenes_tpu.utils.metrics import Metrics
+from cleisthenes_tpu.utils import trace
 from cleisthenes_tpu.utils.trace import maybe_recorder
 from cleisthenes_tpu.transport.broadcast import CoalescingBroadcaster
 from cleisthenes_tpu.transport.message import (
@@ -1156,21 +1157,23 @@ class HoneyBadger:
         additionally tops up the K-deep in-flight window
         (Config.pipeline_depth; no-op at depth 1).
         """
-        try:
-            if epoch is None:
-                self._propose_into(self.epoch)
-                self._drive_pipeline()
-                for hb in self.lanes[1:]:
-                    # the external kick reaches every lane: siblings
-                    # propose into their own frontiers (empty batches
-                    # are fine — lanes run independent HBBFT streams)
-                    if not hb._retired_self:
-                        hb._propose_into(hb.epoch)
-                        hb._drive_pipeline()
-            else:
-                self._propose_into(epoch)
-        finally:
-            self._exit_turn()
+        with trace.span("hb", "start_epoch"):
+            try:
+                if epoch is None:
+                    self._propose_into(self.epoch)
+                    self._drive_pipeline()
+                    for hb in self.lanes[1:]:
+                        # the external kick reaches every lane:
+                        # siblings propose into their own frontiers
+                        # (empty batches are fine — lanes run
+                        # independent HBBFT streams)
+                        if not hb._retired_self:
+                            hb._propose_into(hb.epoch)
+                            hb._drive_pipeline()
+                else:
+                    self._propose_into(epoch)
+            finally:
+                self._exit_turn()
 
     def _propose_into(self, target: int) -> None:
         """One epoch's proposal (the historical start_epoch body):
@@ -1193,17 +1196,14 @@ class HoneyBadger:
                 )
             else:  # keep the depth-1 event byte-stable
                 tr.instant("epoch", "open", epoch=target, **self._lane_kw)
-        t0 = 0.0 if tr is None else tr.now()
-        es.my_txs = self._create_batch()
-        # the EPOCH's key set (an epoch past an activation
-        # boundary encrypts under the reshared key even while the
-        # proposer's active roster is still the old one)
-        view = es.view
-        ct = view.tpke.encrypt(serialize_txs(es.my_txs))
-        if tr is not None:
-            tr.complete(
-                "tpke", "encrypt", t0, epoch=target, txs=len(es.my_txs)
-            )
+        with trace.span("tpke", "encrypt", recorder=tr, epoch=target) as sp:
+            es.my_txs = self._create_batch()
+            # the EPOCH's key set (an epoch past an activation
+            # boundary encrypts under the reshared key even while the
+            # proposer's active roster is still the old one)
+            view = es.view
+            ct = view.tpke.encrypt(serialize_txs(es.my_txs))
+            sp.note(txs=len(es.my_txs))
         es.acs.input(
             serialize_ciphertext(ct, view.keys.tpke_pub.group)
         )
@@ -1697,32 +1697,40 @@ class HoneyBadger:
         # requirement (S lanes share the wave's dispatches instead of
         # multiplying them).
         lanes = self.lanes
-        self._drive_lane_lockstep()
-        for hb in lanes:
-            hb._drain_coin_issues()
-            # the trailing settler (two-frontier mode) runs HERE, off
-            # the ordered critical path: issue pending dec shares,
-            # probe combines, settle ready epochs in order.  It runs
-            # before the hub flush so any CP-verification work it
-            # requests rides this wave's batched dispatch, not the
-            # next one's.
-            hb._drive_settler()
-            # top up the K-deep in-flight window before the hub flush:
-            # fresh proposals' RBC traffic joins this turn's bundle
-            hb._drive_pipeline()
-        self.hub.run_deferred()
-        for hb in lanes:
-            # the flush itself can advance rounds and queue NEW coin
-            # issues (coin reveal -> advance -> next round's aux
-            # quorum); drain again so they ride this turn's bundle,
-            # not the next inbound message's
-            hb._drain_coin_issues()
-            # eagerly staged dec shares (epochs ordered during this
-            # wave, including inside run_deferred) piggyback on this
-            # flush
-            hb._drain_dec_issues()
-            hb._maybe_chase_stall()
-        self._coalesce.flush()
+        with trace.span("hb", "on_idle"):
+            self._drive_lane_lockstep()
+            for hb in lanes:
+                with trace.span("hb", "coin_drain"):
+                    hb._drain_coin_issues()
+                # the trailing settler (two-frontier mode) runs HERE,
+                # off the ordered critical path: issue pending dec
+                # shares, probe combines, settle ready epochs in
+                # order.  It runs before the hub flush so any
+                # CP-verification work it requests rides this wave's
+                # batched dispatch, not the next one's.
+                with trace.span("hb", "settler"):
+                    hb._drive_settler()
+                # top up the K-deep in-flight window before the hub
+                # flush: fresh proposals' RBC traffic joins this
+                # turn's bundle
+                with trace.span("hb", "pipeline"):
+                    hb._drive_pipeline()
+            with trace.span("hb", "deferred"):
+                self.hub.run_deferred()
+            for hb in lanes:
+                # the flush itself can advance rounds and queue NEW
+                # coin issues (coin reveal -> advance -> next round's
+                # aux quorum); drain again so they ride this turn's
+                # bundle, not the next inbound message's
+                with trace.span("hb", "coin_drain"):
+                    hb._drain_coin_issues()
+                # eagerly staged dec shares (epochs ordered during
+                # this wave, including inside run_deferred) piggyback
+                # on this flush
+                with trace.span("hb", "dec_drain"):
+                    hb._drain_dec_issues()
+                hb._maybe_chase_stall()
+            self._coalesce.flush()
 
     def _drive_lane_lockstep(self) -> None:
         """Drag lagging lanes toward the fastest lane's ordered
@@ -1790,9 +1798,13 @@ class HoneyBadger:
         pend = self._pending_coin_issues
         if not pend:
             return
-        tr = self.trace
-        t0 = 0.0 if tr is None else tr.now()
         self._pending_coin_issues = []
+        with trace.span(
+            "coin", "issue_batch", recorder=self.trace, n=len(pend)
+        ):
+            self._issue_coin_shares(pend)
+
+    def _issue_coin_shares(self, pend) -> None:
         if self.config.egress_columnar:
             # wave-batched coin kernel (ISSUE 13): the hub's coin
             # column hands back this node's shares, dispatching the
@@ -1801,8 +1813,6 @@ class HoneyBadger:
             # to the scalar arm below
             for (bba, rnd), share in self.hub.take_coin_issues(self):
                 bba.broadcast_coin_share(rnd, share)
-            if tr is not None:
-                tr.complete("coin", "issue_batch", t0, n=len(pend))
             return
         # per-instance key material: a wave can span an activation
         # boundary (dynamic membership), so each BBA issues under ITS
@@ -1823,8 +1833,6 @@ class HoneyBadger:
                  pub.verification_keys[sec.index - 1])
             )
             metas.append((bba, rnd))
-        if not items:
-            return
         # the scalar comparison arm counts its native dispatches on
         # the same hub counters the columnar arm uses, so
         # coin_dispatches_per_epoch compares like for like across arms
@@ -1838,8 +1846,6 @@ class HoneyBadger:
         )
         for (bba, rnd), share in zip(metas, shares):
             bba.broadcast_coin_share(rnd, share)
-        if tr is not None:
-            tr.complete("coin", "issue_batch", t0, n=len(items))
 
     # -- message demux (transport Handler) ---------------------------------
 
@@ -2070,32 +2076,30 @@ class HoneyBadger:
         local_share = (
             view.local and view.keys.tpke_share is not None
         )
-        tr = self.trace
-        t_share0 = 0.0 if tr is None else tr.now()
-        issue_cts, issue_proposers = self._parse_output_cts(
-            es, local_share
-        )
         if not local_share:
             # no threshold share under this epoch's roster (a joiner
             # bootstrapping, or an adopted ordering from before our
             # membership): the plaintext arrives via peers' shares or
             # CLOG catch-up — nothing to issue
+            self._parse_output_cts(es, local_share)
             return
-        dec_shares = view.tpke.dec_share_batch(
-            view.keys.tpke_share, issue_cts
-        )
-        self._broadcast_dec_shares(epoch, issue_proposers, dec_shares)
-        if tr is not None:
-            tr.complete(
-                # the settler runs this off the ordered critical path
-                # in two-frontier mode: its mass belongs to the settle
-                # track, not the open->ordered window's tpke share
-                "settle" if self._two_frontier else "tpke",
-                "dec_share_issue",
-                t_share0,
-                epoch=epoch,
-                ciphertexts=len(es.ciphertexts),
+        with trace.span(
+            # the settler runs this off the ordered critical path in
+            # two-frontier mode: its mass belongs to the settle track,
+            # not the open->ordered window's tpke share
+            "settle" if self._two_frontier else "tpke",
+            "dec_share_issue",
+            recorder=self.trace,
+            epoch=epoch,
+        ) as sp:
+            issue_cts, issue_proposers = self._parse_output_cts(
+                es, local_share
             )
+            dec_shares = view.tpke.dec_share_batch(
+                view.keys.tpke_share, issue_cts
+            )
+            self._broadcast_dec_shares(epoch, issue_proposers, dec_shares)
+            sp.note(ciphertexts=len(es.ciphertexts))
 
     def _parse_output_cts(
         self, es: _EpochState, local_share: bool
@@ -2474,23 +2478,20 @@ class HoneyBadger:
                 es.opt_short.add(proposer)
                 return
             es.opt_short.discard(proposer)
-            tr = self.trace
-            t0 = 0.0 if tr is None else tr.now()
             try:
-                plain = view.tpke.combine(ct, subset)
+                with trace.span(
+                    "settle" if self._two_frontier else "tpke",
+                    "combine",
+                    recorder=self.trace,
+                    epoch=epoch,
+                    proposer=proposer,
+                ):
+                    plain = view.tpke.combine(ct, subset)
             except ValueError:  # bad tag: an invalid share slipped in
                 es.opt_failed.add(proposer)
                 self.hub.mark_dirty(self)
                 self.hub.request_flush()
                 return
-            if tr is not None:
-                tr.complete(
-                    "settle" if self._two_frontier else "tpke",
-                    "combine",
-                    t0,
-                    epoch=epoch,
-                    proposer=proposer,
-                )
             try:
                 es.decrypted[proposer] = deserialize_txs(
                     plain, self._tx_parse_memo

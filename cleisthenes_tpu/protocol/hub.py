@@ -80,6 +80,7 @@ from cleisthenes_tpu.ops.tpke import (
     issue_shares_batch,
     verify_share_groups,
 )
+from cleisthenes_tpu.utils import trace
 from cleisthenes_tpu.utils.determinism import guarded_by
 from cleisthenes_tpu.utils.lockcheck import new_lock
 from cleisthenes_tpu.utils.memo import BoundedFifoMemo
@@ -369,8 +370,9 @@ class CryptoHub:
     def _drain_dirty(self, wave: HubWave) -> None:
         clients = list(self._dirty)
         self._dirty.clear()
-        for c in clients:
-            c.drain_pending(wave)
+        with trace.span("hub", "drain", clients=len(clients)):
+            for c in clients:
+                c.drain_pending(wave)
         wave.clients.extend(clients)
 
     def flush(self) -> None:
@@ -387,8 +389,10 @@ class CryptoHub:
         self._flushing = True
         self.flush_wanted = False  # any full flush satisfies the want
         self.flushes += 1
-        tr = self.trace
-        t0 = 0.0 if tr is None else tr.now()
+        with trace.span("hub", "flush", recorder=self.trace) as sp:
+            self._flush_waves(sp)
+
+    def _flush_waves(self, sp) -> None:
         d0, b0, k0, s0 = (
             self.dispatches,
             self.branch_items,
@@ -405,21 +409,31 @@ class CryptoHub:
                     break
                 rounds += 1
                 if wave.b_items:
-                    self._run_branches(*wave.take_branches())
+                    with trace.span(
+                        "hub", "branches", items=len(wave.b_items)
+                    ):
+                        self._run_branches(*wave.take_branches())
                     if self._dirty:
                         # verdicts unlocked work (a completed decode
                         # matrix): drain it into THIS round's columns
                         self._drain_dirty(wave)
                 if wave.decodes:
-                    self._run_decodes(wave.take_decodes())
+                    with trace.span(
+                        "hub", "decodes", items=len(wave.decodes)
+                    ):
+                        self._run_decodes(wave.take_decodes())
                 if wave.shares:
-                    self._run_shares(wave.take_shares())
+                    with trace.span(
+                        "hub", "shares", items=len(wave.shares)
+                    ):
+                        self._run_shares(wave.take_shares())
                 # executor callbacks may re-mark clients (e.g. a share
                 # burn with parked replacements); quorum logic runs on
                 # every client drained this round, in drain order
                 clients, wave.clients = wave.clients, []
-                for c in dict.fromkeys(clients):
-                    c.after_crypto_flush()
+                with trace.span("hub", "callbacks", clients=len(clients)):
+                    for c in dict.fromkeys(clients):
+                        c.after_crypto_flush()
         finally:
             self._flushing = False
             width = (
@@ -429,18 +443,14 @@ class CryptoHub:
             )
             if width and len(self.wave_widths) < WAVE_WIDTH_CAP:
                 self.wave_widths.append(width)
-            if tr is not None:
-                tr.complete(
-                    "hub",
-                    "flush",
-                    t0,
-                    dispatches=self.dispatches - d0,
-                    branches=self.branch_items - b0,
-                    decodes=self.decode_items - k0,
-                    shares=self.share_items - s0,
-                    wave_width=width,
-                    rounds=rounds,
-                )
+            sp.note(
+                dispatches=self.dispatches - d0,
+                branches=self.branch_items - b0,
+                decodes=self.decode_items - k0,
+                shares=self.share_items - s0,
+                wave_width=width,
+                rounds=rounds,
+            )
 
     # -- executors ---------------------------------------------------------
 
@@ -737,23 +747,18 @@ class CryptoHub:
             gid = id(row[3])
             groups.setdefault(gid, []).append(row)
             group_objs[gid] = row[3]
-        tr = self.trace
         for gid, rows in groups.items():
-            t0 = 0.0 if tr is None else tr.now()
-            tally(len(rows))
-            shares = kernel(
-                [row[2] for row in rows],
-                group=group_objs[gid],
-                backend=self.crypto.engine_backend,
-                mesh=self.crypto.mesh,
-            )
-            if tr is not None:
-                tr.complete(
-                    trace_cat,
-                    trace_name,
-                    t0,
-                    n=len(rows),
-                    owners=len({id(row[0]) for row in rows}),
+            with trace.span(
+                trace_cat, trace_name, recorder=self.trace, n=len(rows)
+            ) as sp:
+                if sp:
+                    sp.note(owners=len({id(row[0]) for row in rows}))
+                tally(len(rows))
+                shares = kernel(
+                    [row[2] for row in rows],
+                    group=group_objs[gid],
+                    backend=self.crypto.engine_backend,
+                    mesh=self.crypto.mesh,
                 )
             for row, share in zip(rows, shares):
                 results.setdefault(row[0], []).append(

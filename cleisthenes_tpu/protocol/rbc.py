@@ -38,6 +38,7 @@ from cleisthenes_tpu.config import Config
 from cleisthenes_tpu.ops.backend import BatchCrypto
 from cleisthenes_tpu.ops.payload import join_payload, split_payload
 from cleisthenes_tpu.transport.message import RbcPayload, RbcType
+from cleisthenes_tpu.utils import trace
 
 # Per-root shard length sanity cap (a Byzantine proposer must not make
 # honest nodes buffer huge shards; envelopes are separately capped by
@@ -184,16 +185,14 @@ class RBC:
                 f"value of {len(value)} bytes exceeds the "
                 f"{self.k} x {MAX_SHARD_BYTES}-byte shard capacity"
             )
-        tr = self.trace
-        t0 = 0.0 if tr is None else tr.now()
-        data = split_payload(value, self.k)
-        shards = self.crypto.erasure.encode(data)  # (n, L)
-        tree = self.crypto.merkle.build(shards)
-        root = tree.root
-        if tr is not None:
-            tr.complete(
-                "rbc", "propose", t0, epoch=self.epoch, bytes=len(value)
-            )
+        with trace.span(
+            "rbc", "propose", recorder=self.trace,
+            epoch=self.epoch, bytes=len(value),
+        ):
+            data = split_payload(value, self.k)
+            shards = self.crypto.erasure.encode(data)  # (n, L)
+            tree = self.crypto.merkle.build(shards)
+            root = tree.root
         for j, member in enumerate(self.members):
             payload = RbcPayload(
                 type=RbcType.VAL,

@@ -59,6 +59,7 @@ from cleisthenes_tpu.transport.message import (
     ReadyBatchPayload,
     ResharePayload,
 )
+from cleisthenes_tpu.utils import trace
 
 # the scalar chain handles these outside the epoch demux entirely
 # (CATCHUP state transfer + reconfig gossip: epoch-unscoped, rare,
@@ -108,39 +109,35 @@ class WaveRouter:
         hb = self._hb
         metrics = hb.metrics
         metrics.waves_routed.inc()
-        tr = hb.trace
-        t0 = 0.0 if tr is None else tr.now()
-        d0 = metrics.handler_dispatches.value if tr is not None else 0
-        # (kind, epoch) -> item column, first-occurrence order (dicts
-        # preserve insertion order; keys are tuples of str/int, so the
-        # composition is PYTHONHASHSEED-independent)
-        cols: Dict[Tuple[str, int], List] = {}
-        logical = 0
-        n_payloads = 0
-        for msg in msgs:
-            sender = msg.sender_id
-            payload = msg.payload
-            if payload.__class__ is BundlePayload:
-                items = payload.items
-            else:
-                items = (payload,)
-            for p in items:
-                n_payloads += 1
-                logical += _logical(p)
-                if not self._demux(cols, sender, p):
-                    # order-sensitive barrier (CATCHUP): flush what
-                    # accumulated, scalar-dispatch, keep demuxing
-                    self._dispatch_all(cols)
-                    cols = {}
-                    hb._serve_payload(sender, p)
-        metrics.msgs_in.inc(logical)
-        self._dispatch_all(cols)
-        if tr is not None:
-            tr.complete(
-                "router",
-                "route",
-                t0,
-                frames=len(msgs),
+        d0 = metrics.handler_dispatches.value
+        with trace.span(
+            "router", "route", recorder=hb.trace, frames=len(msgs)
+        ) as sp:
+            # (kind, epoch) -> item column, first-occurrence order
+            # (dicts preserve insertion order; keys are tuples of
+            # str/int, so the composition is PYTHONHASHSEED-independent)
+            cols: Dict[Tuple[str, int], List] = {}
+            logical = 0
+            n_payloads = 0
+            for msg in msgs:
+                sender = msg.sender_id
+                payload = msg.payload
+                if payload.__class__ is BundlePayload:
+                    items = payload.items
+                else:
+                    items = (payload,)
+                for p in items:
+                    n_payloads += 1
+                    logical += _logical(p)
+                    if not self._demux(cols, sender, p):
+                        # order-sensitive barrier (CATCHUP): flush what
+                        # accumulated, scalar-dispatch, keep demuxing
+                        self._dispatch_all(cols)
+                        cols = {}
+                        hb._serve_payload(sender, p)
+            metrics.msgs_in.inc(logical)
+            self._dispatch_all(cols)
+            sp.note(
                 payloads=n_payloads,
                 dispatches=metrics.handler_dispatches.value - d0,
             )

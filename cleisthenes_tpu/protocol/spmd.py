@@ -77,6 +77,7 @@ from cleisthenes_tpu.protocol.honeybadger import (
     serialize_txs,
     setup_keys,
 )
+from cleisthenes_tpu.utils import trace
 
 # A round decides with probability 1/2 per instance; 64 rounds is
 # P ~ 2^-64 per instance — the same class of bound as bba.MAX_ROUNDS.
@@ -215,6 +216,10 @@ class LockstepCluster:
     # -- one epoch ---------------------------------------------------------
 
     def run_epoch(self) -> Dict[str, float]:
+        with trace.span("lockstep", "epoch", epoch=self.epoch):
+            return self._run_epoch()
+
+    def _run_epoch(self) -> Dict[str, float]:
         cfg = self.config
         n, f, k = cfg.n, cfg.f, cfg.data_shards
         ids = self.ids
@@ -226,67 +231,71 @@ class LockstepCluster:
 
         # ---- propose: batch select + TPKE encrypt (N ciphertexts) ----
         t0 = time.perf_counter()
-        per_node = self.b // n
-        my_txs: Dict[str, List[bytes]] = {}
-        values: List[bytes] = []
-        for nid in ids:
-            q = self.queues[nid]
-            txs = [q.popleft() for _ in range(min(per_node, len(q)))]
-            my_txs[nid] = txs
-            ct = self.tpke.encrypt(serialize_txs(txs))
-            values.append(serialize_ciphertext(ct, group))
+        with trace.span("lockstep", "propose"):
+            per_node = self.b // n
+            my_txs: Dict[str, List[bytes]] = {}
+            values: List[bytes] = []
+            for nid in ids:
+                q = self.queues[nid]
+                txs = [q.popleft() for _ in range(min(per_node, len(q)))]
+                my_txs[nid] = txs
+                ct = self.tpke.encrypt(serialize_txs(txs))
+                values.append(serialize_ciphertext(ct, group))
         stats["propose_s"] = time.perf_counter() - t0
 
         # ---- RBC: encode + forest + N^2 branch verify + decode ----
         t0 = time.perf_counter()
-        mats = [split_payload(v, k) for v in values]
-        L = max(m.shape[1] for m in mats)
-        data = np.zeros((n, k, L), dtype=np.uint8)
-        for i, m in enumerate(mats):
-            data[i, :, : m.shape[1]] = m
-        full = self.crypto.erasure.encode_batch(data)  # (n, n, L)
-        trees = self.crypto.merkle.build_batch(full)
-        roots = [t.root for t in trees]
+        with trace.span("lockstep", "rbc_encode"):
+            mats = [split_payload(v, k) for v in values]
+            L = max(m.shape[1] for m in mats)
+            data = np.zeros((n, k, L), dtype=np.uint8)
+            for i, m in enumerate(mats):
+                data[i, :, : m.shape[1]] = m
+            full = self.crypto.erasure.encode_batch(data)  # (n, n, L)
+            trees = self.crypto.merkle.build_batch(full)
+            roots = [t.root for t in trees]
         stats["rbc_encode_s"] = time.perf_counter() - t0
 
         # the N^2 distinct ECHO-phase proofs (docs/HONEYBADGER-EN.md:96),
         # one batched verify — the deduplicated receiver-side work
         t0 = time.perf_counter()
-        root_arr = np.repeat(
-            np.frombuffer(b"".join(roots), dtype=np.uint8).reshape(n, 32),
-            n,
-            axis=0,
-        )
-        leaves = np.ascontiguousarray(full.reshape(n * n, L))
-        depth = trees[0].depth
-        branches = np.zeros((n * n, depth, 32), dtype=np.uint8)
-        leaf_idx = np.arange(n)
-        for i, tree in enumerate(trees):
-            for d_ in range(depth):
-                # sibling of leaf j at depth d_ is level[d_][(j>>d_)^1]
-                branches[i * n : (i + 1) * n, d_] = tree.levels[d_][
-                    (leaf_idx >> d_) ^ 1
-                ]
-        indices = np.tile(np.arange(n), n)
-        ok = self.crypto.merkle.verify_batch(
-            root_arr, leaves, branches, indices
-        )
-        if not bool(np.all(ok)):
-            raise AssertionError("honest branch failed verification")
+        with trace.span("lockstep", "rbc_verify"):
+            root_arr = np.repeat(
+                np.frombuffer(b"".join(roots), dtype=np.uint8).reshape(n, 32),
+                n,
+                axis=0,
+            )
+            leaves = np.ascontiguousarray(full.reshape(n * n, L))
+            depth = trees[0].depth
+            branches = np.zeros((n * n, depth, 32), dtype=np.uint8)
+            leaf_idx = np.arange(n)
+            for i, tree in enumerate(trees):
+                for d_ in range(depth):
+                    # sibling of leaf j at depth d_ is level[d_][(j>>d_)^1]
+                    branches[i * n : (i + 1) * n, d_] = tree.levels[d_][
+                        (leaf_idx >> d_) ^ 1
+                    ]
+            indices = np.tile(np.arange(n), n)
+            ok = self.crypto.merkle.verify_batch(
+                root_arr, leaves, branches, indices
+            )
+            if not bool(np.all(ok)):
+                raise AssertionError("honest branch failed verification")
         stats["rbc_verify_s"] = time.perf_counter() - t0
 
         # delivery: fused decode + re-encode + root recheck over all N
         t0 = time.perf_counter()
-        idx_arr = np.tile(np.arange(k), (n, 1))
-        shard_arr = np.ascontiguousarray(full[:, :k, :])
-        dec_data, dec_roots, _disp = self.crypto.decode_recheck_batch(
-            idx_arr, shard_arr
-        )
-        delivered: List[bytes] = []
-        for i in range(n):
-            if dec_roots[i].tobytes() != roots[i]:
-                raise AssertionError("decode root recheck failed")
-            delivered.append(join_payload(dec_data[i]))
+        with trace.span("lockstep", "rbc_decode"):
+            idx_arr = np.tile(np.arange(k), (n, 1))
+            shard_arr = np.ascontiguousarray(full[:, :k, :])
+            dec_data, dec_roots, _disp = self.crypto.decode_recheck_batch(
+                idx_arr, shard_arr
+            )
+            delivered: List[bytes] = []
+            for i in range(n):
+                if dec_roots[i].tobytes() != roots[i]:
+                    raise AssertionError("decode root recheck failed")
+                delivered.append(join_payload(dec_data[i]))
         stats["rbc_decode_s"] = time.perf_counter() - t0
 
         # ---- BBA: every instance gets input 1 (all RBCs delivered);
@@ -309,120 +318,129 @@ class LockstepCluster:
         # mass in proportion to the roster; the doubling schedule
         # keeps the waste proportional to the tail.)
         t0 = time.perf_counter()
-        coin_pub = self.coin.pub
-        coin_vks = coin_pub.verification_keys
-        rounds_used = 0
-        coin_issues = 0
-        coin_verifies = 0
-        undecided = list(range(n))
-        coin_bits: Dict[tuple, bool] = {}  # (inst, rnd) -> toss
+        with trace.span("lockstep", "bba"):
+            coin_pub = self.coin.pub
+            coin_vks = coin_pub.verification_keys
+            rounds_used = 0
+            coin_issues = 0
+            coin_verifies = 0
+            undecided = list(range(n))
+            coin_bits: Dict[tuple, bool] = {}  # (inst, rnd) -> toss
 
-        # the decrypt wave (N^2 share issues + N optimistic combines)
-        # depends only on the RBC-delivered ciphertexts, never on the
-        # coin — so its issue items ride BBA round 0's issue dispatch
-        # and its combines ride round 0's fused verify/combine
-        # dispatch: the whole wave costs ZERO extra device round-trips
-        tpke_pub = self.tpke.pub
-        tpke_vks = tpke_pub.verification_keys
-        cts = [deserialize_ciphertext(v, group) for v in delivered]
-        dec_items = []
-        for ct in cts:
-            context = self.tpke.context(ct)
-            for nid in ids:
-                sec = self.keys[nid].tpke_share
-                dec_items.append(
-                    (sec, ct.c1, context, tpke_vks[sec.index - 1])
-                )
-        # riding round 0 requires one shared Lagrange threshold;
-        # distinct thresholds (non-default configs) fall back to a
-        # separate decrypt wave after BBA
-        fuse_dec = tpke_pub.threshold == coin_pub.threshold
-        dec_subsets: List[list] = []
-
-        def run_rounds(rnd_list, inst_list, dec=False):
-            """Issue + fused verify/combine + toss for every
-            (inst, rnd) pair — two dispatches total; fills coin_bits.
-            With ``dec``, the decrypt wave's issues and combines ride
-            the same two dispatches."""
-            nonlocal coin_issues, coin_verifies
-            items = []
-            metas = []
-            for rnd in rnd_list:
-                for inst in inst_list:
-                    coin_id = b"%d|%s|%d" % (
-                        self.epoch, ids[inst].encode(), rnd,
+            # the decrypt wave (N^2 share issues + N optimistic combines)
+            # depends only on the RBC-delivered ciphertexts, never on the
+            # coin — so its issue items ride BBA round 0's issue dispatch
+            # and its combines ride round 0's fused verify/combine
+            # dispatch: the whole wave costs ZERO extra device round-trips
+            tpke_pub = self.tpke.pub
+            tpke_vks = tpke_pub.verification_keys
+            cts = [deserialize_ciphertext(v, group) for v in delivered]
+            dec_items = []
+            for ct in cts:
+                context = self.tpke.context(ct)
+                for nid in ids:
+                    sec = self.keys[nid].tpke_share
+                    dec_items.append(
+                        (sec, ct.c1, context, tpke_vks[sec.index - 1])
                     )
-                    pub, base, context = self.coin.group_params(coin_id)
-                    metas.append((inst, rnd, coin_id, pub, base, context))
-                    for nid in ids:
-                        sec = self.keys[nid].coin_share
-                        items.append(
-                            (sec, base, context, coin_vks[sec.index - 1])
-                        )
-            n_coin = len(items)
-            if dec:
-                items = items + dec_items
-            shares = issue_shares_batch(
-                items, group=group, backend=backend, mesh=mesh
-            )
-            coin_issues += n_coin
-            if dec:
-                dec_shares = shares[n_coin:]
-                dec_subsets.extend(
-                    dec_shares[i * n : i * n + tpke_pub.threshold]
-                    for i in range(len(cts))
-                )
-            # receivers verify the first f+1 pooled shares per
-            # instance (the honest-case minimum) and combine the same
-            # subset — one fused dispatch for both
-            groups = []
-            subsets = []
-            for mi, (inst, rnd, coin_id, pub, base, context) in enumerate(
-                metas
-            ):
-                sub = shares[mi * n : mi * n + (f + 1)]
-                subsets.append(sub)
-                groups.append((pub, base, sub, context))
-            verdicts, _sigmas, _dec_vals = verify_and_combine_share_groups(
-                groups,
-                coin_pub.threshold,
-                backend=backend,
-                mesh=mesh,
-                combine_only_sets=dec_subsets if dec else (),
-                combine_only_group=group,
-            )
-            coin_verifies += sum(len(v) for v in verdicts)
-            if not all(all(v) for v in verdicts):
-                raise AssertionError("honest coin share failed CP check")
-            for (inst, rnd, coin_id, *_rest), sub in zip(metas, subsets):
-                # pure memo hit on the fused combine: no dispatch
-                coin_bits[(inst, rnd)] = self.coin.toss(coin_id, sub)
+            # riding round 0 requires one shared Lagrange threshold;
+            # distinct thresholds (non-default configs) fall back to a
+            # separate decrypt wave after BBA
+            fuse_dec = tpke_pub.threshold == coin_pub.threshold
+            dec_subsets: List[list] = []
 
-        next_rnd = 0
-        block = self.coin_block_initial
-        coin_waves = 0
-        while undecided and next_rnd < MAX_COIN_ROUNDS:
-            rnds = range(
-                next_rnd, min(next_rnd + block, MAX_COIN_ROUNDS)
-            )
-            run_rounds(rnds, undecided, dec=fuse_dec and next_rnd == 0)
-            coin_waves += 1
-            for rnd in rnds:
-                rounds_used = rnd + 1
-                undecided = [
-                    inst
-                    for inst in undecided
-                    if not coin_bits[(inst, rnd)]
-                ]
-                if not undecided:
-                    break
-            next_rnd = rnds.stop
-            if self.coin_block_doubling:
-                block = block * 2 if next_rnd > 1 else 1
-        if undecided:
-            raise AssertionError(
-                f"instances undecided after {MAX_COIN_ROUNDS} rounds"
-            )
+            def run_rounds(rnd_list, inst_list, dec=False):
+                """Issue + fused verify/combine + toss for every
+                (inst, rnd) pair — two dispatches total; fills coin_bits.
+                With ``dec``, the decrypt wave's issues and combines ride
+                the same two dispatches."""
+                nonlocal coin_issues, coin_verifies
+                with trace.span(
+                    "lockstep",
+                    "coin_wave",
+                    rounds=len(rnd_list),
+                    instances=len(inst_list),
+                    dec=dec,
+                ) as wave:
+                    items = []
+                    metas = []
+                    for rnd in rnd_list:
+                        for inst in inst_list:
+                            coin_id = b"%d|%s|%d" % (
+                                self.epoch, ids[inst].encode(), rnd,
+                            )
+                            pub, base, context = self.coin.group_params(coin_id)
+                            metas.append((inst, rnd, coin_id, pub, base, context))
+                            for nid in ids:
+                                sec = self.keys[nid].coin_share
+                                items.append(
+                                    (sec, base, context, coin_vks[sec.index - 1])
+                                )
+                    n_coin = len(items)
+                    if dec:
+                        items = items + dec_items
+                    wave.note(items=len(items))
+                    shares = issue_shares_batch(
+                        items, group=group, backend=backend, mesh=mesh
+                    )
+                    coin_issues += n_coin
+                    if dec:
+                        dec_shares = shares[n_coin:]
+                        dec_subsets.extend(
+                            dec_shares[i * n : i * n + tpke_pub.threshold]
+                            for i in range(len(cts))
+                        )
+                    # receivers verify the first f+1 pooled shares per
+                    # instance (the honest-case minimum) and combine the same
+                    # subset — one fused dispatch for both
+                    groups = []
+                    subsets = []
+                    for mi, (inst, rnd, coin_id, pub, base, context) in enumerate(
+                        metas
+                    ):
+                        sub = shares[mi * n : mi * n + (f + 1)]
+                        subsets.append(sub)
+                        groups.append((pub, base, sub, context))
+                    verdicts, _sigmas, _dec_vals = verify_and_combine_share_groups(
+                        groups,
+                        coin_pub.threshold,
+                        backend=backend,
+                        mesh=mesh,
+                        combine_only_sets=dec_subsets if dec else (),
+                        combine_only_group=group,
+                    )
+                    coin_verifies += sum(len(v) for v in verdicts)
+                    if not all(all(v) for v in verdicts):
+                        raise AssertionError("honest coin share failed CP check")
+                    for (inst, rnd, coin_id, *_rest), sub in zip(metas, subsets):
+                        # pure memo hit on the fused combine: no dispatch
+                        coin_bits[(inst, rnd)] = self.coin.toss(coin_id, sub)
+
+            next_rnd = 0
+            block = self.coin_block_initial
+            coin_waves = 0
+            while undecided and next_rnd < MAX_COIN_ROUNDS:
+                rnds = range(
+                    next_rnd, min(next_rnd + block, MAX_COIN_ROUNDS)
+                )
+                run_rounds(rnds, undecided, dec=fuse_dec and next_rnd == 0)
+                coin_waves += 1
+                for rnd in rnds:
+                    rounds_used = rnd + 1
+                    undecided = [
+                        inst
+                        for inst in undecided
+                        if not coin_bits[(inst, rnd)]
+                    ]
+                    if not undecided:
+                        break
+                next_rnd = rnds.stop
+                if self.coin_block_doubling:
+                    block = block * 2 if next_rnd > 1 else 1
+            if undecided:
+                raise AssertionError(
+                    f"instances undecided after {MAX_COIN_ROUNDS} rounds"
+                )
         stats["bba_s"] = time.perf_counter() - t0
         stats["bba_rounds"] = rounds_used
         stats["coin_waves"] = coin_waves
@@ -436,45 +454,47 @@ class LockstepCluster:
 
         # ---- decrypt tail: combines are memo hits from round 0 ----
         t0 = time.perf_counter()
-        if not fuse_dec:
-            dec_shares = issue_shares_batch(
-                dec_items, group=group, backend=backend, mesh=mesh
-            )
-            dec_subsets.extend(
-                dec_shares[i * n : i * n + tpke_pub.threshold]
-                for i in range(len(cts))
-            )
-            # optimistic combine (protocol.honeybadger._try_decrypt):
-            # the ciphertext tag authenticates the KEM value, so the
-            # honest case spends zero CP verifications on dec shares
-            combine_shares_batch(
-                dec_subsets,
-                tpke_pub.threshold,
-                group=group,
-                backend=backend,
-                mesh=mesh,
-            )
-        decrypted: Dict[str, List[bytes]] = {}
-        for i, (ct, sub) in enumerate(zip(cts, dec_subsets)):
-            plain = self.tpke.combine(ct, sub)  # memo hit + tag check
-            decrypted[ids[i]] = deserialize_txs(plain)
+        with trace.span("lockstep", "decrypt"):
+            if not fuse_dec:
+                dec_shares = issue_shares_batch(
+                    dec_items, group=group, backend=backend, mesh=mesh
+                )
+                dec_subsets.extend(
+                    dec_shares[i * n : i * n + tpke_pub.threshold]
+                    for i in range(len(cts))
+                )
+                # optimistic combine (protocol.honeybadger._try_decrypt):
+                # the ciphertext tag authenticates the KEM value, so the
+                # honest case spends zero CP verifications on dec shares
+                combine_shares_batch(
+                    dec_subsets,
+                    tpke_pub.threshold,
+                    group=group,
+                    backend=backend,
+                    mesh=mesh,
+                )
+            decrypted: Dict[str, List[bytes]] = {}
+            for i, (ct, sub) in enumerate(zip(cts, dec_subsets)):
+                plain = self.tpke.combine(ct, sub)  # memo hit + tag check
+                decrypted[ids[i]] = deserialize_txs(plain)
         stats["decrypt_s"] = time.perf_counter() - t0
         stats["dec_issues"] = len(dec_items)
 
         # ---- commit: the reference dedup/ordering rule ----
         # (protocol.honeybadger._maybe_commit)
         t0 = time.perf_counter()
-        seen: set = set()
-        contributions: Dict[str, List[bytes]] = {}
-        for proposer in sorted(decrypted):
-            mine = []
-            for tx in decrypted[proposer]:
-                if tx not in seen:
-                    seen.add(tx)
-                    mine.append(tx)
-            if mine:
-                contributions[proposer] = mine
-        self.committed_batches.append(Batch(contributions=contributions))
+        with trace.span("lockstep", "commit"):
+            seen: set = set()
+            contributions: Dict[str, List[bytes]] = {}
+            for proposer in sorted(decrypted):
+                mine = []
+                for tx in decrypted[proposer]:
+                    if tx not in seen:
+                        seen.add(tx)
+                        mine.append(tx)
+                if mine:
+                    contributions[proposer] = mine
+            self.committed_batches.append(Batch(contributions=contributions))
         stats["commit_s"] = time.perf_counter() - t0
 
         stats["epoch_s"] = time.perf_counter() - t_all

@@ -41,6 +41,7 @@ from cleisthenes_tpu.transport.message import (
     RbcType,
     ReadyBatchPayload,
 )
+from cleisthenes_tpu.utils import trace
 
 
 @runtime_checkable
@@ -306,25 +307,17 @@ class CoalescingBroadcaster:
         retries instead of silently stranding a wave's bundles."""
         if not self._dirty:
             return
-        tr = self.trace
-        if tr is None:
-            self._flush_dirty()
-            return
-        t0 = tr.now()
         bundles0 = self.bundles_flushed
-        payloads = len(self._shared) * len(self._members) + sum(
-            len(b) for b in self._extras.values()
-        )
-        try:
-            self._flush_dirty()
-        finally:
-            tr.complete(
-                "transport",
-                "flush",
-                t0,
-                bundles=self.bundles_flushed - bundles0,
-                payloads=payloads,
-            )
+        with trace.span("transport", "flush", recorder=self.trace) as sp:
+            if sp:
+                sp.note(
+                    payloads=len(self._shared) * len(self._members)
+                    + sum(len(b) for b in self._extras.values())
+                )
+            try:
+                self._flush_dirty()
+            finally:
+                sp.note(bundles=self.bundles_flushed - bundles0)
 
     def _merged(self, shared: List[Payload], extras: List[tuple]):
         """One receiver's arrival-order payload list: extras spliced

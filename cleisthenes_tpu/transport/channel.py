@@ -42,6 +42,7 @@ from cleisthenes_tpu.transport.message import (
     payload_body_count,
 )
 from cleisthenes_tpu.transport.wan import WanEmulator, WanProfile
+from cleisthenes_tpu.utils import trace
 
 # A fault filter sees (sender_id, receiver_id, wire_bytes) and returns
 # what to deliver: bytes (pass/tamper), None (drop), or a list of
@@ -522,31 +523,28 @@ class ChannelNetwork:
         need = sum(len(rids) for rids, _msg in entries)
         if self.pending_count() + need > self._queue_capacity:
             raise OverflowError("channel network queue full")
-        tr = getattr(ep.handler, "trace", None)
-        t0 = 0.0 if tr is None else tr.now()
-        frames_list, hits, misses, bodies = sign_wave_counted(
-            ep.auth,
-            [(msg, rids) for rids, msg in entries],
-            ep.encode_memo,
-        )
-        ep.mac_sign_batches += 1
-        self.mac_sign_calls += 1
-        ep.encode_memo_hits += hits
-        ep.encode_memo_misses += misses
-        ep.frames_encoded += bodies
-        self.frames_encoded += bodies
-        if tr is not None:
-            # ONE span per egress wave (mirror of the ingest
-            # frame_decode span): args carry the wave's bundle count
-            # and the encode memo's hit tally, tools/tracetool.py
-            # rolls them into the delivery summary
-            tr.complete(
-                "transport",
-                "frame_encode",
-                t0,
-                frames=len(entries),
-                memo_hits=hits,
+        # ONE span per egress wave (mirror of the ingest frame_decode
+        # span): args carry the wave's bundle count and the encode
+        # memo's hit tally, tools/tracetool.py rolls them into the
+        # delivery summary
+        with trace.span(
+            "transport",
+            "frame_encode",
+            recorder=getattr(ep.handler, "trace", None),
+            frames=len(entries),
+        ) as sp:
+            frames_list, hits, misses, bodies = sign_wave_counted(
+                ep.auth,
+                [(msg, rids) for rids, msg in entries],
+                ep.encode_memo,
             )
+            ep.mac_sign_batches += 1
+            self.mac_sign_calls += 1
+            ep.encode_memo_hits += hits
+            ep.encode_memo_misses += misses
+            ep.frames_encoded += bodies
+            self.frames_encoded += bodies
+            sp.note(memo_hits=hits)
         for (rids, _msg), frames in zip(entries, frames_list):
             for rid in rids:
                 self._enqueue(sender_id, rid, frames[rid])
@@ -589,52 +587,47 @@ class ChannelNetwork:
                 continue
             msgs, prefixes, good = [], [], []
             tr = getattr(ep.handler, "trace", None)
-            t0 = 0.0 if tr is None else tr.now()
             wave_hits0 = memo.hits
-            attempts = 0
-            for it in todo[receiver]:
-                attempts += 1
-                h0 = memo.hits
-                try:
-                    msg, prefix = decode_frame_shared(it[2], memo)
-                except ValueError:
-                    it[4] = (None, "undecodable")
-                    continue
-                if memo.hits > h0:
-                    ep.decode_memo_hits += 1
-                else:
-                    ep.decode_memo_misses += 1
-                    ep.frames_decoded += 1
-                    self.frames_decoded += 1
-                msgs.append(msg)
-                prefixes.append(prefix)
-                good.append(it)
-            if tr is not None and attempts:
-                # ONE span per receiver per wave (a per-frame span at
-                # N=64 is ~350k events/run — it would overflow the
-                # trace ring and distort the attribution it feeds):
-                # args carry the wave's decode-attempt and memo-hit
-                # counts, tools/tracetool.py rolls them up
-                tr.complete(
-                    "transport",
-                    "frame_decode",
-                    t0,
-                    frames=attempts,
-                    memo_hits=memo.hits - wave_hits0,
-                )
+            # ONE span per receiver per wave (a per-frame span at N=64
+            # is ~350k events/run — it would overflow the trace ring
+            # and distort the attribution it feeds): args carry the
+            # wave's decode-attempt and memo-hit counts,
+            # tools/tracetool.py rolls them up.  Every receiver in
+            # ``todo`` has at least one frame.
+            with trace.span(
+                "transport",
+                "frame_decode",
+                recorder=tr,
+                frames=len(todo[receiver]),
+            ) as sp:
+                for it in todo[receiver]:
+                    h0 = memo.hits
+                    try:
+                        msg, prefix = decode_frame_shared(it[2], memo)
+                    except ValueError:
+                        it[4] = (None, "undecodable")
+                        continue
+                    if memo.hits > h0:
+                        ep.decode_memo_hits += 1
+                    else:
+                        ep.decode_memo_misses += 1
+                        ep.frames_decoded += 1
+                        self.frames_decoded += 1
+                    msgs.append(msg)
+                    prefixes.append(prefix)
+                    good.append(it)
+                sp.note(memo_hits=memo.hits - wave_hits0)
             if not msgs:
                 continue
             self.mac_verify_calls += 1
             ep.mac_verify_batches += 1
-            t0 = 0.0 if tr is None else tr.now()
-            oks = ep.auth.verify_wire_many(msgs, prefixes)
-            if tr is not None:
-                tr.complete(
-                    "transport",
-                    "mac_verify_batch",
-                    t0,
-                    batch_width=len(msgs),
-                )
+            with trace.span(
+                "transport",
+                "mac_verify_batch",
+                recorder=tr,
+                batch_width=len(msgs),
+            ):
+                oks = ep.auth.verify_wire_many(msgs, prefixes)
             for it, msg, ok in zip(good, msgs, oks):
                 it[4] = (msg, True) if ok else (None, "bad_mac")
 
@@ -654,6 +647,13 @@ class ChannelNetwork:
         exercised under wire-fault schedules."""
         if not self._pending:
             return False
+        with trace.span(
+            "transport", "step_wave", frames=len(self._pending)
+        ):
+            self._deliver_wave()
+        return True
+
+    def _deliver_wave(self) -> None:
         if self.fault_filter is None and self._unprepared:
             self._prepare_wave()
         waves: Dict[str, List[Message]] = {}
@@ -738,7 +738,6 @@ class ChannelNetwork:
                 for m in waves[receiver]:
                     # handler without wave ingest: per-frame fallback
                     ep.handler.serve_request(m)  # staticcheck: allow[DET004] non-wave fallback
-        return True
 
     def step(self) -> bool:
         """Deliver one message (or, in wave-routing mode, one whole

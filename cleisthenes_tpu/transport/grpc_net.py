@@ -41,6 +41,7 @@ from cleisthenes_tpu.transport.message import (
     decode_frame,
     encode_message,
 )
+from cleisthenes_tpu.utils import trace
 from cleisthenes_tpu.utils.determinism import guarded_by
 from cleisthenes_tpu.utils.lockcheck import new_lock
 
@@ -287,16 +288,13 @@ class GrpcConnection:
                 if not msgs:
                     continue
                 self.mac_verify_batches += 1
-                tr = getattr(self._handler, "trace", None)
-                t0 = 0.0 if tr is None else tr.now()
-                oks = self._auth.verify_wire_many(msgs, prefixes)
-                if tr is not None:
-                    tr.complete(
-                        "transport",
-                        "mac_verify_batch",
-                        t0,
-                        batch_width=len(msgs),
-                    )
+                with trace.span(
+                    "transport",
+                    "mac_verify_batch",
+                    recorder=getattr(self._handler, "trace", None),
+                    batch_width=len(msgs),
+                ):
+                    oks = self._auth.verify_wire_many(msgs, prefixes)
                 handler = self._handler
                 good: List[Message] = []
                 for msg, ok in zip(msgs, oks):

@@ -49,6 +49,7 @@ from cleisthenes_tpu.transport.message import (
     Payload,
     payload_body_count,
 )
+from cleisthenes_tpu.utils import trace
 from cleisthenes_tpu.utils.determinism import guarded_by
 from cleisthenes_tpu.utils.lockcheck import new_lock
 from cleisthenes_tpu.utils.log import NodeLogger
@@ -316,25 +317,22 @@ class GrpcPayloadBroadcaster:
                 if conn is not None:
                     wave.append((msg, [member_id], [conn]))
         if wave:
-            tr = getattr(self._local, "trace", None)
-            t0 = 0.0 if tr is None else tr.now()
-            frames_list, hits, misses, bodies = sign_wave_counted(
-                self._auth,
-                [(msg, rids) for msg, rids, _conns in wave],
-                self._encode_memo,
-            )
-            self.mac_sign_batches += 1
-            self.encode_memo_hits += hits
-            self.encode_memo_misses += misses
-            self.frames_encoded += bodies
-            if tr is not None:
-                tr.complete(
-                    "transport",
-                    "frame_encode",
-                    t0,
-                    frames=len(wave),
-                    memo_hits=hits,
+            with trace.span(
+                "transport",
+                "frame_encode",
+                recorder=getattr(self._local, "trace", None),
+                frames=len(wave),
+            ) as sp:
+                frames_list, hits, misses, bodies = sign_wave_counted(
+                    self._auth,
+                    [(msg, rids) for msg, rids, _conns in wave],
+                    self._encode_memo,
                 )
+                self.mac_sign_batches += 1
+                self.encode_memo_hits += hits
+                self.encode_memo_misses += misses
+                self.frames_encoded += bodies
+                sp.note(memo_hits=hits)
             for (_msg, _rids, conns), frames in zip(wave, frames_list):
                 for conn in conns:
                     conn.send_wire(frames[conn.id()])

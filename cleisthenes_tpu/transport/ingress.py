@@ -61,6 +61,7 @@ from cleisthenes_tpu.transport.message import (
     decode_client_frame,
     encode_client_frame,
 )
+from cleisthenes_tpu.utils import trace
 from cleisthenes_tpu.utils.determinism import guarded_by
 from cleisthenes_tpu.utils.lockcheck import new_lock
 
@@ -155,14 +156,14 @@ class IngressPlane:
             raise ValueError(
                 f"expected a submit frame, got {type(payload).__name__}"
             )
-        tr = self._node.trace
-        t0 = 0.0 if tr is None else tr.now()
-        verdict = self._node.submit_ingress(
-            payload.client_id, payload.fee, payload.tx
-        )
+        with trace.span(
+            "ingress", "submit", recorder=self._node.trace
+        ) as sp:
+            verdict = self._node.submit_ingress(
+                payload.client_id, payload.fee, payload.tx
+            )
+            sp.note(status=verdict.status)
         status = _STATUS[verdict.status]
-        if tr is not None:
-            tr.complete("ingress", "submit", t0, status=verdict.status)
         if status == IngressStatus.OK and self._on_admitted is not None:
             self._on_admitted()
         # frontiers in the ack are MERGED total-order frontiers: at

@@ -10,12 +10,27 @@ events; `tools/tracetool.py` merges N node buffers into one
 Chrome-trace-event artifact (Perfetto-loadable) and derives the
 per-epoch critical-path report (docs/TRACING.md).
 
+Scoped spans (per batch, per wave, per turn, per phase; never per
+message or per item) enter through ONE function, ``span(cat, name,
+recorder=None, **args)``.  It is on when a JAX profiler session runs
+(observed, ``jax.profiler.TraceAnnotation.is_enabled()``; nobody sets
+it) or when the caller hands it a recorder.  On, it enters a
+``TraceAnnotation`` named ``cat/name``, so the span lies on the host
+plane of the same xplane as the device's "XLA Modules" line: the
+profiler's clock is the one clock program and device share.  A
+per-thread stack gives every span its parent and its SELF time
+(duration less what its children cover); while a session runs those
+add up in the process-wide ``totals()``, which the benchmark's
+per-layer readers divide by the traced window.  Off, it returns one
+shared no-op: no clock read, no allocation, no annotation.
+
 Design constraints, in order:
 
 1. **Compiled-out when off.**  `Config.trace=False` (the default)
-   means NO recorder exists: instrumentation sites hold `None` and
-   guard with one attribute load + identity check — no allocation, no
-   call (`tests/test_trace.py` asserts the zero-allocation property).
+   means NO recorder exists: instant sites hold `None` and guard with
+   one attribute load + identity check, span sites cost one
+   ``is_enabled()`` — no allocation, no clock
+   (`tests/test_trace.py`, `tests/test_trace_spans.py`).
 2. **Determinism-plane safe.**  Ordering comes from per-node
    **sequence numbers** assigned at record time; `perf_counter`
    timestamps ride along as PURE OBSERVABILITY data that no protocol
@@ -44,7 +59,8 @@ Event tuple shape (storage; `to_chrome` renders the JSON form):
 from __future__ import annotations
 
 import collections
-import contextlib
+import sys
+import threading
 import time
 from typing import Deque, Dict, Iterable, List, Optional, Tuple
 
@@ -82,6 +98,13 @@ CATEGORIES = frozenset(
         # instants with the verdict, and one "stream" span per
         # subscriber batch delivery — the client-visible latency
         # timeline the ingress_load bench section measures against
+        "ops",  # the ops/ seam: one "ops/<placement family>" span per
+        # batched call, children pack / device / unpack (or host when
+        # the floor keeps the batch off the device)
+        "lockstep",  # protocol.spmd: epoch > propose, rbc_*, bba >
+        # coin_wave, decrypt, commit
+        "hb",  # one HoneyBadger turn: on_idle > coin_drain, settler,
+        # pipeline, deferred, dec_drain; start_epoch
     )
 )
 
@@ -143,16 +166,6 @@ class TraceRecorder:
         t1 = self.now()
         self._record(cat, name, t0, t1 - t0, args)
 
-    @contextlib.contextmanager
-    def span(self, cat: str, name: str, **args):
-        """Context-manager form of ``complete`` for non-hot-path use
-        (tools, tests, demo drivers)."""
-        t0 = self.now()
-        try:
-            yield self
-        finally:
-            self.complete(cat, name, t0, **args)
-
     # -- reading -----------------------------------------------------------
 
     def events(self) -> List[Event]:
@@ -180,6 +193,149 @@ def maybe_recorder(config, node_id: str) -> Optional[TraceRecorder]:
             node_id, getattr(config, "trace_buffer", DEFAULT_CAP)
         )
     return None
+
+
+# ---------------------------------------------------------------------------
+# The span entry point: scoped spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+_clock = time.perf_counter  # durations only; the annotation is the timeline
+_Annotation = None  # jax.profiler.TraceAnnotation, bound with _session_on
+
+
+def _session_unbound() -> bool:
+    """``_session_on`` until ``jax.profiler`` is loaded: no session
+    can run before that, and this module never imports JAX itself."""
+    global _session_on, _Annotation
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None:
+        return False
+    _Annotation = profiler.TraceAnnotation
+    _session_on = _Annotation.is_enabled
+    return _session_on()
+
+
+_session_on = _session_unbound  # is a JAX profiler session running?
+
+_local = threading.local()  # .stack: this thread's open spans
+_totals_lock = threading.Lock()
+_totals: Dict[str, List[float]] = {}  # "cat/name" -> [calls, total_s, self_s]
+
+
+class _Off:
+    """What ``span`` returns when nothing listens: one shared object."""
+
+    __slots__ = ()
+
+    def __bool__(self) -> bool:
+        return False
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+    def note(self, **args) -> None:
+        pass
+
+
+_OFF = _Off()
+
+
+class _Span:
+    __slots__ = (
+        "_cat", "_name", "_recorder", "_args", "_key", "_ann", "_late",
+        "_t0", "_children_s",
+    )
+
+    def __init__(self, cat, name, recorder, args, session) -> None:
+        self._cat = cat
+        self._name = name
+        self._recorder = recorder
+        self._args = args
+        if session:
+            self._key = f"{cat}/{name}"
+            self._ann = _Annotation(self._key, **args)
+            self._late = None
+            self._children_s = 0.0
+        else:
+            self._ann = None
+
+    def note(self, **args) -> None:
+        """Details known only once the work is done (counts, deltas)."""
+        self._args.update(args)
+        if self._ann is not None:
+            self._late = args if self._late is None else {
+                **self._late, **args
+            }
+
+    def __enter__(self):
+        if self._ann is not None:
+            stack = getattr(_local, "stack", None)
+            if stack is None:
+                stack = _local.stack = []
+            stack.append(self)
+            self._ann.__enter__()
+        self._t0 = _clock()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        dur = _clock() - self._t0
+        ann = self._ann
+        if ann is not None:
+            if self._late is not None:
+                ann.set_metadata(**self._late)
+            ann.__exit__(*exc)
+            stack = _local.stack
+            if stack[-1] is self:
+                stack.pop()
+            else:  # not closed innermost-first (a generator's span)
+                stack.remove(self)
+            if stack:
+                stack[-1]._children_s += dur
+            if _session_on():  # a span the session's end cut is left out
+                with _totals_lock:
+                    row = _totals.get(self._key)
+                    if row is None:
+                        row = _totals[self._key] = [0, 0.0, 0.0]
+                    row[0] += 1
+                    row[1] += dur
+                    row[2] += dur - self._children_s
+        if self._recorder is not None:
+            self._recorder._record(
+                self._cat, self._name, self._t0, dur, self._args
+            )
+        return False
+
+
+def span(cat: str, name: str, recorder: Optional[TraceRecorder] = None, **args):
+    """``with span(cat, name, recorder=self.trace, **args) as sp:`` — a
+    scoped span at a layer boundary.  On (a profiler session runs, or
+    ``recorder`` is given) it is a TraceAnnotation ``cat/name`` on the
+    profiler's timeline, a row of ``totals()`` and, with a recorder,
+    the ring tuple ``complete()`` appends; ``sp.note(**args)`` adds
+    what is known only at the end.  Off it is one shared no-op."""
+    session = _session_on()
+    if recorder is None and not session:
+        return _OFF
+    return _Span(cat, name, recorder, args, session)
+
+
+def totals() -> Dict[str, Dict[str, float]]:
+    """{"cat/name": {"calls", "total_s", "self_s"}} of every span that
+    began and ended inside a profiler session since ``reset_totals()``.
+    Self times of one thread's spans partition that thread's wall."""
+    with _totals_lock:
+        return {
+            key: {"calls": row[0], "total_s": row[1], "self_s": row[2]}
+            for key, row in sorted(_totals.items())
+        }
+
+
+def reset_totals() -> None:
+    with _totals_lock:
+        _totals.clear()
 
 
 # ---------------------------------------------------------------------------
@@ -255,6 +411,9 @@ __all__ = [
     "DEFAULT_CAP",
     "TraceRecorder",
     "maybe_recorder",
+    "reset_totals",
+    "span",
     "to_chrome",
+    "totals",
     "write_chrome",
 ]

@@ -25,6 +25,7 @@ from cleisthenes_tpu.utils.trace import (  # noqa: E402
     CATEGORIES,
     TraceRecorder,
     maybe_recorder,
+    span,
     to_chrome,
 )
 from tools import tracetool  # noqa: E402
@@ -56,9 +57,9 @@ def test_ring_overflow_keeps_newest_and_counts_drops():
 def test_span_nesting_and_chrome_rendering():
     tr = TraceRecorder("n0")
     tr.instant("epoch", "open", epoch=0)
-    with tr.span("rbc", "propose", epoch=0):
-        with tr.span("hub", "flush"):
-            pass
+    with span("rbc", "propose", recorder=tr, epoch=0):
+        with span("hub", "flush", recorder=tr) as flush:
+            flush.note(rounds=1)
     tr.instant("epoch", "commit", epoch=0, txs=3)
     events = tr.events()
     assert len(events) == 4
@@ -71,6 +72,8 @@ def test_span_nesting_and_chrome_rendering():
         ("rbc", "propose", False),
         ("epoch", "commit", True),
     ]
+    # exactly the tuple complete() appends: args as given, late ones too
+    assert events[1][5] == {"rounds": 1} and events[2][5] == {"epoch": 0}
     doc = to_chrome({"n0": events})
     evs = doc["traceEvents"]
     assert evs[0]["ph"] == "M" and evs[0]["args"]["name"] == "n0"

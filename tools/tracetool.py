@@ -20,6 +20,13 @@ bounded rings, merged into one Chrome-trace-event JSON by
 - ``--capture OUT``  runs a seeded N-node SimulatedCluster with
   tracing on and writes the merged artifact — the self-contained
   source of CI fixtures and quick local looks.
+- ``--device-gaps DIR [--window lockstep/epoch]``  reads a JAX
+  profiler directory (``jax.profiler.start_trace(DIR)`` around the
+  run): the program's spans (``utils.trace.span``) lie on the host
+  plane of the same xplane as the device's "XLA Modules" line, so the
+  device's idle time splits by the innermost program span that covers
+  it.  Prints the device programs and that split, with
+  ``benchmarks/trace_reduce.py``'s own reduction.
 
 Open artifacts interactively at https://ui.perfetto.dev ("Open trace
 file"); one track per node, spans nested by category.  Schema details:
@@ -456,6 +463,64 @@ def capture(
     return load(out_path)
 
 
+# ---------------------------------------------------------------------------
+# program spans beside the device: the profiler's xplane
+# ---------------------------------------------------------------------------
+
+
+def profile_span_names(log_dir: str) -> set:
+    """Every host annotation of the newest profile under ``log_dir``
+    that is a program span: ``cat/name`` with a known category."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    paths = glob.glob(
+        os.path.join(log_dir, "plugins", "profile", "*", "*.xplane.pb")
+    )
+    if not paths:
+        raise FileNotFoundError(f"no .xplane.pb under {log_dir}")
+    data = ProfileData.from_file(max(paths, key=os.path.getmtime))
+    return {
+        ev.name
+        for plane in data.planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for ev in line.events
+        if "/" in ev.name and ev.name.split("/", 1)[0] in CATEGORIES
+    }
+
+
+def device_gaps(log_dir: str, window: Optional[str] = None) -> dict:
+    """``trace_reduce.reduce`` of the profile with the program's spans
+    kept beside the harness's annotations.  ``window`` names the
+    annotation whose first start and last end bound the reduction
+    (default: the harness's ``traced_window``)."""
+    from benchmarks import trace_reduce
+    from benchmarks.run import SPAN_NAMES
+
+    keep = profile_span_names(log_dir) | set(SPAN_NAMES)
+    trace = trace_reduce.load_xplane(log_dir, keep)
+    return trace_reduce.reduce(trace, window or SPAN_NAMES[0])
+
+
+def device_gaps_report(reduced: dict) -> str:
+    window, busy = reduced["window_s"], reduced["busy_s"]
+    lines = [
+        f"window {window:.6f} s, device busy {busy:.6f} s "
+        f"({100 * busy / window:.1f}%), {reduced['devices']} device(s)"
+        + (", TRACE BUFFERS DROPPED" if reduced["dropped"] else ""),
+        "device programs (s):",
+    ]
+    lines += [f"  {s:12.6f}  {name}" for name, s in reduced["device_ops"]]
+    lines.append("device idle by innermost span, ten longest (s):")
+    lines += [f"  {s:12.6f}  {name}" for name, s in reduced["idle_gaps"]]
+    rest = window - busy - sum(s for _name, s in reduced["idle_gaps"])
+    lines.append(f"  {max(rest, 0.0):12.6f}  (every other span)")
+    return "\n".join(lines)
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(prog="tools.tracetool")
     ap.add_argument(
@@ -484,12 +549,26 @@ def main(argv=None) -> int:
         metavar="OUT",
         help="run a seeded traced cluster and write the artifact here",
     )
+    ap.add_argument(
+        "--device-gaps",
+        metavar="DIR",
+        help="JAX profiler directory: device programs and the device's "
+        "idle time by innermost program span",
+    )
+    ap.add_argument(
+        "--window",
+        help="with --device-gaps: the span that bounds the reduction "
+        "(e.g. lockstep/epoch; default the harness's traced_window)",
+    )
     ap.add_argument("--n", type=int, default=4)
     ap.add_argument("--seed", type=int, default=7)
     ap.add_argument("--txs", type=int, default=24)
     ap.add_argument("--batch", type=int, default=8)
     args = ap.parse_args(argv)
 
+    if args.device_gaps:
+        print(device_gaps_report(device_gaps(args.device_gaps, args.window)))
+        return 0
     if args.capture:
         doc = capture(
             args.capture,
