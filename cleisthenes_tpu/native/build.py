@@ -111,6 +111,14 @@ def _configure_modpow(lib: ctypes.CDLL) -> None:
         ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
         ctypes.c_int,
     ]
+    lib.modreduce256_batch.argtypes = [
+        ctypes.c_void_p, ctypes.c_int, ctypes.c_void_p,
+        ctypes.c_void_p, ctypes.c_int,
+    ]
+    lib.muladdmod256_batch.argtypes = [
+        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int,
+    ]
     lib.modpow256_selftest.restype = ctypes.c_int
     rc = lib.modpow256_selftest()
     if rc != 0:

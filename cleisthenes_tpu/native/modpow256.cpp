@@ -11,6 +11,12 @@
 // item 7) and keeping the live CPU protocol path off the python
 // bignum wall.
 //
+// Beside the exponentiations, the scalar field: modreduce256_batch and
+// muladdmod256_batch do a Chaum-Pedersen proof's arithmetic mod q (nonce
+// and challenge reduction, z = w + e*s) on rows of bytes, so that a
+// wave of ~N^2 proofs costs no Python bigint each (ops/tpke.py's share
+// columns).
+//
 // Conventions: every value crosses the ABI as 32-byte little-endian
 // (4 u64 limbs); the modulus must be odd (Montgomery requirement) and
 // may be any 256-bit odd integer — the group parameters are inputs,
@@ -168,6 +174,28 @@ void dual_pow(const Ctx& c, const u64 u1[4], const u64 e1[4],
     mont_mul(c, acc, one, out);
 }
 
+// x mod n for any x < R = 2^256: into the Montgomery domain and out.
+inline void mod_n(const Ctx& c, const u64 x[4], u64 out[4]) {
+    static const u64 kOne[4] = {1, 0, 0, 0};
+    u64 xm[4];
+    mont_mul(c, x, c.r2, xm);
+    mont_mul(c, xm, kOne, out);
+}
+
+// (a + b) mod n for a, b < n.
+inline void add_mod(const Ctx& c, const u64 a[4], const u64 b[4],
+                    u64 out[4]) {
+    u64 r[4];
+    u128 carry = 0;
+    for (int i = 0; i < 4; ++i) {
+        u128 s = (u128)a[i] + b[i] + carry;
+        r[i] = (u64)s;
+        carry = s >> 64;
+    }
+    if (carry || geq(r, c.n)) sub(r, c.n);
+    memcpy(out, r, sizeof(r));
+}
+
 // Independent exponentiations parallelize trivially; threading kicks
 // in above a batch-size floor where spawn cost (~20 us/thread)
 // amortizes.  ctypes releases the GIL for the whole call.
@@ -236,6 +264,63 @@ void dualpow256_batch(const uint8_t* u1, const uint8_t* e1,
     });
 }
 
+// The scalar field beside the group: the arithmetic mod q that a
+// Chaum-Pedersen proof's nonce, challenge and response need, on rows
+// of bytes (ops/tpke.py's share columns), so that a wave's ~N^2
+// proofs cost no Python bigint each.
+
+// A row costs a few Montgomery products (~0.1-0.3 us), a wave a few
+// milliseconds: one thread.  (Spawning run_batch's pool for every call
+// cost more than the arithmetic: measured on the chip's host.)
+
+// in: b rows of `width` (1..64) little-endian bytes; out: b rows of
+// 32 little-endian bytes, in mod n.  x = hi*R + lo, and hi*R mod n is
+// mont_mul(hi, R^2).
+void modreduce256_batch(const uint8_t* in, int width, const uint8_t* mod,
+                        uint8_t* out, int b) {
+    if (width < 1 || width > 64) return;
+    Ctx c;
+    u64 n[4];
+    memcpy(n, mod, 32);
+    ctx_init(c, n);
+    for (int i = 0; i < b; ++i) {
+        uint8_t buf[64] = {0};
+        memcpy(buf, in + (size_t)width * i, width);
+        u64 lo[4], hi[4], a[4], r[4];
+        memcpy(lo, buf, 32);
+        memcpy(hi, buf + 32, 32);
+        mod_n(c, lo, a);
+        if (width > 32) {
+            mont_mul(c, hi, c.r2, r);
+            add_mod(c, a, r, a);
+        }
+        memcpy(out + 32 * i, a, 32);
+    }
+}
+
+// out = (a*b + c) mod n over b_rows rows of 32 little-endian bytes;
+// any a, b, c < 2^256.
+void muladdmod256_batch(const uint8_t* a, const uint8_t* b,
+                        const uint8_t* c_in, const uint8_t* mod,
+                        uint8_t* out, int b_rows) {
+    Ctx c;
+    u64 n[4];
+    memcpy(n, mod, 32);
+    ctx_init(c, n);
+    for (int i = 0; i < b_rows; ++i) {
+        u64 x[4], y[4], w[4], xm[4], r[4];
+        memcpy(x, a + 32 * i, 32);
+        memcpy(y, b + 32 * i, 32);
+        memcpy(w, c_in + 32 * i, 32);
+        if (geq(y, c.n)) mod_n(c, y, y);
+        if (geq(w, c.n)) mod_n(c, w, w);
+        mont_mul(c, x, c.r2, xm);   // x*R mod n
+        mont_mul(c, xm, y, r);      // x*y mod n
+        add_mod(c, r, w, r);
+        memcpy(out + 32 * i, r, 32);
+    }
+}
+
 int modpow256_selftest() {
     // n = 1000003 (odd), 2^20 mod n = 48573
     uint8_t n[32] = {0}, base[32] = {0}, e[32] = {0}, out[32] = {0};
@@ -259,6 +344,26 @@ int modpow256_selftest() {
     modpow256_batch(base, e, n, out, 1);
     memcpy(&got, out, 8);
     if (got != 1) return 3;
+    // scalars: 2^64 mod n = 16 * (2^20)^3 mod n = 16 * 48573^3 mod n
+    u64 p3 = (u64)48573 * 48573 % nn * 48573 % nn * 16 % nn;
+    uint8_t wide[40] = {0};
+    wide[0] = 5;
+    wide[8] = 1;  // 2^64 + 5
+    modreduce256_batch(wide, 40, n, out, 1);
+    memcpy(&got, out, 8);
+    if (got != (p3 + 5) % nn) return 4;
+    memset(wide, 0xff, 40);  // 2^320 - 1 = (2^64)^5 - 1
+    u64 p5 = p3 * p3 % nn * p3 % nn * p3 % nn * p3 % nn;
+    modreduce256_batch(wide, 40, n, out, 1);
+    memcpy(&got, out, 8);
+    if (got != (p5 + nn - 1) % nn) return 5;
+    // 123456789 * 987654321 + 55 mod 1000003
+    uint8_t ma[32] = {0}, mb[32] = {0}, mc[32] = {0};
+    u64 va = 123456789, vb = 987654321, vc = 55;
+    memcpy(ma, &va, 8); memcpy(mb, &vb, 8); memcpy(mc, &vc, 8);
+    muladdmod256_batch(ma, mb, mc, n, out, 1);
+    memcpy(&got, out, 8);
+    if (got != ((va % nn) * (vb % nn) + vc) % nn) return 6;
     return 0;
 }
 

@@ -1,18 +1,26 @@
 """Batched host-side SHA-256: one native call per wave.
 
-The lockstep executor and the live hub both end every crypto wave
-with a host loop that hashes one short transcript per share (CP
-challenges) or per Merkle node — at N=128 that is ~265k hashlib calls
-per epoch, and the Python call overhead dwarfs the compression work.
-``sha256_rows`` hashes a whole (m, stride) row-matrix in one ctypes
-crossing via native/sha256rows.cpp, degrading to a hashlib loop when
-the toolchain is unavailable (identical digests either way — the
-native kernel is plain FIPS 180-4, selftested at load).
+The lockstep executor and the live hub both end every crypto wave by
+hashing one short transcript per share (CP challenges) or per Merkle
+node — at N=128 ~72k CP transcripts an epoch; as hashlib calls the
+Python call overhead dwarfed the compression work.  ``sha256_rows``
+hashes a whole (m, stride) row-matrix in one ctypes crossing via
+native/sha256rows.cpp, degrading to a hashlib loop when the toolchain
+is unavailable (identical digests either way — the native kernel is
+plain FIPS 180-4, selftested at load).  The transcript rows are filled
+from byte columns (ops.tpke._cp_digest_rows): the lockstep waves'
+columns are the device's own output bytes; ``ints_to_be_rows`` /
+``be_rows_to_ints`` are the crossing for what is a Python int (the
+list entry points' values, the combine's memo keys and Lagrange
+terms).
 """
 
 from __future__ import annotations
 
+import functools
 import hashlib
+import io
+import itertools
 from typing import List, Optional, Sequence
 
 import numpy as np
@@ -74,11 +82,34 @@ def sha256_rows(
 def ints_to_be_rows(values: Sequence[int], nbytes: int) -> np.ndarray:
     """(m, nbytes) big-endian byte matrix from Python ints — the
     transcript field encoder (same bytes as int.to_bytes per item)."""
-    m = len(values)
     # one join + one frombuffer for the whole column: per-item
     # frombuffer assignments were a top-5 profile line at N=128
-    buf = b"".join(v.to_bytes(nbytes, "big") for v in values)
-    return np.frombuffer(buf, dtype=np.uint8).reshape(m, nbytes).copy()
+    buf = b"".join([v.to_bytes(nbytes, "big") for v in values])
+    return (
+        np.frombuffer(buf, dtype=np.uint8).reshape(len(values), nbytes).copy()
+    )
 
 
-__all__ = ["sha256_rows", "ints_to_be_rows"]
+def be_rows_to_ints(rows: np.ndarray) -> List[int]:
+    """``ints_to_be_rows``'s inverse: one Python int per row of a
+    (m, nbytes) big-endian byte matrix — read and converted without a
+    Python-level step per row (the slicing comprehension this replaces
+    cost as much again as ``from_bytes``)."""
+    width = rows.shape[1]
+    if width == 0:
+        return [0] * len(rows)
+    buf = io.BytesIO(np.ascontiguousarray(rows, dtype=np.uint8).tobytes())
+    return list(
+        map(
+            int.from_bytes,
+            iter(functools.partial(buf.read, width), b""),
+            itertools.repeat("big"),
+        )
+    )
+
+
+__all__ = [
+    "sha256_rows",
+    "ints_to_be_rows",
+    "be_rows_to_ints",
+]

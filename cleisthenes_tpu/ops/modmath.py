@@ -26,13 +26,14 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import List, NamedTuple, Optional, Sequence
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from cleisthenes_tpu.ops import placement
+from cleisthenes_tpu.ops.hashrows import be_rows_to_ints, ints_to_be_rows
 from cleisthenes_tpu.utils import trace
 
 # 256-bit safe prime p = 2q + 1 (generated deterministically, seed
@@ -650,7 +651,8 @@ def _pow_fused_grouped(
     The protocol's exponentiation mass has exactly this shape — a
     wave issues N shares per instance against one shared base (the
     coin's hashed base, a ciphertext's c1, or the group generator g;
-    protocol.spmd / ops.tpke.issue_shares_batch) — and a shared base
+    ops.tpke.issue_share_columns for the lockstep executor's byte
+    columns, issue_shares_batch for int lists) — and a shared base
     makes the classic comb trade: build the 64x16 table of
     base^(j * 16^k) ONCE per base on device (~1.2k Montgomery
     multiplies amortized over the whole group), then each
@@ -745,11 +747,83 @@ def _bytes32le_to_ints(arr: np.ndarray) -> List[int]:
     return [int.from_bytes(row.tobytes(), "little") for row in arr]
 
 
+def _le32_to_bytes33(arr: np.ndarray) -> np.ndarray:
+    """(B, 32) uint8 little-endian (the native kernel's rows) ->
+    (B, 33): the device kernels' value rows."""
+    out = np.zeros((len(arr), 33), dtype=np.uint8)
+    out[:, :32] = arr
+    return out
+
+
 def _native_modpow():
     """The compiled Montgomery kernel (native/modpow256.cpp), or None."""
     from cleisthenes_tpu.native.build import load_modpow
 
     return load_modpow()
+
+
+def _native_scalars(modulus: int):
+    """The native library for arithmetic mod ``modulus`` on byte rows,
+    or None: it takes any odd modulus.  A residue is a 32-byte row, so
+    no modulus over 256 bits is served at all."""
+    if modulus.bit_length() > 256:
+        raise ValueError("scalar rows serve moduli of at most 256 bits")
+    return _native_modpow() if modulus % 2 else None
+
+
+def _mod_bytes(modulus: int) -> np.ndarray:
+    return np.frombuffer(modulus.to_bytes(32, "little"), dtype=np.uint8)
+
+
+def mod_rows(rows: np.ndarray, modulus: int) -> np.ndarray:
+    """Each ``(m, width <= 64)`` big-endian row mod ``modulus``, as
+    ``(m, 32)`` big-endian rows — the scalar-field reductions of a CP
+    proof (nonce mod q, challenge digest mod q) without a Python int a
+    row: one native call, or ``int % modulus`` per row without the
+    toolchain (or for a modulus the kernel does not take)."""
+    m, width = rows.shape
+    lib = _native_scalars(modulus) if width <= 64 else None
+    if lib is None or m == 0:
+        return ints_to_be_rows(
+            [x % modulus for x in be_rows_to_ints(rows)], 32
+        )
+    le = np.ascontiguousarray(rows[:, ::-1])
+    out = np.empty((m, 32), dtype=np.uint8)
+    mod = _mod_bytes(modulus)
+    lib.modreduce256_batch(
+        le.ctypes.data, width, mod.ctypes.data, out.ctypes.data, m
+    )
+    return out[:, ::-1]
+
+
+def mul_add_mod_rows(
+    a: np.ndarray, b: np.ndarray, c: np.ndarray, modulus: int
+) -> np.ndarray:
+    """``(a * b + c) mod modulus`` row by row over ``(m, 32)``
+    big-endian rows (a CP response z = w + e * s mod q; a negation
+    -e = e * (q - 1)), as ``(m, 32)`` big-endian rows; native or, as
+    ``mod_rows``, Python ints."""
+    assert a.shape == b.shape == c.shape and a.shape[1] == 32
+    m = len(a)
+    lib = _native_scalars(modulus)
+    if lib is None or m == 0:
+        return ints_to_be_rows(
+            [
+                (x * y + z) % modulus
+                for x, y, z in zip(
+                    be_rows_to_ints(a), be_rows_to_ints(b), be_rows_to_ints(c)
+                )
+            ],
+            32,
+        )
+    cols = [np.ascontiguousarray(col[:, ::-1]) for col in (a, b, c)]
+    out = np.empty((m, 32), dtype=np.uint8)
+    mod = _mod_bytes(modulus)
+    lib.muladdmod256_batch(
+        cols[0].ctypes.data, cols[1].ctypes.data, cols[2].ctypes.data,
+        mod.ctypes.data, out.ctypes.data, m,
+    )
+    return out[:, ::-1]
 
 
 class ModEngine:
@@ -909,6 +983,48 @@ class ModEngine:
             return tuple(jnp.asarray(a) for a in arrays) + (b,)
         return self.mesh.put_flat(*arrays) + (b,)
 
+    # The comb kernel's row width: see _comb_device.
+    G_ROW = 512
+
+    @property
+    def columnar(self) -> bool:
+        """Whether the byte-column entry points (``pow_grouped_cols``,
+        ``pow_cols``, ``dual_pow_cols``) serve this group: their
+        value columns are the 256-bit layout's 33-byte rows.  Wide
+        groups keep the int entry points."""
+        return self._fits256
+
+    def _comb_plan(self, total: int) -> Tuple[bool, str]:
+        """(run the comb on the device?, the ops.placement op a
+        host-routed batch is tallied under) for a grouped batch of
+        ``total`` exponentiations — ONE decision for the int and the
+        column entry points.
+
+        The comb crossover is lower than the generic kernel's (64 vs
+        512 multiplies per exponentiation), so the floor scales by the
+        same 1/6."""
+        if not (
+            self.backend == "tpu"
+            and self.mesh is None
+            and self._fits256  # comb tables are 256-layout only;
+            # wide groups flatten to the generic wide device kernels
+        ):
+            return False, "pow"
+        # the comb crossover scales with the host fallback's speed,
+        # same native-awareness as _host_floor: with the native kernel
+        # the comb wins ~6x earlier than the generic device path;
+        # without it the host is python pow(), which loses almost
+        # immediately
+        host = _get_engine_nomesh("cpu", self.group)
+        floor = (
+            self.HOST_FLOOR // 6
+            if host._nat is not None
+            else self.HOST_FLOOR_NO_NATIVE
+        )
+        # a grouped batch the floor sends to the host is tallied as
+        # the comb's, not the generic kernel's
+        return total >= max(64, floor), "comb"
+
     def pow_batch_grouped(
         self, groups: Sequence[tuple]
     ) -> List[List[int]]:
@@ -919,37 +1035,13 @@ class ModEngine:
         On the device path this runs the fixed-base comb kernel
         (_pow_fused_grouped, ~6x the generic kernel at G >= 64);
         below the host floor, or for oversize groups on 'cpu', it
-        falls back to the generic batched path.  The comb crossover
-        is lower than the generic kernel's (64 vs 512 multiplies per
-        exponentiation), so the floor scales by the same 1/6.
+        falls back to the generic batched path.  The int form of
+        ``pow_grouped_cols``: same plan, same programs, same shapes.
         """
         if not groups:
             return []
         total = sum(len(exps) for _base, exps in groups)
-        if (
-            self.backend == "tpu"
-            and self.mesh is None
-            and self._fits256  # comb tables are 256-layout only;
-            # wide groups flatten to the generic wide device kernels
-        ):
-            # the comb crossover scales with the host fallback's
-            # speed, same native-awareness as _host_floor: with the
-            # native kernel the comb wins ~6x earlier than the
-            # generic device path; without it the host is python
-            # pow(), which loses almost immediately
-            host = _get_engine_nomesh("cpu", self.group)
-            floor = (
-                self.HOST_FLOOR // 6
-                if host._nat is not None
-                else self.HOST_FLOOR_NO_NATIVE
-            )
-            use_device = total >= max(64, floor)
-            # a grouped batch the floor sends to the host is tallied
-            # as the comb's, not the generic kernel's
-            host_op = "comb"
-        else:
-            use_device = False
-            host_op = "pow"
+        use_device, host_op = self._comb_plan(total)
         if not use_device:
             flat_b: List[int] = []
             flat_e: List[int] = []
@@ -963,55 +1055,106 @@ class ModEngine:
                 out.append(flat[off : off + len(exps)])
                 off += len(exps)
             return out
-        # Row-split oversize groups: the comb table builds on the
-        # BASE axis (n_bases vector lanes), so one base with a huge G
-        # would serialize ~1.2k tiny Montgomery multiplies on a single
-        # lane.  Replicating the base across rows of <= G_ROW items
-        # re-parallelizes the build at the cost of redundant tables —
-        # ~2 generic-exp equivalents per extra row, noise at G >= 64.
-        G_ROW = 512
-        split: List[tuple] = []  # (orig_idx, base, exps_slice)
-        # one comb dispatch per G-bucket: groups of similar size pad
-        # to a shared (n_bases_bucket, G_bucket) compile shape
-        chunks: Dict[int, List[List[int]]] = {
-            gi: [] for gi in range(len(groups))
-        }
-        by_bucket: Dict[int, List[int]] = {}
         with trace.span("ops", "pack"):
-            for gi, (base, exps) in enumerate(groups):
-                if len(exps) > G_ROW:
-                    for off in range(0, len(exps), G_ROW):
-                        split.append((gi, base, exps[off : off + G_ROW]))
-                else:
-                    split.append((gi, base, exps))
-            for si, (_gi, _base, exps) in enumerate(split):
-                gb = self._bucket(max(1, len(exps)))
-                by_bucket.setdefault(gb, []).append(si)
+            # same exponent domain as pow_batch: values must fit 256
+            # bits (to_bytes raises loudly otherwise); no mod-q
+            # reduction here — that is only sound for subgroup
+            # elements and is the CALLER's knowledge, not ours
+            blocks = [
+                ([base], exps_to_bytes(list(exps))[None])
+                for base, exps in groups
+            ]
+        outs = self._comb_device(blocks)
+        with trace.span("ops", "unpack"):
+            return [bytes33_to_ints(out[0]) for out in outs]
+
+    def pow_grouped_cols(
+        self, blocks: Sequence[tuple]
+    ) -> List[np.ndarray]:
+        """``pow_batch_grouped`` on byte columns.  ``blocks`` is a
+        sequence of ``(bases, exps)``: ``bases`` the block's ``rows``
+        int bases, ``exps`` a ``(rows, G, 32)`` uint8 big-endian
+        array, every item of row r a power of ``bases[r]`` (a block is
+        ``rows`` groups of one size).  Returns one ``(rows, G, 33)``
+        uint8 little-endian array per block — the device's own output
+        rows, never turned into Python ints."""
+        self._need_columnar()
+        if not blocks:
+            return []
+        total = sum(e.shape[0] * e.shape[1] for _b, e in blocks)
+        use_device, host_op = self._comb_plan(total)
+        if use_device:
+            return self._comb_device(blocks)
+        p = self.group.p
+        flat_b = np.concatenate([
+            np.repeat(
+                ints_to_bytes33([b % p for b in bases]), e.shape[1], axis=0
+            )
+            for bases, e in blocks
+        ])
+        flat_e = np.concatenate([e.reshape(-1, 32) for _b, e in blocks])
+        flat = self._pow_cols(flat_b, flat_e, host_op)
+        outs = []
+        off = 0
+        for _bases, e in blocks:
+            cnt = e.shape[0] * e.shape[1]
+            outs.append(flat[off : off + cnt].reshape(e.shape[:2] + (33,)))
+            off += cnt
+        return outs
+
+    def _comb_device(self, blocks: Sequence[tuple]) -> List[np.ndarray]:
+        """The comb dispatches of a grouped batch (see
+        ``pow_grouped_cols`` for ``blocks``): one per G-bucket, groups
+        of similar size padded to a shared (n_bases_bucket, G_bucket)
+        compile shape.
+
+        Oversize groups are row-split: the comb table builds on the
+        BASE axis (n_bases vector lanes), so one base with a huge G
+        would serialize ~1.2k tiny Montgomery multiplies on a single
+        lane.  Replicating the base across rows of <= G_ROW items
+        re-parallelizes the build at the cost of redundant tables —
+        ~2 generic-exp equivalents per extra row, noise at G >= 64."""
         gp = self.group
-        for g_bucket, sidxs in by_bucket.items():
+        outs = [
+            np.empty(e.shape[:2] + (33,), dtype=np.uint8)
+            for _b, e in blocks
+        ]
+        # (block, column offset, width): all rows of the block, its
+        # exponent columns [offset, offset + width)
+        by_bucket: Dict[int, List[tuple]] = {}
+        with trace.span("ops", "pack"):
+            base_rows = [
+                ints_to_bytes33([b % gp.p for b in bases])
+                for bases, _e in blocks
+            ]
+            for bi, (_bases, exps) in enumerate(blocks):
+                g_all = exps.shape[1]
+                for off in range(0, max(g_all, 1), self.G_ROW):
+                    width = min(self.G_ROW, g_all - off)
+                    by_bucket.setdefault(
+                        self._bucket(max(1, width)), []
+                    ).append((bi, off, width))
+        for g_bucket, chunks in by_bucket.items():
+            heights = [blocks[bi][1].shape[0] for bi, _o, _w in chunks]
             with placement.batch(
                 self._family + ".comb",
                 True,
-                sum(len(split[si][2]) for si in sidxs),
+                sum(h * w for h, (_bi, _o, w) in zip(heights, chunks)),
             ):
                 with trace.span("ops", "pack"):
-                    nb = self._bucket(len(sidxs))
-                    bases_arr = np.ones((nb, 33), dtype=np.uint8)
-                    bases_arr[:, 1:] = 0  # padding bases = 1
+                    nb = self._bucket(sum(heights))
+                    bases_arr = np.zeros((nb, 33), dtype=np.uint8)
+                    bases_arr[:, 0] = 1  # padding bases = 1
                     exps_arr = np.zeros(
                         (nb, g_bucket, 32), dtype=np.uint8
                     )
-                    for row, si in enumerate(sidxs):
-                        _gi, base, exps = split[si]
-                        bases_arr[row] = ints_to_bytes33([base % gp.p])[0]
-                        # same exponent domain as pow_batch: values
-                        # must fit 256 bits (to_bytes raises loudly
-                        # otherwise); no mod-q reduction here — that
-                        # is only sound for subgroup elements and is
-                        # the CALLER's knowledge, not ours
-                        exps_arr[row, : len(exps)] = exps_to_bytes(
-                            list(exps)
-                        )
+                    row = 0
+                    for h, (bi, off, width) in zip(heights, chunks):
+                        bases_arr[row : row + h] = base_rows[bi]
+                        exps_arr[row : row + h, :width] = blocks[bi][1][
+                            :, off : off + width
+                        ]
+                        row += h
                 with trace.span(
                     "ops", "device", program="_pow_fused_grouped"
                 ):
@@ -1023,28 +1166,52 @@ class ModEngine:
                         )
                     )
                 with trace.span("ops", "unpack"):
-                    for row, si in enumerate(sidxs):
-                        gi, _base, exps = split[si]
-                        n_e = len(exps)
-                        chunks[gi].append((
-                            si,
-                            bytes33_to_ints(
-                                out[row, :n_e].reshape(n_e, 33)
-                            ),
-                        ))
-        # a group's tail slice lands in a different size bucket than
-        # its full slices, and buckets run in dict order — reassemble
-        # strictly by split index
-        with trace.span("ops", "unpack"):
-            results: List[List[int]] = [[] for _ in groups]
-            for gi in range(len(groups)):
-                for _si, part in sorted(chunks[gi]):
-                    results[gi].extend(part)
-            return results
+                    # a group's tail slice lands in a different size
+                    # bucket than its full slices: each chunk goes
+                    # back to its own columns of its own block
+                    row = 0
+                    for h, (bi, off, width) in zip(heights, chunks):
+                        outs[bi][:, off : off + width] = out[
+                            row : row + h, :width
+                        ]
+                        row += h
+        return outs
 
     def pow_batch(self, bases: Sequence[int], exps: Sequence[int]) -> List[int]:
         """[b_i^e_i mod p] for all i in one dispatch."""
         return self._pow_batch(bases, exps, "pow")
+
+    def pow_cols(self, base_b: np.ndarray, exp_b: np.ndarray) -> np.ndarray:
+        """``pow_batch`` on byte columns: ``(B, 33)`` uint8
+        little-endian bases (values < p), ``(B, 32)`` uint8 big-endian
+        exponents -> ``(B, 33)`` uint8 little-endian."""
+        return self._pow_cols(base_b, exp_b, "pow")
+
+    def _native_pow(self, base_b: np.ndarray, exp_b: np.ndarray) -> np.ndarray:
+        """The native kernel on its own ABI: (B, 32) uint8
+        little-endian columns in and out."""
+        out = np.empty((len(base_b), 32), dtype=np.uint8)
+        self._nat.modpow256_batch(
+            base_b.ctypes.data,
+            exp_b.ctypes.data,
+            self._mod32,
+            out.ctypes.data,
+            len(base_b),
+        )
+        return out
+
+    def _native_dual_pow(self, u1_b, e1_b, u2_b, e2_b) -> np.ndarray:
+        out = np.empty((len(u1_b), 32), dtype=np.uint8)
+        self._nat.dualpow256_batch(
+            u1_b.ctypes.data,
+            e1_b.ctypes.data,
+            u2_b.ctypes.data,
+            e2_b.ctypes.data,
+            self._mod32,
+            out.ctypes.data,
+            len(u1_b),
+        )
+        return out
 
     def _pow_batch(
         self, bases: Sequence[int], exps: Sequence[int], host_op: str
@@ -1055,24 +1222,18 @@ class ModEngine:
         if not bases:
             return []
         if self.backend == "cpu":
+            gp = self.group.p
             if self._nat is not None:
                 # reduce mod P first: python-pow semantic parity for
                 # any int input (the kernel itself is exact for values
                 # < 2^256, but parity must not depend on that bound);
                 # out-of-range exponents raise in to_bytes — loudly
-                gp = self.group.p
-                base_b = _ints_to_bytes32le([b % gp for b in bases])
-                exp_b = _ints_to_bytes32le(exps)
-                out = np.empty((len(bases), 32), dtype=np.uint8)
-                self._nat.modpow256_batch(
-                    base_b.ctypes.data,
-                    exp_b.ctypes.data,
-                    self._mod32,
-                    out.ctypes.data,
-                    len(bases),
+                return _bytes32le_to_ints(
+                    self._native_pow(
+                        _ints_to_bytes32le([b % gp for b in bases]),
+                        _ints_to_bytes32le(exps),
+                    )
                 )
-                return _bytes32le_to_ints(out)
-            gp = self.group.p
             return [pow(b, e, gp) for b, e in zip(bases, exps)]
         host = self._host_floor(len(bases))
         if host is not None:
@@ -1098,13 +1259,54 @@ class ModEngine:
                     return _val_bytes_to_ints(out)
             with trace.span("ops", "pack"):
                 cols = (ints_to_bytes33(bases), exps_to_bytes(exps))
-            with trace.span("ops", "device", program="_pow_fused"):
-                base_b, exp_b, b = self._place(*cols)
-                out = np.asarray(
-                    _pow_fused(base_b, exp_b, *self._xla_spec)
-                )[:b]
+            out = self._pow_device(*cols)
             with trace.span("ops", "unpack"):
                 return bytes33_to_ints(out)
+
+    def _pow_cols(
+        self, base_b: np.ndarray, exp_b: np.ndarray, host_op: str
+    ) -> np.ndarray:
+        """_pow_batch on byte columns: the same floor, the same
+        tally, the same program."""
+        self._need_columnar()
+        assert len(base_b) == len(exp_b)
+        if len(base_b) == 0:
+            return np.empty((0, 33), dtype=np.uint8)
+        if self.backend == "cpu":
+            if self._nat is not None:
+                return _le32_to_bytes33(
+                    self._native_pow(
+                        np.ascontiguousarray(base_b[:, :32]),
+                        np.ascontiguousarray(exp_b[:, ::-1]),
+                    )
+                )
+            return ints_to_bytes33(
+                self._pow_batch(
+                    bytes33_to_ints(base_b), be_rows_to_ints(exp_b), host_op
+                )
+            )
+        host = self._host_floor(len(base_b))
+        if host is not None:
+            with placement.batch(
+                f"{self._family}.{host_op}", False, len(base_b)
+            ), trace.span("ops", "host"):
+                return host.pow_cols(base_b, exp_b)
+        with placement.batch(self._family + ".pow", True, len(base_b)):
+            return self._pow_device(base_b, exp_b)
+
+    def _pow_device(self, base_b: np.ndarray, exp_b: np.ndarray) -> np.ndarray:
+        with trace.span("ops", "device", program="_pow_fused"):
+            base_d, exp_d, b = self._place(base_b, exp_b)
+            return np.asarray(
+                _pow_fused(base_d, exp_d, *self._xla_spec)
+            )[:b]
+
+    def _need_columnar(self) -> None:
+        if not self.columnar:
+            raise ValueError(
+                "byte-column entry points serve the 256-bit layout "
+                "only; wide groups use the int entry points"
+            )
 
     def dual_pow_batch(
         self,
@@ -1118,24 +1320,16 @@ class ModEngine:
         if not u1:
             return []
         if self.backend == "cpu":
-            if self._nat is not None:
-                gp = self.group.p
-                u1_b = _ints_to_bytes32le([u % gp for u in u1])
-                e1_b = _ints_to_bytes32le(e1)
-                u2_b = _ints_to_bytes32le([u % gp for u in u2])
-                e2_b = _ints_to_bytes32le(e2)
-                out = np.empty((len(u1), 32), dtype=np.uint8)
-                self._nat.dualpow256_batch(
-                    u1_b.ctypes.data,
-                    e1_b.ctypes.data,
-                    u2_b.ctypes.data,
-                    e2_b.ctypes.data,
-                    self._mod32,
-                    out.ctypes.data,
-                    len(u1),
-                )
-                return _bytes32le_to_ints(out)
             gp = self.group.p
+            if self._nat is not None:
+                return _bytes32le_to_ints(
+                    self._native_dual_pow(
+                        _ints_to_bytes32le([u % gp for u in u1]),
+                        _ints_to_bytes32le(e1),
+                        _ints_to_bytes32le([u % gp for u in u2]),
+                        _ints_to_bytes32le(e2),
+                    )
+                )
             return [
                 pow(a, x, gp) * pow(b, y, gp) % gp
                 for a, x, b, y in zip(u1, e1, u2, e2)
@@ -1173,15 +1367,58 @@ class ModEngine:
                     ints_to_bytes33(u2),
                     exps_to_bytes(e2),
                 )
-            with trace.span("ops", "device", program="_dual_pow_fused"):
-                u1_b, e1_b, u2_b, e2_b, b = self._place(*cols)
-                out = np.asarray(
-                    _dual_pow_fused(
-                        u1_b, e1_b, u2_b, e2_b, *self._xla_spec
-                    )
-                )[:b]
+            out = self._dual_pow_device(*cols)
             with trace.span("ops", "unpack"):
                 return bytes33_to_ints(out)
+
+    def dual_pow_cols(
+        self,
+        u1_b: np.ndarray,
+        e1_b: np.ndarray,
+        u2_b: np.ndarray,
+        e2_b: np.ndarray,
+    ) -> np.ndarray:
+        """``dual_pow_batch`` on byte columns: ``(B, 33)`` uint8
+        little-endian values (< p), ``(B, 32)`` uint8 big-endian
+        exponents -> ``(B, 33)`` uint8 little-endian.  The same
+        floor, tally and program as the int form."""
+        self._need_columnar()
+        assert len(u1_b) == len(e1_b) == len(u2_b) == len(e2_b)
+        if len(u1_b) == 0:
+            return np.empty((0, 33), dtype=np.uint8)
+        if self.backend == "cpu":
+            if self._nat is not None:
+                return _le32_to_bytes33(
+                    self._native_dual_pow(
+                        np.ascontiguousarray(u1_b[:, :32]),
+                        np.ascontiguousarray(e1_b[:, ::-1]),
+                        np.ascontiguousarray(u2_b[:, :32]),
+                        np.ascontiguousarray(e2_b[:, ::-1]),
+                    )
+                )
+            return ints_to_bytes33(
+                self.dual_pow_batch(
+                    bytes33_to_ints(u1_b),
+                    be_rows_to_ints(e1_b),
+                    bytes33_to_ints(u2_b),
+                    be_rows_to_ints(e2_b),
+                )
+            )
+        host = self._host_floor(len(u1_b))
+        if host is not None:
+            with placement.batch(
+                self._family + ".dual_pow", False, len(u1_b)
+            ), trace.span("ops", "host"):
+                return host.dual_pow_cols(u1_b, e1_b, u2_b, e2_b)
+        with placement.batch(self._family + ".dual_pow", True, len(u1_b)):
+            return self._dual_pow_device(u1_b, e1_b, u2_b, e2_b)
+
+    def _dual_pow_device(self, u1_b, e1_b, u2_b, e2_b) -> np.ndarray:
+        with trace.span("ops", "device", program="_dual_pow_fused"):
+            u1_d, e1_d, u2_d, e2_d, b = self._place(u1_b, e1_b, u2_b, e2_b)
+            return np.asarray(
+                _dual_pow_fused(u1_d, e1_d, u2_d, e2_d, *self._xla_spec)
+            )[:b]
 
 
 @functools.cache
@@ -1231,6 +1468,8 @@ __all__ = [
     "host_pow",
     "host_pow_batch",
     "mont_mul_batch",
+    "mod_rows",
+    "mul_add_mod_rows",
     "LimbLayout",
     "layout_for_group",
     "xla_capable",

@@ -38,6 +38,11 @@ from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
+from cleisthenes_tpu.ops.hashrows import (
+    be_rows_to_ints,
+    ints_to_be_rows,
+    sha256_rows,
+)
 from cleisthenes_tpu.ops.modmath import (
     DEFAULT_GROUP,
     G,
@@ -47,6 +52,8 @@ from cleisthenes_tpu.ops.modmath import (
     get_engine_degraded,
     host_pow,
     host_pow_batch,
+    mod_rows,
+    mul_add_mod_rows,
 )
 from cleisthenes_tpu.utils import trace
 
@@ -74,15 +81,8 @@ def _cp_challenge_batch(
 ) -> List[int]:
     """All of a wave's CP challenges e = H(cp transcript) mod q in one
     batched native hash — byte-identical to mapping ``_hash_to_int``
-    over the items (tests assert the equivalence), but the transcript
-    rows are assembled as numpy columns and digested in a single
-    ctypes crossing instead of ~m Python hash calls.
-
-    Rows are grouped by context length (field offsets are constant
-    within a group); a lockstep wave has a handful of context shapes,
-    so this stays a couple of matrix fills."""
-    from cleisthenes_tpu.ops.hashrows import ints_to_be_rows, sha256_rows
-
+    over the items (tests assert the equivalence).  The int form of
+    ``_cp_challenge_cols``: one transcript layout for both."""
     m = len(contexts)
     if m == 0:
         return []
@@ -100,35 +100,79 @@ def _cp_challenge_batch(
                 % q
                 for i in range(m)
             ]
-        cols = [
-            ints_to_be_rows(vals, nb)
-            for vals in (bases, his, ds, a1s, a2s)
-        ]
-        head_pfx = (2).to_bytes(4, "big") + b"cp"
-        heads = [
-            head_pfx + len(c).to_bytes(4, "big") + c for c in contexts
-        ]
-        by_hl: Dict[int, List[int]] = {}
-        for i, h in enumerate(heads):
-            by_hl.setdefault(len(h), []).append(i)
-        field_pfx = np.frombuffer(nb.to_bytes(4, "big"), dtype=np.uint8)
-        out: List[int] = [0] * m
-        for hl, idxs in by_hl.items():
-            k = len(idxs)
-            rows = np.empty((k, hl + 5 * (4 + nb)), dtype=np.uint8)
-            rows[:, :hl] = np.frombuffer(
-                b"".join(heads[i] for i in idxs), dtype=np.uint8
-            ).reshape(k, hl)
-            off = hl
-            sel = np.asarray(idxs, dtype=np.intp)
-            for col in cols:
-                rows[:, off : off + 4] = field_pfx
-                rows[:, off + 4 : off + 4 + nb] = col[sel]
-                off += 4 + nb
-            digs = sha256_rows(rows)
-            for row, i in zip(digs, idxs):
-                out[i] = int.from_bytes(row.tobytes(), "big") % q
-        return out
+        digs = _cp_digest_rows(
+            contexts,
+            np.ones(m, dtype=np.intp),
+            [ints_to_be_rows(v, nb) for v in (bases, his, ds, a1s, a2s)],
+            nb,
+        )
+        return [e % q for e in be_rows_to_ints(digs)]
+
+
+def _cp_challenge_cols(
+    contexts: Sequence[bytes],
+    reps,
+    cols: Sequence[np.ndarray],
+    group: "GroupParams",
+) -> np.ndarray:
+    """The CP transcripts' SHA-256 digests, ``(m, 32)`` uint8, from
+    byte columns: ``contexts[j]`` covers ``reps[j]`` consecutive rows
+    (a wave has one context per (base, context) pair, not per share)
+    and ``cols`` are the five ``(m, nbytes)`` big-endian field columns
+    (base, h_i, d, A1, A2).  The challenge is the digest mod q; row
+    for row the bytes ``_hash_to_int`` hashes."""
+    reps = np.broadcast_to(
+        np.asarray(reps, dtype=np.intp), (len(contexts),)
+    )
+    with trace.span("tpke", "cp_challenge", items=int(reps.sum())):
+        return _cp_digest_rows(contexts, reps, cols, group.nbytes)
+
+
+def _cp_digest_rows(
+    contexts: Sequence[bytes],
+    reps: np.ndarray,
+    cols: Sequence[np.ndarray],
+    nb: int,
+) -> np.ndarray:
+    """Transcript rows assembled as numpy columns and digested in a
+    single ctypes crossing per context length (field offsets are
+    constant within a length; a lockstep wave has a handful of context
+    shapes, so this stays a couple of matrix fills)."""
+    m = int(reps.sum())
+    head_pfx = (2).to_bytes(4, "big") + b"cp"
+    heads = [head_pfx + len(c).to_bytes(4, "big") + c for c in contexts]
+    by_hl: Dict[int, List[int]] = {}
+    for j, h in enumerate(heads):
+        by_hl.setdefault(len(h), []).append(j)
+    starts = np.cumsum(reps) - reps
+    field_pfx = np.frombuffer(nb.to_bytes(4, "big"), dtype=np.uint8)
+    out = np.empty((m, 32), dtype=np.uint8)
+    for hl, js in by_hl.items():
+        head_mat = np.frombuffer(
+            b"".join(heads[j] for j in js), dtype=np.uint8
+        ).reshape(len(js), hl)
+        rj = reps[js]
+        k = int(rj.sum())
+        # the rows of these runs (each run's start, counted up); one
+        # context length is every row: no gather, no scatter
+        sel = (
+            slice(None)
+            if k == m
+            else np.repeat(starts[js] - (np.cumsum(rj) - rj), rj)
+            + np.arange(k)
+        )
+        rows = np.empty((k, hl + 5 * (4 + nb)), dtype=np.uint8)
+        rows[:, :hl] = np.repeat(head_mat, rj, axis=0)
+        off = hl
+        for col in cols:
+            rows[:, off : off + 4] = field_pfx
+            rows[:, off + 4 : off + 4 + nb] = col[sel]
+            off += 4 + nb
+        digs = sha256_rows(rows)
+        if k == m:
+            return digs
+        out[sel] = digs
+    return out
 
 
 def _ibytes(x: int, nbytes: int = 32) -> bytes:
@@ -243,6 +287,124 @@ class DhShare(NamedTuple):
     z: int
 
 
+def _be_to_le33(be: np.ndarray) -> np.ndarray:
+    """(m, nbytes <= 32) big-endian value rows -> the engine's
+    (m, 33) little-endian value rows."""
+    out = np.zeros((len(be), 33), dtype=np.uint8)
+    out[:, : be.shape[1]] = be[:, ::-1]
+    return out
+
+
+def _be_rows_lt(rows: np.ndarray, bound: int) -> np.ndarray:
+    """(m,) bool: each big-endian row, read as an integer, < bound."""
+    m, width = rows.shape
+    if bound >= 1 << (8 * width):
+        return np.ones(m, dtype=bool)
+    b = np.frombuffer(bound.to_bytes(width, "big"), dtype=np.uint8)
+    differs = rows != b
+    first = differs.argmax(axis=1)  # the most significant differing byte
+    r = np.arange(m)
+    return differs[r, first] & (rows[r, first] < b[first])
+
+
+# How shares were issued and how many DhShare objects were made, since
+# the last reset — beside ops.placement's tally (which side ran a
+# batch), this one says whether a wave stayed byte columns.  Read by
+# tests and tools/tracetool.py.
+_TALLY_FIELDS = (
+    "shares_issued_columnar",
+    "shares_issued_listed",
+    "shares_materialized",
+)
+_tally: Dict[str, int] = dict.fromkeys(_TALLY_FIELDS, 0)
+
+
+def share_tally() -> Dict[str, int]:
+    """{shares_issued_columnar, shares_issued_listed,
+    shares_materialized}: shares issued as ``ShareColumns`` rows,
+    shares issued as ``DhShare`` lists, and ``DhShare`` objects made
+    (by a list issue or by ``ShareColumns.to_shares``)."""
+    return dict(_tally)
+
+
+def reset_share_tally() -> None:
+    for key in _TALLY_FIELDS:
+        _tally[key] = 0
+
+
+class ShareColumns:
+    """A wave of threshold shares as numpy byte columns: ``index``
+    ``(m,)`` int32 and ``d``, ``e``, ``z`` ``(m, group.nbytes)`` uint8
+    big-endian (the transcript's and the wire's byte order), from the
+    moment the device returns them until they go back to it.  A slice
+    is a view of the same rows; a ``DhShare`` is made only by
+    ``to_shares``, for rows some caller reads one by one."""
+
+    __slots__ = ("group", "index", "d", "e", "z")
+
+    def __init__(
+        self,
+        group: GroupParams,
+        index: np.ndarray,
+        d: np.ndarray,
+        e: np.ndarray,
+        z: np.ndarray,
+    ):
+        self.group = group
+        self.index = index
+        self.d = d
+        self.e = e
+        self.z = z
+
+    def __len__(self) -> int:
+        return len(self.index)
+
+    def __getitem__(self, rows) -> "ShareColumns":
+        """The rows a slice (or an index array) names."""
+        return ShareColumns(
+            self.group,
+            self.index[rows],
+            self.d[rows],
+            self.e[rows],
+            self.z[rows],
+        )
+
+    def to_shares(self) -> List[DhShare]:
+        _tally["shares_materialized"] += len(self)
+        return [
+            DhShare(i, d, e, z)
+            for i, d, e, z in zip(
+                self.index.tolist(),
+                be_rows_to_ints(self.d),
+                be_rows_to_ints(self.e),
+                be_rows_to_ints(self.z),
+            )
+        ]
+
+    @classmethod
+    def from_shares(
+        cls, shares: Sequence[DhShare], group: GroupParams = DEFAULT_GROUP
+    ) -> "ShareColumns":
+        nb = group.nbytes
+        return cls(
+            group,
+            np.asarray([s.index for s in shares], dtype=np.int32),
+            ints_to_be_rows([s.d for s in shares], nb),
+            ints_to_be_rows([s.e for s in shares], nb),
+            ints_to_be_rows([s.z for s in shares], nb),
+        )
+
+
+class ShareWave(NamedTuple):
+    """A rectangular issue wave: every one of ``secrets`` (with its
+    verification key in ``vks``, same order) issues a share on every
+    ``(base, context)`` of ``pairs``."""
+
+    secrets: Sequence[ThresholdSecretShare]
+    vks: Sequence[int]
+    pairs: Sequence[Tuple[int, bytes]]
+
+
 def deal(
     n: int,
     threshold: int,
@@ -314,6 +476,18 @@ def issue_share(
     return DhShare(index=share.index, d=d, e=e, z=z)
 
 
+def _cp_nonce_rows(m: int, group: GroupParams) -> np.ndarray:
+    """A wave's m CP-proof nonces before reduction, ``(m, nbytes + 8)``
+    big-endian rows from ONE urandom draw (a lockstep wave issues ~N^2
+    shares; per-item token_bytes was one syscall each) — the unbiased
+    nonce rule (and reason) of issue_share: w = row mod q."""
+    stride = group.nbytes + 8
+    pool = secrets.token_bytes(  # staticcheck: allow[DET001] CP-proof nonces
+        stride * m
+    )
+    return np.frombuffer(pool, dtype=np.uint8).reshape(m, stride)
+
+
 def issue_shares_batch(
     items: Sequence[tuple],
     group: GroupParams = DEFAULT_GROUP,
@@ -325,82 +499,217 @@ def issue_shares_batch(
     ``items``: sequence of ``(share, base, context, vk)`` — ``vk`` is
     the issuer's public verification key g^{s_i} (``None`` recomputes
     it, costing one extra exponentiation per item).  Semantics match
-    ``issue_share`` exactly; this is the lockstep executor's path,
-    where a synchronous wave issues N^2 coin/decryption shares at once
-    (protocol.spmd) instead of one 4-exponentiation batch per share.
+    ``issue_share`` exactly, in one dispatch instead of one
+    4-exponentiation batch per share.  The served path's entry point
+    (CryptoHub, HoneyBadger, ``Tpke.dec_share_batch``,
+    ``coin.share_batch``): its callers read the shares one by one, so
+    it returns ``DhShare`` objects; ``issue_share_columns`` is the
+    lockstep executor's, whose waves stay byte columns.
     """
     if not items:
         return []
-    with trace.span("tpke", "issue_batch", items=len(items)):
-        eng = get_engine_degraded(backend, mesh, group)
-        q, g = group.q, group.g
-        nbytes = group.nbytes
-        # Exponentiations grouped by base — a wave shares a handful of
-        # bases (the generator g plus one coin base / ciphertext c1 per
-        # instance), which is exactly the fixed-base comb kernel's shape
-        # (ModEngine.pow_batch_grouped).
-        ws = []
-        g_exps: List[int] = []
-        by_base: Dict[int, List[int]] = {}
-        # ONE urandom draw for the whole wave (a lockstep wave issues
-        # ~N^2 shares; per-item token_bytes was one syscall each), sliced
-        # per item — same unbiased nonce rule (and reason) as issue_share
-        stride = nbytes + 8
-        nonce_pool = secrets.token_bytes(  # staticcheck: allow[DET001] CP-proof nonces
-            stride * len(items)
+    with trace.span(
+        "tpke",
+        "issue_batch",
+        items=len(items),
+        columnar=False,
+        materialized=len(items),
+    ):
+        return _issue_listed(
+            items, group, get_engine_degraded(backend, mesh, group)
         )
-        off = 0
-        for share, base, _context, vk in items:
-            w = int.from_bytes(nonce_pool[off : off + stride], "big") % q
-            off += stride
-            ws.append(w)
-            g_exps.append(w)  # a1 = g^w
-            if vk is None:
-                g_exps.append(share.value)  # h_i = g^{s_i}
-            be = by_base.setdefault(base, [])
-            be.append(w)  # a2 = base^w
-            be.append(share.value)  # d = base^{s_i}
-        base_order = list(by_base)
-        groups = [(g, g_exps)] + [(b, by_base[b]) for b in base_order]
-        pows = eng.pow_batch_grouped(groups)
-        g_res = pows[0]
-        base_res = {b: res for b, res in zip(base_order, pows[1:])}
-        base_off = {b: 0 for b in base_order}
-        g_off = 0
-        a1s: List[int] = []
-        his: List[int] = []
-        a2s: List[int] = []
-        ds: List[int] = []
-        for share, base, _context, vk in items:
-            a1s.append(g_res[g_off])
+
+
+def _issue_listed(
+    items: Sequence[tuple], group: GroupParams, eng
+) -> List[DhShare]:
+    """The list issue: Python ints through the engine's int entry
+    points, one ``DhShare`` per item."""
+    _tally["shares_issued_listed"] += len(items)
+    _tally["shares_materialized"] += len(items)
+    q, g = group.q, group.g
+    # Exponentiations grouped by base — a wave shares a handful of
+    # bases (the generator g plus one coin base / ciphertext c1 per
+    # instance), which is exactly the fixed-base comb kernel's shape
+    # (ModEngine.pow_batch_grouped).
+    ws = [w % q for w in be_rows_to_ints(_cp_nonce_rows(len(items), group))]
+    g_exps: List[int] = []
+    by_base: Dict[int, List[int]] = {}
+    for (share, base, _context, vk), w in zip(items, ws):
+        g_exps.append(w)  # a1 = g^w
+        if vk is None:
+            g_exps.append(share.value)  # h_i = g^{s_i}
+        be = by_base.setdefault(base, [])
+        be.append(w)  # a2 = base^w
+        be.append(share.value)  # d = base^{s_i}
+    base_order = list(by_base)
+    groups = [(g, g_exps)] + [(b, by_base[b]) for b in base_order]
+    pows = eng.pow_batch_grouped(groups)
+    g_res = pows[0]
+    base_res = {b: res for b, res in zip(base_order, pows[1:])}
+    base_off = {b: 0 for b in base_order}
+    g_off = 0
+    a1s: List[int] = []
+    his: List[int] = []
+    a2s: List[int] = []
+    ds: List[int] = []
+    for share, base, _context, vk in items:
+        a1s.append(g_res[g_off])
+        g_off += 1
+        if vk is None:
+            his.append(g_res[g_off])
             g_off += 1
-            if vk is None:
-                his.append(g_res[g_off])
-                g_off += 1
-            else:
-                his.append(vk)
-            bo = base_off[base]
-            a2s.append(base_res[base][bo])
-            ds.append(base_res[base][bo + 1])
-            base_off[base] = bo + 2
-        es = _cp_challenge_batch(
-            [it[2] for it in items],
-            [it[1] for it in items],
-            his,
-            ds,
-            a1s,
-            a2s,
+        else:
+            his.append(vk)
+        bo = base_off[base]
+        a2s.append(base_res[base][bo])
+        ds.append(base_res[base][bo + 1])
+        base_off[base] = bo + 2
+    es = _cp_challenge_batch(
+        [it[2] for it in items],
+        [it[1] for it in items],
+        his,
+        ds,
+        a1s,
+        a2s,
+        group,
+    )
+    return [
+        DhShare(
+            index=share.index,
+            d=d,
+            e=e,
+            z=(w + e * share.value) % q,
+        )
+        for (share, _b, _c, _vk), w, d, e in zip(items, ws, ds, es)
+    ]
+
+
+def issue_share_columns(
+    waves: Sequence[ShareWave],
+    group: GroupParams = DEFAULT_GROUP,
+    backend: str = "cpu",
+    mesh=None,
+) -> ShareColumns:
+    """``issue_shares_batch`` for rectangular waves, as byte columns:
+    the same shares with the same CP proofs in the same exponentiation
+    dispatch, returned as one ``ShareColumns`` — wave after wave, pair
+    after pair, issuer after issuer (row ``j * n + i`` of a wave is
+    ``secrets[i]``'s share on ``pairs[j]``).  The lockstep executor's
+    path: its waves are (coin ids or ciphertexts) x nodes.
+
+    A group the engine's column entry points do not serve (a wide
+    layout) is issued through the list body and packed."""
+    m = sum(len(wave.secrets) * len(wave.pairs) for wave in waves)
+    if m == 0:
+        return ShareColumns.from_shares([], group)
+    eng = get_engine_degraded(backend, mesh, group)
+    with trace.span(
+        "tpke",
+        "issue_batch",
+        items=m,
+        columnar=eng.columnar,
+        materialized=0 if eng.columnar else m,
+    ):
+        if eng.columnar:
+            return _issue_columns(waves, m, group, eng)
+        return ShareColumns.from_shares(
+            _issue_listed(
+                [
+                    (sec, base, context, vk)
+                    for wave in waves
+                    for base, context in wave.pairs
+                    for sec, vk in zip(wave.secrets, wave.vks)
+                ],
+                group,
+                eng,
+            ),
             group,
         )
-        return [
-            DhShare(
-                index=share.index,
-                d=d,
-                e=e,
-                z=(w + e * share.value) % q,
-            )
-            for (share, _b, _c, _vk), w, d, e in zip(items, ws, ds, es)
-        ]
+
+
+def _issue_columns(
+    waves: Sequence[ShareWave], m: int, group: GroupParams, eng
+) -> ShareColumns:
+    _tally["shares_issued_columnar"] += m
+    q, nb = group.q, group.nbytes
+    w_col = mod_rows(_cp_nonce_rows(m, group), q)
+    # Exponentiations grouped by base, the fixed-base comb kernel's
+    # shape (ModEngine.pow_grouped_cols): g^w for every share, then
+    # per pair base^w (a2) and base^{s_i} (d), n of each
+    blocks = [([group.g], w_col[None])]
+    s_col = np.empty((m, 32), dtype=np.uint8)  # each row's secret
+    row = 0
+    for wave in waves:
+        n, k = len(wave.secrets), len(wave.pairs)
+        rows = slice(row, row + k * n)
+        s_col[rows] = np.tile(
+            ints_to_be_rows([s.value for s in wave.secrets], 32), (k, 1)
+        )
+        exps = np.empty((k, 2 * n, 32), dtype=np.uint8)
+        exps[:, :n] = w_col[rows].reshape(k, n, 32)
+        exps[:, n:] = s_col[rows].reshape(k, n, 32)
+        blocks.append(([base for base, _c in wave.pairs], exps))
+        row += k * n
+    pows = eng.pow_grouped_cols(blocks)
+    # the device's little-endian rows, read as the transcript's
+    # big-endian columns: views, copied once into the hash matrix
+    a1 = pows[0][0][:, nb - 1 :: -1]
+    base_col = np.empty((m, nb), dtype=np.uint8)
+    hi_col = np.empty((m, nb), dtype=np.uint8)
+    a2 = np.empty((m, nb), dtype=np.uint8)
+    d = np.empty((m, nb), dtype=np.uint8)
+    index = np.empty(m, dtype=np.int32)
+    contexts: List[bytes] = []
+    reps: List[int] = []
+    row = 0
+    for wave, res in zip(waves, pows[1:]):
+        n, k = len(wave.secrets), len(wave.pairs)
+        rows = slice(row, row + k * n)
+        base_col[rows] = np.repeat(
+            ints_to_be_rows([base for base, _c in wave.pairs], nb),
+            n,
+            axis=0,
+        )
+        hi_col[rows] = np.tile(ints_to_be_rows(wave.vks, nb), (k, 1))
+        a2[rows] = res[:, :n, nb - 1 :: -1].reshape(k * n, nb)
+        d[rows] = res[:, n:, nb - 1 :: -1].reshape(k * n, nb)
+        index[rows] = np.tile(
+            np.asarray([s.index for s in wave.secrets], dtype=np.int32), k
+        )
+        contexts.extend(context for _b, context in wave.pairs)
+        reps.extend([n] * k)
+        row += k * n
+    digs = _cp_challenge_cols(
+        contexts, reps, (base_col, hi_col, d, a1, a2), group
+    )
+    # e = digest mod q and z = w + e * s_i mod q for every share,
+    # verified or not: each carries its whole proof
+    e = mod_rows(digs, q)
+    z = mul_add_mod_rows(e, s_col, w_col, q)
+    return ShareColumns(group, index, d, e[:, 32 - nb :], z[:, 32 - nb :])
+
+
+def _combine_subset(shares, threshold: int) -> Tuple[List[int], List[int]]:
+    """(indices, d values) of the subset a combine uses: the first
+    ``threshold`` shares by Shamir index — of a ``DhShare`` sequence
+    or of ``ShareColumns`` rows, the same ints either way (so the two
+    forms share memo entries)."""
+    if len(shares) < threshold:
+        raise ValueError(
+            f"need >= {threshold} shares to combine, got {len(shares)}"
+        )
+    if isinstance(shares, ShareColumns):
+        order = np.argsort(shares.index, kind="stable")[:threshold]
+        xs = shares.index[order].tolist()
+        ds = be_rows_to_ints(shares.d[order])
+    else:
+        use = sorted(shares, key=lambda s: s.index)[:threshold]
+        xs = [s.index for s in use]
+        ds = [s.d for s in use]
+    if len(set(xs)) != len(xs):
+        raise ValueError("duplicate share indices")
+    return xs, ds
 
 
 def combine_shares_batch(
@@ -423,21 +732,14 @@ def combine_shares_batch(
         exps_flat: List[int] = []
         spans: List[tuple] = []  # (set_idx, memo_key, n_terms)
         for si, shares in enumerate(share_sets):
-            if len(shares) < threshold:
-                raise ValueError(
-                    f"need >= {threshold} shares to combine, got {len(shares)}"
-                )
-            use = sorted(shares, key=lambda s: s.index)[:threshold]
-            xs = [s.index for s in use]
-            if len(set(xs)) != len(xs):
-                raise ValueError("duplicate share indices")
-            key = (group, threshold, tuple((s.index, s.d) for s in use))
+            xs, ds = _combine_subset(shares, threshold)
+            key = (group, threshold, tuple(zip(xs, ds)))
             hit = _COMBINE_MEMO.get(key)
             if hit is not None:
                 results[si] = hit
                 continue
             lams = lagrange_coeff_at_zero(xs, group.q)
-            bases_flat.extend(sh.d % group.p for sh in use)
+            bases_flat.extend(d % group.p for d in ds)
             exps_flat.extend(lams)
             spans.append((si, key, threshold))
         if bases_flat:
@@ -569,6 +871,155 @@ def _verify_pows_dual(gp, eng, groups, idx_list) -> List[int]:
     return eng.dual_pow_batch(u1, e1, u2, e2)
 
 
+def _as_share_list(shares) -> Sequence[DhShare]:
+    return shares.to_shares() if isinstance(shares, ShareColumns) else shares
+
+
+@functools.lru_cache(maxsize=256)
+def _lagrange_exp_rows(xs: tuple, q: int) -> np.ndarray:
+    """The Lagrange coefficients of ``xs`` as the engine's (t, 32)
+    big-endian exponent rows (a wave combines the same index set for
+    every instance)."""
+    return ints_to_be_rows(_lagrange_cached(xs, q), 32)
+
+
+def _verify_combine_columns(
+    gp: GroupParams,
+    eng,
+    groups: Sequence[tuple],
+    threshold: int,
+    combine_only_sets: Sequence["ShareColumns"],
+) -> Tuple[List[List[bool]], List[Optional[int]], List[int]]:
+    """``verify_and_combine_share_groups`` for one GroupParams on byte
+    columns: the list form's checks and its one dual-exponentiation
+    dispatch (A1 rows, A2 rows, then the combine terms as u2^0 = 1
+    duals), with every share's d, e, z going to the device, and every
+    recomputed (A1, A2) into the transcript rows, as the bytes they
+    already are."""
+    nb, p, q = gp.nbytes, gp.p, gp.q
+    counts = np.asarray([len(g[2]) for g in groups], dtype=np.intp)
+    n_ver = int(counts.sum())
+    empty = ShareColumns.from_shares([], gp)  # concatenate needs one
+    cols = [empty] + [g[2] for g in groups]
+    index = np.concatenate([c.index for c in cols])
+    d = np.concatenate([c.d for c in cols])
+    e = np.concatenate([c.e for c in cols])
+    z = np.concatenate([c.z for c in cols])
+    # structural checks: 1 <= index <= n, 0 < d < p; an out-of-roster
+    # index is verified vacuously false by pinning to vk = 1 (never
+    # matches a real transcript)
+    pubs: Dict[int, ThresholdPublicKey] = {}  # by identity, first seen
+    for pub, _base, _shares, _context in groups:
+        pubs.setdefault(id(pub), pub)
+    pub_no = {key: k for k, key in enumerate(pubs)}
+    # one table of every key set's [1, vk_1 .. vk_n]
+    vk_rows = [[1, *pub.verification_keys] for pub in pubs.values()]
+    vk_table = ints_to_be_rows(
+        [vk for rows in vk_rows for vk in rows], nb
+    )
+    vk_off = np.cumsum([0] + [len(rows) for rows in vk_rows], dtype=np.intp)
+    pub_row = np.repeat(
+        np.asarray([pub_no[id(g[0])] for g in groups], dtype=np.intp), counts
+    )
+    n_row = np.asarray([pub.n for pub in pubs.values()], dtype=np.intp)[
+        pub_row
+    ]
+    in_roster = (index >= 1) & (index <= n_row)
+    hi = vk_table[vk_off[pub_row] + np.where(in_roster, index, 0)]
+    struct_ok = in_roster & d.any(axis=1) & _be_rows_lt(d, p)
+    # the list verifier's d % p, z % q, e % q and -e % q, as byte rows
+    d = mod_rows(d, p)[:, 32 - nb :]
+    z32 = mod_rows(z, q)
+    e32 = mod_rows(e, q)
+    neg_e = mul_add_mod_rows(
+        e32,
+        np.tile(ints_to_be_rows([q - 1], 32), (n_ver, 1)),
+        np.zeros((n_ver, 32), dtype=np.uint8),
+        q,
+    )
+    base_col = np.repeat(
+        ints_to_be_rows([g[1] for g in groups], nb), counts, axis=0
+    )
+
+    # combine terms: memo hits now, the rest queued on the dispatch
+    values: List[Optional[int]] = [None] * len(groups)
+    co_values: List[int] = [0] * len(combine_only_sets)
+    queued: List[tuple] = []  # (store, slot, memo key)
+    term_d: List[int] = []
+    term_lam: List[np.ndarray] = []
+
+    def queue_combine(shares, store, slot) -> None:
+        xs, ds = _combine_subset(shares, threshold)
+        key = (gp, threshold, tuple(zip(xs, ds)))
+        hit = _COMBINE_MEMO.get(key)
+        if hit is not None:
+            store[slot] = hit
+            return
+        term_d.extend(x % p for x in ds)
+        term_lam.append(_lagrange_exp_rows(tuple(xs), q))
+        queued.append((store, slot, key))
+
+    for gi, (_pub, _base, shares, _context) in enumerate(groups):
+        if len(shares) >= threshold:
+            queue_combine(shares, values, gi)
+    for ci, shares in enumerate(combine_only_sets):
+        queue_combine(shares, co_values, ci)
+
+    n_term = len(term_d)
+    total = 2 * n_ver + n_term
+    u1 = np.zeros((total, 33), dtype=np.uint8)
+    e1 = np.zeros((total, 32), dtype=np.uint8)
+    u2 = np.zeros((total, 33), dtype=np.uint8)
+    e2 = np.zeros((total, 32), dtype=np.uint8)
+    # A1 = g^z * hi^{-e}
+    u1[:n_ver] = _be_to_le33(ints_to_be_rows([gp.g], nb))
+    e1[:n_ver] = z32
+    u2[:n_ver] = _be_to_le33(hi)
+    e2[:n_ver] = neg_e
+    # A2 = base^z * d^{-e}
+    u1[n_ver : 2 * n_ver] = _be_to_le33(base_col)
+    e1[n_ver : 2 * n_ver] = z32
+    u2[n_ver : 2 * n_ver] = _be_to_le33(d)
+    e2[n_ver : 2 * n_ver] = neg_e
+    # d^lambda * 1^0
+    if n_term:
+        u1[2 * n_ver :] = _be_to_le33(ints_to_be_rows(term_d, nb))
+        e1[2 * n_ver :] = np.concatenate(term_lam)
+        u2[2 * n_ver :, 0] = 1
+    a = eng.dual_pow_cols(u1, e1, u2, e2)
+
+    digs = _cp_challenge_cols(
+        [g[3] for g in groups],
+        counts,
+        (
+            base_col,
+            hi,
+            d,
+            a[:n_ver, nb - 1 :: -1],
+            a[n_ver : 2 * n_ver, nb - 1 :: -1],
+        ),
+        gp,
+    )
+    match = (mod_rows(digs, q) == e32).all(axis=1)
+    ok = struct_ok & match
+    verdicts = [
+        v.tolist() for v in np.split(ok, np.cumsum(counts))[:-1]
+    ]
+
+    terms = be_rows_to_ints(a[2 * n_ver :, nb - 1 :: -1])
+    off = 0
+    for store, slot, key in queued:
+        acc = 1
+        for term in terms[off : off + threshold]:
+            acc = acc * term % p
+        off += threshold
+        if len(_COMBINE_MEMO) >= _COMBINE_MEMO_CAP:
+            _COMBINE_MEMO.clear()
+        _COMBINE_MEMO[key] = acc
+        store[slot] = acc
+    return verdicts, values, co_values
+
+
 def verify_and_combine_share_groups(
     groups: Sequence[tuple],
     threshold: int,
@@ -583,9 +1034,13 @@ def verify_and_combine_share_groups(
     separately — the lockstep BBA's per-round critical path).
 
     ``groups`` is ``(pub, base, shares, context)`` as in
-    ``verify_share_groups``; returns ``(verdicts, values)`` where
-    ``values[i]`` is the combination of group i's shares (``None``
-    when the group has fewer than ``threshold`` shares).  Combination
+    ``verify_share_groups``, each ``shares`` a ``DhShare`` sequence or
+    a ``ShareColumns`` slice; returns ``(verdicts, values,
+    combine_only_values)`` where ``values[i]`` is the combination of
+    group i's shares (``None`` when the group has fewer than
+    ``threshold`` shares).  When every ``shares`` is ``ShareColumns``
+    (and the engine serves columns) the wave stays byte columns from
+    here to the device and back: no ``DhShare`` is made.  Combination
     does not wait for the verdicts — callers must discard the value
     of any group whose verdicts fail (the lockstep executor asserts
     them; the live path uses the unfused ops).  Results seed the
@@ -605,7 +1060,32 @@ def verify_and_combine_share_groups(
         "verify_combine_batch",
         groups=len(groups),
         combine_only=len(combine_only_sets),
-    ):
+    ) as sp:
+        made = _tally["shares_materialized"]
+        columnar = all(
+            isinstance(g[2], ShareColumns) for g in groups
+        ) and all(isinstance(cs, ShareColumns) for cs in combine_only_sets)
+        if columnar:
+            gps = [g[0].group for g in groups]
+            if combine_only_group is not None:
+                gps.append(combine_only_group)
+            columnar = all(
+                get_engine_degraded(backend, mesh, gp).columnar
+                for gp in dict.fromkeys(gps)
+            )
+        if not columnar:
+            # mixed or wide: the list form, on materialised rows
+            groups = [
+                (pub, base, _as_share_list(shares), context)
+                for pub, base, shares, context in groups
+            ]
+            combine_only_sets = [
+                _as_share_list(cs) for cs in combine_only_sets
+            ]
+        sp.note(
+            columnar=columnar,
+            materialized=_tally["shares_materialized"] - made,
+        )
         by_gp: Dict[GroupParams, List[int]] = {}
         for gi, (pub, _base, _shares, _context) in enumerate(groups):
             by_gp.setdefault(pub.group, []).append(gi)
@@ -628,6 +1108,19 @@ def verify_and_combine_share_groups(
         co_values: List[int] = [0] * len(combine_only_sets)
         for gp, idx_list in by_gp.items():
             eng = get_engine_degraded(backend, mesh, gp)
+            if columnar:
+                v, vals, co = _verify_combine_columns(
+                    gp,
+                    eng,
+                    [groups[gi] for gi in idx_list],
+                    threshold,
+                    combine_only_sets if gp == co_gp else (),
+                )
+                verdicts.update(zip(idx_list, v))
+                values.update(zip(idx_list, vals))
+                if gp == co_gp:
+                    co_values = co
+                continue
             # verification duals first (2 per share), then combine terms
             # (threshold per set) ride the same dispatch as u2^0 = 1
             # dummy-factor duals
@@ -916,21 +1409,14 @@ def combine_shares(
     group: GroupParams = DEFAULT_GROUP,
 ) -> int:
     """Lagrange-combine >= threshold verified shares into base^s."""
-    if len(shares) < threshold:
-        raise ValueError(
-            f"need >= {threshold} shares to combine, got {len(shares)}"
-        )
-    use = sorted(shares, key=lambda s: s.index)[:threshold]
-    xs = [s.index for s in use]
-    if len(set(xs)) != len(xs):
-        raise ValueError("duplicate share indices")
-    key = (group, threshold, tuple((s.index, s.d) for s in use))
+    xs, ds = _combine_subset(shares, threshold)
+    key = (group, threshold, tuple(zip(xs, ds)))
     hit = _COMBINE_MEMO.get(key)
     if hit is not None:
         return hit
     lams = lagrange_coeff_at_zero(xs, group.q)
     acc = 1
-    for term in host_pow_batch([sh.d % group.p for sh in use], lams, group):
+    for term in host_pow_batch([d % group.p for d in ds], lams, group):
         acc = acc * term % group.p
     if len(_COMBINE_MEMO) >= _COMBINE_MEMO_CAP:
         _COMBINE_MEMO.clear()
@@ -956,8 +1442,6 @@ def _keystream(key: bytes, length: int) -> bytes:
         # batch-size payloads (tens of KB per proposer): hash every
         # counter block in one native crossing — byte-identical to
         # the scalar loop below
-        from cleisthenes_tpu.ops.hashrows import sha256_rows
-
         k = len(key)
         rows = np.empty((n_blocks, k + 6), dtype=np.uint8)
         rows[:, :k] = np.frombuffer(key, dtype=np.uint8)
@@ -1104,6 +1588,11 @@ __all__ = [
     "deal",
     "issue_share",
     "issue_shares_batch",
+    "issue_share_columns",
+    "ShareColumns",
+    "ShareWave",
+    "share_tally",
+    "reset_share_tally",
     "verify_shares",
     "verify_share_groups",
     "verify_and_combine_share_groups",

@@ -66,8 +66,9 @@ from cleisthenes_tpu.core.batch import Batch
 from cleisthenes_tpu.ops.backend import get_backend
 from cleisthenes_tpu.ops.payload import join_payload, split_payload
 from cleisthenes_tpu.ops.tpke import (
+    ShareWave,
     combine_shares_batch,
-    issue_shares_batch,
+    issue_share_columns,
     verify_and_combine_share_groups,
 )
 from cleisthenes_tpu.protocol.honeybadger import (
@@ -333,16 +334,18 @@ class LockstepCluster:
             # and its combines ride round 0's fused verify/combine
             # dispatch: the whole wave costs ZERO extra device round-trips
             tpke_pub = self.tpke.pub
-            tpke_vks = tpke_pub.verification_keys
             cts = [deserialize_ciphertext(v, group) for v in delivered]
-            dec_items = []
-            for ct in cts:
-                context = self.tpke.context(ct)
-                for nid in ids:
-                    sec = self.keys[nid].tpke_share
-                    dec_items.append(
-                        (sec, ct.c1, context, tpke_vks[sec.index - 1])
-                    )
+            # a wave is (coin ids or ciphertexts) x nodes: described as
+            # that, issued and verified as byte columns (ops.tpke)
+            coin_secs = [self.keys[nid].coin_share for nid in ids]
+            coin_wave_vks = [coin_vks[s.index - 1] for s in coin_secs]
+            dec_secs = [self.keys[nid].tpke_share for nid in ids]
+            dec_wave = ShareWave(
+                dec_secs,
+                [tpke_pub.verification_keys[s.index - 1] for s in dec_secs],
+                [(ct.c1, self.tpke.context(ct)) for ct in cts],
+            )
+            n_dec = len(cts) * n
             # riding round 0 requires one shared Lagrange threshold;
             # distinct thresholds (non-default configs) fall back to a
             # separate decrypt wave after BBA
@@ -362,7 +365,6 @@ class LockstepCluster:
                     instances=len(inst_list),
                     dec=dec,
                 ) as wave:
-                    items = []
                     metas = []
                     for rnd in rnd_list:
                         for inst in inst_list:
@@ -371,17 +373,19 @@ class LockstepCluster:
                             )
                             pub, base, context = self.coin.group_params(coin_id)
                             metas.append((inst, rnd, coin_id, pub, base, context))
-                            for nid in ids:
-                                sec = self.keys[nid].coin_share
-                                items.append(
-                                    (sec, base, context, coin_vks[sec.index - 1])
-                                )
-                    n_coin = len(items)
+                    waves = [
+                        ShareWave(
+                            coin_secs,
+                            coin_wave_vks,
+                            [(base, context) for *_m, base, context in metas],
+                        )
+                    ]
+                    n_coin = len(metas) * n
                     if dec:
-                        items = items + dec_items
-                    wave.note(items=len(items))
-                    shares = issue_shares_batch(
-                        items, group=group, backend=backend, mesh=mesh
+                        waves.append(dec_wave)
+                    wave.note(items=n_coin + (n_dec if dec else 0))
+                    shares = issue_share_columns(
+                        waves, group=group, backend=backend, mesh=mesh
                     )
                     coin_issues += n_coin
                     if dec:
@@ -456,8 +460,8 @@ class LockstepCluster:
         t0 = time.perf_counter()
         with trace.span("lockstep", "decrypt"):
             if not fuse_dec:
-                dec_shares = issue_shares_batch(
-                    dec_items, group=group, backend=backend, mesh=mesh
+                dec_shares = issue_share_columns(
+                    [dec_wave], group=group, backend=backend, mesh=mesh
                 )
                 dec_subsets.extend(
                     dec_shares[i * n : i * n + tpke_pub.threshold]
@@ -478,7 +482,7 @@ class LockstepCluster:
                 plain = self.tpke.combine(ct, sub)  # memo hit + tag check
                 decrypted[ids[i]] = deserialize_txs(plain)
         stats["decrypt_s"] = time.perf_counter() - t0
-        stats["dec_issues"] = len(dec_items)
+        stats["dec_issues"] = n_dec
 
         # ---- commit: the reference dedup/ordering rule ----
         # (protocol.honeybadger._maybe_commit)

@@ -5,6 +5,9 @@ hosts)."""
 
 import random
 
+import numpy as np
+import pytest
+
 from cleisthenes_tpu.ops import modmath as mm
 from cleisthenes_tpu.ops import tpke as T
 
@@ -93,3 +96,203 @@ def test_pow_batch_grouped_device_path_with_splits_and_tails():
         for i in range(0, len(exps), 97):
             assert res[i] == pow(base, exps[i], p)
         assert res[-1] == pow(base, exps[-1], p)  # tail ordering
+
+
+# ---------------------------------------------------------------------------
+# the byte-column entry points: the int ones' results, floors, programs
+# ---------------------------------------------------------------------------
+
+# the sizes of test_pow_batch_grouped_device_path_with_splits_and_tails
+# (its four compiled comb shapes are reused): G_ROW splits with odd
+# tails, a padded bucket, a tiny group
+_GROUP_SIZES = (700, 1200, 100, 3)
+
+
+def _grouped_inputs(seed):
+    rnd = random.Random(seed)
+    p, q = mm.DEFAULT_GROUP.p, mm.DEFAULT_GROUP.q
+    return [
+        (rnd.randrange(2, p), [rnd.randrange(0, q) for _ in range(sz)])
+        for sz in _GROUP_SIZES
+    ]
+
+
+def _as_blocks(groups):
+    return [([base], mm.exps_to_bytes(exps)[None]) for base, exps in groups]
+
+
+def _engine_case(case, monkeypatch):
+    if case == "cpu":
+        return mm.ModEngine("cpu")
+    if case == "cpu_no_native":
+        eng = mm.ModEngine("cpu")
+        eng._nat = None  # python pow(), as without a toolchain
+        return eng
+    if case == "tpu_under_floor":
+        # 2003 exponentiations: over the comb's floor only with a
+        # native host kernel to beat, so pin the floor above them
+        monkeypatch.setattr(mm.ModEngine, "HOST_FLOOR", 6 * 4096)
+        return mm.ModEngine("tpu")
+    assert case == "tpu_device"
+    monkeypatch.setattr(mm.ModEngine, "host_delegation", False)
+    return mm.ModEngine("tpu")
+
+
+@pytest.mark.parametrize(
+    "case", ["cpu", "cpu_no_native", "tpu_under_floor", "tpu_device"]
+)
+def test_column_entry_points_match_int_entry_points(case, monkeypatch):
+    """pow / dual_pow / grouped on byte columns give the int entry
+    points' values on every backend: the native host kernel, python
+    pow(), the 'tpu' engine under its floors and the XLA kernels —
+    across the G_ROW split, odd tails and padded buckets."""
+    eng = _engine_case(case, monkeypatch)
+    rnd = random.Random(23)
+    p, q = mm.DEFAULT_GROUP.p, mm.DEFAULT_GROUP.q
+    n = 11  # pads to the 16 bucket on the device
+    u1 = [rnd.randrange(1, p) for _ in range(n)]
+    u2 = [rnd.randrange(1, p) for _ in range(n)]
+    e1 = [rnd.randrange(0, q) for _ in range(n)]
+    e2 = [0, 1] + [rnd.randrange(0, q) for _ in range(n - 2)]
+    cols = (
+        mm.ints_to_bytes33(u1), mm.exps_to_bytes(e1),
+        mm.ints_to_bytes33(u2), mm.exps_to_bytes(e2),
+    )
+    assert mm.bytes33_to_ints(eng.pow_cols(cols[0], cols[1])) == (
+        eng.pow_batch(u1, e1)
+    )
+    assert mm.bytes33_to_ints(eng.dual_pow_cols(*cols)) == (
+        eng.dual_pow_batch(u1, e1, u2, e2)
+    )
+    groups = _grouped_inputs(29)
+    want = eng.pow_batch_grouped(groups)
+    got = eng.pow_grouped_cols(_as_blocks(groups))
+    assert [mm.bytes33_to_ints(g[0]) for g in got] == want
+    for (base, exps), res in zip(groups, want):  # and both are right
+        assert res[-1] == pow(base, exps[-1], p)
+    # a block of several rows is that many groups of one size
+    b3, e3 = groups[2]
+    b4 = rnd.randrange(2, p)
+    two = eng.pow_grouped_cols(
+        [([b3, b4], np.stack([mm.exps_to_bytes(e3)] * 2))] + _as_blocks(
+            [groups[0], groups[1], groups[3]]
+        )
+    )[0]
+    assert mm.bytes33_to_ints(two[0]) == want[2]
+    assert mm.bytes33_to_ints(two[1])[::37] == [
+        pow(b4, e, p) for e in e3[::37]
+    ]
+    assert eng.pow_grouped_cols([]) == []
+    assert eng.pow_cols(cols[0][:0], cols[1][:0]).shape == (0, 33)
+
+
+@pytest.mark.parametrize("side", ["device", "host"])
+def test_column_entry_points_add_no_program_and_tally_alike(
+    side, monkeypatch
+):
+    """After the int call at a shape, the column call at that shape
+    compiles nothing and tallies the same families, calls and items:
+    the warm-up through the int entry points covers the column path,
+    and the roofline's work count cannot tell the two apart."""
+    from cleisthenes_tpu.ops import placement
+
+    if side == "device":
+        monkeypatch.setattr(mm.ModEngine, "host_delegation", False)
+    else:
+        monkeypatch.setattr(mm.ModEngine, "HOST_FLOOR", 6 * 4096)
+    eng = mm.ModEngine("tpu")
+    groups = _grouped_inputs(31)
+    rnd = random.Random(37)
+    p, q = mm.DEFAULT_GROUP.p, mm.DEFAULT_GROUP.q
+    u = [rnd.randrange(1, p) for _ in range(11)]
+    e = [rnd.randrange(0, q) for _ in range(11)]
+    ub, eb = mm.ints_to_bytes33(u), mm.exps_to_bytes(e)
+
+    def int_calls():
+        eng.pow_batch_grouped(groups)
+        eng.pow_batch(u, e)
+        eng.dual_pow_batch(u, e, u, e)
+
+    def col_calls():
+        eng.pow_grouped_cols(_as_blocks(groups))
+        eng.pow_cols(ub, eb)
+        eng.dual_pow_cols(ub, eb, ub, eb)
+
+    placement.reset()
+    int_calls()
+    by_ints = placement.snapshot()
+    programs = (mm._pow_fused_grouped, mm._dual_pow_fused, mm._pow_fused)
+    compiled = [f._cache_size() for f in programs]
+    placement.reset()
+    col_calls()
+    assert placement.snapshot() == by_ints
+    assert [f._cache_size() for f in programs] == compiled
+    fam = by_ints["modexp_12x22.comb"]
+    calls = "device_calls" if side == "device" else "host_calls"
+    assert fam[calls] > 0 and fam[calls.replace("calls", "items")] == sum(
+        _GROUP_SIZES
+    )
+
+
+# ---------------------------------------------------------------------------
+# the scalar field on byte rows (a CP proof's arithmetic mod q)
+# ---------------------------------------------------------------------------
+
+
+def _edge_rows(width, modulus, rnd, m=64):
+    rows = np.frombuffer(
+        rnd.randbytes(m * width), dtype=np.uint8
+    ).reshape(m, width).copy()
+    rows[0] = 0
+    rows[1] = 255  # 2^(8 width) - 1
+    top = 1 << (8 * width)
+    for i, x in enumerate((modulus - 1, modulus, modulus + 1, 2 * modulus)):
+        if x < top:
+            rows[2 + i] = np.frombuffer(
+                x.to_bytes(width, "big"), dtype=np.uint8
+            )
+    return rows
+
+
+@pytest.mark.parametrize("native", [True, False])
+@pytest.mark.parametrize(
+    "modulus", [mm.Q, mm.P, 1000003, 3, 1 << 200, mm.GROUP384.q],
+    ids=["q", "p", "small", "three", "even", "wide"],
+)
+def test_scalar_rows_match_python_ints(modulus, native, monkeypatch):
+    """mod_rows / mul_add_mod_rows are ``%`` and ``(a*b+c) %`` row by
+    row, for every width up to the nonce's 64 bytes, at the edges of
+    the modulus, with the native kernel and without it, and for moduli
+    the kernel does not take (even, wider than 256 bits)."""
+    from cleisthenes_tpu.ops.hashrows import be_rows_to_ints
+
+    if native:
+        if mm._native_modpow() is None:
+            pytest.skip("no native toolchain")
+    else:
+        monkeypatch.setattr(mm, "_native_modpow", lambda: None)
+    rnd = random.Random(41)
+    if modulus.bit_length() > 256:
+        # a residue would not fit the 32-byte rows: refused, loudly
+        rows = _edge_rows(48, modulus, rnd)
+        with pytest.raises(ValueError):
+            mm.mod_rows(rows, modulus)
+        with pytest.raises(ValueError):
+            mm.mul_add_mod_rows(rows[:, :32], rows[:, :32], rows[:, :32], modulus)
+        return
+    for width in (1, 31, 32, 33, 40, 64, 72):
+        rows = _edge_rows(width, modulus, rnd)
+        got = mm.mod_rows(rows, modulus)
+        assert got.shape == (len(rows), 32)
+        assert be_rows_to_ints(got) == [
+            x % modulus for x in be_rows_to_ints(rows)
+        ]
+    a, b, c = (_edge_rows(32, modulus, rnd) for _ in range(3))
+    a[1], b[1], c[1] = 255, 255, 255
+    got = mm.mul_add_mod_rows(a, b, c, modulus)
+    assert be_rows_to_ints(got) == [
+        (x * y + z) % modulus
+        for x, y, z in zip(*(be_rows_to_ints(col) for col in (a, b, c)))
+    ]
+    assert mm.mod_rows(a[:0], modulus).shape == (0, 32)
+    assert mm.mul_add_mod_rows(a[:0], b[:0], c[:0], modulus).shape == (0, 32)

@@ -187,3 +187,55 @@ def test_lockstep_reduced_quorum_roster():
         c.submit(_tx(i))
     c.run_epochs()
     assert _committed_txs(c.committed()) == {_tx(i) for i in range(20)}
+
+
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_lockstep_columnar_waves_match_list_driven(backend, monkeypatch):
+    """The executor's waves as byte columns (ShareColumns from issue
+    to verify to combine) commit the batches, and take the BBA rounds,
+    of the same cluster driven through the list entry points — and
+    issue no share as a list, make no DhShare."""
+    from cleisthenes_tpu.ops import tpke
+    from cleisthenes_tpu.ops.modmath import ModEngine
+    from cleisthenes_tpu.protocol import spmd
+
+    if backend == "tpu":  # toy waves sit under the floors: pin the kernels
+        monkeypatch.setattr(ModEngine, "host_delegation", False)
+
+    def run():
+        c = LockstepCluster(
+            n=5, batch_size=40, key_seed=9, crypto_backend=backend
+        )
+        for i in range(80):
+            c.submit(_tx(i))
+        rounds = []
+        while c.pending_tx_count():
+            rounds.append(c.run_epoch()["bba_rounds"])
+        return [b.contributions for b in c.committed()], rounds
+
+    tpke.reset_share_tally()
+    columnar = run()
+    tally = tpke.share_tally()
+    assert tally["shares_issued_listed"] == 0
+    assert tally["shares_materialized"] == 0
+    assert tally["shares_issued_columnar"] > 0
+
+    def issue_listed(waves, group, backend, mesh):
+        return tpke.issue_shares_batch(
+            [
+                (sec, base, context, vk)
+                for w in waves
+                for base, context in w.pairs
+                for sec, vk in zip(w.secrets, w.vks)
+            ],
+            group=group, backend=backend, mesh=mesh,
+        )
+
+    monkeypatch.setattr(spmd, "issue_share_columns", issue_listed)
+    tpke.reset_share_tally()
+    listed = run()
+    tally = tpke.share_tally()
+    assert tally["shares_issued_columnar"] == 0
+    assert tally["shares_issued_listed"] == tally["shares_materialized"] > 0
+    assert columnar == listed
+    assert len(columnar[1]) >= 2 and all(r >= 1 for r in columnar[1])

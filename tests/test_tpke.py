@@ -330,3 +330,240 @@ class TestFusedVerifyCombine:
             [(pub, base, bad, ctx)], 2
         )
         assert v[0] == [True, True, False, True, True]
+
+
+class TestShareColumns:
+    """A wave as byte columns (ShareColumns) from issue to verify to
+    combine: the list path's shares, transcripts, verdicts and values,
+    with no DhShare made on the way."""
+
+    N, T = 7, 3
+
+    def _wave(self, seed, k=4, tag=b"w"):
+        pub, secs = tpke.deal(n=self.N, threshold=self.T, seed=seed)
+        pairs = [
+            # mixed context lengths: the transcript's group-by-length
+            (tpke.hash_to_group(b"%s|%d" % (tag, j)), b"c|%d" % (10 ** j))
+            for j in range(k)
+        ]
+        vks = [pub.verification_keys[s.index - 1] for s in secs]
+        return pub, secs, tpke.ShareWave(secs, vks, pairs)
+
+    def _groups(self, pub, wave, shares, rows=None):
+        n = len(wave.secrets)
+        rows = n if rows is None else rows
+        return [
+            (pub, base, shares[j * n : j * n + rows], ctx)
+            for j, (base, ctx) in enumerate(wave.pairs)
+        ]
+
+    @pytest.mark.parametrize("backend", ["cpu", "tpu"])
+    def test_columnar_issue_is_the_list_issue(self, backend):
+        """Same d as host_pow, index order as documented, and every
+        columnar share passes the LIST verifier."""
+        pub, secs, wave = self._wave(61)
+        tpke.reset_share_tally()
+        cols = tpke.issue_share_columns([wave], backend=backend)
+        assert tpke.share_tally() == {
+            "shares_issued_columnar": 4 * self.N,
+            "shares_issued_listed": 0,
+            "shares_materialized": 0,
+        }
+        assert len(cols) == 4 * self.N
+        assert cols.d.shape == (4 * self.N, mm.DEFAULT_GROUP.nbytes)
+        shares = cols.to_shares()
+        assert tpke.share_tally()["shares_materialized"] == 4 * self.N
+        for j, (base, _ctx) in enumerate(wave.pairs):
+            for i, sec in enumerate(secs):
+                sh = shares[j * self.N + i]
+                assert sh.index == sec.index
+                assert sh.d == mm.host_pow(base, sec.value)
+        assert tpke.verify_share_groups(
+            self._groups(pub, wave, shares), backend
+        ) == [[True] * self.N] * 4
+
+    def test_list_issued_shares_pass_the_columnar_verifier(self):
+        pub, secs, wave = self._wave(62)
+        listed = tpke.issue_shares_batch(
+            [
+                (sec, base, ctx, vk)
+                for base, ctx in wave.pairs
+                for sec, vk in zip(wave.secrets, wave.vks)
+            ]
+        )
+        cols = tpke.ShareColumns.from_shares(listed)
+        v, vals, _ = tpke.verify_and_combine_share_groups(
+            self._groups(pub, wave, cols), self.T
+        )
+        assert v == [[True] * self.N] * 4
+        assert vals == tpke.combine_shares_batch(
+            [g[2] for g in self._groups(pub, wave, listed, self.T)], self.T
+        )
+
+    def test_two_waves_in_one_dispatch_and_the_empty_wave(self):
+        """The lockstep round 0 shape: a coin wave and a decrypt wave
+        under different key sets, one call, rows wave after wave."""
+        pub_a, _sa, wave_a = self._wave(63, k=2, tag=b"a")
+        pub_b, _sb, wave_b = self._wave(64, k=3, tag=b"b")
+        cols = tpke.issue_share_columns([wave_a, wave_b])
+        na = 2 * self.N
+        assert len(cols) == 5 * self.N
+        shares = cols.to_shares()
+        assert tpke.verify_share_groups(
+            self._groups(pub_a, wave_a, shares[:na])
+            + self._groups(pub_b, wave_b, shares[na:])
+        ) == [[True] * self.N] * 5
+        assert len(tpke.issue_share_columns([])) == 0
+        assert len(tpke.issue_share_columns([wave_a._replace(pairs=[])])) == 0
+
+    @pytest.mark.parametrize("m", [5, 100])
+    def test_cp_challenge_cols_is_hash_to_int(self, m):
+        """The column transcript is byte-identical to _hash_to_int's,
+        over mixed context lengths and runs, on both sides of the int
+        form's 64-row line."""
+        import numpy as np
+        import secrets as _s
+
+        from cleisthenes_tpu.ops.hashrows import be_rows_to_ints
+
+        gp = mm.DEFAULT_GROUP
+        nb = gp.nbytes
+        reps = [(3, 1, 2, 4)[j % 4] for j in range(m)]
+        ctxs = [b"ctx|%d" % (10 ** (j % 5)) for j in range(m)]
+        rows = sum(reps)
+        cols = [
+            np.frombuffer(_s.token_bytes(rows * nb), dtype=np.uint8)
+            .reshape(rows, nb)
+            for _ in range(5)
+        ]
+        digs = tpke._cp_challenge_cols(ctxs, reps, cols, gp)
+        assert digs.shape == (rows, 32)
+        row_ctx = [c for c, r in zip(ctxs, reps) for _ in range(r)]
+        want = [
+            tpke._hash_to_int(
+                b"cp", row_ctx[i], *(col[i].tobytes() for col in cols)
+            )
+            for i in range(rows)
+        ]
+        assert be_rows_to_ints(digs) == want
+        # and the int form, now a wrapper on the same layout
+        ints = [be_rows_to_ints(col) for col in cols]
+        assert tpke._cp_challenge_batch(row_ctx, *ints, gp) == [
+            w % gp.q for w in want
+        ]
+
+    def test_to_shares_round_trips_and_slices_are_views(self):
+        _pub, _secs, wave = self._wave(65)
+        cols = tpke.issue_share_columns([wave])
+        shares = cols.to_shares()
+        back = tpke.ShareColumns.from_shares(shares)
+        for name in ("index", "d", "e", "z"):
+            assert (getattr(back, name) == getattr(cols, name)).all()
+        part = cols[self.N : self.N + 3]
+        assert len(part) == 3 and part.d.base is not None
+        assert part.to_shares() == shares[self.N : self.N + 3]
+        assert part.group is cols.group
+
+    @pytest.mark.parametrize("backend", ["cpu", "tpu"])
+    def test_columnar_verify_combine_is_the_list_form(self, backend):
+        pub, _secs, wave = self._wave(66)
+        cols = tpke.issue_share_columns([wave], backend=backend)
+        listed = cols.to_shares()
+        co = [cols[0 : self.T], cols[self.N + 2 : self.N + 2 + self.T]]
+        co_listed = [listed[0 : self.T], listed[self.N + 2 : self.N + 2 + self.T]]
+        tpke._COMBINE_MEMO.clear()
+        want = tpke.verify_and_combine_share_groups(
+            self._groups(pub, wave, listed, self.T + 1), self.T,
+            backend=backend, combine_only_sets=co_listed,
+        )
+        tpke._COMBINE_MEMO.clear()
+        tpke.reset_share_tally()
+        got = tpke.verify_and_combine_share_groups(
+            self._groups(pub, wave, cols, self.T + 1), self.T,
+            backend=backend, combine_only_sets=co,
+        )
+        assert got == want
+        assert all(all(v) for v in got[0])
+        assert tpke.share_tally()["shares_materialized"] == 0
+        # the memo is seeded under the list form's keys: a combine on
+        # either form of the subset is a pure hit
+        g0 = self._groups(pub, wave, cols, self.T + 1)[0][2]
+        assert tpke.combine_shares(g0, self.T) == got[1][0]
+        assert tpke.combine_shares(g0.to_shares(), self.T) == got[1][0]
+        # a group under the threshold has no value; combine-only alone
+        # needs its group named
+        v, vals, _ = tpke.verify_and_combine_share_groups(
+            self._groups(pub, wave, cols, self.T - 1), self.T
+        )
+        assert vals == [None] * 4 and all(all(x) for x in v)
+        with pytest.raises(ValueError):
+            tpke.verify_and_combine_share_groups(
+                [], self.T, combine_only_sets=co
+            )
+        assert tpke.verify_and_combine_share_groups(
+            [], self.T, combine_only_sets=co,
+            combine_only_group=mm.DEFAULT_GROUP,
+        )[2] == want[2]
+
+    FORGERIES = {
+        "wrong_e": lambda sh, p: sh._replace(e=sh.e ^ 1),
+        "wrong_z": lambda sh, p: sh._replace(z=sh.z + 1),
+        "wrong_d": lambda sh, p: sh._replace(d=sh.d * 4 % p),
+        "d_zero": lambda sh, p: sh._replace(d=0),
+        "d_over_p": lambda sh, p: sh._replace(d=sh.d + p),
+        "index_zero": lambda sh, p: sh._replace(index=0),
+        "index_past_n": lambda sh, p: sh._replace(index=8),
+    }
+
+    @pytest.mark.parametrize("kind", sorted(FORGERIES))
+    def test_columnar_verifier_reads_each_forgery_false(self, kind):
+        """Every forgery kind reads False in the columnar form, as in
+        the list form, and only at its own row."""
+        pub, secs, wave = self._wave(67, k=2)
+        bad_at = 2
+        if kind == "d_over_p":
+            # d + p passes every check but 0 < d < p, and fits the
+            # column's 32 bytes for one d in ~330: find such a base
+            for j in range(10_000):
+                base = tpke.hash_to_group(b"small-d|%d" % j)
+                if mm.host_pow(base, secs[bad_at].value) + mm.P < 1 << 256:
+                    break
+            wave = wave._replace(pairs=[(base, b"c|x"), wave.pairs[1]])
+        listed = tpke.issue_share_columns([wave]).to_shares()
+        bad = list(listed)
+        bad[bad_at] = self.FORGERIES[kind](listed[bad_at], mm.P)
+        want = [[True] * self.N, [True] * self.N]
+        want[0][bad_at] = False
+        groups = self._groups(pub, wave, bad)
+        assert tpke.verify_share_groups(groups) == want
+        v, _vals, _ = tpke.verify_and_combine_share_groups(
+            self._groups(pub, wave, tpke.ShareColumns.from_shares(bad)),
+            self.T,
+        )
+        assert v == want
+
+    def test_columnar_combine_rejects_duplicate_indices(self):
+        pub, _secs, wave = self._wave(68, k=1)
+        cols = tpke.issue_share_columns([wave])
+        dup = cols[[0, 0, 1, 2]]
+        with pytest.raises(ValueError, match="duplicate"):
+            tpke.verify_and_combine_share_groups(
+                [(pub, wave.pairs[0][0], dup, wave.pairs[0][1])], self.T
+            )
+        with pytest.raises(ValueError, match="duplicate"):
+            tpke.combine_shares(dup, self.T)
+        with pytest.raises(ValueError, match="need >="):
+            tpke.combine_shares(cols[:2], self.T)
+
+    def test_mixed_forms_fall_back_to_the_list_form(self):
+        """Columns beside a DhShare list in one call: the columns are
+        materialised, the answer is the list form's."""
+        pub, _secs, wave = self._wave(69, k=2)
+        cols = tpke.issue_share_columns([wave])
+        groups = self._groups(pub, wave, cols)
+        mixed = [groups[0], groups[1][:2] + (groups[1][2].to_shares(),) + groups[1][3:]]
+        tpke.reset_share_tally()
+        v, vals, _ = tpke.verify_and_combine_share_groups(mixed, self.T)
+        assert v == [[True] * self.N] * 2
+        assert tpke.share_tally()["shares_materialized"] == self.N
+        assert vals == tpke.verify_and_combine_share_groups(groups, self.T)[1]

@@ -374,6 +374,104 @@ def test_family_spans_on_both_sides_of_its_floor(family, session, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# a share wave as byte columns: the same spans, and args that say so
+# ---------------------------------------------------------------------------
+
+
+def _find(nodes, name):
+    out = []
+    for node in nodes:
+        if node[0] == name:
+            out.append(node)
+        out.extend(_find(node[2], name))
+    return out
+
+
+@pytest.mark.parametrize("side", ["device", "host"])
+def test_columnar_wave_opens_the_spans_the_metrics_read(
+    side, session, monkeypatch
+):
+    """tpke_host_pct reads tpke/issue_batch, cp_challenge and
+    verify_combine_batch; ops_marshal_pct / ops_device_wait_pct /
+    ops_host_kernel_pct read ops/pack, unpack, device, host.  The
+    columnar path opens all of them, with ``columnar`` and
+    ``materialized`` on the two batch spans and the tally beside."""
+    from cleisthenes_tpu.ops import modmath, tpke
+
+    monkeypatch.setattr(
+        modmath.ModEngine, "HOST_FLOOR", 16 if side == "device" else 8192
+    )
+    pub, secs = tpke.deal(n=4, threshold=2, seed=71)
+    wave = tpke.ShareWave(
+        secs,
+        [pub.verification_keys[s.index - 1] for s in secs],
+        [(tpke.hash_to_group(b"sp|%d" % j), b"sp|%d" % j) for j in range(8)],
+    )
+    tpke.reset_share_tally()
+    tpke._COMBINE_MEMO.clear()
+    cols = tpke.issue_share_columns([wave], backend="tpu")
+    groups = [
+        (pub, base, cols[j * 4 : j * 4 + 3], ctx)
+        for j, (base, ctx) in enumerate(wave.pairs)
+    ]
+    verdicts, values, _ = tpke.verify_and_combine_share_groups(
+        groups, 2, backend="tpu"
+    )
+    assert all(all(v) for v in verdicts) and None not in values
+    assert tpke.share_tally() == {
+        "shares_issued_columnar": 32,
+        "shares_issued_listed": 0,
+        "shares_materialized": 0,
+    }
+    tree = span_tree(FakeAnnotation.log)
+    assert [n[0] for n in tree] == [
+        "tpke/issue_batch", "tpke/verify_combine_batch",
+    ]
+    issue, verify = tree
+    assert issue[1]["items"] == 32
+    assert issue[1]["columnar"] is True and issue[1]["materialized"] == 0
+    assert verify[1]["columnar"] is True and verify[1]["materialized"] == 0
+    assert verify[1]["groups"] == 8
+    for node, family, items in (
+        (issue, "ops/modexp_12x22.comb", 96),
+        (verify, "ops/modexp_12x22.dual_pow", 2 * 24 + 8 * 2),
+    ):
+        assert len(_find([node], "tpke/cp_challenge")) == 1
+        fams = _find([node], family)
+        assert sum(f[1]["items"] for f in fams) == items
+        for fam in fams:
+            kinds = {c[0] for c in fam[2]}
+            if side == "device":
+                assert fam[1]["on_device"] is True
+                assert "ops/device" in kinds
+                assert kinds <= {"ops/pack", "ops/device", "ops/unpack"}
+            else:
+                assert fam[1]["on_device"] is False
+                assert kinds == {"ops/host"}
+    if side == "device":  # the comb packs and unpacks inside its batch
+        assert _find([issue], "ops/pack") and _find([issue], "ops/unpack")
+    # the list entry point says what it is, and what it made
+    FakeAnnotation.log = []
+    tpke.issue_shares_batch(
+        [(secs[0], wave.pairs[0][0], b"x", wave.vks[0])], backend="tpu"
+    )
+    (listed,) = span_tree(FakeAnnotation.log)
+    assert listed[0] == "tpke/issue_batch"
+    assert listed[1]["columnar"] is False and listed[1]["materialized"] == 1
+    assert tpke.share_tally()["shares_issued_listed"] == 1
+    # a materialised slice is counted, call by call
+    FakeAnnotation.log = []
+    tpke.verify_and_combine_share_groups(
+        [groups[0][:2] + (groups[0][2].to_shares(),) + groups[0][3:]],
+        2,
+        backend="tpu",
+    )
+    (mixed,) = span_tree(FakeAnnotation.log)
+    assert mixed[1]["columnar"] is False and mixed[1]["materialized"] == 0
+    assert tpke.share_tally()["shares_materialized"] == 1 + 3
+
+
+# ---------------------------------------------------------------------------
 # the lockstep executor under a real profiler session, on the CPU platform
 # ---------------------------------------------------------------------------
 
@@ -432,6 +530,7 @@ def test_lockstep_epoch_in_a_real_profile(tmp_path, monkeypatch):
         assert key in got, key
     assert got["lockstep/epoch"]["calls"] == 1
     assert got["lockstep/coin_wave"]["calls"] == stats["coin_waves"]
+    assert got["tpke/issue_batch"]["calls"] == stats["coin_waves"]
     # self times partition the epoch, and the spans see all of it
     covered = sum(row["self_s"] for row in got.values())
     assert covered == pytest.approx(got["lockstep/epoch"]["total_s"])
@@ -453,7 +552,16 @@ def test_lockstep_epoch_in_a_real_profile(tmp_path, monkeypatch):
     assert tracetool.profile_span_names(str(tmp_path)) == set(got)
     reduced = tracetool.device_gaps(str(tmp_path), window="lockstep/epoch")
     assert reduced["window_s"] == pytest.approx(epochs[0][2] * 1e-9)
-    assert "window" in tracetool.device_gaps_report(reduced)
+    report = tracetool.device_gaps_report(reduced)
+    assert "window" in report
+    # the waves stayed byte columns, and the profile says so
+    tally = reduced["share_tally"]
+    assert tally["shares_issued_columnar"] == (
+        stats["coin_issues"] + stats["dec_issues"]
+    )
+    assert tally["shares_issued_listed"] == 0
+    assert tally["shares_materialized"] == 0
+    assert "issued_columnar %d" % tally["shares_issued_columnar"] in report
 
 
 # ---------------------------------------------------------------------------

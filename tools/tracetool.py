@@ -468,9 +468,8 @@ def capture(
 # ---------------------------------------------------------------------------
 
 
-def profile_span_names(log_dir: str) -> set:
-    """Every host annotation of the newest profile under ``log_dir``
-    that is a program span: ``cat/name`` with a known category."""
+def _host_events(log_dir: str):
+    """The host planes' events of the newest profile under ``log_dir``."""
     import glob
     import os
 
@@ -482,14 +481,41 @@ def profile_span_names(log_dir: str) -> set:
     if not paths:
         raise FileNotFoundError(f"no .xplane.pb under {log_dir}")
     data = ProfileData.from_file(max(paths, key=os.path.getmtime))
+    for plane in data.planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                yield from line.events
+
+
+def profile_span_names(log_dir: str) -> set:
+    """Every host annotation of the newest profile under ``log_dir``
+    that is a program span: ``cat/name`` with a known category."""
     return {
         ev.name
-        for plane in data.planes
-        if plane.name.startswith("/host:")
-        for line in plane.lines
-        for ev in line.events
+        for ev in _host_events(log_dir)
         if "/" in ev.name and ev.name.split("/", 1)[0] in CATEGORIES
     }
+
+
+def share_tally(log_dir: str) -> Dict[str, int]:
+    """``ops.tpke.share_tally()`` as the profile saw it: shares issued
+    as byte columns and as lists (the ``items`` and ``columnar`` args
+    of ``tpke/issue_batch``) and ``DhShare`` objects made inside the
+    two batch spans (their ``materialized`` args)."""
+    out = {
+        "shares_issued_columnar": 0,
+        "shares_issued_listed": 0,
+        "shares_materialized": 0,
+    }
+    for ev in _host_events(log_dir):
+        if ev.name not in ("tpke/issue_batch", "tpke/verify_combine_batch"):
+            continue
+        args = dict(ev.stats)
+        out["shares_materialized"] += int(args.get("materialized", 0))
+        if ev.name == "tpke/issue_batch":
+            how = "columnar" if args.get("columnar") else "listed"
+            out["shares_issued_" + how] += int(args.get("items", 0))
+    return out
 
 
 def device_gaps(log_dir: str, window: Optional[str] = None) -> dict:
@@ -502,7 +528,9 @@ def device_gaps(log_dir: str, window: Optional[str] = None) -> dict:
 
     keep = profile_span_names(log_dir) | set(SPAN_NAMES)
     trace = trace_reduce.load_xplane(log_dir, keep)
-    return trace_reduce.reduce(trace, window or SPAN_NAMES[0])
+    reduced = trace_reduce.reduce(trace, window or SPAN_NAMES[0])
+    reduced["share_tally"] = share_tally(log_dir)
+    return reduced
 
 
 def device_gaps_report(reduced: dict) -> str:
@@ -518,6 +546,12 @@ def device_gaps_report(reduced: dict) -> str:
     lines += [f"  {s:12.6f}  {name}" for name, s in reduced["idle_gaps"]]
     rest = window - busy - sum(s for _name, s in reduced["idle_gaps"])
     lines.append(f"  {max(rest, 0.0):12.6f}  (every other span)")
+    tally = reduced.get("share_tally")
+    if tally and any(tally.values()):
+        lines.append(
+            "threshold shares in the profile: "
+            + ", ".join(f"{k[7:]} {v}" for k, v in tally.items())
+        )
     return "\n".join(lines)
 
 
