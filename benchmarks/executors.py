@@ -25,9 +25,14 @@ from typing import Callable, Dict, List, Optional, Sequence
 from benchmarks.traffic import KIND_WARM, Arrival, TxSource
 
 DRAIN_LIMIT_S = 60.0  # an answer may come a minute late, not never
+# ... or, where rounds are long, this many of the longest round: what a
+# drain has to settle is the held backlog (``backlog_batches``, 2) and
+# the ``pipeline_depth`` (2) epochs in flight, and two rounds to spare
+DRAIN_ROUNDS = 6
 # warm-up rounds of the served path, as shares of a full batch: full
 # ones, then a ramp down, since the device's RS decode compiles one
-# program per shard length and only nearly full batches reach it
+# program per shard length and only nearly full batches reach it.  A
+# configuration's file may give its own as ``warm_up_fills``
 WARMUP_FILLS = (1.0, 1.0, 0.97, 0.94, 0.91)
 LOCKSTEP_WARMUP_MAX = 12
 LOCKSTEP_WARMUP_CLEAN = 2
@@ -36,6 +41,56 @@ COMB_FILLER = (8, 512)  # groups, exponents a group
 
 def _no_tick(_now: float) -> None:
     return None
+
+
+def drain_limit_s(longest_round_s: float) -> float:
+    """How long a drain may take: DRAIN_LIMIT_S, or DRAIN_ROUNDS of the
+    longest round where that is more.  Rounds are never cut, so the
+    limit only decides whether another one starts."""
+    return max(DRAIN_LIMIT_S, DRAIN_ROUNDS * longest_round_s)
+
+
+class RoundClock:
+    """The longest of the loop's iterations (served round, lockstep
+    epoch) timed so far, warm-up's and the window's; 0.0 before the
+    first.  One that met a compilation is left out: it says how long
+    the compiler took, not how long a round is."""
+
+    def __init__(self, meter) -> None:
+        self._meter = meter
+        self.longest_s = 0.0
+
+    @contextlib.contextmanager
+    def timed(self):
+        compiles = self._meter.count
+        t0 = time.perf_counter()
+        yield
+        if self._meter.count == compiles:
+            self.longest_s = max(self.longest_s, time.perf_counter() - t0)
+
+
+def warm_shapes(crypto, group, shapes: Dict) -> None:
+    """Run each exponentiation program the cell can meet once, at the
+    sizes the configuration's file lists (``warm_shapes``: the share
+    and coin waves' sizes move with the BBA round count, each size
+    bucket is a program, and the rare ones would otherwise be met
+    first inside a window).  Through the engine's own entry points."""
+    from cleisthenes_tpu.ops import modmath
+
+    eng = modmath.get_engine(crypto.engine_backend, crypto.mesh, group)
+    # a grouped call is split by group size, so a small shape rides
+    # with a filler of COMB_FILLER that lifts the call over the
+    # comb's host floor
+    filler = [(group.g, [3] * COMB_FILLER[1])] * COMB_FILLER[0]
+    for groups, exps in shapes.get("comb", ()):
+        call = [(group.g, [3] * exps)] * groups
+        if exps != COMB_FILLER[1]:
+            call = call + filler
+        eng.pow_batch_grouped(call)
+    for rows in shapes.get("dual_pow", ()):
+        eng.dual_pow_batch(
+            [group.g] * rows, [3] * rows, [group.g] * rows, [5] * rows
+        )
 
 
 class Spans:
@@ -78,10 +133,12 @@ class Served:
         self.spans = spans
         self.meter = meter
         self.cfg = _config(cell.config, seed)
-        extra = dict(cell.config.get("cluster", {}))
+        # the schedule's seed and the keys' come from --seed, unless the
+        # configuration's file fixes one (``cluster.key_seed``)
+        kwargs = {"seed": seed, "key_seed": seed}
+        kwargs.update(cell.config.get("cluster", {}))
         self.cluster = SimulatedCluster(
-            config=self.cfg, seed=seed, key_seed=seed, auto_propose=False,
-            **extra,
+            config=self.cfg, auto_propose=False, **kwargs
         )
         self.ids: List[str] = list(self.cluster.ids)
         self._nodes = [self.cluster.nodes[nid] for nid in self.ids]
@@ -98,6 +155,7 @@ class Served:
         self.timed: List[bytes] = []
         self.timed_ok: List[bool] = []
         self.rounds = 0
+        self.clock = RoundClock(meter)
 
     # -- driving -------------------------------------------------------
 
@@ -133,22 +191,23 @@ class Served:
     def _round(self, between: Callable[[], None]) -> None:
         spans = self.spans
         net = self.cluster.net
-        with spans("start_epoch"):
-            for hb in self._nodes:
-                hb.start_epoch()
-        while True:
-            with spans("step"):
-                stepped = net.step()
-            if not stepped:
-                # the manual-driving contract (ChannelNetwork.step): a
-                # drained queue needs the idle phase, and another pass
-                # if that produced traffic
-                with spans("idle_phase"):
-                    net.idle_phase()
-            self._stamp()
-            between()
-            if not stepped and net.pending_count() == 0:
-                break
+        with self.clock.timed():
+            with spans("start_epoch"):
+                for hb in self._nodes:
+                    hb.start_epoch()
+            while True:
+                with spans("step"):
+                    stepped = net.step()
+                if not stepped:
+                    # the manual-driving contract (ChannelNetwork.step):
+                    # a drained queue needs the idle phase, and another
+                    # pass if that produced traffic
+                    with spans("idle_phase"):
+                        net.idle_phase()
+                self._stamp()
+                between()
+                if not stepped and net.pending_count() == 0:
+                    break
         self.rounds += 1
 
     def _quiet(self) -> bool:
@@ -156,16 +215,22 @@ class Served:
         return self.cluster.pending() == 0 and ordered == settled
 
     def _drain(self) -> None:
-        limit = time.perf_counter() + DRAIN_LIMIT_S
-        while not self._quiet() and time.perf_counter() < limit:
+        start = time.perf_counter()
+        while not self._quiet() and (
+            time.perf_counter() - start < drain_limit_s(self.clock.longest_s)
+        ):
             self._round(lambda: None)
 
     def warm_up(self) -> None:
+        """The listed shapes, if any, then full rounds."""
+        hb0 = self._nodes[0]
+        warm_shapes(hb0.crypto, hb0.tpke.group,
+                    self.cell.config.get("warm_shapes", {}))
         source = TxSource(
             self.cell.traffic, self.cell.config["tx_bytes"], self.seed,
             kind=KIND_WARM,
         )
-        for fill in WARMUP_FILLS:
+        for fill in self.cell.config.get("warm_up_fills", WARMUP_FILLS):
             for a in source.take(int(fill * self.cfg.batch_size)):
                 self._submit(a, None)
             self._round(lambda: None)
@@ -218,11 +283,13 @@ class Served:
         self,
         seconds: float,
         tick: Callable[[float], None] = _no_tick,
+        closed: Callable[[], None] = lambda: None,
     ) -> Dict:
         """Backlog held at ``backlog_batches`` full batches, topped up
         after every round.  The window closes with the round in which
         the first settle at or after ``seconds`` falls: rounds run to
-        quiescence, so no epoch is in flight at either end."""
+        quiescence, so no epoch is in flight at either end.  ``closed``
+        is called as it closes, before the drain."""
         source = TxSource(
             self.cell.traffic, self.cell.config["tx_bytes"], self.seed
         )
@@ -230,6 +297,7 @@ class Served:
         spans = self.spans
         t0 = time.perf_counter()
         first = len(self.t_settled)
+        first_round = self.rounds
         while True:
             need = target - self.cluster.pending()
             if need > 0:
@@ -241,9 +309,12 @@ class Served:
             if self.t_settled[first:] and self.t_settled[-1] - t0 >= seconds:
                 break
         t_end = time.perf_counter()
+        rounds = self.rounds - first_round
+        closed()
         with spans("drain"):
             self._drain()
-        return {"t0": t0, "t_end": t_end, "first_epoch": first}
+        return {"t0": t0, "t_end": t_end, "first_epoch": first,
+                "rounds": rounds}
 
     # -- what the harness reads ------------------------------------------
 
@@ -314,18 +385,20 @@ class Lockstep:
             cell.traffic, cell.config["tx_bytes"], seed
         )
         self.epochs: List[Dict] = []  # one row per epoch, warm-up too
+        self.clock = RoundClock(meter)
 
     def _epoch(self) -> Dict:
         n = len(self.ids)
         submitted: Dict[str, List[bytes]] = {nid: [] for nid in self.ids}
-        with self.spans("submit"):
-            for j, a in enumerate(self._source.take(self.per_epoch)):
-                nid = self.ids[j % n]
-                self.cluster.submit(a.tx, nid)
-                submitted[nid].append(a.tx)
-        before = len(self.cluster.committed_batches)
-        with self.spans("run_epoch"):
-            stats = dict(self.cluster.run_epoch())
+        with self.clock.timed():
+            with self.spans("submit"):
+                for j, a in enumerate(self._source.take(self.per_epoch)):
+                    nid = self.ids[j % n]
+                    self.cluster.submit(a.tx, nid)
+                    submitted[nid].append(a.tx)
+            before = len(self.cluster.committed_batches)
+            with self.spans("run_epoch"):
+                stats = dict(self.cluster.run_epoch())
         row = {
             "epoch": before,
             "submitted": submitted,
@@ -335,35 +408,11 @@ class Lockstep:
         self.epochs.append(row)
         return row
 
-    def _warm_shapes(self, shapes: Dict) -> None:
-        """Run each exponentiation program the cell can meet once, at
-        the sizes the configuration's file lists (``warm_shapes``: the
-        coin waves' sizes move with the round count, each size bucket
-        is a program, and the rare ones would otherwise be met first
-        inside a window).  Through the engine's own entry points."""
-        from cleisthenes_tpu.ops import modmath
-
-        crypto = self.cluster.crypto
-        group = self.cluster.tpke.group
-        eng = modmath.get_engine(crypto.engine_backend, crypto.mesh, group)
-        # a grouped call is split by group size, so a small shape rides
-        # with a filler of COMB_FILLER that lifts the call over the
-        # comb's host floor
-        filler = [(group.g, [3] * COMB_FILLER[1])] * COMB_FILLER[0]
-        for groups, exps in shapes.get("comb", ()):
-            call = [(group.g, [3] * exps)] * groups
-            if exps != COMB_FILLER[1]:
-                call = call + filler
-            eng.pow_batch_grouped(call)
-        for rows in shapes.get("dual_pow", ()):
-            eng.dual_pow_batch(
-                [group.g] * rows, [3] * rows, [group.g] * rows, [5] * rows
-            )
-
     def warm_up(self) -> None:
         """The listed shapes, then epochs until LOCKSTEP_WARMUP_CLEAN
         in a row compile nothing."""
-        self._warm_shapes(self.cell.config.get("warm_shapes", {}))
+        warm_shapes(self.cluster.crypto, self.cluster.tpke.group,
+                    self.cell.config.get("warm_shapes", {}))
         clean = 0
         for _ in range(LOCKSTEP_WARMUP_MAX):
             before = self.meter.count
@@ -434,4 +483,5 @@ class Lockstep:
 
 EXECUTORS = {"served": Served, "lockstep": Lockstep}
 
-__all__ = ["Served", "Lockstep", "Spans", "EXECUTORS"]
+__all__ = ["Served", "Lockstep", "Spans", "EXECUTORS", "RoundClock",
+           "drain_limit_s"]

@@ -13,8 +13,10 @@ the one JSON object of the result.  The numbers compared, each beside
 its limit, are the last lines of stderr and the last key of the result.
 
 ``--trace 0`` reports the cell's end-to-end metrics, ``--trace 1`` its
-per-layer metrics: the profiler runs over the last TRACE_SECONDS (2.5) of
-the window, the counters cover the whole of it.
+per-layer metrics: the profiler runs over the window's last whole loop
+iterations (rounds, epochs), from the first boundary within TRACE_SECONDS
+(2.5), or within the longest iteration timed so far where that is more, of
+the window's nominal end; the counters cover the whole of the window.
 """
 
 from __future__ import annotations
@@ -92,15 +94,30 @@ def percentile(sorted_vals: List[float], q: float) -> float:
     return sorted_vals[max(0, math.ceil(q * len(sorted_vals)) - 1)]
 
 
+def trace_start_s(seconds: float, longest_round_s: float) -> float:
+    """From when on, in seconds of the window, a loop boundary starts
+    the trace: TRACE_SECONDS before the nominal end, or one longest
+    round before it where rounds are longer, so that some boundary
+    falls in the stretch and what is traced is whole rounds."""
+    return max(0.0, seconds - max(TRACE_SECONDS, longest_round_s))
+
+
 class Tracer:
-    """The profiler over the last TRACE_SECONDS of the window."""
+    """The profiler over the window's last whole loop iterations.
+    ``tick`` is called at every loop boundary; the trace starts at the
+    first one at or after ``trace_start_s`` and ends with the window.
+    The longest iteration is what warm-up timed (``warm_round_s``) and
+    what the ticks of this window lie apart."""
 
     def __init__(self, on: bool, seconds: float, spans, name: str,
-                 counters: Callable[[], Dict]) -> None:
+                 counters: Callable[[], Dict],
+                 warm_round_s: float = 0.0) -> None:
         self.on = on
         self._counters = counters
         self.counters: Dict = {}  # at the trace's two ends
-        self._start_at = max(0.0, seconds - TRACE_SECONDS)
+        self._seconds = seconds
+        self._longest = warm_round_s
+        self._last_tick: Optional[float] = None
         self._spans = spans
         self._dir = TRACE_DIR / name
         self._window = None
@@ -113,13 +130,20 @@ class Tracer:
             probe_round_trip()
 
     def tick(self, now: float) -> None:
-        if not self.on or now < self._start_at:
+        if not self.on:
             return
+        if self._last_tick is not None:
+            self._longest = max(self._longest, now - self._last_tick)
+        self._last_tick = now
         if self.started:
             # the device's tracer may start a moment after the host's:
             # one probe at every loop boundary, 1.4 ms each
             self._probe()
             return
+        if now >= trace_start_s(self._seconds, self._longest):
+            self._start()
+
+    def _start(self) -> None:
         import jax.profiler
 
         shutil.rmtree(self._dir, ignore_errors=True)
@@ -134,26 +158,43 @@ class Tracer:
         self._window.__enter__()
         self._probe()
 
-    def finish(self) -> Optional[Dict]:
-        if not self.started:
-            return None
+    def stop(self) -> None:
+        """Ends the traced part; ``finish`` reads it.  The held-backlog
+        loop calls this as its window closes, so that the drain's
+        rounds, which no end-to-end metric covers, are not traced."""
+        if not self.started or "after" in self.counters:
+            return
         import jax.profiler
 
-        from benchmarks import trace_reduce
-
+        t0 = time.perf_counter()
         self._probe()
         self.counters["after"] = self._counters()
         self._window.__exit__(None, None, None)
         self._spans.on = False
         jax.profiler.stop_trace()
+        self._stop_s = time.perf_counter() - t0
+
+    def finish(self) -> Optional[Dict]:
+        if not self.started:
+            return None
+        from benchmarks import trace_reduce
+
+        self.stop()
+        t0 = time.perf_counter()
         try:
             trace = trace_reduce.load_xplane(str(self._dir), SPAN_NAMES)
             reduced = trace_reduce.reduce(trace)
-            if reduced["dropped"]:
-                say("the device dropped trace buffers: busy_s reads low")
-            return reduced
         finally:
             shutil.rmtree(self._dir, ignore_errors=True)
+        if reduced["dropped"]:
+            say("the device dropped trace buffers: busy_s reads low")
+        before, after = self.counters["before"], self.counters["after"]
+        unit = "rounds" if "rounds" in after else "epochs"
+        say(f"traced {reduced['window_s']:.3f} s from a loop boundary: "
+            f"{after[unit] - before[unit]} whole {unit}; stopping the "
+            f"profiler took {self._stop_s:.2f} s, reading the trace "
+            f"{time.perf_counter() - t0:.2f} s")
+        return reduced
 
 
 # -- end-to-end metrics: taken by the harness, over the whole window --------
@@ -259,7 +300,8 @@ def run_cell(
         fault(executor)
     gc.collect()
     gc.freeze()  # set-up's garbage is not collected inside the window
-    tracer = Tracer(trace, seconds, spans, workload, executor.counters)
+    tracer = Tracer(trace, seconds, spans, workload, executor.counters,
+                    executor.clock.longest_s)
     before = executor.counters()
     setup_s = time.perf_counter() - t_process
     say(
@@ -275,7 +317,7 @@ def run_cell(
     if loop == "open":
         ends = executor.run_open(schedule[0], schedule[1], seconds, tracer.tick)
     elif loop == "backlog":
-        ends = executor.run_backlog(seconds, tracer.tick)
+        ends = executor.run_backlog(seconds, tracer.tick, tracer.stop)
     elif loop == "epoch":
         ends = executor.run_epochs(seconds, tracer.tick)
     else:
@@ -319,6 +361,7 @@ def run_cell(
             e for e, at in enumerate(t_settled) if t0 < at <= t_end
         ]
         run["epochs_in_window"] = len(in_window)
+        run["rounds_in_window"] = ends.get("rounds")
         run["settled_in_window"] = sum(
             len(txs) for e in in_window for txs in ledger[e].values()
         )

@@ -3,6 +3,7 @@ control, the faults, and the yardstick's arithmetic."""
 
 import json
 import pathlib
+import re
 import shutil
 import subprocess
 import sys
@@ -482,3 +483,227 @@ def test_benchmark_json_names_files_that_exist():
     for cfg in bench["configs"]:
         data = json.loads((ROOT / cfg["file"]).read_text())
         assert sorted(data["reduced"]) == sorted(cfg["reduced"])
+
+
+# -- long rounds: the trace, the drain, the warm-up ------------------------------
+
+
+@pytest.mark.parametrize("round_s,start_s,limit_s", [
+    (0.7, 42.5, 60.0),  # n128-b10k-lockstep: the rule it always had
+    (1.0, 42.5, 60.0),  # n16-b4k: the same
+    (19.0, 26.0, 114.0),  # n64-b10k: one round before the end, six to drain
+])
+def test_trace_start_and_drain_limit_follow_the_longest_round(
+    round_s, start_s, limit_s
+):
+    from benchmarks import executors, run
+
+    assert run.trace_start_s(45.0, round_s) == pytest.approx(start_s)
+    assert executors.drain_limit_s(round_s) == pytest.approx(limit_s)
+    # boundaries lie at most one longest round apart, so one of them
+    # falls between the start and the window's nominal end
+    boundaries = [i * round_s for i in range(1, int(45 / round_s) + 2)]
+    assert any(start_s <= b < 45.0 for b in boundaries)
+
+
+def test_before_any_round_the_rules_are_the_old_ones():
+    from benchmarks import executors, run
+
+    assert executors.RoundClock(None).longest_s == 0.0
+    assert run.trace_start_s(45.0, 0.0) == 45.0 - run.TRACE_SECONDS
+    assert run.trace_start_s(1.0, 0.0) == 0.0
+    assert executors.drain_limit_s(0.0) == executors.DRAIN_LIMIT_S
+
+
+def test_round_clock_leaves_out_a_round_that_compiled():
+    import time
+
+    from benchmarks import executors
+
+    class Meter:
+        count = 0
+
+    meter = Meter()
+    clock = executors.RoundClock(meter)
+    with clock.timed():
+        time.sleep(0.01)
+    with clock.timed():
+        meter.count += 1  # the compiler ran inside this one
+        time.sleep(0.1)
+    with clock.timed():
+        pass
+    assert 0.01 <= clock.longest_s < 0.1
+
+
+def _boundary_the_trace_starts_at(monkeypatch, seconds, warm_round_s, ticks):
+    from benchmarks import run
+
+    tracer = run.Tracer(True, seconds, None, "toy", dict, warm_round_s)
+    started = []
+
+    def start():
+        tracer.started = True
+        started.append(tracer._last_tick)
+
+    monkeypatch.setattr(tracer, "_start", start)
+    monkeypatch.setattr(tracer, "_probe", lambda: None)
+    for now in ticks:
+        tracer.tick(now)
+    return started
+
+
+@pytest.mark.parametrize("seconds,warm_round_s,round_s,want", [
+    # 19 s rounds, nothing known from warm-up: boundaries at 0, 19, 38;
+    # the old rule (now >= 42.5) never starts, this one starts at 38
+    (45.0, 0.0, 19.0, 38.0),
+    (45.0, 0.9, 0.9, 43.2),  # short rounds: the rule as it was
+    (45.0, 0.0, 0.7, 42.7),
+    # a window shorter than the round warm-up timed: the first boundary
+    (6.0, 19.0, 19.0, 0.0),
+])
+def test_tracer_starts_at_a_boundary_one_longest_round_before_the_end(
+    monkeypatch, seconds, warm_round_s, round_s, want
+):
+    ticks = [i * round_s for i in range(int(seconds / round_s) + 1)]
+    started = _boundary_the_trace_starts_at(
+        monkeypatch, seconds, warm_round_s, ticks
+    )
+    assert started == [pytest.approx(want)]
+
+
+def test_rounds_longer_than_the_least_trace_are_traced_whole(
+    harness, toy_root, monkeypatch, capsys
+):
+    """With the least length at a millisecond no boundary falls within
+    it of the window's end; the trace starts one round before the end
+    all the same, covers whole rounds, and the span readers read it."""
+    monkeypatch.setattr(harness, "TRACE_SECONDS", 0.001)
+    result = harness.run_cell(
+        "toy-served.saturated", 2**31 + 23, 1.0, True, root=toy_root
+    )
+    assert result["correct"] is True, result["compared"]
+    device = result["device"]
+    assert device["window_s"] > 0.001 and device["busy_s"] >= 0
+    assert "breakdown" in result
+    metrics = result["metrics"]
+    for name in ("hub_self_pct", "router_banks_pct", "hb_turn_pct",
+                 "codec_mac_pct", "span_coverage_pct", "round_ms"):
+        assert metrics[name]["value"] > 0, name
+    said = [
+        line for line in capsys.readouterr().out.splitlines()
+        if line.startswith("[bench] traced")
+    ]
+    assert len(said) == 1
+    rounds = int(re.search(r": (\d+) whole rounds", said[0]).group(1))
+    assert rounds >= 1
+    # whole rounds: no shorter than that many of the window's mean round
+    # would be if every round were half the mean
+    assert device["window_s"] >= 0.5 * rounds * metrics["round_ms"]["value"] / 1e3
+
+
+def _warm_up_submissions(harness, toy_root, cell):
+    seen = {}
+
+    def look(executor):
+        seen["submitted"] = len(executor.submissions)
+        seen["rounds"] = executor.rounds
+
+    harness.run_cell(cell, 7, 0.5, False, root=toy_root, fault=look)
+    return seen
+
+
+def test_warm_up_fills_come_from_the_configuration_or_stay(harness, toy_root):
+    from benchmarks import executors
+
+    default = _warm_up_submissions(harness, toy_root, "toy-served.saturated")
+    assert default["submitted"] == sum(
+        int(fill * 64) for fill in executors.WARMUP_FILLS
+    )
+    cfg = json.loads(
+        (toy_root / "benchmarks/configs/toy-served.json").read_text()
+    )
+    assert "warm_up_fills" not in cfg
+    # n64-b10k's file gives its own (and warm_shapes, here cut to toys)
+    add_config(toy_root, "toy-served-own", "n64-b10k",
+               {"n": 4, "batch_size": 64})
+    add_cell(toy_root, "toy-served-own.saturated", "n64-b10k.saturated",
+             "toy-served-own")
+    fills = json.loads(
+        (toy_root / "benchmarks/configs/toy-served-own.json").read_text()
+    )["warm_up_fills"]
+    assert 0 < len(fills) < len(executors.WARMUP_FILLS)
+    own = _warm_up_submissions(harness, toy_root, "toy-served-own.saturated")
+    assert own["submitted"] == sum(int(fill * 64) for fill in fills)
+    assert own["rounds"] < default["rounds"]
+
+
+def test_device_item_share_by_family_and_the_round():
+    from benchmarks import spec
+
+    before = {"modexp_12x22.comb": {"device_items": 10, "host_items": 10}}
+    after = {
+        "modexp_12x22.comb": {"device_items": 810, "host_items": 110},
+        "modexp_12x22.dual_pow": {"device_items": 100, "host_items": 0},
+        "merkle.verify_branches": {"device_items": 0, "host_items": 4096},
+        "sha256.hash_batch": {"device_items": 0, "host_items": 50},
+    }
+    run = {
+        "counters": {"before": {"placement": before},
+                     "after": {"placement": after}},
+        "t0": 10.0, "t_end": 67.0, "rounds_in_window": 3,
+    }
+    read = {
+        name: spec.load_reader(name)
+        for name in ("modexp_device_item_pct", "merkle_device_item_pct",
+                     "rs_device_item_pct", "device_item_pct", "round_ms")
+    }
+    assert read["modexp_device_item_pct"](run) == pytest.approx(90.0)
+    assert read["merkle_device_item_pct"](run) == 0.0  # saw items, sent none
+    assert read["rs_device_item_pct"](run) is None  # saw none: left out
+    assert read["device_item_pct"](run) == pytest.approx(
+        100 * 900 / (900 + 100 + 4096 + 50)
+    )
+    assert read["round_ms"](run) == pytest.approx(19_000.0)
+    assert read["round_ms"]({"t0": 0.0, "t_end": 1.0}) is None
+
+
+def test_a_configuration_may_fix_the_keys_and_then_seeds_share_them(
+    harness, toy_root
+):
+    """``cluster.key_seed`` in the file: every --seed deals the same
+    threshold keys (the same coin, so the same BBA round counts) and
+    still makes its own transactions; without it the keys follow
+    --seed."""
+    cfg_path = toy_root / "benchmarks/configs/toy-served.json"
+    cfg = json.loads(cfg_path.read_text())
+    cfg["name"] = "toy-served-keyed"
+    cfg["cluster"]["key_seed"] = 5
+    (toy_root / "benchmarks/configs/toy-served-keyed.json").write_text(
+        json.dumps(cfg)
+    )
+    bench = json.loads((toy_root / "BENCHMARK.json").read_text())
+    bench["configs"].append({
+        "name": "toy-served-keyed", "source": "toy", "reduced": [],
+        "file": "benchmarks/configs/toy-served-keyed.json", "why": "a test's",
+    })
+    (toy_root / "BENCHMARK.json").write_text(json.dumps(bench))
+    add_cell(toy_root, "toy-served-keyed.saturated", "n16-b4k.saturated",
+             "toy-served-keyed")
+
+    def look(cell, seed):
+        seen = {}
+
+        def fault(executor):
+            keys = executor.cluster.keys[executor.ids[0]]
+            seen["coin"] = keys.coin_pub.master
+            seen["tx"] = executor.submissions[0][0]
+
+        harness.run_cell(cell, seed, 0.3, False, root=toy_root, fault=fault)
+        return seen
+
+    a = look("toy-served-keyed.saturated", 11)
+    b = look("toy-served-keyed.saturated", 12)
+    assert a["coin"] == b["coin"] and a["tx"] != b["tx"]
+    c = look("toy-served.saturated", 11)
+    d = look("toy-served.saturated", 12)
+    assert c["coin"] != d["coin"]
