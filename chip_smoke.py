@@ -184,6 +184,7 @@ class Stage:
         ]
         report = {
             "stage": self.name,
+            "tally": tally,
             "wall_s": round(time.perf_counter() - self._t0, 2),
             "compiles": m.count - self._c0[0],
             "compile_s": round(m.seconds - self._c0[1], 2),
@@ -205,11 +206,12 @@ class Stage:
         )
         if tally:
             say(f"  {'placement family':<28}{'device calls/items':>22}"
-                f"{'host calls/items':>22}")
+                f"{'host calls/items':>22}{'of device: sharded':>22}")
             for fam, r in tally.items():
                 dev = f"{r['device_calls']} / {r['device_items']}"
                 host = f"{r['host_calls']} / {r['host_items']}"
-                say(f"  {fam:<28}{dev:>22}{host:>22}")
+                mesh = f"{r['mesh_calls']} / {r['mesh_items']}"
+                say(f"  {fam:<28}{dev:>22}{host:>22}{mesh:>22}")
             say(f"  device items total {device_items}; never on the "
                 f"device: {', '.join(never) or 'none'}")
         return report
@@ -713,7 +715,10 @@ def stage_four_chip(
 ) -> Dict:
     """The lockstep epoch over Config.mesh_shape=(2, 2) on the real
     devices; ``reference`` is the single-device tpu arm's committed
-    batches (run here when the lockstep stage was not)."""
+    batches (run here when the lockstep stage was not).  The mesh
+    arm runs the one-device programs sharded, the comb among them,
+    under the one-device floors: every batch it sent to the device
+    is tallied as sharded."""
     import jax
 
     from cleisthenes_tpu.config import Config
@@ -728,6 +733,16 @@ def stage_four_chip(
         "tpu", mesh_shape=(2, 2), on_warm=stage.warmed, **arm
     )
     report = stage.finish()
+    tally = report["tally"]
+    comb = tally.get("modexp_12x22.comb", {})
+    check(
+        comb.get("mesh_items", 0) > 0,
+        f"four_chip: no comb item ran sharded over the mesh: {comb}",
+    )
+    check(
+        all(r["mesh_items"] == r["device_items"] for r in tally.values()),
+        "four_chip: a device batch of the mesh arm ran on one device",
+    )
     say(f"  committed digest mesh(2,2) {_bodies_digest(got)}")
     say(f"  committed digest 1 device  {_bodies_digest(reference)}")
     check(

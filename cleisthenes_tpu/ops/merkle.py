@@ -24,6 +24,7 @@ from typing import List, Sequence
 import numpy as np
 
 from cleisthenes_tpu.ops import placement
+from cleisthenes_tpu.parallel.mesh import host_array
 from cleisthenes_tpu.utils import trace
 
 _LEAF_PREFIX = b"\x00"
@@ -221,10 +222,13 @@ class XlaMerkle(MerkleBackend):
     def _put(self, x):
         import jax.numpy as jnp
 
-        x = jnp.asarray(x)
         if self._mesh is None:
-            return x
+            return jnp.asarray(x)
+        # the host array itself: each device is sent its own rows
         return self._mesh.put_flat(x)[0]
+
+    def _fetch(self, out) -> np.ndarray:
+        return host_array(self._mesh, out)
 
     def _hash_batch(self, msgs: np.ndarray) -> np.ndarray:
         from cleisthenes_tpu.ops.sha256_xla import sha256_batch
@@ -237,7 +241,7 @@ class XlaMerkle(MerkleBackend):
                 "ops", "host"
             ):
                 return self._host._hash_batch(msgs)
-        with placement.batch("sha256.hash_batch", True, b):
+        with placement.batch("sha256.hash_batch", True, b, self._mesh):
             with trace.span("ops", "pack"):
                 bucket = self._bucket(b)
                 if bucket != b:
@@ -248,7 +252,7 @@ class XlaMerkle(MerkleBackend):
                         ),
                     ])
             with trace.span("ops", "device", program="sha256_batch"):
-                return np.asarray(sha256_batch(self._put(msgs)))[:b]
+                return self._fetch(sha256_batch(self._put(msgs)))[:b]
 
     def build_batch(self, shards: np.ndarray) -> List[MerkleTree]:
         from cleisthenes_tpu.ops.sha256_xla import build_forest
@@ -259,7 +263,9 @@ class XlaMerkle(MerkleBackend):
                 "merkle.build_forest", False, b * n
             ), trace.span("ops", "host"):
                 return self._host.build_batch(shards)
-        with placement.batch("merkle.build_forest", True, b * n):
+        with placement.batch(
+            "merkle.build_forest", True, b * n, self._mesh
+        ):
             with trace.span("ops", "pack"):
                 bucket = self._bucket(b)
                 if bucket != b:
@@ -271,7 +277,7 @@ class XlaMerkle(MerkleBackend):
                     ])
             with trace.span("ops", "device", program="build_forest"):
                 # (bucket, 2p-1, 32): the whole forest in one transfer
-                forest = np.asarray(build_forest(self._put(shards)))
+                forest = self._fetch(build_forest(self._put(shards)))
             with trace.span("ops", "unpack"):
                 p = _next_pow2(n)
                 levels = []
@@ -310,7 +316,9 @@ class XlaMerkle(MerkleBackend):
             reps = np.repeat(a[:1], bucket - b, axis=0)
             return np.concatenate([a, reps])
 
-        with placement.batch("merkle.verify_branches", True, b):
+        with placement.batch(
+            "merkle.verify_branches", True, b, self._mesh
+        ):
             with trace.span("ops", "pack"):
                 columns = (
                     pad(np.ascontiguousarray(roots, dtype=np.uint8)),
@@ -320,7 +328,7 @@ class XlaMerkle(MerkleBackend):
                 )
             with trace.span("ops", "device", program="verify_branches"):
                 ok = verify_branches(*(self._put(c) for c in columns))
-                return np.asarray(ok)[:b]
+                return self._fetch(ok)[:b]
 
 
 def make_merkle(backend: str, mesh=None) -> MerkleBackend:

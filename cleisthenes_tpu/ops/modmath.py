@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
@@ -34,6 +35,7 @@ import numpy as np
 
 from cleisthenes_tpu.ops import placement
 from cleisthenes_tpu.ops.hashrows import be_rows_to_ints, ints_to_be_rows
+from cleisthenes_tpu.parallel.mesh import host_array
 from cleisthenes_tpu.utils import trace
 
 # 256-bit safe prime p = 2q + 1 (generated deterministically, seed
@@ -837,9 +839,12 @@ class ModEngine:
     ``'cpu'`` uses the native C++ Montgomery kernel
     (native/modpow256.cpp) when the toolchain builds it, else python
     pow() — which also serves arbitrarily large moduli.
-    With a ``parallel.mesh.CryptoMesh`` the batch axis shards over
-    every mesh device flat (each exponentiation is sequential
-    internally, independent across the batch).
+    With a ``parallel.mesh.CryptoMesh`` a batch the floors leave to
+    the device shards over every mesh device flat (each
+    exponentiation is sequential internally, independent across the
+    batch; the comb shards its base rows, a table a row); the floors
+    themselves are the one-device ones, so a wave one chip leaves on
+    the native host kernel is not spread over four.
     """
 
     def __init__(
@@ -930,12 +935,9 @@ class ModEngine:
 
     def _host_floor(self, b: int):
         """The host engine to delegate to for tiny batches under the
-        'tpu' backend, or None when the device path should run."""
-        if (
-            self.backend != "tpu"
-            or self.mesh is not None
-            or not self.host_delegation
-        ):
+        'tpu' backend, or None when the device path should run.  Mesh
+        or not: the decision reads the batch, not the layout."""
+        if self.backend != "tpu" or not self.host_delegation:
             return None
         host = _get_engine_nomesh("cpu", self.group)
         if self._fits256:
@@ -967,11 +969,7 @@ class ModEngine:
         compile bucket (and the mesh's device count when sharded);
         returns (*devs, b) with b the true batch size."""
         b = len(arrays[0])
-        bucket = self._bucket(b)
-        if self.mesh is not None:
-            import math as _math
-
-            bucket = _math.lcm(bucket, self.mesh.n_devices)
+        bucket = self._rows_bucket(b)
         if bucket != b:
             arrays = tuple(
                 np.concatenate(
@@ -979,9 +977,26 @@ class ModEngine:
                 )
                 for a in arrays
             )
+        return self._put(*arrays) + (b,)
+
+    def _put(self, *arrays):
+        """Host arrays on the device: as they are, or with the batch
+        axis sharded flat over the mesh."""
         if self.mesh is None:
-            return tuple(jnp.asarray(a) for a in arrays) + (b,)
-        return self.mesh.put_flat(*arrays) + (b,)
+            return tuple(jnp.asarray(a) for a in arrays)
+        return self.mesh.put_flat(*arrays)
+
+    def _fetch(self, out) -> np.ndarray:
+        """A program's result as a host array."""
+        return host_array(self.mesh, out)
+
+    def _rows_bucket(self, b: int) -> int:
+        """``_bucket``, and a multiple of the mesh's device count when
+        sharded (lcm: a (3, 2) mesh divides no power of two)."""
+        bucket = self._bucket(b)
+        if self.mesh is not None:
+            bucket = math.lcm(bucket, self.mesh.n_devices)
+        return bucket
 
     # The comb kernel's row width: see _comb_device.
     G_ROW = 512
@@ -1005,7 +1020,6 @@ class ModEngine:
         same 1/6."""
         if not (
             self.backend == "tpu"
-            and self.mesh is None
             and self._fits256  # comb tables are 256-layout only;
             # wide groups flatten to the generic wide device kernels
         ):
@@ -1140,9 +1154,13 @@ class ModEngine:
                 self._family + ".comb",
                 True,
                 sum(h * w for h, (_bi, _o, w) in zip(heights, chunks)),
+                self.mesh,
             ):
                 with trace.span("ops", "pack"):
-                    nb = self._bucket(sum(heights))
+                    # under a mesh the base rows shard flat: a row's
+                    # table builds where its exponents are, so the
+                    # program needs no collective
+                    nb = self._rows_bucket(sum(heights))
                     bases_arr = np.zeros((nb, 33), dtype=np.uint8)
                     bases_arr[:, 0] = 1  # padding bases = 1
                     exps_arr = np.zeros(
@@ -1158,10 +1176,9 @@ class ModEngine:
                 with trace.span(
                     "ops", "device", program="_pow_fused_grouped"
                 ):
-                    out = np.asarray(
+                    out = self._fetch(
                         _pow_fused_grouped(
-                            jnp.asarray(bases_arr),
-                            jnp.asarray(exps_arr),
+                            *self._put(bases_arr, exps_arr),
                             *self._xla_spec,
                         )
                     )
@@ -1241,7 +1258,9 @@ class ModEngine:
                 f"{self._family}.{host_op}", False, len(bases)
             ), trace.span("ops", "host"):
                 return host.pow_batch(bases, exps)
-        with placement.batch(self._family + ".pow", True, len(bases)):
+        with placement.batch(
+            self._family + ".pow", True, len(bases), self.mesh
+        ):
             if self._wide is not None:
                 lay = self._layout
                 gp = self.group.p
@@ -1252,7 +1271,7 @@ class ModEngine:
                     )
                 with trace.span("ops", "device", program="_pow_wide"):
                     base_b, exp_b, b = self._place(*cols)
-                    out = np.asarray(
+                    out = self._fetch(
                         self._wide[0](base_b, exp_b, *self._xla_spec)
                     )[:b]
                 with trace.span("ops", "unpack"):
@@ -1291,13 +1310,15 @@ class ModEngine:
                 f"{self._family}.{host_op}", False, len(base_b)
             ), trace.span("ops", "host"):
                 return host.pow_cols(base_b, exp_b)
-        with placement.batch(self._family + ".pow", True, len(base_b)):
+        with placement.batch(
+            self._family + ".pow", True, len(base_b), self.mesh
+        ):
             return self._pow_device(base_b, exp_b)
 
     def _pow_device(self, base_b: np.ndarray, exp_b: np.ndarray) -> np.ndarray:
         with trace.span("ops", "device", program="_pow_fused"):
             base_d, exp_d, b = self._place(base_b, exp_b)
-            return np.asarray(
+            return self._fetch(
                 _pow_fused(base_d, exp_d, *self._xla_spec)
             )[:b]
 
@@ -1340,7 +1361,9 @@ class ModEngine:
                 self._family + ".dual_pow", False, len(u1)
             ), trace.span("ops", "host"):
                 return host.dual_pow_batch(u1, e1, u2, e2)
-        with placement.batch(self._family + ".dual_pow", True, len(u1)):
+        with placement.batch(
+            self._family + ".dual_pow", True, len(u1), self.mesh
+        ):
             if self._wide is not None:
                 lay = self._layout
                 gp = self.group.p
@@ -1353,7 +1376,7 @@ class ModEngine:
                     )
                 with trace.span("ops", "device", program="_dual_pow_wide"):
                     u1_b, e1_b, u2_b, e2_b, b = self._place(*cols)
-                    out = np.asarray(
+                    out = self._fetch(
                         self._wide[1](
                             u1_b, e1_b, u2_b, e2_b, *self._xla_spec
                         )
@@ -1410,13 +1433,15 @@ class ModEngine:
                 self._family + ".dual_pow", False, len(u1_b)
             ), trace.span("ops", "host"):
                 return host.dual_pow_cols(u1_b, e1_b, u2_b, e2_b)
-        with placement.batch(self._family + ".dual_pow", True, len(u1_b)):
+        with placement.batch(
+            self._family + ".dual_pow", True, len(u1_b), self.mesh
+        ):
             return self._dual_pow_device(u1_b, e1_b, u2_b, e2_b)
 
     def _dual_pow_device(self, u1_b, e1_b, u2_b, e2_b) -> np.ndarray:
         with trace.span("ops", "device", program="_dual_pow_fused"):
             u1_d, e1_d, u2_d, e2_d, b = self._place(u1_b, e1_b, u2_b, e2_b)
-            return np.asarray(
+            return self._fetch(
                 _dual_pow_fused(u1_d, e1_d, u2_d, e2_d, *self._xla_spec)
             )[:b]
 

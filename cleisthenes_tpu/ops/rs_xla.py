@@ -101,7 +101,8 @@ class XlaErasureCoder(ErasureCoder):
     # small case.  The value is carried over from an earlier
     # attachment of the chip and is UNMEASURED on a local one
     # (ops.placement counts which side each batch took; PERF.md holds
-    # the dispatch cost).
+    # the dispatch cost).  It holds under a mesh as without one: the
+    # floors read the bytes, not the layout.
     HOST_FLOOR_BYTES = 1 << 16
 
     def __init__(self, n: int, k: int, mesh=None):
@@ -130,7 +131,12 @@ class XlaErasureCoder(ErasureCoder):
         v, l_dim = self._mesh.shape
         data, b = self._mesh.pad_rows(data, v)
         data, l = self._mesh.pad_cols(data, l_dim)
-        return self._mesh.put_vl(jnp.asarray(data)), b, l
+        # the host array itself: each device is sent its own block
+        return self._mesh.put_vl(data), b, l
+
+    def _fetch_vl(self, out, b: int, l: int) -> np.ndarray:
+        """A ``_put_vl`` batch's result, padding cut off."""
+        return self._mesh.gather(out, "vl")[:b, :, :l]
 
     def encode(self, data: np.ndarray) -> np.ndarray:
         data = np.ascontiguousarray(data, dtype=np.uint8)
@@ -168,21 +174,22 @@ class XlaErasureCoder(ErasureCoder):
         assert data.ndim == 3 and data.shape[1] == self.k, data.shape
         if self.n == self.k:
             return data.copy()
-        if self._mesh is None and data.nbytes < 4 * self.HOST_FLOOR_BYTES:
+        if data.nbytes < 4 * self.HOST_FLOOR_BYTES:
             with placement.batch(
                 "rs_gf256.encode_batch", False, len(data)
             ), trace.span("ops", "host"):
                 return self._host.encode_batch(data)
         with placement.batch(
-            "rs_gf256.encode_batch", True, len(data)
+            "rs_gf256.encode_batch", True, len(data), self._mesh
         ), trace.span("ops", "device", program="_encode_kernel_batch"):
             if self._mesh is None:
                 return np.asarray(
                     _encode_kernel_batch(self._g_enc, jnp.asarray(data))
                 )
             dev, b, l = self._put_vl(data)
-            out = _encode_kernel_batch(self._g_enc, dev)
-            return np.asarray(out)[:b, :, :l]
+            return self._fetch_vl(
+                _encode_kernel_batch(self._g_enc, dev), b, l
+            )
 
     def decode_recheck_batch(self, indices: np.ndarray, shards: np.ndarray):
         """Fused decode + re-encode + Merkle roots, or None when the
@@ -229,12 +236,14 @@ class XlaErasureCoder(ErasureCoder):
         self, indices: np.ndarray, shards: np.ndarray
     ) -> np.ndarray:
         shards = np.ascontiguousarray(shards, dtype=np.uint8)
-        if self._mesh is None and shards.nbytes < 4 * self.HOST_FLOOR_BYTES:
+        if shards.nbytes < 4 * self.HOST_FLOOR_BYTES:
             with placement.batch(
                 "rs_gf256.decode_batch", False, len(shards)
             ), trace.span("ops", "host"):
                 return self._host.decode_batch(indices, shards)
-        with placement.batch("rs_gf256.decode_batch", True, len(shards)):
+        with placement.batch(
+            "rs_gf256.decode_batch", True, len(shards), self._mesh
+        ):
             with trace.span("ops", "pack"):
                 patterns = [self._normalize_indices(ix) for ix in indices]
             with trace.span("ops", "device", program="_decode_kernel_batch"):
@@ -248,7 +257,7 @@ class XlaErasureCoder(ErasureCoder):
                     _decode_kernel_shared(g, jnp.asarray(shards))
                 )
             dev, b, l = self._put_vl(shards)
-            return np.asarray(_decode_kernel_shared(g, dev))[:b, :, :l]
+            return self._fetch_vl(_decode_kernel_shared(g, dev), b, l)
         g = jnp.stack([self._decode_bits(p) for p in patterns])
         if self._mesh is None:
             return np.asarray(_decode_kernel_batch(g, jnp.asarray(shards)))
@@ -257,8 +266,8 @@ class XlaErasureCoder(ErasureCoder):
         # the per-instance decode matrices shard batch-only: their
         # trailing axes are the contraction dims
         g_np, _ = self._mesh.pad_rows(np.asarray(g), v)
-        g_dev = self._mesh.put_v(jnp.asarray(g_np))
-        return np.asarray(_decode_kernel_batch(g_dev, dev))[:b, :, :l]
+        g_dev = self._mesh.put_v(g_np)
+        return self._fetch_vl(_decode_kernel_batch(g_dev, dev), b, l)
 
 
 __all__ = ["XlaErasureCoder"]

@@ -40,6 +40,8 @@ from typing import Optional, Sequence, Tuple
 
 import numpy as np
 
+from cleisthenes_tpu.utils import trace
+
 
 def validate_mesh_shape(mesh_shape) -> Tuple[int, int]:
     """Normalize/validate a (v, l) mesh shape (shared by Config and
@@ -113,27 +115,49 @@ class CryptoMesh:
         return self._sharding(P(("v", "l"), *([None] * (ndim - 1))))
 
     # -- placement ---------------------------------------------------------
+    #
+    # The whole multi-chip seam: host array -> ``put_*`` (span
+    # ``ops/shard``) -> the same jitted program, partitioned by GSPMD
+    # -> ``gather`` (span ``ops/gather``) -> host array.  No layout
+    # the crypto plane uses puts a collective between the two.
+
+    def _span(self, name: str, layout: str):
+        return trace.span("ops", name, devices=self.n_devices, layout=layout)
 
     def put_vl(self, x):
         """Place an array batch-over-'v', length-over-'l'."""
         import jax
 
-        return jax.device_put(x, self.spec_vl(np.ndim(x)))
+        with self._span("shard", "vl"):
+            return jax.device_put(x, self.spec_vl(np.ndim(x)))
 
     def put_v(self, x):
         """Place an array batch-over-'v', everything else replicated."""
         import jax
 
-        return jax.device_put(x, self.spec_v(np.ndim(x)))
+        with self._span("shard", "v"):
+            return jax.device_put(x, self.spec_v(np.ndim(x)))
 
     def put_flat(self, *arrays):
         """Place arrays with the batch axis sharded over all devices.
         Returns a tuple matching the inputs."""
         import jax
 
-        return tuple(
-            jax.device_put(a, self.spec_flat(np.ndim(a))) for a in arrays
-        )
+        with self._span("shard", "flat"):
+            return tuple(
+                jax.device_put(a, self.spec_flat(np.ndim(a)))
+                for a in arrays
+            )
+
+    def gather(self, x, layout: str) -> np.ndarray:
+        """A sharded result as one host array.  The program has run to
+        its end before the ``ops/gather`` span opens, so the span is
+        the collecting of the shards and not the waiting for them."""
+        import jax
+
+        jax.block_until_ready(x)
+        with self._span("gather", layout):
+            return np.asarray(x)
 
     # -- batch padding -----------------------------------------------------
 
@@ -161,6 +185,14 @@ class CryptoMesh:
         return a, l
 
 
+def host_array(mesh: Optional[CryptoMesh], x, layout: str = "flat"):
+    """A program's result as one host array: ``np.asarray`` on one
+    device, ``mesh.gather`` (and its span) under a mesh."""
+    if mesh is None:
+        return np.asarray(x)
+    return mesh.gather(x, layout)
+
+
 def make_crypto_mesh(
     mesh_shape: Optional[Tuple[int, int]],
     devices: Optional[Sequence] = None,
@@ -171,4 +203,9 @@ def make_crypto_mesh(
     return CryptoMesh(tuple(mesh_shape), devices)
 
 
-__all__ = ["CryptoMesh", "make_crypto_mesh", "validate_mesh_shape"]
+__all__ = [
+    "CryptoMesh",
+    "host_array",
+    "make_crypto_mesh",
+    "validate_mesh_shape",
+]

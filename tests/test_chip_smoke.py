@@ -94,12 +94,27 @@ def test_kernels_stage_toy(meter, device_arm):
     assert not report["never_on_device"]
 
 
-def test_four_chip_stage_on_virtual_devices(meter, device_arm):
+def test_four_chip_stage_on_virtual_devices(meter, device_arm, monkeypatch):
     """The (2, 2) mesh arm against the single-device arm, on four of
     the suite's eight virtual CPU devices (peak memory is a TPU-only
     statistic, so the stage's last check is expected to be the one
-    that refuses here)."""
+    that refuses here: the digests agreed, comb items ran sharded and
+    no device batch of the mesh arm ran on one device)."""
+    # an N=8 share wave is 64 items: bring the comb's floor down to it
+    monkeypatch.setattr(ModEngine, "HOST_FLOOR", 6)
     with pytest.raises(chip_smoke.SmokeFailure, match="never held memory"):
+        chip_smoke.stage_four_chip(
+            meter, n=8, batch=64, epochs=1, seed=7, reference=None
+        )
+
+
+def test_four_chip_stage_refuses_a_mesh_without_the_comb(
+    meter, device_arm
+):
+    """With the comb's floor above the toy waves every grouped wave
+    flattens to the generic kernel, as every mesh wave did before the
+    comb ran sharded: the stage says so."""
+    with pytest.raises(chip_smoke.SmokeFailure, match="no comb item"):
         chip_smoke.stage_four_chip(
             meter, n=8, batch=64, epochs=1, seed=7, reference=None
         )
