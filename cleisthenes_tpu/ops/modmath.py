@@ -5,9 +5,20 @@ and the common-coin combine (reference docs/THRESHOLD_ENCRYPTION-EN.md:
 33-36, docs/BBA-EN.md:163-181; cost model "4N^2 signature sharings"
 docs/HONEYBADGER-EN.md:94).  Those workloads are thousands of
 *independent* modular exponentiations per epoch — perfect for a batch
-axis — while each individual exponentiation is deeply sequential, so
-the kernel is a fori_loop over exponent bits whose body is elementwise
-int32 limb math across the whole batch.
+axis — while each individual exponentiation is deeply sequential: 516
+Montgomery products in a row for u1^e1 * u2^e2, 337 for a comb.  What
+such a program costs on the chip is therefore the latency of ONE
+product, not its width, and a product is written for that: limbs on
+the leading axis of (22, S, 128) planes, the batch over whole vector
+registers, the limb loop and the carry chain unrolled at trace time
+into straight-line elementwise int32 code with no inner loop, no
+dynamic slice and no row scatter.  The only loops left are the bit
+ladder and the comb's three.  On a TPU the ladders and the comb's
+loops run as Pallas kernels, a (22, 8, 128) block at a time out of
+vector memory (2.3 us a product at 2,048 rows on a v5e chip, 9.3 at
+16,384, where the same straight-line code as plain XLA takes 35 and
+52: ~100 fusions a product; PERF.md section 6, PR 30); on the CPU the
+same functions run as plain XLA.
 
 Representation: a 256-bit integer is 22 limbs of 12 bits in int32.
 Limb products are < 2^24 and a CIOS Montgomery pass accumulates < 22
@@ -180,8 +191,8 @@ _P_LIMBS, _M_PRIME, *_ = _spec256(DEFAULT_GROUP)
 
 
 class LimbLayout(NamedTuple):
-    """One XLA limb layout family: values are (nlimbs, B) int32 limb
-    planes of limb_bits each; packed I/O is nlimbs*limb_bits/8 bytes
+    """One XLA limb layout family: values are (nlimbs, S, 128) int32
+    limb planes of limb_bits each; packed I/O is nlimbs*limb_bits/8 bytes
     per value (the product is always byte-aligned by construction).
     Each family compiles its own kernels once; the group constants
     stay traced, so one family serves every group that fits it."""
@@ -196,10 +207,6 @@ class LimbLayout(NamedTuple):
     @property
     def val_bytes(self) -> int:
         return self.bits // 8
-
-    @property
-    def exp_bits(self) -> int:
-        return self.bits
 
 
 # Widths beyond the tuned 256-bit layout.  Constraints per family:
@@ -314,339 +321,624 @@ def _exps_to_bytes_w(exps: Sequence[int], lay: LimbLayout) -> np.ndarray:
     ).reshape(len(exps), vb)
 
 
-@functools.cache
-def _wide_kernels(lay: LimbLayout):
-    """Jitted packed-I/O pow and dual-pow kernels for one wide limb
-    family (same program structure as _pow_fused/_dual_pow_fused; the
-    layout constants close over the trace, the group constants stay
-    traced arguments).  Carry passes run as runtime loops — a fully
-    unrolled 192-limb carry chain would bloat the trace for no
-    measured gain at these widths."""
-    nl, lb = lay.nlimbs, lay.limb_bits
-    vb, ebits = lay.val_bytes, lay.exp_bits
-    mul_raw = _make_mont_mul(lb, nl, 2, False)
+# ---------------------------------------------------------------------------
+# The Montgomery product: straight-line code over limb planes
+# ---------------------------------------------------------------------------
+#
+# LAYOUT: a batch of values is (nlimbs, S, 128) int32: limbs on the
+# leading, untiled axis, the batch over whole (sublane, lane) tiles
+# (padded to a multiple of 128 inside the program).  Every limb is a
+# plane of full vector registers, every per-limb step of the product
+# is one elementwise operation on whole registers, and the CIOS shift
+# re-indexes planes without moving a byte.  The product itself is
+# rank-agnostic in what follows the limb axis: the comb builds its
+# tables on (nlimbs, 1, n_bases) and accumulates on
+# (nlimbs, G / rep, n_bases * rep).
 
-    def unpack(b):  # (B, vb) uint8 LE -> (B, nl) int32
-        n = b.shape[0]
-        bits = (
-            (
-                b[:, :, None].astype(jnp.int32)
-                >> jnp.arange(8, dtype=jnp.int32)
-            )
-            & 1
-        ).reshape(n, vb * 8)
-        weights = jnp.int32(1) << jnp.arange(lb, dtype=jnp.int32)
-        return (
-            bits.reshape(n, nl, lb) * weights[None, None, :]
-        ).sum(axis=2)
-
-    def pack(limbs):  # (B, nl) int32 -> (B, vb) uint8 LE
-        n = limbs.shape[0]
-        bits = (
-            (limbs[:, :, None] >> jnp.arange(lb, dtype=jnp.int32)) & 1
-        ).reshape(n, nl * lb)
-        weights = jnp.int32(1) << jnp.arange(8, dtype=jnp.int32)
-        return (
-            (bits.reshape(n, vb, 8) * weights[None, None, :]).sum(axis=2)
-        ).astype(jnp.uint8)
-
-    one_limbs = _int_to_limbs_w(1, lay)
-
-    @jax.jit
-    def pow_fused(base_b, exp_b, m_limbs, m_prime, r_limbs, r2_limbs):
-        base_limbs = unpack(base_b).T  # (nl, B)
-        m_col = m_limbs[:, None]
-
-        def mul(a, b):
-            return mul_raw(a, b, m_col, m_prime)
-
-        one = jnp.asarray(one_limbs)[:, None]
-        one_m = jnp.broadcast_to(
-            r_limbs[:, None], base_limbs.shape
-        ).astype(jnp.int32)
-        base_m = mul(
-            base_limbs,
-            jnp.broadcast_to(r2_limbs[:, None], base_limbs.shape),
-        )
-
-        def step(t, acc):
-            acc = mul(acc, acc)
-            bit = _exp_bit(exp_b, t).astype(jnp.int32)  # (B, 1)
-            mult = jnp.where(bit.T == 1, base_m, one_m)
-            return mul(acc, mult)
-
-        out_m = jax.lax.fori_loop(0, ebits, step, one_m)
-        out = mul(out_m, jnp.broadcast_to(one, out_m.shape))
-        return pack(out.T)
-
-    @jax.jit
-    def dual_pow_fused(
-        u1_b, e1_b, u2_b, e2_b, m_limbs, m_prime, r_limbs, r2_limbs
-    ):
-        u1_limbs = unpack(u1_b).T
-        u2_limbs = unpack(u2_b).T
-        m_col = m_limbs[:, None]
-
-        def mul(a, b):
-            return mul_raw(a, b, m_col, m_prime)
-
-        r2 = jnp.broadcast_to(r2_limbs[:, None], u1_limbs.shape)
-        one = jnp.asarray(one_limbs)[:, None]
-        one_m = jnp.broadcast_to(
-            r_limbs[:, None], u1_limbs.shape
-        ).astype(jnp.int32)
-        u1_m = mul(u1_limbs, r2)
-        u2_m = mul(u2_limbs, r2)
-        u12_m = mul(u1_m, u2_m)
-
-        def step(t, acc):
-            acc = mul(acc, acc)
-            b1 = _exp_bit(e1_b, t).astype(jnp.int32).T  # (1, B)
-            b2 = _exp_bit(e2_b, t).astype(jnp.int32).T
-            mult = jnp.where(
-                (b1 == 1) & (b2 == 1),
-                u12_m,
-                jnp.where(
-                    b1 == 1, u1_m, jnp.where(b2 == 1, u2_m, one_m)
-                ),
-            )
-            return mul(acc, mult)
-
-        out_m = jax.lax.fori_loop(0, ebits, step, one_m)
-        out = mul(out_m, jnp.broadcast_to(one, out_m.shape))
-        return pack(out.T)
-
-    return pow_fused, dual_pow_fused
+_LANES = 128
+# Sublanes a kernel block (TPU): one vector register a limb plane.
+_TILE_ROWS = 8
+# Layouts of at most this many limbs get the product unrolled at
+# trace time (22 limbs: ~2,200 operations).  The wide families keep
+# the limb loop: unrolled, the 72- and 192-limb products would be
+# 20-150 thousand operations, and the 32-limb one, ~4,400, took the
+# CPU 36-41 s to compile in each of tests/test_groups.py's 384-bit
+# tests where the loop takes 2; no cell runs a wide group.
+_STRAIGHT_LINE_MAX_LIMBS = 24
 
 
-# Loop unrolling: the kernels are thousands of tiny sequential
-# (B, NLIMBS) int32 ops, so per-iteration launch overhead dominates;
-# moderate unrolling of the CIOS limb loop and the exponent-bit loop
-# measured ~1.7x on a v5e chip while keeping compile times sane.
-_UNROLL_LIMB = 4
-_UNROLL_BITS = 2
-
-
-def _make_mont_mul(
-    limb_bits: int, nlimbs: int, unroll_limb: int, unroll_carry: bool
-):
+def _make_mont_mul(limb_bits: int, nlimbs: int):
     """Build the lazy-carry CIOS Montgomery product for one limb
-    layout.  The default 256-bit layout and every wide layout share
-    THIS single implementation — only the trace-time constants differ.
+    layout.  The 256-bit layout and every wide layout share THIS one
+    builder; the form (straight-line or looped over limbs) follows
+    from ``nlimbs`` at trace time.
 
-    Lazy-carry overflow bound: each outer iteration adds two limb
-    products of < 2^(2*limb_bits) per limb, so t stays below
+    ``mont_mul(a, b, m_col, m_prime)``: a, b (nlimbs, ...) int32 with
+    canonical limbs (< 2^limb_bits); m_col the modulus limbs,
+    broadcastable against them ((nlimbs, 1, 1) or whole planes);
+    m_prime = -p^-1 mod 2^limb_bits.  Returns a*b*R^-1 mod p as
+    canonical limbs in [0, p).
+
+    Lazy-carry overflow bound: each limb step adds two limb products
+    of < 2^(2*limb_bits) per limb, so t stays below
     2 * nlimbs * 2^(2*limb_bits), which must fit int32 — every entry
     of _WIDE_LAYOUTS satisfies it (checked in layout_for_group)."""
     mask = (1 << limb_bits) - 1
 
-    def mont_mul(a, b, m_col, m_prime):
-        """Montgomery product a*b*R^-1 mod p.
+    def limb_step(t, ai, b, m_col, m_prime):
+        t = t + ai * b
+        m = ((t[0] & mask) * m_prime) & mask
+        t = t + m * m_col
+        # divide by the radix: limb 0 is now = 0 mod 2^limb_bits; drop
+        # it and fold its high bits into the limb above
+        return jnp.concatenate(
+            [t[1:2] + (t[0:1] >> limb_bits), t[2:], jnp.zeros_like(t[:1])],
+            axis=0,
+        )
 
-        a, b: (nlimbs, B) int32 with limbs canonical (< 2^limb_bits);
-        m_col the modulus limbs as a (nlimbs, 1) column, m_prime =
-        -p^-1 mod 2^limb_bits.  Returns canonical limbs in [0, p).
-
-        LAYOUT: limbs ride the SUBLANE axis and the batch rides the
-        LANE axis.  The transposed (B, nlimbs) form wastes most of
-        every vector register (limbs padded to the 128-lane minor
-        dimension) and turns the CIOS shift into a lane rotation;
-        this orientation keeps all B items lane-parallel and makes
-        the per-iteration shift a cheap sublane move — measured ~8x
-        on a v5e chip at B=64k."""
+    def straight_line(a, b, m_col, m_prime):
         t = jnp.zeros_like(a)
+        for i in range(nlimbs):
+            t = limb_step(t, a[i], b, m_col, m_prime)
+        # one carry propagation -> canonical limbs, of t and of t - p
+        # side by side (the result is < 2p, so one of the two is it):
+        # chains over planes, stacked once.  A limb of t - p may be
+        # negative; the arithmetic shift carries its borrow.
+        kept, less, carry, borrow = [], [], 0, 0
+        for j in range(nlimbs):
+            v = t[j] + carry
+            d = t[j] - m_col[j] + borrow
+            # the top limb keeps its overflow (t may pass the radix
+            # where p fills the layout, t - p never does) and its sign
+            top = j == nlimbs - 1
+            kept.append(v if top else v & mask)
+            less.append(d if top else d & mask)
+            carry, borrow = v >> limb_bits, d >> limb_bits
+        return jnp.where(
+            (less[-1] < 0)[None], jnp.stack(kept), jnp.stack(less)
+        )
 
+    def looped(a, b, m_col, m_prime):
         def outer(i, t):
-            ai = jax.lax.dynamic_slice_in_dim(a, i, 1, axis=0)  # (1, B)
-            t = t + ai * b
-            m = ((t[0] & mask) * m_prime) & mask  # (B,)
-            t = t + m[None, :] * m_col
-            # divide by the radix: limb 0 is now ≡ 0 mod 2^limb_bits;
-            # shift down and fold its high bits into the new limb 0
-            carry = t[0:1] >> limb_bits
-            t = jnp.concatenate(
-                [t[1:2] + carry, t[2:], jnp.zeros_like(t[0:1])], axis=0
-            )
-            return t
+            ai = jax.lax.dynamic_index_in_dim(a, i, axis=0, keepdims=False)
+            return limb_step(t, ai, b, m_col, m_prime)
 
-        t = jax.lax.fori_loop(0, nlimbs, outer, t, unroll=unroll_limb)
+        t = jax.lax.fori_loop(0, nlimbs, outer, jnp.zeros_like(a), unroll=2)
 
-        # one full carry propagation -> canonical limbs
         def carry_pass(j, t):
             c = t[j] >> limb_bits
-            t = t.at[j].set(t[j] & mask)
-            t = t.at[j + 1].add(c)
-            return t
+            return t.at[j].set(t[j] & mask).at[j + 1].add(c)
 
-        t = jax.lax.fori_loop(
-            0, nlimbs - 1, carry_pass, t, unroll=unroll_carry
-        )
+        t = jax.lax.fori_loop(0, nlimbs - 1, carry_pass, t)
+        m_rows = jnp.broadcast_to(m_col, t.shape)
 
-        # conditional subtract p (result < 2p guaranteed)
         def borrow_pass(j, state):
             d, borrow = state
-            diff = t[j] - m_col[j, 0] - borrow
-            new_borrow = (diff < 0).astype(jnp.int32)
-            d = d.at[j].set(diff + (new_borrow << limb_bits))
-            return d, new_borrow
+            diff = t[j] - m_rows[j] - borrow
+            new_borrow = (diff >> 31) & 1
+            return d.at[j].set(diff + (new_borrow << limb_bits)), new_borrow
 
         d, borrow = jax.lax.fori_loop(
-            0,
-            nlimbs,
-            borrow_pass,
-            (jnp.zeros_like(t), jnp.zeros((t.shape[1],), jnp.int32)),
-            unroll=unroll_carry,
+            0, nlimbs, borrow_pass, (jnp.zeros_like(t), jnp.zeros_like(t[0]))
         )
-        return jnp.where((borrow == 0)[None, :], d, t)
+        return jnp.where((borrow == 0)[None], d, t)
 
-    return mont_mul
+    # jitted: a program traces the product once a shape, however many
+    # times its text holds it (the kernels all share one block shape)
+    return jax.jit(
+        straight_line if nlimbs <= _STRAIGHT_LINE_MAX_LIMBS else looped
+    )
 
 
-_mont_mul = _make_mont_mul(LIMB_BITS, NLIMBS, _UNROLL_LIMB, True)
+_mont_mul = _make_mont_mul(LIMB_BITS, NLIMBS)
+
+
+def _to_planes(x: jnp.ndarray) -> jnp.ndarray:
+    """(k, B) -> (k, S, 128): the batch over whole lane rows, padded
+    with zeros to a multiple of 128; a batch of under one row stays
+    (k, 1, B), as short as it is."""
+    if x.shape[1] < _LANES:
+        return x[:, None, :]
+    pad = -x.shape[1] % _LANES
+    if pad:
+        x = jnp.pad(x, ((0, 0), (0, pad)))
+    return x.reshape(x.shape[0], -1, _LANES)
+
+
+def _from_planes(x: jnp.ndarray, b: int) -> jnp.ndarray:
+    """(k, S, 128) -> (k, b): ``_to_planes`` undone."""
+    return x.reshape(x.shape[0], -1)[:, :b]
 
 
 @jax.jit
 def mont_mul_batch(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """Default-group Montgomery product on (B, NLIMBS) canonical limbs
-    (property-test surface; the transposed-layout kernel inside)."""
+    (property-test surface; the limb-plane product inside)."""
     out = _mont_mul(
-        a.T, b.T, jnp.asarray(_P_LIMBS)[:, None], _M_PRIME
+        _to_planes(a.T),
+        _to_planes(b.T),
+        jnp.asarray(_P_LIMBS)[:, None, None],
+        _M_PRIME,
     )
-    return out.T
+    return _from_planes(out, a.shape[0]).T
 
 
 # -- packed device I/O: values cross host<->device as raw bytes
-# (33 B/value, 32 B/exponent) and are unpacked/repacked on device.
+# (33 B/value, 32 B/exponent) and are unpacked/repacked on device,
+# bytes and limbs both on the leading axis.
 
 
 def _bytes33_to_limbs_dev(b: jnp.ndarray) -> jnp.ndarray:
-    """(B, 33) uint8 little-endian -> (B, NLIMBS) int32 limbs."""
-    n = b.shape[0]
-    bits = (
-        (b[:, :, None].astype(jnp.int32) >> jnp.arange(8, dtype=jnp.int32))
-        & 1
-    ).reshape(n, 33 * 8)
-    weights = (jnp.int32(1) << jnp.arange(LIMB_BITS, dtype=jnp.int32))
-    return (
-        bits.reshape(n, NLIMBS, LIMB_BITS) * weights[None, None, :]
-    ).sum(axis=2)
+    """(33, ...) little-endian bytes -> (NLIMBS, ...) int32 limbs:
+    three bytes are two 12-bit limbs."""
+    x = b.astype(jnp.int32).reshape((11, 3) + b.shape[1:])
+    lo = x[:, 0] | ((x[:, 1] & 0xF) << 8)
+    hi = (x[:, 1] >> 4) | (x[:, 2] << 4)
+    return jnp.stack([lo, hi], axis=1).reshape((NLIMBS,) + b.shape[1:])
 
 
 def _limbs_to_bytes33_dev(limbs: jnp.ndarray) -> jnp.ndarray:
-    """(B, NLIMBS) int32 canonical limbs -> (B, 33) uint8 little-endian."""
-    n = limbs.shape[0]
+    """(NLIMBS, ...) int32 canonical limbs -> (33, ...) uint8
+    little-endian bytes."""
+    x = limbs.reshape((11, 2) + limbs.shape[1:])
+    lo, hi = x[:, 0], x[:, 1]
+    out = jnp.stack(
+        [lo & 0xFF, (lo >> 8) | ((hi & 0xF) << 4), hi >> 4], axis=1
+    )
+    return out.reshape((33,) + limbs.shape[1:]).astype(jnp.uint8)
+
+
+def _bits_to_limbs_dev(b: jnp.ndarray, lay: LimbLayout) -> jnp.ndarray:
+    """``_bytes33_to_limbs_dev`` for any layout, bit by bit:
+    (val_bytes, ...) little-endian bytes -> (nlimbs, ...) limbs."""
     bits = (
-        (limbs[:, :, None] >> jnp.arange(LIMB_BITS, dtype=jnp.int32)) & 1
-    ).reshape(n, NLIMBS * LIMB_BITS)
-    weights = (jnp.int32(1) << jnp.arange(8, dtype=jnp.int32))
-    return (
-        (bits.reshape(n, 33, 8) * weights[None, None, :]).sum(axis=2)
-    ).astype(jnp.uint8)
+        b.astype(jnp.int32)[:, None] >> _iota(8, b.ndim)
+    ) & 1  # (val_bytes, 8, ...)
+    bits = bits.reshape((lay.nlimbs, lay.limb_bits) + b.shape[1:])
+    return (bits << _iota(lay.limb_bits, b.ndim)).sum(axis=1)
 
 
-def _exp_bit(exp_bytes: jnp.ndarray, t) -> jnp.ndarray:
-    """Bit t (MSB-first) of each row of (B, 32) big-endian bytes."""
-    byte = jax.lax.dynamic_slice_in_dim(exp_bytes, t >> 3, 1, axis=1)
-    shift = (7 - (t & 7)).astype(jnp.uint8)
-    return (byte >> shift) & jnp.uint8(1)
+def _limbs_to_bits_dev(limbs: jnp.ndarray, lay: LimbLayout) -> jnp.ndarray:
+    """(nlimbs, ...) canonical limbs -> (val_bytes, ...) uint8
+    little-endian bytes, any layout."""
+    bits = (limbs[:, None] >> _iota(lay.limb_bits, limbs.ndim)) & 1
+    bits = bits.reshape((lay.val_bytes, 8) + limbs.shape[1:])
+    return (bits << _iota(8, limbs.ndim)).sum(axis=1).astype(jnp.uint8)
 
 
-@jax.jit
-def _pow_fused(
-    base_b: jnp.ndarray,
-    exp_b: jnp.ndarray,
-    m_limbs: jnp.ndarray,
-    m_prime: jnp.ndarray,
-    r_limbs: jnp.ndarray,
-    r2_limbs: jnp.ndarray,
-) -> jnp.ndarray:
-    """Packed-bytes in/out single-dispatch pow: unpack, to-Montgomery,
-    exponentiate, from-Montgomery, repack — all in ONE program (one
-    dispatch, one transfer each way).  The group constants are traced
-    arguments (_spec256), so one compiled program serves every
-    <=256-bit group."""
-    base_limbs = _bytes33_to_limbs_dev(base_b).T  # (NLIMBS, B)
-    m_col = m_limbs[:, None]
+def _iota(n: int, ndim: int) -> jnp.ndarray:
+    """0..n-1 along axis 1 of an (x, n, ...) array of ndim + 1 axes."""
+    return jnp.arange(n, dtype=jnp.int32).reshape((1, n) + (1,) * (ndim - 1))
+
+
+# ---------------------------------------------------------------------------
+# The bit ladders
+# ---------------------------------------------------------------------------
+#
+# A ladder is a pure function of limb planes, elementwise over
+# whatever follows the leading axis.  ``consts`` carries the group:
+# rows [0, nl) the modulus limbs, [nl, 2nl) R mod p (Montgomery one),
+# [2nl, 3nl) R^2 mod p, row 3nl m_prime; (3nl + 1, 1, 1), or whole
+# planes inside a kernel block.  An exponent is (words, ...) int32,
+# word 0 the most significant; a step reads its top bit and shifts
+# the whole exponent left by one: no index that depends on the step.
+# A step's multiplier is picked at the end of the step before and
+# carried: a loop-carried array is materialised once, where a select
+# left beside its product is fused into, and recomputed by, every
+# limb step of it (XLA on the CPU: 5x the program's time).
+
+
+def _exp_words(e: jnp.ndarray, word_bytes: int) -> jnp.ndarray:
+    """(exp_bytes, ...) big-endian bytes -> (exp_bytes / word_bytes,
+    ...) int32 words of ``word_bytes`` bytes, most significant first."""
+    x = e.astype(jnp.int32).reshape(
+        (e.shape[0] // word_bytes, word_bytes) + e.shape[1:]
+    )
+    out = x[:, 0]
+    for k in range(1, word_bytes):
+        out = (out << 8) | x[:, k]
+    return out
+
+
+def _group_of(mul_raw, nl: int, consts):
+    """(product, Montgomery one, R^2) of a group from its column or
+    planes ``consts``."""
+    m_col, one_m, r2 = (consts[k * nl : (k + 1) * nl] for k in range(3))
 
     def mul(a, b):
-        return _mont_mul(a, b, m_col, m_prime)
+        return mul_raw(a, b, m_col, consts[3 * nl])
 
-    one = jnp.asarray(int_to_limbs(1))[:, None]
-    one_m = jnp.broadcast_to(
-        r_limbs[:, None], base_limbs.shape
-    ).astype(jnp.int32)
-    base_m = mul(
-        base_limbs,
-        jnp.broadcast_to(r2_limbs[:, None], base_limbs.shape),
+    return mul, one_m, r2
+
+
+def _from_montgomery(mul, acc):
+    one = jnp.concatenate(
+        [jnp.ones_like(acc[:1]), jnp.zeros_like(acc[1:])], axis=0
+    )
+    return mul(acc, one)
+
+
+def _make_ladders(lay: LimbLayout):
+    """(pow ladder, dual-pow ladder, bytes of an exponent word) of one
+    limb layout."""
+    nl = lay.nlimbs
+    mul_raw = _make_mont_mul(lay.limb_bits, nl)
+    # exponents cross the seam as 32 bytes in the 256-bit layout, as
+    # val_bytes in a wide one
+    exp_bytes = EXP_BITS // 8 if lay == LAYOUT256 else lay.val_bytes
+    word_bytes = 4 if exp_bytes % 4 == 0 else 1
+    top = 8 * word_bytes - 1
+    word_mask = -1 if word_bytes == 4 else (1 << (top + 1)) - 1
+
+    def top_bit(e):
+        return ((e[0] >> top) & 1) == 1
+
+    def shift_left(e):
+        below = jnp.concatenate([e[1:], jnp.zeros_like(e[:1])], axis=0)
+        return ((e << 1) & word_mask) | ((below >> top) & 1)
+
+    def pow_ladder(consts, base, e):
+        """base^e, square-and-multiply from the top bit."""
+        mul, one_m, r2 = _group_of(mul_raw, nl, consts)
+        one_m = jnp.broadcast_to(one_m, base.shape)
+        base_m = mul(base, jnp.broadcast_to(r2, base.shape))
+
+        def multiplier(e):
+            return jnp.where(top_bit(e)[None], base_m, one_m)
+
+        def step(_, state):
+            acc, mult, e = state
+            acc = mul(mul(acc, acc), mult)
+            e = shift_left(e)
+            return acc, multiplier(e), e
+
+        acc, _, _ = jax.lax.fori_loop(
+            0, 8 * exp_bytes, step, (one_m, multiplier(e), e)
+        )
+        return _from_montgomery(mul, acc)
+
+    def dual_ladder(consts, u1, u2, e1, e2):
+        """u1^e1 * u2^e2 (Shamir's trick: one square and one
+        select-multiply a bit, the Chaum-Pedersen verification
+        shape)."""
+        mul, one_m, r2 = _group_of(mul_raw, nl, consts)
+        one_m = jnp.broadcast_to(one_m, u1.shape)
+        r2 = jnp.broadcast_to(r2, u1.shape)
+        u1_m = mul(u1, r2)
+        u2_m = mul(u2, r2)
+        u12_m = mul(u1_m, u2_m)
+
+        def multiplier(e1, e2):
+            b1, b2 = top_bit(e1)[None], top_bit(e2)[None]
+            return jnp.where(
+                b1 & b2,
+                u12_m,
+                jnp.where(b1, u1_m, jnp.where(b2, u2_m, one_m)),
+            )
+
+        def step(_, state):
+            acc, mult, e1, e2 = state
+            acc = mul(mul(acc, acc), mult)
+            e1, e2 = shift_left(e1), shift_left(e2)
+            return acc, multiplier(e1, e2), e1, e2
+
+        acc, _, _, _ = jax.lax.fori_loop(
+            0, 8 * exp_bytes, step, (one_m, multiplier(e1, e2), e1, e2)
+        )
+        return _from_montgomery(mul, acc)
+
+    return pow_ladder, dual_ladder, word_bytes
+
+
+def _group_consts(m_limbs, m_prime, r_limbs, r2_limbs) -> jnp.ndarray:
+    """The traced group constants as one (3 * nlimbs + 1, 1, 1)
+    column (see the ladders for the rows)."""
+    return jnp.concatenate(
+        [m_limbs, r_limbs, r2_limbs, jnp.reshape(m_prime, (1,))]
+    ).astype(jnp.int32)[:, None, None]
+
+
+# ---------------------------------------------------------------------------
+# Where a program runs: Pallas kernels on a TPU, the same functions as
+# plain XLA elsewhere
+# ---------------------------------------------------------------------------
+#
+# Read on a v5e chip (PERF.md section 6, PR 30): the straight-line
+# product as plain XLA is still ~100 fusions a product (XLA will not
+# fuse across the limb-0 dependency of the CIOS chain, nor along a
+# carry chain): 35 us a product at 2,048 rows, 52 at 16,384, where the
+# looped form it replaced took 43 and 113.  The same functions as the
+# body of a kernel, a (limbs, 8, 128) block at a time out of vector
+# memory, take 2.3 and 9.3.  So on a TPU every product of the 256-bit
+# programs runs inside a kernel; on the CPU (the tests, the dry runs)
+# the same functions run as they are.  The process's backend picks at
+# trace time, nothing a caller sets (and not
+# ``jax.lax.platform_dependent``, which traces both forms of every
+# program: seconds of a warm start).
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _tile_call(
+    body, out_leads, consts, planes, resident=(), **compiler_params
+):
+    """``body(consts_ref, *resident_refs, *plane_refs, *out_refs)`` as
+    a Pallas kernel over the (_TILE_ROWS, 128) tiles of the planes'
+    two trailing axes (padded to whole tiles here, cut back on
+    return).  ``out_leads``: the leading axes of each output.
+    ``resident``: arrays whose last axis is the planes' lane axis,
+    blocked by lane tile alone; the lane tile is the outer grid axis,
+    so such a block is fetched once and serves all its tile's rows."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows, lanes = planes[0].shape[-2:]
+    pad = (-rows % _TILE_ROWS, -lanes % _LANES)
+    if any(pad):
+        planes = tuple(
+            jnp.pad(x, ((0, 0),) * (x.ndim - 2) + ((0, pad[0]), (0, pad[1])))
+            for x in planes
+        )
+        resident = tuple(
+            jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, pad[1]),))
+            for x in resident
+        )
+    tiled = (rows + pad[0], lanes + pad[1])
+    consts = jnp.broadcast_to(consts, (consts.shape[0], _TILE_ROWS, _LANES))
+
+    def block(lead):
+        n = len(lead)
+        return pl.BlockSpec(
+            tuple(lead) + (_TILE_ROWS, _LANES),
+            lambda j, i: (0,) * n + (i, j),
+        )
+
+    def lane_block(x):
+        n = x.ndim - 1
+        return pl.BlockSpec(
+            x.shape[:-1] + (_LANES,), lambda j, i: (0,) * n + (j,)
+        )
+
+    outs = pl.pallas_call(
+        body,
+        grid=(tiled[1] // _LANES, tiled[0] // _TILE_ROWS),
+        in_specs=[pl.BlockSpec(consts.shape, lambda j, i: (0, 0, 0))]
+        + [lane_block(x) for x in resident]
+        + [block(x.shape[:-2]) for x in planes],
+        out_specs=[block(lead) for lead in out_leads],
+        out_shape=[
+            jax.ShapeDtypeStruct(tuple(lead) + tiled, jnp.int32)
+            for lead in out_leads
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"), **compiler_params
+        ),
+    )(consts, *resident, *planes)
+    return [out[..., :rows, :lanes] for out in outs]
+
+
+def _run_planes(fn, consts, *planes):
+    """``fn(consts, *planes)``, a pure function of limb planes (a whole
+    ladder): on a TPU one kernel a call, a block at a time out of
+    vector memory."""
+
+    def kernel(consts, *planes):
+        def body(consts_ref, *refs):
+            refs[-1][...] = fn(consts_ref[...], *(r[...] for r in refs[:-1]))
+
+        return _tile_call(body, [planes[0].shape[:1]], consts, planes)[0]
+
+    return (kernel if _on_tpu() else fn)(consts, *planes)
+
+
+def _iterate(step, length, every, consts, state, *planes):
+    """The states after steps ``every``, ``2 * every``, ... of
+    ``length`` applications of ``state = step(consts, state,
+    *planes)``, stacked on a new leading axis: a scan of one step an
+    iteration as plain XLA; on a TPU one kernel that stores a state
+    every ``every`` steps."""
+
+    def scanned(consts, state, *planes):
+        def one(state, _):
+            state = step(consts, state, *planes)
+            return state, state
+
+        _, states = jax.lax.scan(one, state, None, length=length)
+        return states[every - 1 :: every]
+
+    def kernel(consts, state, *planes):
+        def body(consts_ref, state_ref, *refs):
+            consts = consts_ref[...]
+            held = tuple(r[...] for r in refs[:-1])
+
+            def some(i, state):
+                for _ in range(every):
+                    state = step(consts, state, *held)
+                refs[-1][i] = state
+                return state
+
+            jax.lax.fori_loop(0, length // every, some, state_ref[...])
+
+        lead = (length // every,) + state.shape[:1]
+        return _tile_call(body, [lead], consts, (state,) + planes)[0]
+
+    return (kernel if _on_tpu() else scanned)(consts, state, *planes)
+
+
+def _jit_program(program, batched: int, name: str):
+    """``program(*batched arrays, *group constants)`` jitted under
+    ``name`` (the profiler's module is ``jit_<name>``), with the jax
+    Mesh the batch is sharded over as the static keyword ``mesh``
+    (None: one device).  Under a mesh each device runs ``program`` on
+    its own rows (``shard_map``: a kernel is not GSPMD's to partition,
+    and a program that never looks across rows needs no collective)."""
+
+    def fused(*args, mesh=None):
+        if mesh is None:
+            return program(*args)
+        from jax.sharding import PartitionSpec
+
+        rows = PartitionSpec(mesh.axis_names)
+        return jax.shard_map(
+            program,
+            mesh=mesh,
+            in_specs=(rows,) * batched + (PartitionSpec(),) * 4,
+            out_specs=rows,
+            check_vma=False,
+        )(*args)
+
+    fused.__name__ = name
+    return jax.jit(fused, static_argnames="mesh")
+
+
+def _make_programs(lay: LimbLayout):
+    """The jitted packed-I/O pow and dual-pow programs of one limb
+    layout: unpack, to-Montgomery, exponentiate, from-Montgomery,
+    repack in ONE program (one dispatch, one transfer each way).  The
+    group constants are traced arguments, so one compiled program
+    serves every group the layout fits.  The 256-bit layout's ladders
+    run as the Pallas kernel on a TPU; the wide families stay plain
+    XLA everywhere."""
+    pow_ladder, dual_ladder, word_bytes = _make_ladders(lay)
+    is256 = lay == LAYOUT256
+
+    def values(b):  # (B, val_bytes) uint8 LE -> limb planes
+        x = _to_planes(b.T)
+        if is256:
+            return _bytes33_to_limbs_dev(x)
+        return _bits_to_limbs_dev(x, lay)
+
+    def exponents(e):  # (B, exp bytes) uint8 BE -> word planes
+        return _exp_words(_to_planes(e.T), word_bytes)
+
+    def packed(limbs, b):  # limb planes -> (b, val_bytes) uint8 LE
+        if is256:
+            out = _limbs_to_bytes33_dev(limbs)
+        else:
+            out = _limbs_to_bits_dev(limbs, lay)
+        return _from_planes(out, b).T
+
+    def run(ladder, consts, planes):
+        if is256:
+            return _run_planes(ladder, consts, *planes)
+        return ladder(consts, *planes)
+
+    def pow_program(base_b, exp_b, *group):
+        planes = (values(base_b), exponents(exp_b))
+        out = run(pow_ladder, _group_consts(*group), planes)
+        return packed(out, len(base_b))
+
+    def dual_pow_program(u1_b, e1_b, u2_b, e2_b, *group):
+        planes = (
+            values(u1_b), values(u2_b), exponents(e1_b), exponents(e2_b)
+        )
+        out = run(dual_ladder, _group_consts(*group), planes)
+        return packed(out, len(u1_b))
+
+    # the 256-bit programs' names are what the profiler's modules are
+    # read by
+    prefix = "_" if is256 else ""
+    return (
+        _jit_program(pow_program, 2, prefix + "pow_fused"),
+        _jit_program(dual_pow_program, 4, prefix + "dual_pow_fused"),
     )
 
-    def step(t, acc):
-        acc = mul(acc, acc)
-        bit = _exp_bit(exp_b, t).astype(jnp.int32)  # (B, 1)
-        mult = jnp.where(bit.T == 1, base_m, one_m)
-        return mul(acc, mult)
 
-    out_m = jax.lax.fori_loop(0, EXP_BITS, step, one_m, unroll=_UNROLL_BITS)
-    out = mul(out_m, jnp.broadcast_to(one, out_m.shape))
-    return _limbs_to_bytes33_dev(out.T)
+# Packed-bytes in/out, single-dispatch base^e and u1^e1 * u2^e2 of the
+# 256-bit layout: (B, 33) uint8 LE values, (B, 32) uint8 BE exponents,
+# the _spec256 constants, and the jax Mesh the batch is sharded over
+# (None on one device).
+_pow_fused, _dual_pow_fused = _make_programs(LAYOUT256)
 
 
-@jax.jit
-def _dual_pow_fused(
-    u1_b: jnp.ndarray,
-    e1_b: jnp.ndarray,
-    u2_b: jnp.ndarray,
-    e2_b: jnp.ndarray,
-    m_limbs: jnp.ndarray,
-    m_prime: jnp.ndarray,
-    r_limbs: jnp.ndarray,
-    r2_limbs: jnp.ndarray,
-) -> jnp.ndarray:
-    """Single-dispatch u1^e1 * u2^e2, packed bytes in/out (Shamir's
-    trick: one square + one select-multiply per bit — the
-    Chaum-Pedersen verification shape).  Group constants traced, as in
-    _pow_fused."""
-    u1_limbs = _bytes33_to_limbs_dev(u1_b).T  # (NLIMBS, B)
-    u2_limbs = _bytes33_to_limbs_dev(u2_b).T
-    m_col = m_limbs[:, None]
+def _comb_group(consts):
+    return _group_of(_mont_mul, NLIMBS, consts)
 
-    def mul(a, b):
-        return _mont_mul(a, b, m_col, m_prime)
 
-    r2 = jnp.broadcast_to(r2_limbs[:, None], u1_limbs.shape)
-    one = jnp.asarray(int_to_limbs(1))[:, None]
-    one_m = jnp.broadcast_to(
-        r_limbs[:, None], u1_limbs.shape
-    ).astype(jnp.int32)
-    u1_m = mul(u1_limbs, r2)
-    u2_m = mul(u2_limbs, r2)
-    u12_m = mul(u1_m, u2_m)
+def _comb_to_montgomery(consts, x):
+    mul, _one_m, r2 = _comb_group(consts)
+    return mul(x, jnp.broadcast_to(r2, x.shape))
 
-    def step(t, acc):
-        acc = mul(acc, acc)
-        b1 = _exp_bit(e1_b, t).astype(jnp.int32).T  # (1, B)
-        b2 = _exp_bit(e2_b, t).astype(jnp.int32).T
-        mult = jnp.where(
-            (b1 == 1) & (b2 == 1),
-            u12_m,
-            jnp.where(b1 == 1, u1_m, jnp.where(b2 == 1, u2_m, one_m)),
+
+def _comb_square(consts, s):
+    mul = _comb_group(consts)[0]
+    return mul(s, s)
+
+
+def _comb_times(consts, t, s):
+    return _comb_group(consts)[0](t, s)
+
+
+def _comb_select(entries, nib):
+    """Of an item's 16 table entries the one its nibble names:
+    integer selects by compares, no contraction.  ``entries[j]``
+    broadcasts against ``nib`` along the sublanes."""
+    sel = entries[0]
+    for j in range(1, 16):
+        sel = jnp.where(nib == j, entries[j], sel)
+    return sel
+
+
+def _comb_accumulate(consts, table, nibs):
+    """The 64 comb steps: acc *= T[k, nib[k]], then out of the
+    Montgomery domain.  ``table`` (64, 16, NLIMBS, L), ``nibs``
+    (64, Gs, L) int32; returns canonical limbs (NLIMBS, Gs, L)."""
+
+    def scanned(consts, table, nibs):
+        mul, one_m, _r2 = _comb_group(consts)
+
+        def step(state, ahead):
+            # the entries are selected a step ahead and carried (see
+            # the ladders' note on the multiplier)
+            acc, sel = state
+            t_k, nib_k = ahead
+            return (mul(acc, sel), _comb_select(t_k[:, :, None], nib_k)), None
+
+        acc0 = jnp.broadcast_to(one_m, (NLIMBS,) + nibs.shape[1:])
+        (acc, _), _ = jax.lax.scan(
+            step,
+            (acc0, _comb_select(table[0][:, :, None], nibs[0])),
+            (jnp.roll(table, -1, axis=0), jnp.roll(nibs, -1, axis=0)),
         )
-        return mul(acc, mult)
+        return _from_montgomery(mul, acc)
 
-    out_m = jax.lax.fori_loop(0, EXP_BITS, step, one_m, unroll=_UNROLL_BITS)
-    out = mul(out_m, jnp.broadcast_to(one, out_m.shape))
-    return _limbs_to_bytes33_dev(out.T)
+    def kernel(consts, table, nibs):
+        def body(consts_ref, table_ref, nibs_ref, out_ref):
+            mul, one_m, _r2 = _comb_group(consts_ref[...])
+
+            def step(k, acc):
+                nib = nibs_ref[k]
+                hot = [nib == j for j in range(1, 16)]
+                planes = []
+                for limb in range(NLIMBS):
+                    # a (1, 128) row of the table, read along the
+                    # sublanes as it lies
+                    sel = table_ref[k, 0, limb : limb + 1, :]
+                    for j in range(1, 16):
+                        sel = jnp.where(
+                            hot[j - 1],
+                            table_ref[k, j, limb : limb + 1, :],
+                            sel,
+                        )
+                    planes.append(sel)
+                return mul(acc, jnp.stack(planes))
+
+            acc = jax.lax.fori_loop(0, 64, step, one_m)
+            out_ref[...] = _from_montgomery(mul, acc)
+
+        # a lane tile's table is 12.6 MB of vector memory, twice with
+        # its double buffer
+        return _tile_call(
+            body, [(NLIMBS,)], consts, (nibs,), resident=(table,),
+            vmem_limit_bytes=48 << 20,
+        )[0]
+
+    return (kernel if _on_tpu() else scanned)(consts, table, nibs)
 
 
-@jax.jit
-def _pow_fused_grouped(
-    bases_b: jnp.ndarray,  # (n_bases, 33) uint8 LE
-    exps_b: jnp.ndarray,  # (n_bases, G, 32) uint8 BE
-    m_limbs: jnp.ndarray,
-    m_prime: jnp.ndarray,
-    r_limbs: jnp.ndarray,
-    r2_limbs: jnp.ndarray,
-) -> jnp.ndarray:
+def _comb_program(bases_b, exps_b, *group):
     """Fixed-base comb exponentiation for GROUPED batches: every item
     of group i is a power of bases[i].
 
@@ -656,84 +948,66 @@ def _pow_fused_grouped(
     ops.tpke.issue_share_columns for the lockstep executor's byte
     columns, issue_shares_batch for int lists) — and a shared base
     makes the classic comb trade: build the 64x16 table of
-    base^(j * 16^k) ONCE per base on device (~1.2k Montgomery
-    multiplies amortized over the whole group), then each
-    exponentiation is 64 table-select multiplies instead of the
-    generic kernel's 512 square-and-multiply steps — ~6x fewer vector
-    ops at G >= 64.  Returns (n_bases, G, 33) uint8 LE.
+    base^(j * 16^k) ONCE per base on device (271 Montgomery products
+    in a row at the width of n_bases, shared by the whole group), then
+    each exponentiation is 64 table-select multiplies instead of the
+    generic ladder's 512 square-and-multiply steps.  Three loops: the
+    squarings that give base^(16^k), the 15 table rows, the 64 comb
+    steps (scans as plain XLA, a kernel each on a TPU).  Returns
+    (n_bases, G, 33) uint8 LE.
+
+    Layouts: the tables build on (NLIMBS, 1, n_bases) and
+    (NLIMBS, 64, n_bases), bases on the lanes; the accumulator is
+    (NLIMBS, G / rep, n_bases * rep): an item's lane is its base's
+    lane, so a table row is read along the sublanes as it lies, and
+    ``rep`` items of a base sit side by side so that few bases still
+    fill the 128 lanes.  Under a mesh the base rows shard flat and
+    each device runs the whole comb on its own: a table builds where
+    its exponents are.
     """
     n_bases, G = exps_b.shape[0], exps_b.shape[1]
-    m_col = m_limbs[:, None]
+    consts = _group_consts(*group)
 
-    def mul2(a, b):  # (NLIMBS, X) x (NLIMBS, X)
-        return _mont_mul(a, b, m_col, m_prime)
-
-    base_limbs = _bytes33_to_limbs_dev(bases_b).T  # (NLIMBS, n_bases)
-    r2 = jnp.broadcast_to(r2_limbs[:, None], base_limbs.shape)
-    base_m = mul2(base_limbs, r2)
-    one_col = jnp.broadcast_to(
-        r_limbs[:, None], base_limbs.shape
-    ).astype(jnp.int32)  # Montgomery 1 per base
-
-    # ---- device table build: T[k, j] = base^(j * 16^k), Montgomery
-    # domain, shape (64, 16, NLIMBS, n_bases) ----
-    def next_s(s, _):
-        s2 = mul2(s, s)
-        s4 = mul2(s2, s2)
-        s8 = mul2(s4, s4)
-        s16 = mul2(s8, s8)
-        return s16, s
-    _, s_all = jax.lax.scan(next_s, base_m, None, length=64)
-    # s_all: (64, NLIMBS, n_bases) = base^(16^k)
-
-    def fill_row(s_k):
-        def step(t, _):
-            t2 = mul2(t, s_k)
-            return t2, t2
-        _, rows = jax.lax.scan(step, one_col, None, length=15)
-        # rows: j = 1..15; prepend j = 0 (Montgomery one)
-        return jnp.concatenate([one_col[None], rows], axis=0)
-    table = jax.vmap(fill_row)(s_all)  # (64, 16, NLIMBS, n_bases)
-
-    # ---- nibbles: exps (n_bases, G, 32) BE -> (64, n_bases, G) with
-    # nib[k] = bits [4k, 4k+4) (little-endian nibble order) ----
-    lo = (exps_b & 0xF).astype(jnp.int32)  # (n_bases, G, 32)
-    hi = (exps_b >> 4).astype(jnp.int32)
-    # byte 31 (LSB) supplies nibbles k=0 (lo) and k=1 (hi)
-    nib = jnp.stack([lo[..., ::-1], hi[..., ::-1]], axis=-1).reshape(
-        n_bases, G, 64
+    # ---- the table: T[k, j] = base^(j * 16^k), Montgomery domain,
+    # bases on the lanes ----
+    base = _bytes33_to_limbs_dev(bases_b.T[:, None, :])
+    base_m = _run_planes(_comb_to_montgomery, consts, base)
+    # base^(16^k): every fourth of 252 squarings in a row
+    powers = jnp.concatenate(
+        [base_m[None], _iterate(_comb_square, 252, 4, consts, base_m)]
     )
-    nibs = jnp.moveaxis(nib, -1, 0)  # (64, n_bases, G)
+    powers = jnp.moveaxis(powers[:, :, 0], 0, 1)  # (NLIMBS, 64, n_bases)
+    one_rows = jnp.broadcast_to(consts[NLIMBS : 2 * NLIMBS], powers.shape)
+    rows = _iterate(_comb_times, 15, 1, consts, one_rows, powers)
+    table = jnp.concatenate([one_rows[None], rows])  # (16, NLIMBS, 64, ·)
 
-    # ---- comb accumulation over the 64 positions ----
-    flat = (NLIMBS, n_bases * G)
-    acc0 = jnp.broadcast_to(
-        r_limbs[:, None, None], (NLIMBS, n_bases, G)
-    ).astype(jnp.int32)
+    # ---- items on (G / rep, n_bases * rep): an item's lane is its
+    # base's lane, ``rep`` items of a base side by side ----
+    rep = min(G, 1 << max(0, (_LANES // n_bases).bit_length() - 1))
+    width = (G // rep, n_bases * rep)
+    table = jnp.repeat(jnp.moveaxis(table, 2, 0), rep, axis=-1)
+    # nibbles: exps (n_bases, G, 32) BE -> nib[k] = bits [4k, 4k+4);
+    # byte 31 (LSB) supplies nibbles k=0 (low half) and k=1 (high)
+    nib = jnp.stack(
+        [exps_b[..., ::-1] & 0xF, exps_b[..., ::-1] >> 4], axis=-1
+    ).reshape(n_bases, G // rep, rep, 64)
+    nibs = jnp.transpose(nib, (3, 1, 0, 2)).reshape((64,) + width)
 
-    def comb_step(acc, xs):
-        t_k, nib_k = xs  # (16, NLIMBS, n_bases), (n_bases, G)
-        # one-hot select in int32: exactly one of the 16 table entries
-        # is hot per item.  NOT an fp32 dot — JAX's default matmul
-        # precision feeds fp32 contractions through bf16 (8-bit
-        # mantissa), which silently corrupts 12-bit limbs.
-        onehot = (
-            nib_k[None, :, :] == jnp.arange(16, dtype=jnp.int32)[:, None, None]
-        ).astype(jnp.int32)  # (16, n_bases, G)
-        sel = jnp.einsum(
-            "jlb,jbg->lbg", t_k, onehot, preferred_element_type=jnp.int32
-        )  # (NLIMBS, n_bases, G)
-        acc = mul2(
-            acc.reshape(flat), sel.reshape(flat)
-        ).reshape(NLIMBS, n_bases, G)
-        return acc, None
+    out = _comb_accumulate(consts, table, nibs.astype(jnp.int32))
+    out = _limbs_to_bytes33_dev(out).reshape(33, G // rep, n_bases, rep)
+    return jnp.transpose(out, (2, 1, 3, 0)).reshape(n_bases, G, 33)
 
-    acc, _ = jax.lax.scan(comb_step, acc0, (table, nibs))
-    one = jnp.asarray(int_to_limbs(1))[:, None]
-    out = mul2(
-        acc.reshape(flat), jnp.broadcast_to(one, flat)
-    )  # leave Montgomery domain
-    return _limbs_to_bytes33_dev(out.T).reshape(n_bases, G, 33)
+
+# (n_bases, 33) uint8 LE bases, (n_bases, G, 32) uint8 BE exponents,
+# the _spec256 constants -> (n_bases, G, 33) uint8 LE
+_pow_fused_grouped = _jit_program(_comb_program, 2, "_pow_fused_grouped")
+
+
+@functools.cache
+def _wide_kernels(lay: LimbLayout):
+    """The same two programs for one wide limb family (the looped
+    product from 72 limbs up), compiled once per (layout, shape)."""
+    return _make_programs(lay)
 
 
 def _ints_to_bytes32le(xs: Sequence[int]) -> np.ndarray:
@@ -857,6 +1131,8 @@ class ModEngine:
             raise ValueError(backend)
         self.backend = backend
         self.mesh = mesh
+        # the jax Mesh itself: a static argument of the 256-bit programs
+        self._jax_mesh = None if mesh is None else mesh.mesh
         self.group = group
         self._fits256 = group.p.bit_length() <= 256 and group.p % 2 == 1
         self._layout = layout_for_group(group)
@@ -1047,7 +1323,8 @@ class ModEngine:
         against one coin base / ciphertext c1 / generator).
 
         On the device path this runs the fixed-base comb kernel
-        (_pow_fused_grouped, ~6x the generic kernel at G >= 64);
+        (_pow_fused_grouped: 64 products an item where the ladder takes
+        512; single-call times by width in PERF.md section 6, PR 30);
         below the host floor, or for oversize groups on 'cpu', it
         falls back to the generic batched path.  The int form of
         ``pow_grouped_cols``: same plan, same programs, same shapes.
@@ -1180,6 +1457,7 @@ class ModEngine:
                         _pow_fused_grouped(
                             *self._put(bases_arr, exps_arr),
                             *self._xla_spec,
+                            mesh=self._jax_mesh,
                         )
                     )
                 with trace.span("ops", "unpack"):
@@ -1319,7 +1597,9 @@ class ModEngine:
         with trace.span("ops", "device", program="_pow_fused"):
             base_d, exp_d, b = self._place(base_b, exp_b)
             return self._fetch(
-                _pow_fused(base_d, exp_d, *self._xla_spec)
+                _pow_fused(
+                    base_d, exp_d, *self._xla_spec, mesh=self._jax_mesh
+                )
             )[:b]
 
     def _need_columnar(self) -> None:
@@ -1442,7 +1722,10 @@ class ModEngine:
         with trace.span("ops", "device", program="_dual_pow_fused"):
             u1_d, e1_d, u2_d, e2_d, b = self._place(u1_b, e1_b, u2_b, e2_b)
             return self._fetch(
-                _dual_pow_fused(u1_d, e1_d, u2_d, e2_d, *self._xla_spec)
+                _dual_pow_fused(
+                    u1_d, e1_d, u2_d, e2_d, *self._xla_spec,
+                    mesh=self._jax_mesh,
+                )
             )[:b]
 
 

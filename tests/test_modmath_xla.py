@@ -52,6 +52,254 @@ def test_mont_mul_batch_layout_roundtrip():
         assert mm.limbs_to_int(out[i]) == xs[i] * ys[i] * r_inv % p
 
 
+def _below_p_all_ones() -> int:
+    """The largest value under p whose limbs below the top one are all
+    0xFFF."""
+    top = mm.LIMB_BITS * (mm.NLIMBS - 1)
+    x = (((mm.P >> top) - 1) << top) | ((1 << top) - 1)
+    assert x < mm.P and all(l == mm.LIMB_MASK for l in mm.int_to_limbs(x)[:-1])
+    return x
+
+
+@pytest.mark.parametrize("width", [1, 8, 127, 128, 129, 1024])
+def test_mont_mul_matches_python_ints(width):
+    """The limb-plane product against Python integers: random operands
+    and the edges, at widths on both sides of the in-program padding
+    to whole rows of 128."""
+    p = mm.P
+    rnd = random.Random(width)
+    edges = [0, 1, p - 1, p - 2, _below_p_all_ones()]
+    xs = [edges[i % 5] if i < 10 else rnd.randrange(p) for i in range(width)]
+    ys = [
+        edges[(i // 5) % 5] if i < 25 else rnd.randrange(p)
+        for i in range(width)
+    ]
+    if width >= 8:  # every edge against every edge, and against random
+        xs[-5:] = edges
+        ys[-5:] = [p - 1] * 5
+    out = np.asarray(
+        mm.mont_mul_batch(mm.ints_to_limbs(xs), mm.ints_to_limbs(ys))
+    )
+    assert out.shape == (width, mm.NLIMBS)
+    assert (out >= 0).all() and (out <= mm.LIMB_MASK).all()
+    r_inv = pow(mm.R, -1, p)
+    assert mm.limbs_to_ints(out) == [
+        x * y * r_inv % p for x, y in zip(xs, ys)
+    ]
+
+
+def test_lazy_carry_accumulators_stay_under_int32():
+    """The worst case of the lazy carries, a = b = p - 1 (and, beyond
+    what the product is ever given, every limb 0xFFF): an int64 replay
+    of the limb steps never reaches 2^31, and the int32 kernel agrees
+    with it."""
+    p = mm.P
+    m = mm.int_to_limbs(p).astype(np.int64)
+    m_prime = (-pow(p, -1, 1 << mm.LIMB_BITS)) % (1 << mm.LIMB_BITS)
+
+    def replay(x):
+        a = mm.int_to_limbs(x).astype(np.int64)
+        t = np.zeros(mm.NLIMBS, dtype=np.int64)
+        peak = 0
+        for i in range(mm.NLIMBS):
+            t = t + a[i] * a
+            q = ((t[0] & mm.LIMB_MASK) * m_prime) & mm.LIMB_MASK
+            t = t + q * m
+            peak = max(peak, int(t.max()))
+            t = np.concatenate([[t[1] + (t[0] >> mm.LIMB_BITS)], t[2:], [0]])
+            peak = max(peak, int(t.max()))
+        return peak, sum(int(v) << (mm.LIMB_BITS * j) for j, v in enumerate(t))
+
+    for x in (p - 1, mm.R - 1):
+        peak, t = replay(x)
+        assert peak < 1 << 31
+        assert t < 2 * p or x >= p
+    _peak, t = replay(p - 1)
+    got = np.asarray(
+        mm.mont_mul_batch(mm.ints_to_limbs([p - 1]), mm.ints_to_limbs([p - 1]))
+    )
+    assert mm.limbs_to_int(got[0]) == t % p == pow(p - 1, 2, p) * pow(
+        mm.R, -1, p
+    ) % p
+
+
+def _random_cols(rnd, n):
+    p = mm.P
+    vals = [0, 1, p - 1, p - 2][: min(4, n)] + [
+        rnd.randrange(p) for _ in range(max(0, n - 4))
+    ]
+    rnd.shuffle(vals)
+    exps = np.frombuffer(rnd.randbytes(n * 32), dtype=np.uint8).reshape(n, 32)
+    exps = exps.copy()
+    exps[0] = 0
+    exps[-1] = 255
+    return mm.ints_to_bytes33(vals), exps
+
+
+@pytest.mark.parametrize("rows", [11, 128, 300])
+def test_device_columns_equal_the_native_host_kernel(rows, monkeypatch):
+    """pow_cols and dual_pow_cols on the device programs
+    (host_delegation off) against the native host kernel, byte for
+    byte: a bucket under one row of 128, one row exactly, and a
+    padded bucket of several rows."""
+    if mm._native_modpow() is None:
+        pytest.skip("no native toolchain")
+    monkeypatch.setattr(mm.ModEngine, "host_delegation", False)
+    dev, host = mm.ModEngine("tpu"), mm.ModEngine("cpu")
+    rnd = random.Random(rows)
+    u1, e1 = _random_cols(rnd, rows)
+    u2, e2 = _random_cols(rnd, rows)
+    assert (dev.pow_cols(u1, e1) == host.pow_cols(u1, e1)).all()
+    assert (
+        dev.dual_pow_cols(u1, e1, u2, e2) == host.dual_pow_cols(u1, e1, u2, e2)
+    ).all()
+
+
+@pytest.mark.parametrize(
+    "heights,widths",
+    [((3,), (40,)), ((1, 2), (600, 130)), ((9,), (128,))],
+    ids=["one-bucket", "row-split-and-tail", "nine-rows"],
+)
+def test_device_comb_equals_the_native_host_kernel(
+    heights, widths, monkeypatch
+):
+    """pow_grouped_cols on the comb program against the native host
+    kernel, byte for byte: a single padded bucket; a group over G_ROW
+    whose tail lands in another bucket beside a block of two rows; a
+    block of nine rows (padded to sixteen bases)."""
+    if mm._native_modpow() is None:
+        pytest.skip("no native toolchain")
+    monkeypatch.setattr(mm.ModEngine, "host_delegation", False)
+    dev, host = mm.ModEngine("tpu"), mm.ModEngine("cpu")
+    rnd = random.Random(sum(widths))
+    blocks = []
+    for h, w in zip(heights, widths):
+        bases = [0, 1, mm.P - 1][:h] + [
+            rnd.randrange(2, mm.P) for _ in range(max(0, h - 3))
+        ]
+        exps = np.frombuffer(
+            rnd.randbytes(h * w * 32), dtype=np.uint8
+        ).reshape(h, w, 32).copy()
+        exps[0, 0] = 0
+        exps[-1, -1] = 255
+        blocks.append((bases, exps))
+    got = dev.pow_grouped_cols(blocks)
+    want = host.pow_grouped_cols(blocks)
+    assert [g.shape for g in got] == [w.shape for w in want]
+    for g, w in zip(got, want):
+        assert (g == w).all()
+
+
+# ---------------------------------------------------------------------------
+# the compiled programs' structure: what the product must not grow back
+# ---------------------------------------------------------------------------
+
+
+def _computations(hlo: str):
+    """name -> body text of every computation of an HLO module."""
+    import re
+
+    out = {}
+    for block in re.split(r"\n(?=(?:ENTRY )?%?[\w.\-]+ \()", hlo):
+        m = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(", block)
+        if m:
+            out[m.group(1)] = block
+    return out
+
+
+def _loop_bodies(hlo: str):
+    """The text of every ``while`` body, with the computations it
+    calls (fusions, calls) appended."""
+    import re
+
+    comps = _computations(hlo)
+    bodies = []
+    for name in re.findall(r"body=%?([\w.\-]+)", hlo):
+        text, seen, todo = "", set(), [name]
+        while todo:
+            n = todo.pop()
+            if n in seen or n not in comps:
+                continue
+            seen.add(n)
+            text += comps[n]
+            todo += re.findall(r"(?:calls|to_apply)=%?([\w.\-]+)", comps[n])
+        bodies.append(text)
+    return bodies
+
+
+def _spec_arrays():
+    import jax.numpy as jnp
+
+    m_limbs, m_prime, r_limbs, r2_limbs = mm._spec256(mm.DEFAULT_GROUP)
+    return (
+        jnp.asarray(m_limbs), jnp.int32(m_prime),
+        jnp.asarray(r_limbs), jnp.asarray(r2_limbs),
+    )
+
+
+def _program_args(program, shape):
+    u8 = np.uint8
+    if program == "comb":
+        nb, g = shape
+        return mm._pow_fused_grouped, (
+            np.zeros((nb, 33), u8), np.zeros((nb, g, 32), u8)
+        )
+    val, exp = np.zeros((shape, 33), u8), np.zeros((shape, 32), u8)
+    if program == "pow":
+        return mm._pow_fused, (val, exp)
+    return mm._dual_pow_fused, (val, exp, val, exp)
+
+
+@pytest.mark.parametrize(
+    "program,shape", [("dual_pow", 8), ("pow", 8), ("comb", (8, 8))]
+)
+def test_compiled_programs_hold_no_loop_or_scatter_in_a_product(
+    program, shape
+):
+    """The ladders compile to exactly one ``while`` (the bit ladder),
+    the comb to its three scans, none nested in another; no loop body
+    holds a ``dynamic-update-slice`` but the comb's own stacking of
+    its two table scans' outputs: a product is straight-line code, and
+    no limb loop, dynamic slice or row scatter grows back into it."""
+    fn, args = _program_args(program, shape)
+    hlo = fn.lower(*args, *_spec_arrays()).compile().as_text()
+    bodies = _loop_bodies(hlo)
+    assert len(bodies) == (3 if program == "comb" else 1)
+    assert hlo.count(" while(") == len(bodies)
+    for body in bodies:
+        assert " while(" not in body
+    scatters = sum(body.count("dynamic-update-slice(") for body in bodies)
+    assert scatters == (2 if program == "comb" else 0)
+    assert "scatter(" not in hlo
+
+
+@pytest.mark.parametrize(
+    "program,shape", [("dual_pow", 16384), ("comb", (64, 256))]
+)
+def test_sharded_programs_hold_no_collective(program, shape):
+    """On four (virtual) devices the programs of the mesh cell's
+    shapes partition with no collective: every device runs its own
+    rows from unpack to repack."""
+    import jax
+
+    from cleisthenes_tpu.parallel.mesh import CryptoMesh
+
+    mesh = CryptoMesh((2, 2))
+    fn, args = _program_args(program, shape)
+    shapes = [
+        jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=mesh.spec_flat(a.ndim))
+        for a in args
+    ]
+    hlo = (
+        fn.lower(*shapes, *_spec_arrays(), mesh=mesh.mesh).compile().as_text()
+    )
+    for collective in (
+        "all-gather", "all-to-all", "all-reduce", "collective-permute"
+    ):
+        assert collective + "(" not in hlo
+        assert collective + "-start(" not in hlo
+
+
 def test_issue_and_combine_batch_match_scalar():
     """issue_shares_batch / combine_shares_batch vs their scalar
     equivalents (ops/tpke.py)."""
