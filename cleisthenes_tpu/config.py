@@ -23,17 +23,12 @@ from typing import Optional
 # each entry must be a bool Config field, read by the package, pinned
 # explicitly (flag=True/False) in the equivalence tests, and a
 # perfgate fingerprint key (a mode flip must never gate against the
-# other mode's trend records); every ``*_wave`` entry point must be
-# reachable from a module that reads one of these flags.  Adding an
-# arm seam = add its flag here + the fingerprint key + the pinned
-# equivalence test, or the analyzer gates the merge.
+# other mode's trend records).  Adding an arm seam = add its flag
+# here + the fingerprint key + the pinned equivalence test, or the
+# analyzer gates the merge.
 ARM_FLAGS = (
     "epoch_pipelining",
-    "hub_wave_flush",
     "order_then_settle",
-    "delivery_columnar",
-    "wave_routing",
-    "egress_columnar",
     "attested_log",
     "reduced_quorum",
     # int-valued arm: lanes=1 is the byte-equivalence baseline arm,
@@ -151,22 +146,16 @@ class Config:
         decrypt_lag_max so the activation boundary lands past every
         epoch the old roster could already have ordered OR still
         have in flight in the K-deep window.
-      delivery_columnar: columnar inbound delivery plane — wave-batched
-        MAC verification + shared-prefix frame-decode memoization on
-        both transports (see the field comment below).  False is the
-        scalar byte-equivalence arm.
-      wave_routing: wave-routed protocol ingest — the routing-layer
-        twin of delivery_columnar: one batch handler dispatch per
-        (message kind, delivery wave) through protocol.router's
-        WaveRouter instead of one Python call chain per payload (see
-        the field comment below).  False is the scalar per-payload
-        routing comparison arm.
-      egress_columnar: columnar outbound plane — one batched
-        encode+MAC-sign pass per node per wave (Authenticator
-        .sign_wire_wave + FrameEncodeMemo), coalesced frame writes,
-        and wave-batched native coin-share issue through the hub's
-        coin column (see the field comment below).  False is the
-        scalar per-send egress comparison arm.
+
+    The wave seams have ONE path each and no option: on a transport
+    that promises an idle callback the CryptoHub flushes once per
+    message wave; transports decode a wave's frames through the
+    shared-prefix memo, verify its MACs in one
+    Authenticator.verify_wire_many call and hand it to the handler in
+    one serve_wave call (protocol.router.WaveRouter: one batch handler
+    dispatch per (message kind, wave)); a coalescer flush signs in one
+    Authenticator.sign_wire_wave pass and a wave's coin-share issues
+    pool in the hub's coin column.
     """
 
     n: int = 4
@@ -195,14 +184,6 @@ class Config:
     # and VAL/ECHO exchange overlap e's decryption-share phase.
     # Commit order is unaffected (commits gate on the epoch counter).
     epoch_pipelining: bool = True
-    # Wave-deferred hub flushing (the columnar fast path): on
-    # transports that promise an idle callback, batched crypto runs
-    # ONLY at quiescence points, one columnar flush per message wave.
-    # False reverts to the pre-wave scalar discipline — every quorum
-    # event flushes the hub immediately — kept as the comparison arm
-    # of the cross-path equivalence test (seeded runs must commit
-    # byte-identical ledgers under either discipline).
-    hub_wave_flush: bool = True
     # Order-then-decrypt (the two-frontier commit split, after "The
     # Latency Price of Threshold Cryptosystems in Blockchains"): at
     # ACS output the epoch commits its CIPHERTEXT-ORDERED batch — a
@@ -217,54 +198,6 @@ class Config:
     # byte-equivalence comparison arm — same seed, same settled
     # plaintext log).
     order_then_settle: bool = True
-    # Delivery-plane columnarization (the inbound twin of
-    # hub_wave_flush): transports buffer inbound frames per message
-    # wave and verify their MACs through ONE
-    # Authenticator.verify_wire_many batch call per wave, and frame
-    # decode memoizes on the signing-prefix digest so a broadcast's N
-    # receiver frames decode once (transport.message.FrameDecodeMemo,
-    # FIFO-evicting).  False reverts to the per-frame scalar receive
-    # path — kept as the live byte-equivalence comparison arm (seeded
-    # runs must commit byte-identical ledgers under either arm;
-    # tests/test_delivery_equivalence.py).
-    delivery_columnar: bool = True
-    # Wave-routed protocol ingest (the routing-layer twin of
-    # delivery_columnar): transports hand a delivery wave's verified,
-    # decoded frames to the handler in ONE serve_wave call; the
-    # WaveRouter (protocol.router) demuxes them in a single pass into
-    # typed ingest columns keyed by (epoch, message kind) and invokes
-    # ONE batch handler entry point per (kind, wave) on ACS/RBC/BBA —
-    # replacing the per-payload HoneyBadger.handle_message -> ACS ->
-    # RBC/BBA Python call chain.  Effective only together with
-    # delivery_columnar on the channel wave path; the gRPC transport
-    # additionally folds a wave into one SerialDispatcher mailbox
-    # entry.  False reverts to the per-payload scalar routing chain —
-    # kept as the live byte-equivalence comparison arm (seeded runs
-    # must commit byte-identical ledgers under either arm;
-    # tests/test_delivery_equivalence.py).
-    wave_routing: bool = True
-    # Egress columnarization (the send-side twin of delivery_columnar,
-    # mirroring PR 9 on the outbound path): the CoalescingBroadcaster
-    # hands each flush's whole wave of folded bundles to ONE
-    # Authenticator.sign_wire_wave call per node per wave — the
-    # envelope body encodes once per distinct payload object (the
-    # shared-prefix FrameEncodeMemo, transport.message) and the
-    # per-receiver HMACs run as one batched pass over the PR-7
-    # precomputed key schedules — and the resulting frames coalesce
-    # into one write per peer per flush on both transports (one
-    # pending-queue post carrying the wave on ChannelNetwork; one
-    # stream write per peer on the gRPC send loop).  The same flag
-    # routes the protocol plane's pending coin-share issues through
-    # the CryptoHub's coin work column (ops.coin.share_batch): a
-    # wave's coin issues across ALL BBA instances and rounds execute
-    # as one native multi-exponentiation dispatch with one CP-nonce
-    # draw, instead of one issue_shares_batch call per node per wave.
-    # False reverts to the per-send scalar egress path (one
-    # sign_wire_many per post, one coin issue batch per node per
-    # drain) — kept as the live byte-equivalence comparison arm
-    # (seeded runs must commit byte-identical ledgers under either
-    # arm; tests/test_egress_equivalence.py).
-    egress_columnar: bool = True
     # K-deep pipelined epoch frontiers (ISSUE 15, the PR-8 split
     # generalized): epochs [self.epoch, self.epoch + K - 1] run their
     # RBC/BBA concurrently against the K-deep ordered window, each

@@ -59,13 +59,11 @@ def share_batch(
     verification key (None recomputes it in the same dispatch).
     Semantics match mapping ``tpke.issue_share`` over the items;
     result order matches input order.  The CryptoHub's coin-issue
-    column (``take_coin_issues``) dispatches through here; the scalar
-    comparison arm (``HoneyBadger._drain_coin_issues``) calls
-    ``tpke.issue_shares_batch`` directly (the lockstep spmd plane
-    issues byte columns, ``tpke.issue_share_columns``) —
-    the ``coin_share_batches`` counter is the hub's own tally,
-    incremented at BOTH the hub dispatch and the scalar drain, not a
-    call count of this function."""
+    column (``take_coin_issues``) dispatches through here (the
+    lockstep spmd plane issues byte columns,
+    ``tpke.issue_share_columns``) — the ``coin_share_batches``
+    counter is the hub's own tally of its dispatches, not a call
+    count of this function."""
     return issue_shares_batch(
         items, group=group, backend=backend, mesh=mesh
     )

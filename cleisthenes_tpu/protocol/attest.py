@@ -398,7 +398,7 @@ class AttestingAuthenticator(HmacAuthenticator):
         )
 
     def sign_wire_many(self, msg: Message, receiver_ids) -> "Dict[str, bytes]":
-        frames = super().sign_wire_many(  # staticcheck: allow[DET006] scalar arm
+        frames = super().sign_wire_many(  # staticcheck: allow[DET006] authenticator primitive
             msg, receiver_ids
         )
         refused = self.vault.observe(msg.payload)

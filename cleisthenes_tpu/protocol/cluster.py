@@ -138,9 +138,6 @@ class SimulatedCluster:
         # on a virtual clock, still byte-identical for a fixed seed
         self.net = ChannelNetwork(
             seed=seed,
-            delivery_columnar=self.config.delivery_columnar,
-            wave_routing=self.config.wave_routing,
-            egress_columnar=self.config.egress_columnar,
             wan_profile=wan_profile,
         )
         # dedup=True: the shared hub verifies each distinct pure crypto

@@ -39,10 +39,9 @@ pops WHOLESALE into the hub wave's branch columns, replacing the old
 per-root dict-of-dicts walk with one list handoff.
 
 Consistency contract: the bank is the SINGLE source of truth for
-ECHO/READY receipt state.  RBC's scalar path (per-payload deliveries,
-unit tests, non-columnar transports) writes through the same arrays,
-so columnar and scalar deliveries interleave freely and the
-``Config.delivery_columnar`` transport arms cannot diverge here.
+ECHO/READY receipt state.  RBC's per-payload entry points (VAL
+leaves, self-delivery, unit tests) write through the same arrays, so
+wave and per-payload deliveries interleave freely.
 
 Quorum semantics mirrored from RBC (docs/RBC-EN.md:35-42): +1
 increments under one-vote-per-sender dedup make exact-equality

@@ -52,7 +52,6 @@ from cleisthenes_tpu.ops.tpke import (
     ThresholdPublicKey,
     ThresholdSecretShare,
     Tpke,
-    issue_shares_batch,
 )
 from cleisthenes_tpu.protocol.acs import ACS
 from cleisthenes_tpu.utils.determinism import proposal_rng
@@ -504,7 +503,7 @@ class _LaneTagger:
 class HoneyBadger:
     """One validator node (reference honeybadger.go:18-34 + the absent
     epoch driver).  Implements transport.base.Handler, plus the
-    wave-ingest extension ``serve_wave`` (Config.wave_routing)."""
+    wave-ingest extension ``serve_wave``."""
 
     # the demux window's forward horizon, re-exported as a class
     # attribute so the WaveRouter reads it off its owner without a
@@ -602,8 +601,7 @@ class HoneyBadger:
         self.on_commit: Optional[Callable[[int, Batch], None]] = None
         self.metrics = Metrics()
         # coin-issue dispatch tallies -> snapshot()["hub"] (a shared
-        # hub reports cluster-wide numbers, like hub_dispatches; the
-        # counters move on BOTH egress arms — see _drain_coin_issues)
+        # hub reports cluster-wide numbers, like hub_dispatches)
         self.metrics.set_hub_stats(
             lambda: {
                 "coin_share_batches": self.hub.coin_issue_batches,
@@ -639,7 +637,6 @@ class HoneyBadger:
                 out,
                 self.members,
                 trace=self.trace,
-                egress_columnar=config.egress_columnar,
             )
         else:
             # ONE coalescer per node: sibling lanes tag their payloads
@@ -681,11 +678,9 @@ class HoneyBadger:
         # entries one serving window behind the settled frontier are
         # pruned (_advance_epoch), bounding the store.
         self._ordered_bodies: Dict[int, bytes] = {}
-        # wave-routed ingest (Config.wave_routing): transports in wave
-        # mode hand whole delivery waves to serve_wave; the router
-        # demuxes them into typed columns and makes one batch handler
-        # dispatch per (kind, wave).  Constructed unconditionally
-        # (cheap); only transports that saw wave_routing on call it.
+        # wave-routed ingest: transports hand whole delivery waves to
+        # serve_wave; the router demuxes them into typed columns and
+        # makes one batch handler dispatch per (kind, wave).
         from cleisthenes_tpu.protocol.router import WaveRouter
 
         self._router = WaveRouter(self)
@@ -1247,9 +1242,9 @@ class HoneyBadger:
             self._pipeline_active = False
 
     def maybe_follow_epoch(self, epoch: int, es: _EpochState) -> None:
-        """Follow-the-epoch — THE shared rule of both routing arms
-        (the scalar `_serve_payload` chain and the WaveRouter call
-        here, so the arms' follow windows can never drift apart):
+        """Follow-the-epoch — THE shared rule of both ingest entries
+        (`_serve_payload` and the WaveRouter call here, so their
+        follow windows can never drift apart):
         peer traffic showed an epoch inside our pipeline window
         [self.epoch, self.epoch + depth - 1] running without our
         proposal — contribute it (every correct node must propose or
@@ -1667,15 +1662,12 @@ class HoneyBadger:
         phase; SerialDispatcher's empty-mailbox check).  Moves outbound
         flushing and batched-crypto execution to those points, so one
         hub flush + one bundle per receiver absorbs an entire message
-        wave.  ``Config.hub_wave_flush=False`` keeps the hub on the
-        pre-wave scalar discipline (flush per quorum event) — the
-        equivalence-test comparison arm; outbound coalescing still
-        moves to the idle callback either way."""
+        wave.  A hub no transport has made this promise to stays
+        ``defer = False`` and flushes at every quorum event."""
         self._transport_managed = True
         for hb in self.lanes[1:]:  # siblings drain at OUR idle points
             hb._transport_managed = True
-        if self.config.hub_wave_flush:
-            self.hub.defer = True
+        self.hub.defer = True
 
     def flush_outbound(self) -> None:
         self._coalesce.flush()
@@ -1771,28 +1763,29 @@ class HoneyBadger:
         turn-exit / idle drain issues every parked share in ONE
         batched exponentiation dispatch instead of 4 scalar host exps
         per instance (a vote wave triggers a whole roster's worth of
-        aux quorums at once).  Under ``Config.egress_columnar`` the
-        want ALSO stages into the CryptoHub's coin-issue column at
-        queue time — during the message wave — so the idle phase's
-        FIRST drain executes the whole roster's wants (shared-hub
-        cluster) in one ``ops.coin.share_batch`` dispatch and later
-        drains claim precomputed shares."""
+        aux quorums at once).  The want ALSO stages into the
+        CryptoHub's coin-issue column at queue time — during the
+        message wave — so the idle phase's FIRST drain executes the
+        whole roster's wants (shared-hub cluster) in one
+        ``ops.coin.share_batch`` dispatch and later drains claim
+        precomputed shares."""
         self._pending_coin_issues.append((bba, rnd))
-        if self.config.egress_columnar:
-            # per-instance key material: a wave can span an activation
-            # boundary (dynamic membership), so each BBA issues under
-            # ITS epoch's coin key/share — the group is deployment-
-            # wide, so the whole mixed pool still batches into one
-            # dispatch
-            pub, base, context = bba.coin.group_params(bba._coin_id(rnd))
-            sec = bba.coin_secret
-            self.hub.stage_coin_issue(
-                self,
-                (bba, rnd),
-                (sec, base, context,
-                 pub.verification_keys[sec.index - 1]),
-                self.group,
-            )
+        # per-instance key material: a wave can span an activation
+        # boundary (dynamic membership), so each BBA issues under ITS
+        # epoch's coin key/share — the group is deployment-wide, so
+        # the whole mixed pool still batches into one dispatch.
+        # Halted BBAs still contribute: the issue was queued when the
+        # aux quorum fired, and withholding the (public,
+        # deterministic) share after a TERM decision can leave slower
+        # peers one share short of the coin threshold
+        pub, base, context = bba.coin.group_params(bba._coin_id(rnd))
+        sec = bba.coin_secret
+        self.hub.stage_coin_issue(
+            self,
+            (bba, rnd),
+            (sec, base, context, pub.verification_keys[sec.index - 1]),
+            self.group,
+        )
 
     def _drain_coin_issues(self) -> None:
         pend = self._pending_coin_issues
@@ -1802,59 +1795,20 @@ class HoneyBadger:
         with trace.span(
             "coin", "issue_batch", recorder=self.trace, n=len(pend)
         ):
-            self._issue_coin_shares(pend)
-
-    def _issue_coin_shares(self, pend) -> None:
-        if self.config.egress_columnar:
             # wave-batched coin kernel (ISSUE 13): the hub's coin
             # column hands back this node's shares, dispatching the
             # WHOLE staged pool natively iff some of ours are still
-            # pending — broadcast site, order, and timing identical
-            # to the scalar arm below
+            # pending
             for (bba, rnd), share in self.hub.take_coin_issues(self):
                 bba.broadcast_coin_share(rnd, share)
-            return
-        # per-instance key material: a wave can span an activation
-        # boundary (dynamic membership), so each BBA issues under ITS
-        # epoch's coin key/share — the group is deployment-wide, so
-        # the whole mixed wave still batches into one dispatch
-        group = self.group
-        items = []
-        metas = []
-        for bba, rnd in pend:
-            # halted BBAs still contribute: the issue was queued when
-            # the aux quorum fired, and withholding the (public,
-            # deterministic) share after a TERM decision can leave
-            # slower peers one share short of the coin threshold
-            pub, base, context = bba.coin.group_params(bba._coin_id(rnd))
-            sec = bba.coin_secret
-            items.append(
-                (sec, base, context,
-                 pub.verification_keys[sec.index - 1])
-            )
-            metas.append((bba, rnd))
-        # the scalar comparison arm counts its native dispatches on
-        # the same hub counters the columnar arm uses, so
-        # coin_dispatches_per_epoch compares like for like across arms
-        self.hub.coin_issue_batches += 1
-        self.hub.coin_issue_items += len(items)
-        shares = issue_shares_batch(
-            items,
-            group=group,
-            backend=self.crypto.engine_backend,
-            mesh=self.crypto.mesh,
-        )
-        for (bba, rnd), share in zip(metas, shares):
-            bba.broadcast_coin_share(rnd, share)
 
     # -- message demux (transport Handler) ---------------------------------
 
     def serve_wave(self, msgs) -> None:
-        """Wave-ingest entry (Config.wave_routing): one call carries a
-        whole delivery wave of verified, decoded frames; the router
-        demuxes them into typed columns and invokes one batch handler
-        per (message kind, wave) — the per-payload scalar chain below
-        stays live as the byte-equivalence comparison arm."""
+        """Wave-ingest entry: one call carries a whole delivery wave
+        of verified, decoded frames; the router demuxes them into
+        typed columns and invokes one batch handler per (message
+        kind, wave)."""
         try:
             self._idle_rx += len(msgs)
             if self.trace is not None:
@@ -1957,8 +1911,8 @@ class HoneyBadger:
                 # stale by definition, only dec shares still matter
                 return
             # follow the epoch: a peer is running it, so contribute our
-            # (possibly empty) proposal too (the shared rule of both
-            # routing arms — window and RNG-order discipline live in
+            # (possibly empty) proposal too (the rule the wave router
+            # shares — window and RNG-order discipline live in
             # maybe_follow_epoch)
             self.maybe_follow_epoch(epoch, es)
             self.metrics.handler_dispatches.inc()
@@ -1975,8 +1929,8 @@ class HoneyBadger:
 
     def _note_farahead(self) -> None:
         """One sighting of traffic beyond the forward demux horizon
-        (shared by the scalar chain and the wave router, per payload
-        so the renudge cadence matches across arms).  The first
+        (shared by serve_request and the wave router, counted per
+        payload on both).  The first
         sighting requests catch-up immediately (dedup'd per
         frontier); if the frontier then fails to move (our request or
         its responses were lost), every further CATCHUP_RENUDGE_EVERY
@@ -2377,8 +2331,8 @@ class HoneyBadger:
         """One sender's decryption shares across many proposers
         (DecShareBatchPayload): a width-1 wave — probes once per
         touched proposer, commit check once per frame (the shared
-        pooling loop lives in _handle_dec_share_wave, so the scalar
-        and wave arms cannot drift apart on the crossing rule)."""
+        pooling loop lives in _handle_dec_share_wave, so the
+        single-message and wave entries share the crossing rule)."""
         self._handle_dec_share_wave(epoch, es, ((sender, payload),))
 
     def _handle_dec_share_wave(

@@ -269,20 +269,18 @@ class CryptoHub:
         self.decode_items = 0
         self.share_items = 0
         self.dispatches = 0
-        # Wave-batched coin-issue column (Config.egress_columnar,
-        # ISSUE 13): owners park (secret, base, context, vk) issue
-        # items at aux-quorum time (stage_coin_issue) and collect the
-        # shares at their own drain point (take_coin_issues).  The
-        # FIRST taker of a wave executes EVERY staged owner's pending
-        # items in one ops.coin.share_batch dispatch — one native
-        # multi-exponentiation and one CP-nonce draw for all BBA
-        # instances and rounds the wave touched, across ALL nodes of
-        # a shared-hub cluster — and parks each owner's shares until
-        # its drain claims them, so broadcast order and timing stay
-        # byte-identical to the scalar arm (one issue batch per node
-        # per drain).  Counter semantics: coin_issue_batches counts
-        # native coin-issue dispatches on BOTH arms (the scalar drain
-        # increments it too), the number bench.py reports as
+        # Wave-batched coin-issue column (ISSUE 13): owners park
+        # (secret, base, context, vk) issue items at aux-quorum time
+        # (stage_coin_issue) and collect the shares at their own drain
+        # point (take_coin_issues).  The FIRST taker of a wave executes
+        # EVERY staged owner's pending items in one ops.coin.share_batch
+        # dispatch — one native multi-exponentiation and one CP-nonce
+        # draw for all BBA instances and rounds the wave touched, across
+        # ALL nodes of a shared-hub cluster — and parks each owner's
+        # shares until its drain claims them, so each owner still
+        # broadcasts at its own drain point, in stage order.  Counter
+        # semantics: coin_issue_batches counts native coin-issue
+        # dispatches, the number bench.py reports as
         # coin_dispatches_per_epoch and perfgate gates.
         self.coin_issue_batches = 0
         self.coin_issue_items = 0
@@ -695,7 +693,7 @@ class CryptoHub:
         for (item, keys) in zip(items, item_keys):
             item[5](item[3], [local[k] for k in keys])
 
-    # -- coin-issue column (Config.egress_columnar) ------------------------
+    # -- coin-issue column --------------------------------------------------
 
     def stage_coin_issue(self, owner, meta, item, group) -> None:
         """Park one coin-share issue want: ``item`` is the

@@ -17,20 +17,21 @@ entry points on ACS (which write EchoBank/VoteBank slots wholesale)
 and the dec-share wave handler on HoneyBadger.  Stale/future-epoch
 filtering happens once per column against the demux window instead of
 once per payload; far-ahead traffic still feeds the CATCHUP renudge
-counter payload-by-payload, so the traffic-clocked retry cadence is
-identical to the scalar arm's.
+counter payload-by-payload, so the traffic-clocked retry cadence
+counts sightings, not waves.
 
-The scalar ``handle_message`` chain stays live behind
-``Config.wave_routing=False`` as the byte-equivalence comparison arm
-(tests/test_delivery_equivalence.py): same seeded schedule, either
-routing discipline, byte-identical committed ledgers.
+``HoneyBadger.serve_request`` stays as the single-message entry
+(local self-delivery on the gRPC host, handlers driven one message at
+a time); it reaches the same protocol objects through
+``_serve_payload``, which is also the router's leaf for the
+order-sensitive barriers below.
 
 Ordering contract: within a wave, columns dispatch in first-occurrence
 order of their (kind, epoch) key — deterministic given the transport's
 (seeded or FIFO) delivery order, independent of PYTHONHASHSEED.
 CATCHUP payloads are order-sensitive barriers: the router flushes the
 columns accumulated so far, dispatches the catch-up payload through
-the scalar chain, and keeps demuxing — catch-up traffic is rare, so a
+``_serve_payload``, and keeps demuxing — catch-up traffic is rare, so a
 steady-state wave is one flush.
 """
 
@@ -61,7 +62,7 @@ from cleisthenes_tpu.transport.message import (
 )
 from cleisthenes_tpu.utils import trace
 
-# the scalar chain handles these outside the epoch demux entirely
+# _serve_payload handles these outside the epoch demux entirely
 # (CATCHUP state transfer + reconfig gossip: epoch-unscoped, rare,
 # and order-sensitive relative to the columns around them)
 _CATCHUP_PAYLOADS = (
@@ -86,7 +87,7 @@ class WaveRouter:
 
     Owned by (and coupled to) one HoneyBadger: the router reads the
     node's epoch window through ``_epoch_state`` and dispatches into
-    the same protocol objects the scalar chain reaches — it changes
+    the same protocol objects ``serve_request`` reaches — it decides
     HOW MANY Python calls carry a wave, never what state they write.
 
     Lock audit (ISSUE 17): deliberately unlocked.  The router holds no
@@ -131,7 +132,7 @@ class WaveRouter:
                     logical += _logical(p)
                     if not self._demux(cols, sender, p):
                         # order-sensitive barrier (CATCHUP): flush what
-                        # accumulated, scalar-dispatch, keep demuxing
+                        # accumulated, dispatch it alone, keep demuxing
                         self._dispatch_all(cols)
                         cols = {}
                         hb._serve_payload(sender, p)
@@ -154,11 +155,11 @@ class WaveRouter:
         if cls is LanePayload:
             lane = p.lane
             if not (0 < lane < len(self._hb.lanes)):
-                return True  # unknown lane: drop, like the scalar arm
+                return True  # unknown lane: drop
             p = p.inner
             cls = p.__class__
             if cls in _CATCHUP_PAYLOADS:
-                # barrier: the scalar chain demuxes the WRAPPED
+                # barrier: _serve_payload demuxes the WRAPPED
                 # payload into the sibling (route() passes the
                 # original payload object)
                 return False
@@ -191,7 +192,7 @@ class WaveRouter:
             elif t == RbcType.READY:
                 item = (sender, (p.proposer,), (p.root_hash,))
                 key = (_K_READY, p.epoch)
-            else:  # VAL: bulky one-per-instance payloads stay scalar
+            else:  # VAL: bulky one-per-instance payloads go one by one
                 item = (sender, p)
                 key = (_K_VAL, p.epoch)
         elif cls is BbaPayload:
@@ -205,7 +206,7 @@ class WaveRouter:
             key = (_K_COIN, p.epoch)
         elif cls in _CATCHUP_PAYLOADS:
             return False
-        else:  # unknown/epochless payloads drop, like the scalar arm
+        else:  # unknown/epochless payloads drop
             return True
         if lane:
             # lane columns stay distinct but ride the SAME wave: one
@@ -234,8 +235,7 @@ class WaveRouter:
         """One column = one handler invocation (the counter perfgate
         gates).  The demux window is checked HERE — column granularity
         — because an earlier column's dispatch may advance the epoch
-        frontier mid-wave, exactly like a handler turn does on the
-        scalar arm."""
+        frontier mid-wave."""
         hb = self._hb
         es = hb._epochs.get(epoch) or hb._epoch_state(epoch)
         if es is None:  # outside the sliding window, or not a member
@@ -244,10 +244,10 @@ class WaveRouter:
                 and not hb.roster_for(epoch).local
             ):
                 # per-payload sightings: the CATCHUP renudge cadence
-                # is counted in payloads, and must tick identically
-                # under either routing arm (the second arm is the
-                # dynamic-membership joiner watching epochs it cannot
-                # participate in run ahead of its adopted frontier)
+                # is counted in payloads, as serve_request counts it
+                # (the second clause is the dynamic-membership joiner
+                # watching epochs it cannot participate in run ahead
+                # of its adopted frontier)
                 for _ in items:
                     hb._note_farahead()
             return
@@ -263,7 +263,7 @@ class WaveRouter:
             return
         # the K-deep follow window (== {hb.epoch} at depth 1); the
         # predicate and RNG-order discipline are the owner's, shared
-        # with the scalar arm so the two can never drift apart
+        # with serve_request so the two can never drift apart
         hb.maybe_follow_epoch(epoch, es)
         metrics.handler_dispatches.inc()
         if kind == _K_VOTE:

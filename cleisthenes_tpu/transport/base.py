@@ -144,14 +144,13 @@ class Authenticator(abc.ABC):
         return self.verify(msg)
 
     def verify_wire_many(self, msgs, signing_prefixes) -> "List[bool]":
-        """Verdicts for one inbound wave's frames in ONE call
-        (Config.delivery_columnar): the transports buffer frames per
-        message wave and verify them together, so per-frame python
-        dispatch amortizes across the batch.  Default: loop
-        verify_wire.  MAC backends override to hoist the per-sender
-        key-schedule lookup out of the loop (PR 7's _hmac_sha256_fn
-        contexts are per-pair constants — one dict probe per DISTINCT
-        sender per wave instead of one per frame)."""
+        """Verdicts for one inbound wave's frames in ONE call: the
+        transports buffer frames per message wave and verify them
+        together, so per-frame python dispatch amortizes across the
+        batch.  Default: loop verify_wire.  MAC backends override to
+        hoist the per-sender key-schedule lookup out of the loop (PR 7's
+        _hmac_sha256_fn contexts are per-pair constants — one dict probe
+        per DISTINCT sender per wave instead of one per frame)."""
         return [
             self.verify_wire(m, p) for m, p in zip(msgs, signing_prefixes)
         ]
@@ -172,8 +171,8 @@ class Authenticator(abc.ABC):
         }
 
     def sign_wire_wave(self, items, memo=None) -> "List[Dict[str, bytes]]":
-        """One EGRESS wave's frames in ONE call (Config.egress_columnar)
-        — the send-side twin of ``verify_wire_many``.
+        """One EGRESS wave's frames in ONE call — the send-side twin
+        of ``verify_wire_many``.
 
         ``items`` is ``[(msg, receiver_ids)]``: everything one
         coalescer flush ships (one folded bundle per receiver, or one
@@ -201,17 +200,15 @@ def sign_wave_counted(auth: "Authenticator", items, memo):
     unit) is the FrameEncodeMemo's miss delta when the signer
     consulted the memo (Hmac/Null always probe at least once per
     item); a backend whose wave path ignores the memo (the ABC's
-    per-item default) falls back to the scalar arm's unit — payload
+    per-item default) falls back to the per-frame unit — payload
     bodies per entry — WITHOUT inventing memo misses for probes that
     never happened, so the memo stat surfaces stay truthful and the
     perfgate-gated counter never silently reads zero."""
     from cleisthenes_tpu.transport.message import payload_body_count
 
-    h0 = memo.hits if memo is not None else 0
-    m0 = memo.misses if memo is not None else 0
+    h0, m0 = memo.hits, memo.misses
     frames_list = auth.sign_wire_wave(items, memo)
-    hits = (memo.hits - h0) if memo is not None else 0
-    misses = (memo.misses - m0) if memo is not None else 0
+    hits, misses = memo.hits - h0, memo.misses - m0
     if hits or misses:
         return frames_list, hits, misses, misses
     bodies = sum(payload_body_count(m.payload) for m, _rids in items)
@@ -497,8 +494,8 @@ class HmacAuthenticator(Authenticator):
         return out
 
     def sign_wire_wave(self, items, memo=None) -> "List[Dict[str, bytes]]":
-        """Egress wave fast path (Config.egress_columnar): the whole
-        flush's envelope bodies encode once per distinct payload
+        """Egress wave fast path: the whole flush's envelope bodies
+        encode once per distinct payload
         OBJECT through the caller's FrameEncodeMemo — a mixed wave's
         per-receiver bundles share their broadcast run's sub-payloads,
         so N receiver bundles cost one encode each plus joins — and

@@ -83,12 +83,11 @@ class ChannelBroadcaster:
         self._network.post(self._node_id, member_id, self._wrap(payload))
 
     def post_wave(self, entries) -> None:
-        """One egress wave (Config.egress_columnar): ``entries`` are
-        ``(member_id | None, payload)`` pairs — None addresses the full
-        broadcast set.  The whole wave crosses into the network in ONE
-        call, where the sender endpoint's ``sign_wire_wave`` encodes
-        each distinct body once and MACs the wave in one batched
-        pass."""
+        """One egress wave: ``entries`` are ``(member_id | None,
+        payload)`` pairs — None addresses the full broadcast set.  The
+        whole wave crosses into the network in ONE call, where the
+        sender endpoint's ``sign_wire_wave`` encodes each distinct body
+        once and MACs the wave in one batched pass."""
         wave = [
             (
                 self._members if member_id is None else (member_id,),
@@ -229,21 +228,17 @@ class CoalescingBroadcaster:
         inner,
         member_ids: Sequence[str],
         trace=None,
-        egress_columnar: bool = False,
     ) -> None:
         self._inner = inner
         self._members: List[str] = sorted(member_ids)
-        # Config.egress_columnar: hand each flush's whole wave of
-        # folded bundles to the inner broadcaster in ONE post_wave
-        # call — the transport signs it through one
-        # Authenticator.sign_wire_wave pass (shared-prefix
+        # Each flush's whole wave of folded bundles goes to the inner
+        # broadcaster in ONE post_wave call — the transport signs it
+        # through one Authenticator.sign_wire_wave pass (shared-prefix
         # FrameEncodeMemo, batched MACs) and writes one frame per peer
-        # per flush.  Falls back to the scalar per-post path when the
-        # inner broadcaster has no wave entry point (bare test
-        # broadcasters).
-        self._egress_wave = (
-            egress_columnar and getattr(inner, "post_wave", None) is not None
-        )
+        # per flush.  An inner broadcaster with no wave entry point (a
+        # bare PayloadBroadcaster) gets one broadcast/send_to per
+        # bundle instead.
+        self._egress_wave = getattr(inner, "post_wave", None) is not None
         # Broadcast payloads buffer ONCE on a shared list (a wave is
         # ~50k broadcasts at N=64; appending each to N per-receiver
         # buffers was ~1 s of epoch wall).  send_to payloads park per
@@ -386,11 +381,10 @@ class CoalescingBroadcaster:
         self,
     ) -> Tuple[List[Payload], Dict[str, List[Payload]]]:
         """Pop the wave's buffers into every receiver's arrival-order
-        merged view (shared between the scalar mixed path and the
-        columnar wave path, so the two byte-equivalence arms cannot
-        diverge here).  Receivers with no extras ALIAS the shared
-        list — never mutated downstream; the columnar path keys on
-        that identity to fold it once."""
+        merged view (shared between the wave path and the per-bundle
+        path of a bare inner broadcaster).  Receivers with no extras
+        ALIAS the shared list — never mutated downstream; the wave
+        path keys on that identity to fold it once."""
         shared, self._shared = self._shared, []
         merged: Dict[str, List[Payload]] = {}
         for m in self._members:
@@ -403,8 +397,8 @@ class CoalescingBroadcaster:
         return shared, merged
 
     def _flush_mixed_wave(self) -> None:
-        """Mixed-wave columnar flush (Config.egress_columnar): every
-        receiver's merged bundle ships in ONE ``post_wave`` call.
+        """Mixed-wave flush: every receiver's merged bundle ships in
+        ONE ``post_wave`` call.
         Receivers whose bundle is exactly the shared broadcast run
         share one folded payload OBJECT, so the transport's
         FrameEncodeMemo collapses their envelope bodies to a single
@@ -412,7 +406,7 @@ class CoalescingBroadcaster:
         injected per-receiver lies) fold individually but still share
         their sub-payload objects with the run.  A transport failure
         re-parks every receiver's merged view for the retry, exactly
-        like the scalar mixed path."""
+        like the per-bundle path."""
         shared, merged = self._merged_views()
         entries: List[tuple] = []
         shared_fold: Optional[Payload] = None

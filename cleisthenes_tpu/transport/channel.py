@@ -39,7 +39,6 @@ from cleisthenes_tpu.transport.message import (
     decode_frame,
     decode_frame_shared,
     encode_message,
-    payload_body_count,
 )
 from cleisthenes_tpu.transport.wan import WanEmulator, WanProfile
 from cleisthenes_tpu.utils import trace
@@ -61,31 +60,29 @@ class ChannelEndpoint:
         node_id: str,
         handler: Handler,
         auth: Authenticator,
-        encode_memo: Optional[FrameEncodeMemo] = None,
     ) -> None:
         self.node_id = node_id
         self.auth = auth
         self.delivered = 0
         self.rejected = 0  # failed MAC verification
-        # delivery-plane counters (Config.delivery_columnar; zeroed
-        # keys of Metrics.snapshot()["transport"] via endpoint_stats):
-        # payload decodes actually executed / shared-prefix memo
-        # hits+misses / Authenticator verify invocations (one per
-        # frame scalar, one per wave batch columnar)
+        # delivery-plane counters (keys of
+        # Metrics.snapshot()["transport"] via endpoint_stats): payload
+        # decodes actually executed / shared-prefix memo hits+misses /
+        # Authenticator verify invocations (one per wave batch, one
+        # per frame a fault_filter had to see)
         self.frames_decoded = 0
         self.decode_memo_hits = 0
         self.decode_memo_misses = 0
         self.mac_verify_batches = 0
-        # egress-plane counters (Config.egress_columnar, the send-side
-        # twins): payload bodies actually encoded / shared-prefix
-        # encode-memo hits+misses / Authenticator sign invocations
-        # (one per post scalar, one per wave columnar).  The memo is
-        # THIS node's outbound encode memo (None on the scalar arm).
+        # egress-plane counters (the send-side twins): payload bodies
+        # actually encoded / shared-prefix encode-memo hits+misses /
+        # Authenticator sign invocations (one per egress wave).  The
+        # memo is THIS node's outbound encode memo.
         self.frames_encoded = 0
         self.encode_memo_hits = 0
         self.encode_memo_misses = 0
         self.mac_sign_batches = 0
-        self.encode_memo = encode_memo
+        self.encode_memo = FrameEncodeMemo()
         self.bind(handler)
 
     def bind(self, handler: Handler) -> None:
@@ -146,9 +143,6 @@ class ChannelNetwork:
         self,
         seed: Optional[int] = None,
         queue_capacity: int = 1_000_000,
-        delivery_columnar: bool = False,
-        wave_routing: bool = False,
-        egress_columnar: bool = False,
         wan_profile: Optional[Union[str, WanProfile]] = None,
     ):
         # seed=None -> FIFO delivery; seed=int -> seeded random-order
@@ -159,8 +153,8 @@ class ChannelNetwork:
         # FIFO mode uses a deque (O(1) popleft); seeded mode uses a
         # list with swap-pop (O(1) uniform removal, order irrelevant).
         # Entries are 5-slot LISTS [sender, receiver, wire, prefiltered,
-        # prepared] — slot 4 holds the columnar arm's pre-wave decode +
-        # MAC verdict (None until a wave pass prepares it).
+        # prepared] — slot 4 holds the pre-wave decode + MAC verdict
+        # (None until a wave pass prepares it).
         self._pending = collections.deque() if seed is None else []
         self._queue_capacity = queue_capacity
         self._crashed: Set[str] = set()
@@ -169,45 +163,37 @@ class ChannelNetwork:
         self.messages_posted = 0
         self.bytes_posted = 0
         # (kind, body) -> payload: one broadcast's body parses once
-        # for all local receivers (scalar arm; see message.decode_frame)
+        # for all local receivers on the per-frame path (_verify_frame;
+        # see message.decode_frame)
         self._payload_memo: dict = {}
-        # Columnar delivery plane (Config.delivery_columnar): frames
-        # decode through the shared-prefix memo and MAC-verify in ONE
-        # Authenticator.verify_wire_many batch per receiver per wave
-        # (_prepare_wave).  The scalar arm above stays byte-equivalent.
-        self._columnar = delivery_columnar
-        self._decode_memo = FrameDecodeMemo() if delivery_columnar else None
+        # Delivery plane: frames decode through the shared-prefix memo
+        # and MAC-verify in ONE Authenticator.verify_wire_many batch
+        # per receiver per wave (_prepare_wave); one step() drains the
+        # whole prepared wave, bucketing verified frames per receiver,
+        # and hands each receiver its bundle in ONE serve_wave call
+        # (protocol.router demuxes it into typed columns).  Frames a
+        # mounted fault_filter must see per-frame decode and verify
+        # one at a time (_verify_frame) and still join the wave.
+        self._decode_memo = FrameDecodeMemo()
         self._unprepared = 0  # pending entries awaiting a wave pass
-        # Wave-routed ingest (Config.wave_routing): one step() drains
-        # the whole prepared wave, bucketing verified frames per
-        # receiver, and hands each receiver its bundle in ONE
-        # serve_wave call (protocol.router demuxes it into typed
-        # columns) instead of one serve_request per frame.  Handlers
-        # without serve_wave — and frames a mounted fault_filter must
-        # see per-frame — fall back to the scalar chain.
-        self._wave_routing = wave_routing and delivery_columnar
         # network-wide delivery counters (the per-epoch numbers
         # bench.py sections and perfgate gate on; per-endpoint twins
         # live on ChannelEndpoint for Metrics.snapshot)
         self.frames_decoded = 0
         self.mac_verify_calls = 0
-        # Columnar egress plane (Config.egress_columnar): each flush's
-        # whole wave of folded bundles arrives in ONE post_wave call,
-        # signs through the sender endpoint's sign_wire_wave (payload
-        # bodies encode once per distinct object via the per-endpoint
-        # FrameEncodeMemo, MACs in one batched pass) and enqueues one
-        # frame per peer per flush.  The scalar per-post path stays
-        # byte-equivalent (tests/test_egress_equivalence.py).
-        self._egress_columnar = egress_columnar
-        # network-wide egress counters (the send-side twins of the
-        # delivery counters above)
+        # Egress plane: each flush's whole wave of folded bundles
+        # arrives in ONE post_wave call, signs through the sender
+        # endpoint's sign_wire_wave (payload bodies encode once per
+        # distinct object via the per-endpoint FrameEncodeMemo, MACs
+        # in one batched pass) and enqueues one frame per peer per
+        # flush.  Network-wide egress counters (the send-side twins
+        # of the delivery counters above):
         self.frames_encoded = 0
         self.mac_sign_calls = 0
         # test hook (tests/test_egress_equivalence.py): when set,
         # called (sender_id, receiver_id, wire bytes) for every frame
-        # at enqueue time — the frame-stream capture the egress
-        # byte-equivalence proof compares across arms.  None in all
-        # non-test use.
+        # at enqueue time — the frame-stream capture the pinned
+        # wire-bytes test digests.  None in all non-test use.
         self.frame_tap: Optional[Callable[[str, str, bytes], None]] = None
         # Seeded WAN emulation plane (ISSUE 16): when a profile is
         # mounted, every _enqueue prices the frame through a per-link
@@ -237,12 +223,7 @@ class ChannelNetwork:
         auth: Optional[Authenticator] = None,
     ) -> None:
         self._endpoints[node_id] = ChannelEndpoint(
-            node_id,
-            handler,
-            auth or NullAuthenticator(),
-            encode_memo=(
-                FrameEncodeMemo() if self._egress_columnar else None
-            ),
+            node_id, handler, auth or NullAuthenticator()
         )
         if self.wan is not None:
             self.wan.register(node_id)
@@ -284,18 +265,16 @@ class ChannelNetwork:
         memo = self._decode_memo
         ehits = emisses = 0
         for ep in self._endpoints.values():
-            em = ep.encode_memo
-            if em is not None:
-                ehits += em.hits
-                emisses += em.misses
+            ehits += ep.encode_memo.hits
+            emisses += ep.encode_memo.misses
         return {
             "frames_decoded": self.frames_decoded,
             "mac_verifies": self.mac_verify_calls,
-            "decode_memo_hits": 0 if memo is None else memo.hits,
-            "decode_memo_misses": 0 if memo is None else memo.misses,
-            # egress twins (Config.egress_columnar): payload bodies
-            # actually encoded, Authenticator sign invocations, and
-            # the per-endpoint encode memos' pooled hit/miss tallies
+            "decode_memo_hits": memo.hits,
+            "decode_memo_misses": memo.misses,
+            # egress twins: payload bodies actually encoded,
+            # Authenticator sign invocations, and the per-endpoint
+            # encode memos' pooled hit/miss tallies
             "frames_encoded": self.frames_encoded,
             "mac_signs": self.mac_sign_calls,
             "encode_memo_hits": ehits,
@@ -444,74 +423,39 @@ class ChannelNetwork:
             self._unprepared += 1
 
     def post(self, sender_id: str, receiver_id: str, msg: Message) -> None:
-        """Sign, encode and enqueue one message."""
+        """Sign, encode and enqueue one message: a one-entry egress
+        wave, so a mid-wave re-send of a payload object the encode
+        memo already holds reuses its encoded body."""
         if sender_id in self._crashed:
             return
-        ep = self._endpoints.get(sender_id)
-        if ep is not None and self._egress_columnar:
-            # single-receiver sends take the SAME wave signer as flush
-            # waves (ISSUE 13 satellite): a mid-wave re-send of a
-            # payload object the encode memo already holds reuses its
-            # encoded body instead of re-encoding the envelope
+        if sender_id in self._endpoints:
             self.post_wave(sender_id, (((receiver_id,), msg),))
             return
         if self.pending_count() >= self._queue_capacity:
             raise OverflowError("channel network queue full")
-        if ep is None:
-            wire = encode_message(msg)  # staticcheck: allow[DET006] non-endpoint test rig
-        else:  # sign_wire_many encodes the envelope exactly once
-            bodies = payload_body_count(msg.payload)
-            ep.frames_encoded += bodies
-            ep.mac_sign_batches += 1
-            self.frames_encoded += bodies
-            self.mac_sign_calls += 1
-            frames = ep.auth.sign_wire_many(  # staticcheck: allow[DET006] scalar arm
-                msg, [receiver_id]
-            )
-            wire = frames[receiver_id]
+        wire = encode_message(msg)  # staticcheck: allow[DET006] non-endpoint test rig
         self._enqueue(sender_id, receiver_id, wire)
 
     def post_many(
         self, sender_id: str, receiver_ids, msg: Message
     ) -> None:
-        """Broadcast enqueue: ONE payload encode for the whole receiver
-        set via the authenticator's sign_wire_many fast path (pairwise
-        MACs differ per receiver; the envelope bytes do not)."""
-        if sender_id in self._crashed:
-            return
-        ep = self._endpoints.get(sender_id)
-        if ep is None:
-            for rid in receiver_ids:
-                self.post(sender_id, rid, msg)
-            return
-        if self._egress_columnar:
-            self.post_wave(sender_id, ((tuple(receiver_ids), msg),))
-            return
-        bodies = payload_body_count(msg.payload)
-        ep.frames_encoded += bodies
-        ep.mac_sign_batches += 1
-        self.frames_encoded += bodies
-        self.mac_sign_calls += 1
-        frames = ep.auth.sign_wire_many(  # staticcheck: allow[DET006] scalar arm
-            msg, receiver_ids
-        )
-        for rid, wire in frames.items():
-            if self.pending_count() >= self._queue_capacity:
-                raise OverflowError("channel network queue full")
-            self._enqueue(sender_id, rid, wire)
+        """Broadcast enqueue: a one-entry egress wave — ONE payload
+        encode for the whole receiver set (pairwise MACs differ per
+        receiver; the envelope bytes do not)."""
+        self.post_wave(sender_id, ((tuple(receiver_ids), msg),))
 
     def post_wave(self, sender_id: str, entries) -> None:
-        """One egress wave (Config.egress_columnar): ``entries`` are
-        ``(receiver_ids, msg)`` pairs — everything one coalescer flush
-        ships.  The whole wave signs through the sender endpoint's
-        ``Authenticator.sign_wire_wave`` (payload bodies encode once
-        per distinct object via the per-endpoint FrameEncodeMemo, MACs
-        in one batched pass over the precomputed pair-key schedules)
-        and enqueues in one pass — one frame per peer per flush, since
-        the coalescer already folded each receiver's wave into a
-        single bundle.  Admission is atomic: the wave is rejected
-        whole when it would overflow the queue, so a coalescer retry
-        never double-posts a partially shipped wave."""
+        """One egress wave: ``entries`` are ``(receiver_ids, msg)``
+        pairs — everything one coalescer flush ships.  The whole wave
+        signs through the sender endpoint's
+        ``Authenticator.sign_wire_wave`` (payload bodies encode once per
+        distinct object via the per-endpoint FrameEncodeMemo, MACs in
+        one batched pass over the precomputed pair-key schedules) and
+        enqueues in one pass — one frame per peer per flush, since the
+        coalescer already folded each receiver's wave into a single
+        bundle.  Admission is atomic: the wave is rejected whole when
+        it would overflow the queue, so a coalescer retry never
+        double-posts a partially shipped wave."""
         if sender_id in self._crashed:
             return
         ep = self._endpoints.get(sender_id)
@@ -554,25 +498,23 @@ class ChannelNetwork:
         return len(self._pending) + len(self._wan_holding)
 
     def _prepare_wave(self) -> None:
-        """Columnar arm: decode (shared-prefix memoized) and
-        MAC-verify every not-yet-prepared pending frame — ONE
-        ``verify_wire_many`` batch per receiver per wave.  A wave is
-        whatever the previous handler turns posted since the last
-        pass; the scheduler then delivers prepared frames in its usual
-        (FIFO or seeded) order, so the interleaving semantics are
-        untouched.  Skipped entirely while a fault_filter is mounted:
-        tampering adversaries must see — and re-verify — the exact
-        delivered bytes (the scalar per-frame path below)."""
+        """Decode (shared-prefix memoized) and MAC-verify every
+        not-yet-prepared pending frame — ONE ``verify_wire_many``
+        batch per receiver per wave.  A wave is whatever the previous
+        handler turns posted since the last pass; the scheduler then
+        pops prepared frames in its usual (FIFO or seeded) order.
+        Skipped entirely while a fault_filter is mounted: tampering
+        adversaries must see — and re-verify — the exact delivered
+        bytes (_verify_frame)."""
         self._unprepared = 0
         todo: Dict[str, list] = {}
         crashed, partitions = self._crashed, self._partitions
         for it in self._pending:
             # frames the delivery checks would drop anyway (crashed
             # ends, severed pairs) must not burn digest+decode+MAC
-            # work here or skew the delivery counters — the scalar arm
-            # checks these before ever decoding.  A frame skipped now
-            # that becomes deliverable later (heal/recover) falls to
-            # the scalar per-frame path at pop time.
+            # work here or skew the delivery counters.  A frame
+            # skipped now that becomes deliverable later
+            # (heal/recover) falls to _verify_frame at pop time.
             if (
                 it[4] is None
                 and it[1] not in crashed
@@ -631,20 +573,33 @@ class ChannelNetwork:
             for it, msg, ok in zip(good, msgs, oks):
                 it[4] = (msg, True) if ok else (None, "bad_mac")
 
-    def _step_wave(self) -> bool:
-        """Wave-routing delivery (Config.wave_routing): ONE step
-        drains the entire pending queue — one message wave, everything
-        the previous handler turns posted — bucketing verified frames
-        per receiver in scheduler pop order, then hands each receiver
+    def step(self) -> bool:
+        """Deliver one WAVE; returns False if nothing is pending.
+
+        ONE step drains the entire pending queue — one message wave,
+        everything the previous handler turns posted — bucketing
+        verified frames per receiver in scheduler pop order (FIFO
+        without a seed, seeded-uniform-random with one: the same seed
+        replays the identical interleaving), then hands each receiver
         its bundle in a single ``serve_wave`` call (the WaveRouter
         demuxes it into typed ingest columns; one batch handler
         dispatch per message kind).  Receivers fire in sorted-id order
         (the idle_phase discipline); messages their handlers post form
         the NEXT wave.  Frames a mounted fault_filter must see — and
         frames the wave pass skipped (crashed/severed at prepare time)
-        — decode and verify through the per-frame scalar path, but
-        still JOIN the receiver's wave, so the router seam stays
-        exercised under wire-fault schedules."""
+        — decode and verify one at a time (_verify_frame), but still
+        JOIN the receiver's wave, so the router seam stays exercised
+        under wire-fault schedules.
+
+        Manual driving contract: handlers joined to this network defer
+        outbound bundles and batched crypto to idle callbacks, so a
+        caller looping ``step()`` directly MUST call ``idle_phase()``
+        whenever ``step()`` returns False (and keep going if new
+        messages appear) — exactly what ``run()`` does — or buffered
+        work strands and the protocol stalls without error.
+        """
+        if self.wan is not None:
+            self._wan_release()
         if not self._pending:
             return False
         with trace.span(
@@ -679,8 +634,7 @@ class ChannelNetwork:
                 # cached pre-wave verdict — only usable while NO
                 # filter is mounted: a filter mounted mid-run (with
                 # prepared frames still in flight) must see and
-                # re-verify the exact delivered bytes, exactly like
-                # the scalar arm re-filters prepared entries
+                # re-verify the exact delivered bytes
                 msg, verdict = prepared
                 if verdict is not True:
                     ep.rejected += 1
@@ -696,9 +650,10 @@ class ChannelNetwork:
                             continue
                         wire = maybe[0]
                         # injected duplicates re-enter pending (never
-                        # re-filtered); the drain loop folds them into
-                        # this wave's tail — dedup absorbs them like
-                        # any replay
+                        # re-filtered: a filtered frame re-entering
+                        # the filter would branch exponentially); the
+                        # drain loop folds them into this wave's tail
+                        # — dedup absorbs them like any replay
                         for extra in maybe[1:]:
                             if len(self._pending) < self._queue_capacity:
                                 self._pending.append(
@@ -707,21 +662,8 @@ class ChannelNetwork:
                                 self._unprepared += 1
                     else:
                         wire = maybe
-                try:
-                    msg, signing_prefix = decode_frame(
-                        wire, payload_memo=self._payload_memo
-                    )
-                except ValueError:
-                    ep.rejected += 1
-                    self._trace_rejected(ep, sender, "undecodable")
-                    continue
-                ep.frames_decoded += 1
-                self.frames_decoded += 1
-                ep.mac_verify_batches += 1
-                self.mac_verify_calls += 1
-                if not ep.auth.verify_wire(msg, signing_prefix):
-                    ep.rejected += 1
-                    self._trace_rejected(ep, sender, "bad_mac")
+                msg = self._verify_frame(ep, sender, wire)
+                if msg is None:
                     continue
             ep.delivered += 1
             wave = waves.get(receiver)
@@ -739,100 +681,30 @@ class ChannelNetwork:
                     # handler without wave ingest: per-frame fallback
                     ep.handler.serve_request(m)  # staticcheck: allow[DET004] non-wave fallback
 
-    def step(self) -> bool:
-        """Deliver one message (or, in wave-routing mode, one whole
-        wave); returns False if none pending.
-
-        Delivery order: FIFO without a seed, seeded-uniform-random with
-        one — every run with the same seed replays the identical
-        interleaving.
-
-        Manual driving contract: handlers joined to this network defer
-        outbound bundles and batched crypto to idle callbacks, so a
-        caller looping ``step()`` directly MUST call ``idle_phase()``
-        whenever ``step()`` returns False (and keep going if new
-        messages appear) — exactly what ``run()`` does — or buffered
-        work strands and the protocol stalls without error.
-        """
-        if self.wan is not None:
-            self._wan_release()
-        if self._wave_routing:
-            return self._step_wave()
-        columnar = self._columnar and self.fault_filter is None
-        if columnar and self._unprepared:
-            self._prepare_wave()
-        while self._pending:
-            if self._rng is None:
-                item = self._pending.popleft()
-            else:
-                idx = self._rng.randrange(len(self._pending))
-                item = self._pending[idx]
-                self._pending[idx] = self._pending[-1]
-                self._pending.pop()
-            sender, receiver, wire, prefiltered, prepared = item
-            if prepared is None and self._unprepared > 0:
-                # frames skipped by a wave pass (crashed receiver)
-                # deliver through the scalar fallback below
-                self._unprepared -= 1
-            if receiver in self._crashed or sender in self._crashed:
-                continue
-            if (sender, receiver) in self._partitions:
-                continue
-            ep = self._endpoints.get(receiver)
-            if columnar and prepared is not None:
-                # pre-waved frame: decode + MAC verdict already batched
-                if ep is None:
-                    continue
-                msg, verdict = prepared
-                if verdict is not True:
-                    ep.rejected += 1
-                    self._trace_rejected(ep, sender, verdict)
-                    continue
-                ep.delivered += 1
-                ep.handler.serve_request(msg)  # staticcheck: allow[DET004] scalar comparison arm
-                return True
-            if self.fault_filter is not None and not prefiltered:
-                maybe = self.fault_filter(sender, receiver, wire)
-                if maybe is None:
-                    continue
-                if isinstance(maybe, list):
-                    if not maybe:
-                        continue
-                    wire = maybe[0]
-                    # duplicates / injections: deliver later WITHOUT
-                    # re-filtering (a filtered frame re-entering the
-                    # filter would branch exponentially)
-                    for extra in maybe[1:]:
-                        if len(self._pending) < self._queue_capacity:
-                            self._pending.append(
-                                [sender, receiver, extra, True, None]
-                            )
-                            self._unprepared += 1
-                else:
-                    wire = maybe
-            if ep is None:
-                continue
-            try:
-                msg, signing_prefix = decode_frame(
-                    wire, payload_memo=self._payload_memo
-                )
-            except ValueError:
-                ep.rejected += 1
-                self._trace_rejected(ep, sender, "undecodable")
-                continue
-            ep.frames_decoded += 1
-            self.frames_decoded += 1
-            ep.mac_verify_batches += 1
-            self.mac_verify_calls += 1
-            if not ep.auth.verify_wire(msg, signing_prefix):
-                # the implemented version of conn.go:134-137's TODO
-                ep.rejected += 1
-                self._trace_rejected(ep, sender, "bad_mac")
-                continue
-            ep.delivered += 1
-            ep.handler.serve_request(msg)  # staticcheck: allow[DET004] scalar comparison arm
-            return True
-        return False
+    def _verify_frame(
+        self, ep: ChannelEndpoint, sender: str, wire: bytes
+    ) -> Optional[Message]:
+        """Decode and MAC-verify ONE frame outside a wave batch (the
+        exact bytes a fault_filter returned, or a frame the wave pass
+        skipped); None when the receiver rejects it."""
+        try:
+            msg, signing_prefix = decode_frame(
+                wire, payload_memo=self._payload_memo
+            )
+        except ValueError:
+            ep.rejected += 1
+            self._trace_rejected(ep, sender, "undecodable")
+            return None
+        ep.frames_decoded += 1
+        self.frames_decoded += 1
+        ep.mac_verify_batches += 1
+        self.mac_verify_calls += 1
+        if not ep.auth.verify_wire(msg, signing_prefix):
+            # the implemented version of conn.go:134-137's TODO
+            ep.rejected += 1
+            self._trace_rejected(ep, sender, "bad_mac")
+            return None
+        return msg
 
     @staticmethod
     def _trace_rejected(ep: ChannelEndpoint, sender: str, why: str) -> None:
@@ -861,8 +733,7 @@ class ChannelNetwork:
     ) -> int:
         """Deliver until quiescent (handlers may enqueue more while we
         drain).  Returns the number of delivery steps — one per
-        message, or one per WAVE in wave-routing mode (``max_steps``
-        bounds the same unit).
+        WAVE (``max_steps`` bounds the same unit).
 
         Quiescence is two-level: when the pending queue drains, every
         endpoint gets its idle callback (running deferred crypto and

@@ -59,7 +59,12 @@ class GrpcConnection:
 
     ``send`` enqueues onto a bounded mailbox consumed by the stream's
     writer; ``start`` runs the reader loop that decodes, verifies and
-    dispatches inbound frames to the registered Handler."""
+    dispatches inbound frames to the registered Handler: an ingest
+    thread (stream -> queue) feeds a verify loop that drains the
+    queue's backlog per pass — one message wave — MACs it through ONE
+    Authenticator.verify_wire_many call and dispatches it as ONE
+    handler call (SerialDispatcher.serve_wave: one actor mailbox entry
+    per wave, not N)."""
 
     def __init__(
         self,
@@ -68,8 +73,6 @@ class GrpcConnection:
         capacity: int = DEFAULT_CHANNEL_CAPACITY,
         conn_id: Optional[str] = None,
         on_close: Optional[Callable[["GrpcConnection"], None]] = None,
-        delivery_columnar: bool = False,
-        wave_routing: bool = False,
     ) -> None:
         self._inbound = inbound
         self._auth = auth
@@ -79,16 +82,6 @@ class GrpcConnection:
         self._closed = threading.Event()
         self._reader: Optional[threading.Thread] = None
         self._on_close = on_close
-        # Config.delivery_columnar: the reader splits into an ingest
-        # thread (stream -> queue) and a verify loop that drains the
-        # queue's backlog per pass — one message wave — and MACs it
-        # through ONE Authenticator.verify_wire_many call.
-        self._columnar = delivery_columnar
-        # Config.wave_routing: the verified wave dispatches as ONE
-        # handler call (SerialDispatcher.serve_wave — one actor
-        # mailbox entry per wave, not N) instead of one serve_request
-        # per frame.  Rides the columnar verify loop.
-        self._wave_routing = wave_routing and delivery_columnar
         self.delivered = 0
         self.rejected = 0
         # delivery-plane counters (Metrics.snapshot()["transport"])
@@ -116,7 +109,7 @@ class GrpcConnection:
             # (host.py DialOpts conn_id=member), so conn_id names the
             # receiver for the pairwise MAC
             signed = self._auth.sign(msg, self._conn_id)
-            wire = encode_message(signed)  # staticcheck: allow[DET006] scalar arm / pre-pool path
+            wire = encode_message(signed)  # staticcheck: allow[DET006] pre-pool boot path
         except Exception as exc:
             if on_err is not None:
                 on_err(exc)
@@ -182,46 +175,13 @@ class GrpcConnection:
                 return
             yield item
 
-    def _read_loop(self) -> None:
-        """readStream + dispatch (conn.go:110-128,164-180)."""
-        if self._columnar:
-            self._read_loop_columnar()
-            return
-        try:
-            for wire in self._inbound:
-                if self._closed.is_set():
-                    break
-                try:
-                    msg, signing_prefix = decode_frame(wire)
-                except ValueError:
-                    self.rejected += 1
-                    self._trace_rejected("undecodable")
-                    continue
-                self.frames_decoded += 1
-                self.mac_verify_batches += 1
-                if not self._auth.verify_wire(  # conn.go:134-137, real
-                    msg, signing_prefix
-                ):
-                    self.rejected += 1
-                    self._trace_rejected("bad_mac")
-                    continue
-                self.delivered += 1
-                handler = self._handler
-                if handler is not None:
-                    handler.serve_request(msg)  # staticcheck: allow[DET004] scalar comparison arm
-        except Exception:  # staticcheck: allow[ERR001] finally closes the conn
-            pass  # stream broken: fall through to close
-        finally:
-            self.close()
-
     def _ingest_loop(self, q: "queue.Queue") -> None:
         """Stream -> local queue: the wave buffer's producer side.  The
-        queue is BOUNDED (the scalar path's synchronous consumption
-        exerted backpressure through gRPC flow control; an unbounded
-        buffer here would re-open the flood-to-OOM hole), so a full
-        buffer blocks ingest — and with it the gRPC window — until the
-        verify loop drains.  The sentinel (stream end OR break)
-        releases the verify loop."""
+        queue is BOUNDED (backpressure reaches the peer through gRPC
+        flow control; an unbounded buffer here would open a flood-to-OOM
+        hole), so a full buffer blocks ingest — and with it the gRPC
+        window — until the verify loop drains.  The sentinel (stream
+        end OR break) releases the verify loop."""
         try:
             for wire in self._inbound:
                 if self._closed.is_set():
@@ -243,14 +203,15 @@ class GrpcConnection:
                     if self._closed.is_set():
                         break  # verify loop already exiting on the flag
 
-    def _read_loop_columnar(self) -> None:
-        """Wave-batched inbound path (Config.delivery_columnar): drain
-        the ingest queue's current backlog — one message wave, however
-        many frames arrived since the last pass — decode them, and MAC
-        the whole wave through ONE verify_wire_many call before
-        dispatching in arrival order.  Width follows the actual burst
-        shape: a peer's bundle fan-in lands together, so steady-state
-        waves are much wider than 1."""
+    def _read_loop(self) -> None:
+        """readStream + dispatch (conn.go:110-128,164-180), wave by
+        wave: drain the ingest queue's current backlog — one message
+        wave, however many frames arrived since the last pass — decode
+        them, and MAC the whole wave through ONE verify_wire_many call
+        (conn.go:134-137's TODO, real) before dispatching in arrival
+        order.  Width follows the actual burst shape: a peer's bundle
+        fan-in lands together, so steady-state waves are much wider than
+        1."""
         q: "queue.Queue" = queue.Queue(maxsize=self._out.maxsize)
         threading.Thread(
             target=self._ingest_loop,
@@ -306,18 +267,14 @@ class GrpcConnection:
                     good.append(msg)
                 if not good or handler is None:
                     continue
-                serve_wave = (
-                    getattr(handler, "serve_wave", None)
-                    if self._wave_routing
-                    else None
-                )
+                serve_wave = getattr(handler, "serve_wave", None)
                 if serve_wave is not None:
                     # one actor message per wave: the dispatcher's
                     # mailbox carries the whole verified burst
                     serve_wave(good)
                 else:
                     for msg in good:
-                        handler.serve_request(msg)  # staticcheck: allow[DET004] scalar arm
+                        handler.serve_request(msg)  # staticcheck: allow[DET004] non-wave fallback
         finally:
             self.close()
 
@@ -357,14 +314,10 @@ class GrpcServer:
         addr: str,
         auth: Optional[Authenticator] = None,
         capacity: int = DEFAULT_CHANNEL_CAPACITY,
-        delivery_columnar: bool = False,
-        wave_routing: bool = False,
     ) -> None:
         self.addr = addr
         self._auth = auth or NullAuthenticator()
         self._capacity = capacity
-        self._delivery_columnar = delivery_columnar
-        self._wave_routing = wave_routing
         self._on_conn: Optional[ConnHandler] = None
         self._on_err: Optional[ErrHandler] = None
         self._server: Optional[grpc.Server] = None
@@ -424,8 +377,6 @@ class GrpcServer:
             self._auth,
             capacity=self._capacity,
             on_close=lambda c: (self._remove_conn(c), context.cancel()),
-            delivery_columnar=self._delivery_columnar,
-            wave_routing=self._wave_routing,
         )
         with self._lock:
             self._conns.append(conn)
@@ -489,15 +440,8 @@ class DialOpts:
 class GrpcClient:
     """Reference comm.go:119-140 GrpcClient."""
 
-    def __init__(
-        self,
-        auth: Optional[Authenticator] = None,
-        delivery_columnar: bool = False,
-        wave_routing: bool = False,
-    ):
+    def __init__(self, auth: Optional[Authenticator] = None):
         self._auth = auth or NullAuthenticator()
-        self._delivery_columnar = delivery_columnar
-        self._wave_routing = wave_routing
         self._channels: List[grpc.Channel] = []
 
     def dial(self, opts: DialOpts) -> GrpcConnection:
@@ -523,8 +467,6 @@ class GrpcClient:
             self._auth,
             capacity=opts.capacity,
             conn_id=opts.conn_id,
-            delivery_columnar=self._delivery_columnar,
-            wave_routing=self._wave_routing,
         )
         call = multi(conn.outbound())
         conn._inbound = call

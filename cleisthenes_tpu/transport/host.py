@@ -56,9 +56,9 @@ from cleisthenes_tpu.utils.log import NodeLogger
 
 
 class _Wave:
-    """One delivery wave riding the dispatcher mailbox as a SINGLE
-    actor message (Config.wave_routing): the gRPC verify loop hands a
-    whole verified burst over in one queue entry instead of N."""
+    """One delivery wave riding the dispatcher mailbox as a SINGLE actor
+    message: the gRPC verify loop hands a whole verified burst over in
+    one queue entry instead of N."""
 
     __slots__ = ("msgs",)
 
@@ -99,8 +99,8 @@ class SerialDispatcher:
             self._q.put(msg)
 
     def serve_wave(self, msgs: List[Message]) -> None:
-        """Wave ingest (Config.wave_routing): enqueue one verified
-        delivery wave as ONE mailbox entry — the worker hands it to
+        """Wave ingest: enqueue one verified delivery wave as ONE
+        mailbox entry — the worker hands it to
         the bound handler's serve_wave (the WaveRouter seam) in a
         single call."""
         if msgs and not self._stopped.is_set():
@@ -156,7 +156,7 @@ class SerialDispatcher:
                             for m in item.msgs:
                                 handler.serve_request(m)  # staticcheck: allow[DET004] fallback
                 elif self._handler is not None:
-                    self._handler.serve_request(item)  # staticcheck: allow[DET004] scalar arm
+                    self._handler.serve_request(item)  # staticcheck: allow[DET004] self-delivery
             except Exception:
                 # a poisoned message must not kill the node's actor
                 import traceback
@@ -213,7 +213,6 @@ class GrpcPayloadBroadcaster:
         pool: ConnectionPool,
         local: SerialDispatcher,
         auth,
-        egress_columnar: bool = False,
     ) -> None:
         self._node_id = node_id
         self._pool = pool
@@ -225,18 +224,15 @@ class GrpcPayloadBroadcaster:
         self._ready = False
         self._pending: List = []
         self._lock = new_lock()
-        # Columnar egress (Config.egress_columnar): the coalescer
-        # hands each flush's whole wave to post_wave, which signs it
-        # in ONE Authenticator.sign_wire_wave pass (payload bodies
-        # encode once per distinct object via the encode memo, MACs
-        # batched over the precomputed pair schedules) and makes one
-        # stream write per peer per flush (the wave already folds to
-        # one bundle per receiver).  Counters are the egress twins of
-        # the connection-side delivery counters, folded into
-        # Metrics.snapshot()["transport"] by the host.
-        self._encode_memo = (
-            FrameEncodeMemo() if egress_columnar else None
-        )
+        # Egress: the coalescer hands each flush's whole wave to
+        # post_wave, which signs it in ONE Authenticator.sign_wire_wave
+        # pass (payload bodies encode once per distinct object via the
+        # encode memo, MACs batched over the precomputed pair schedules)
+        # and makes one stream write per peer per flush (the wave
+        # already folds to one bundle per receiver).  Counters are the
+        # egress twins of the connection-side delivery counters, folded
+        # into Metrics.snapshot()["transport"] by the host.
+        self._encode_memo = FrameEncodeMemo()
         self.frames_encoded = 0
         self.encode_memo_hits = 0
         self.encode_memo_misses = 0
@@ -265,7 +261,7 @@ class GrpcPayloadBroadcaster:
             # design ADVICE.md retired).  The envelope is encoded once;
             # only the 32-byte MAC differs per frame.
             conns = self._pool.get_all()
-            frames = self._auth.sign_wire_many(  # staticcheck: allow[DET006] scalar arm
+            frames = self._auth.sign_wire_many(  # staticcheck: allow[DET006] pre-pool boot path
                 msg, [c.id() for c in conns]
             )
             for conn in conns:
@@ -274,16 +270,15 @@ class GrpcPayloadBroadcaster:
             self._pool.send_to(member_id, msg)
 
     def post_wave(self, entries) -> None:
-        """One egress wave (Config.egress_columnar): ``entries`` are
-        ``(member_id | None, payload)`` pairs — one coalescer flush.
-        The whole wave signs in ONE ``sign_wire_wave`` pass and ships
-        as one stream write per peer per flush; local self-delivery
-        short-circuits through the dispatcher exactly like the scalar
-        arm, but only AFTER the fallible sign pass — a sign failure
+        """One egress wave: ``entries`` are ``(member_id | None,
+        payload)`` pairs — one coalescer flush.  The whole wave signs
+        in ONE ``sign_wire_wave`` pass and ships as one stream write
+        per peer per flush; local self-delivery short-circuits through
+        the dispatcher, but only AFTER the fallible sign pass — a sign failure
         re-parks the wave in the coalescer, and serving local first
         would double-deliver the node's own payloads on the retry.
         Before the dial pool completes, the WHOLE wave parks per
-        receiver in one pass and re-delivers scalar on mark_ready
+        receiver in one pass and re-delivers per message on mark_ready
         (boot-time traffic is a handful of frames; parking all-or-
         nothing keeps a mid-wave failure from re-parking entries the
         pending list already holds)."""
@@ -298,7 +293,7 @@ class GrpcPayloadBroadcaster:
                     if member_id != self._node_id:
                         self._pending.append((member_id, msg))
         if not ready:
-            # scalar parity: local delivery never waits on the pool
+            # local delivery never waits on the pool
             for member_id, msg in msgs:
                 if member_id is None or member_id == self._node_id:
                     self._local.serve_request(msg)  # staticcheck: allow[DET004] self-delivery
@@ -420,16 +415,10 @@ class ValidatorHost:
             listen_addr,
             self._auth,
             capacity=config.channel_capacity,
-            delivery_columnar=config.delivery_columnar,
-            wave_routing=config.wave_routing,
         )
         self.server.on_conn(self._accept)
         self.pool = ConnectionPool()
-        self._client = GrpcClient(
-            self._auth,
-            delivery_columnar=config.delivery_columnar,
-            wave_routing=config.wave_routing,
-        )
+        self._client = GrpcClient(self._auth)
         # frame counters of dialed streams that have since been lost:
         # folded in at loss time so the transport metric stays
         # cumulative across self-healing redials
@@ -448,7 +437,6 @@ class ValidatorHost:
             self.pool,
             self.dispatcher,
             self._auth,
-            egress_columnar=config.egress_columnar,
         )
         batch_log = None
         if batch_log_path is not None:
@@ -592,9 +580,8 @@ class ValidatorHost:
             "rejected": rejected,
             "frames_decoded": decoded,
             "mac_verify_batches": batches,
-            # egress twins (Config.egress_columnar): the payload
-            # broadcaster owns the outbound signer seam, so its
-            # counters are already host-cumulative
+            # egress twins: the payload broadcaster owns the outbound
+            # signer seam, so its counters are already host-cumulative
             "frames_encoded": self.out.frames_encoded,
             "encode_memo_hits": self.out.encode_memo_hits,
             "encode_memo_misses": self.out.encode_memo_misses,

@@ -1047,14 +1047,14 @@ def _assemble_signing(msg: Message, kind: int, body: bytes) -> bytes:
 
 
 class FrameEncodeMemo(BoundedFifoMemo):
-    """Shared outbound payload-encode memo (Config.egress_columnar) —
-    the encode twin of ``FrameDecodeMemo``.
+    """Shared outbound payload-encode memo — the encode twin of
+    ``FrameDecodeMemo``.
 
     One egress wave's per-receiver frames are mostly re-encodings of
     SHARED payload objects: a mixed flush folds the wave's broadcast
     run into each receiver's bundle, so N receiver bundles carry the
-    same sub-payload objects and the scalar path re-encoded each of
-    them once per receiver.  Keying the encoded ``(kind, body)`` on
+    same sub-payload objects; encoding per frame would re-encode each
+    of them once per receiver.  Keying the encoded ``(kind, body)`` on
     the payload OBJECT collapses those to one encode + N joins.
 
     The decode memo keys on the wire prefix's SHA-256 digest because
@@ -1067,7 +1067,7 @@ class FrameEncodeMemo(BoundedFifoMemo):
     insertion first, never clear-all).  ``hits``/``misses`` feed the
     transport egress metrics (``encode_memo_hit_rate`` in the bench
     sections); a miss is a payload body actually encoded — the
-    ``frames_encoded`` counter's unit on both egress arms."""
+    ``frames_encoded`` counter's unit."""
 
     __slots__ = ("hits", "misses")
 
@@ -1115,9 +1115,9 @@ def signing_bytes_shared(msg: Message, memo: FrameEncodeMemo) -> bytes:
 
 def payload_body_count(p: Payload) -> int:
     """Payload bodies one envelope encode touches (bundle items, or
-    1): the ``frames_encoded`` counter's unit on the SCALAR egress arm
-    — the columnar arm counts FrameEncodeMemo misses, which probe per
-    body, so both arms tally the same work unit."""
+    1): the ``frames_encoded`` counter's unit where a signer ignores
+    the encode memo — the wave signer counts FrameEncodeMemo misses,
+    which probe per body: the same work unit."""
     return len(p.items) if isinstance(p, BundlePayload) else 1
 
 
@@ -1152,7 +1152,7 @@ def encode_message(msg: Message) -> bytes:
 
 
 class FrameDecodeMemo(BoundedFifoMemo):
-    """Shared-prefix inbound decode memo (Config.delivery_columnar).
+    """Shared-prefix inbound decode memo.
 
     A broadcast's N receiver frames are ``signing_bytes || len || MAC``
     (attach_signature) and differ ONLY in the 32-byte MAC — the
@@ -1187,8 +1187,8 @@ class FrameDecodeMemo(BoundedFifoMemo):
 def decode_frame_shared(
     data: bytes, memo: FrameDecodeMemo
 ) -> Tuple[Message, "memoryview"]:
-    """Decode a frame through the shared-prefix memo (the columnar
-    delivery arm of ``decode_frame``).
+    """Decode a frame through the shared-prefix memo (the wave twin
+    of ``decode_frame``).
 
     The envelope is walked as OFFSETS over ``data`` — no body slice,
     no signing-prefix copy — and the returned signing prefix is a

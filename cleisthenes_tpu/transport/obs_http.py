@@ -242,8 +242,8 @@ def render_prometheus(targets: Sequence[ObsTarget]) -> str:
             labels,
             int(transport["dedup_absorbed"]),
         )
-        # delivery-plane columnarization counters (always present —
-        # zeroed on the scalar arm per the schema-stability rule)
+        # delivery-plane counters (always present — zeroed on bare
+        # nodes per the schema-stability rule)
         exp.add(
             exp.family(
                 "transport_frames_decoded_total", "counter",
@@ -273,8 +273,8 @@ def render_prometheus(targets: Sequence[ObsTarget]) -> str:
             labels,
             int(transport["mac_verify_batches"]),
         )
-        # egress-columnarization counters (ISSUE 13; always present —
-        # zeroed on the scalar arm per the schema-stability rule)
+        # egress counters (ISSUE 13; always present — zeroed on bare
+        # nodes per the schema-stability rule)
         exp.add(
             exp.family(
                 "transport_frames_encoded_total", "counter",
@@ -323,8 +323,8 @@ def render_prometheus(targets: Sequence[ObsTarget]) -> str:
             labels,
             int(hub["coin_share_items"]),
         )
-        # wave-routed ingest counters (always present — zeroed on the
-        # scalar routing arm per the schema-stability rule)
+        # wave-routed ingest counters (always present — zeroed on bare
+        # nodes per the schema-stability rule)
         router = snap["router"]
         exp.add(
             exp.family(

@@ -220,14 +220,14 @@ class Metrics:
         # dynamic membership (protocol.reconfig): completed roster
         # switches this node activated (joins, retirements, re-keys)
         self.reconfigs_total = Counter()
-        # wave-routed ingest (Config.wave_routing): batch handler
-        # invocations crossing the router seam into protocol logic
-        # (ACS/RBC/BBA/dec-share entry points).  The scalar routing
-        # arm counts one per payload; the wave arm counts one per
-        # (message kind, delivery wave) — DETERMINISTIC for a seeded
-        # schedule, the counter perfgate gates like hub dispatches.
+        # wave-routed ingest: batch handler invocations crossing the
+        # router seam into protocol logic (ACS/RBC/BBA/dec-share entry
+        # points) — one per (message kind, delivery wave), one per
+        # payload on the single-message serve_request entry.
+        # DETERMINISTIC for a seeded schedule, the counter perfgate
+        # gates like hub dispatches.
         self.handler_dispatches = Counter()
-        # delivery waves the router demuxed (0 on the scalar arm)
+        # delivery waves the router demuxed
         self.waves_routed = Counter()
         # K-deep pipelined frontiers (Config.pipeline_depth): waves
         # whose coalescer flush carried eagerly piggybacked dec
@@ -475,7 +475,7 @@ class Metrics:
             reconfig["roster_version"] = int(self._roster_version())
         out["reconfig"] = reconfig
         # wave-routing block: ALWAYS present with every key, zeroed on
-        # the scalar arm / bare nodes (the PR-9 schema-stability rule
+        # bare nodes (the PR-9 schema-stability rule
         # — scrapers and the timeseries sampler must never see a key
         # appear or disappear between snapshots)
         out["router"] = {
@@ -500,16 +500,15 @@ class Metrics:
             "delivered": 0,
             "rejected": 0,
             "dedup_absorbed": self.dedup_absorbed.value,
-            # delivery-plane counters (Config.delivery_columnar): the
-            # PR-5 schema-stability rule — every key present and
-            # zeroed on EVERY path (scalar arm, bare HoneyBadger,
-            # early boot); transports with counters overwrite below
+            # delivery-plane counters: the PR-5 schema-stability rule
+            # — every key present and zeroed on EVERY path (bare
+            # HoneyBadger, early boot); transports with counters
+            # overwrite below
             "frames_decoded": 0,
             "decode_memo_hits": 0,
             "decode_memo_misses": 0,
             "mac_verify_batches": 0,
-            # egress-plane twins (Config.egress_columnar): same
-            # zeroed-key schema rule on both egress arms
+            # egress-plane twins: same zeroed-key schema rule
             "frames_encoded": 0,
             "encode_memo_hits": 0,
             "encode_memo_misses": 0,
@@ -519,9 +518,7 @@ class Metrics:
             transport.update(self._transport_stats())
         out["transport"] = transport
         # crypto-hub block: ALWAYS present with every key, zeroed on
-        # bare nodes (the PR-9 schema-stability rule); the coin-issue
-        # dispatch tallies are counted on BOTH egress arms, so the
-        # scalar arm reports its per-node-per-drain batches here too
+        # bare nodes (the PR-9 schema-stability rule)
         hub: Dict[str, object] = {
             "coin_share_batches": 0,
             "coin_share_items": 0,

@@ -1,7 +1,6 @@
 """known-good ARM001: the declared arm flag is a bool Config field,
-read as the gate that selects between the wave entry point and its
-scalar twin — so the wave seam is reachable from an arm-flag reader
-and the scalar arm stays live."""
+read as the gate that selects between the fast path and its live
+comparison arm, and a key of the perfgate-shaped fingerprint."""
 
 import dataclasses
 
@@ -14,18 +13,18 @@ class Config:
     batch: int = 8
 
 
-def handle_ag_wave(items):
-    return [i for i in items]
-
-
 class Plane:
     def __init__(self, config):
-        self._wave = bool(config.ag_live_arm)
+        self._fast = bool(config.ag_live_arm)
 
     def ingest(self, items):
-        if self._wave:
-            return handle_ag_wave(items)
+        if self._fast:
+            return list(items)
         return [self.ingest_one(i) for i in items]
 
     def ingest_one(self, item):
         return item
+
+
+def record(cfg):
+    return {"fingerprint": {"ag_live_arm": bool(cfg.ag_live_arm)}}

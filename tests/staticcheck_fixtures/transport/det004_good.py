@@ -1,7 +1,7 @@
 """Known-good DET004 fixture: the wave-router seam discipline — the
 transport buffers a delivery wave and hands it over in ONE serve_wave
 call; the per-frame fallback for handlers without wave ingest carries
-a justified pragma (the scalar comparison arm pattern)."""
+a justified pragma."""
 
 
 def read_loop(inbound, handler, decode):

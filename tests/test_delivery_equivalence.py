@@ -1,29 +1,24 @@
-"""Scalar vs columnar DELIVERY equivalence (ISSUE 9).
+"""The columnar delivery plane and the wave router (ISSUE 9, 10).
 
-The delivery-plane columnarization moved inbound work to wave
-granularity: frame decode memoizes on the signing-prefix digest
-(transport.message.FrameDecodeMemo), MAC verification batches through
-one ``Authenticator.verify_wire_many`` call per wave, and RBC receipt
-state lives in the roster-wide EchoBank.  That reshapes WHEN frames
-decode and verify — but it must never reshape WHAT the roster
-commits.  ``Config.delivery_columnar=False`` keeps the per-frame
-scalar receive path as a live comparison arm; these tests run the
-same seeded schedule under both arms and require byte-identical
-committed ledgers on both transports, that the columnar arm's
-deterministic frame/MAC counters actually DROP, that the PR-4
-semantic coalitions (equivocating per-receiver roots included) run
-green against the EchoBank, and that the whole columnar receive path
-is PYTHONHASHSEED-independent.
+Inbound work runs at wave granularity: frame decode memoizes on the
+signing-prefix digest (transport.message.FrameDecodeMemo), MAC
+verification batches through one ``Authenticator.verify_wire_many``
+call per wave, RBC receipt state lives in the roster-wide EchoBank,
+and the WaveRouter makes one batch handler dispatch per (kind, wave).
+These tests hold the shared-prefix decoder to the per-frame decoder's
+accept/reject behaviour, run the PR-4 semantic coalitions
+(equivocating per-receiver roots included) against the EchoBank and
+the router, run the fuzz bands, and check that the whole receive path
+is PYTHONHASHSEED-independent.  What a seeded run COMMITS is pinned in
+tests/test_seeded_pins.py.
 """
 
 from __future__ import annotations
 
-import hashlib
 import os
 import pathlib
 import subprocess
 import sys
-import threading
 
 import pytest
 
@@ -31,141 +26,7 @@ REPO = pathlib.Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO))
 
 from cleisthenes_tpu.config import Config  # noqa: E402
-from cleisthenes_tpu.core.ledger import encode_batch_body  # noqa: E402
 from cleisthenes_tpu.protocol.cluster import SimulatedCluster  # noqa: E402
-
-
-def _channel_run(columnar: bool) -> tuple:
-    """(ledger digest, depth, delivery counters) for one seeded
-    4-node channel-transport run under the given delivery arm."""
-    cluster = SimulatedCluster(
-        config=Config(
-            n=4, batch_size=8, seed=2027, delivery_columnar=columnar
-        ),
-        seed=2027,
-        key_seed=15,
-    )
-    for i in range(24):
-        cluster.submit(b"dlv-tx-%04d" % i)
-    cluster.run_epochs()
-    depth = cluster.assert_agreement()
-    h = hashlib.sha256()
-    for nid in cluster.ids:
-        for epoch, batch in enumerate(
-            cluster.nodes[nid].committed_batches
-        ):
-            h.update(encode_batch_body(epoch, batch))
-    return h.hexdigest(), depth, cluster.net.delivery_stats()
-
-
-def test_scalar_vs_columnar_identical_ledgers_channel():
-    col = _channel_run(columnar=True)
-    sca = _channel_run(columnar=False)
-    assert col[1] >= 2 and sca[1] >= 2  # both actually committed
-    assert col[0] == sca[0], (
-        "columnar delivery committed different ledger bytes than the "
-        f"scalar arm:\n  columnar: {col}\n  scalar:   {sca}"
-    )
-    # the refactor's entire point: the columnar arm decodes FEWER
-    # frames (shared-prefix memo) and makes FEWER verify calls (wave
-    # batches) for the identical schedule — never more
-    assert col[2]["frames_decoded"] < sca[2]["frames_decoded"], (
-        col[2], sca[2],
-    )
-    assert col[2]["mac_verifies"] < sca[2]["mac_verifies"], (
-        col[2], sca[2],
-    )
-    # and the memo genuinely hit (a broadcast's N receiver frames
-    # share one decode)
-    probes = col[2]["decode_memo_hits"] + col[2]["decode_memo_misses"]
-    assert probes > 0 and col[2]["decode_memo_hits"] > 0
-    # scalar arm reports zeroed memo keys (schema stability)
-    assert sca[2]["decode_memo_hits"] == 0
-    assert sca[2]["decode_memo_misses"] == 0
-
-
-def test_transport_metrics_surface_delivery_counters():
-    """Metrics.snapshot()["transport"] carries the delivery-plane
-    counters on the channel transport (endpoint_stats provider)."""
-    cluster = SimulatedCluster(
-        config=Config(n=4, batch_size=8, seed=5, delivery_columnar=True),
-        seed=5,
-        key_seed=2,
-    )
-    for i in range(8):
-        cluster.submit(b"mtx-%04d" % i)
-    cluster.run_epochs()
-    snap = cluster.nodes[cluster.ids[0]].metrics.snapshot()["transport"]
-    for key in (
-        "frames_decoded",
-        "decode_memo_hits",
-        "decode_memo_misses",
-        "mac_verify_batches",
-    ):
-        assert key in snap, snap
-    assert snap["mac_verify_batches"] > 0
-    assert snap["delivered"] > 0
-
-
-def _grpc_epoch0_bodies(
-    columnar: bool, wave_routing: bool = True
-) -> tuple:
-    """(per-node epoch-0 bodies, one host's metrics snapshot) from a
-    4-node run over real localhost gRPC under the given arms."""
-    from cleisthenes_tpu.protocol.honeybadger import setup_keys
-    from cleisthenes_tpu.transport.host import ValidatorHost
-
-    n = 4
-    cfg = Config(
-        n=n,
-        batch_size=8,
-        seed=78,
-        delivery_columnar=columnar,
-        wave_routing=wave_routing,
-    )
-    ids = [f"node{i}" for i in range(n)]
-    keys = setup_keys(cfg, ids, seed=56)
-    hosts = {i: ValidatorHost(cfg, i, ids, keys[i]) for i in ids}
-    try:
-        addrs = {i: h.listen() for i, h in hosts.items()}
-        threads = [
-            threading.Thread(target=h.connect, args=(addrs,))
-            for h in hosts.values()
-        ]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join(timeout=15)
-        for i in range(8):
-            hosts[ids[i % n]].submit(b"grpc-dlv-%02d" % i)
-        for h in hosts.values():
-            h.propose()
-        first = {i: h.wait_commit(timeout=60) for i, h in hosts.items()}
-        assert {e for e, _ in first.values()} == {0}
-        snap = hosts[ids[0]].node.metrics.snapshot()
-        return [encode_batch_body(0, b) for _, b in first.values()], snap
-    finally:
-        for h in hosts.values():
-            h.stop()
-
-
-def test_scalar_vs_columnar_identical_ledgers_grpc():
-    """Same roster, same submissions, real sockets: the columnar and
-    scalar delivery arms must commit byte-identical epoch-0 batches,
-    and the columnar arm's wave verify must actually engage (batch
-    count > 0, batches <= frames)."""
-    col, col_snap = _grpc_epoch0_bodies(columnar=True)
-    sca, _sca_snap = _grpc_epoch0_bodies(columnar=False)
-    # within-run agreement is byte-exact on both arms...
-    assert all(b == col[0] for b in col)
-    assert all(b == sca[0] for b in sca)
-    # ...and across the delivery-arm boundary too
-    assert col[0] == sca[0], (
-        "columnar vs scalar gRPC runs committed different epoch-0 bytes"
-    )
-    transport = col_snap["transport"]
-    assert transport["mac_verify_batches"] > 0
-    assert transport["mac_verify_batches"] <= transport["frames_decoded"]
 
 
 # Prints one line digesting the ledger bytes AND the columnar delivery
@@ -180,7 +41,7 @@ from cleisthenes_tpu.core.ledger import encode_batch_body
 from cleisthenes_tpu.protocol.cluster import SimulatedCluster
 
 cluster = SimulatedCluster(
-    config=Config(n=4, batch_size=8, seed=909, delivery_columnar=True),
+    config=Config(n=4, batch_size=8, seed=909),
     seed=909,
     key_seed=4,
 )
@@ -194,7 +55,6 @@ for nid in cluster.ids:
     for epoch, batch in enumerate(cluster.nodes[nid].committed_batches):
         h.update(encode_batch_body(epoch, batch))
 d = cluster.net.delivery_stats()
-assert Config().wave_routing is True  # the router is the default arm
 dispatches = sum(
     cluster.nodes[nid].metrics.handler_dispatches.value
     for nid in cluster.ids
@@ -248,101 +108,6 @@ def test_delivery_ordering_identical_across_hash_seeds():
         f"  {a}\n  {b}\n-> hash-order iteration is leaking into the "
         "wave-prepare / EchoBank path (see staticcheck DET002)"
     )
-
-
-# ---------------------------------------------------------------------------
-# wave routing (ISSUE 10): scalar vs wave-routed ingest
-# ---------------------------------------------------------------------------
-
-
-def _routing_run(wave_routing: bool) -> tuple:
-    """(ledger digest, depth, cluster-wide handler dispatches, waves
-    routed) for one seeded 4-node channel run under the given ROUTING
-    arm (delivery_columnar stays on for both — the router rides it)."""
-    cluster = SimulatedCluster(
-        config=Config(
-            n=4,
-            batch_size=8,
-            seed=4041,
-            delivery_columnar=True,
-            wave_routing=wave_routing,
-        ),
-        seed=4041,
-        key_seed=23,
-    )
-    for i in range(24):
-        cluster.submit(b"rtr-tx-%04d" % i)
-    cluster.run_epochs()
-    depth = cluster.assert_agreement()
-    h = hashlib.sha256()
-    for nid in cluster.ids:
-        for epoch, batch in enumerate(
-            cluster.nodes[nid].committed_batches
-        ):
-            h.update(encode_batch_body(epoch, batch))
-    dispatches = sum(
-        cluster.nodes[nid].metrics.handler_dispatches.value
-        for nid in cluster.ids
-    )
-    waves = sum(
-        cluster.nodes[nid].metrics.waves_routed.value
-        for nid in cluster.ids
-    )
-    return h.hexdigest(), depth, dispatches, waves
-
-
-def test_scalar_vs_wave_routing_identical_ledgers_channel():
-    wav = _routing_run(wave_routing=True)
-    sca = _routing_run(wave_routing=False)
-    assert wav[1] >= 2 and sca[1] >= 2  # both actually committed
-    assert wav[0] == sca[0], (
-        "wave-routed ingest committed different ledger bytes than the "
-        f"scalar routing arm:\n  wave:   {wav}\n  scalar: {sca}"
-    )
-    # the refactor's entire point: one batch handler invocation per
-    # (kind, wave) instead of one Python call chain per payload —
-    # the deterministic counter must drop by a real factor, and the
-    # router must actually have demuxed waves
-    assert sca[2] >= 3 * wav[2], (wav, sca)
-    assert wav[3] > 0
-    assert sca[3] == 0  # scalar arm never routes a wave
-
-
-def test_router_metrics_schema_zeroed_on_scalar_arm():
-    """snapshot()["router"] keys are present on BOTH arms (the PR-9
-    schema rule) and zeroed on the scalar one."""
-    for wave in (True, False):
-        cluster = SimulatedCluster(
-            config=Config(
-                n=4, batch_size=8, seed=7, wave_routing=wave
-            ),
-            seed=7,
-            key_seed=2,
-        )
-        for i in range(8):
-            cluster.submit(b"rs-%04d" % i)
-        cluster.run_epochs()
-        snap = cluster.nodes[cluster.ids[0]].metrics.snapshot()["router"]
-        assert set(snap) == {"handler_dispatches", "waves_routed"}
-        assert snap["handler_dispatches"] > 0  # both arms dispatch
-        assert (snap["waves_routed"] > 0) == wave
-
-
-def test_scalar_vs_wave_routing_identical_ledgers_grpc():
-    """Same roster, same submissions, real sockets + the dispatcher's
-    wave mailbox: the wave-routed and scalar routing arms must commit
-    byte-identical epoch-0 batches, and the wave arm must actually
-    route waves."""
-    wav, wav_snap = _grpc_epoch0_bodies(columnar=True, wave_routing=True)
-    sca, _ = _grpc_epoch0_bodies(columnar=True, wave_routing=False)
-    assert all(b == wav[0] for b in wav)
-    assert all(b == sca[0] for b in sca)
-    assert wav[0] == sca[0], (
-        "wave vs scalar routing gRPC runs committed different "
-        "epoch-0 bytes"
-    )
-    assert wav_snap["router"]["waves_routed"] > 0
-    assert wav_snap["router"]["handler_dispatches"] > 0
 
 
 # ---------------------------------------------------------------------------
@@ -421,18 +186,18 @@ def test_decode_frame_shared_parity_and_rejections():
 
 
 # ---------------------------------------------------------------------------
-# PR-4 semantic coalitions against the EchoBank arm
+# PR-4 semantic coalitions against the EchoBank
 # ---------------------------------------------------------------------------
 
 
 def _drive_coalition(behaviors: dict, n: int, seed: int) -> int:
-    """Run a Byzantine coalition on the columnar arm; returns the
-    agreed honest depth (assert_agreement = identical ledger
+    """Run a Byzantine coalition; returns the agreed honest depth
+    (assert_agreement = identical ledger
     prefixes)."""
     bad = sorted(behaviors)
     cluster = SimulatedCluster(
         n=n,
-        config=Config(n=n, batch_size=8, delivery_columnar=True),
+        config=Config(n=n, batch_size=8),
         seed=seed,
         key_seed=21,
         behaviors=behaviors,
@@ -498,11 +263,6 @@ def test_epoch_sprayer_coalition_columnar_bank():
 
 
 # ---------------------------------------------------------------------------
-# fuzz bands on the columnar arm
-# ---------------------------------------------------------------------------
-
-
-# ---------------------------------------------------------------------------
 # PR-4 semantic coalitions against the wave router (ISSUE 10)
 # ---------------------------------------------------------------------------
 
@@ -514,7 +274,6 @@ def test_equivocator_coalition_wave_router():
     quorums separate when whole waves land in one dispatch."""
     from cleisthenes_tpu.protocol.byzantine import make_behavior
 
-    assert Config().wave_routing is True  # the arm under test
     behaviors = {"node003": make_behavior("equivocator", seed=41)}
     depth = _drive_coalition(behaviors, n=4, seed=23)
     assert depth >= 1
@@ -558,12 +317,11 @@ def test_selective_mute_coalition_wave_router():
 @pytest.mark.faults
 def test_fuzz_band_columnar_delivery():
     """20 sampled composite schedules (semantic behaviors x wire
-    faults x crash/partition timelines) with delivery_columnar=True —
-    a seed band disjoint from ci.sh's 0:20 smoke band, so the
+    faults x crash/partition timelines) on the columnar delivery
+    plane — a seed band disjoint from ci.sh's 0:20 smoke band, so the
     delivery plane adds coverage instead of re-running it."""
     from tools.fuzz import run_schedule, sample_schedule
 
-    assert Config().delivery_columnar is True  # the fuzzer's arm
     for seed in range(300, 320):
         v = run_schedule(sample_schedule(seed))
         assert v is None, f"seed {seed}: {v}"
@@ -572,27 +330,24 @@ def test_fuzz_band_columnar_delivery():
 @pytest.mark.slow
 @pytest.mark.faults
 def test_fuzz_deep_sweep_columnar_delivery():
-    """The 200-seed slow band on the columnar delivery arm."""
+    """The 200-seed slow band on the columnar delivery plane."""
     from tools.fuzz import run_schedule, sample_schedule
 
-    assert Config().delivery_columnar is True
     for seed in range(320, 520):
         v = run_schedule(sample_schedule(seed))
         assert v is None, f"seed {seed}: {v}"
 
 
 @pytest.mark.faults
-def test_fuzz_band_wave_routing():
-    """20 sampled composite schedules against the WAVE ROUTER (the
-    fuzzer's default arm since wave_routing defaults True) — a seed
-    band disjoint from the ci.sh smoke band and the PR-9 delivery
+def test_fuzz_band_wave_router():
+    """20 sampled composite schedules against the WAVE ROUTER — a
+    seed band disjoint from the ci.sh smoke band and the PR-9 delivery
     band, so the router seam adds coverage instead of re-running it.
     Wire-fault schedules mount a fault_filter, which on the channel
     transport keeps per-frame decode/verify but still routes the
     verified wave — the seam is exercised under tampering too."""
     from tools.fuzz import run_schedule, sample_schedule
 
-    assert Config().wave_routing is True  # the fuzzer's arm
     for seed in range(520, 540):
         v = run_schedule(sample_schedule(seed))
         assert v is None, f"seed {seed}: {v}"
@@ -600,28 +355,10 @@ def test_fuzz_band_wave_routing():
 
 @pytest.mark.slow
 @pytest.mark.faults
-def test_fuzz_deep_sweep_wave_routing():
-    """The 200-seed slow band on the wave-routing arm."""
+def test_fuzz_deep_sweep_wave_router():
+    """The 200-seed slow band on the wave router."""
     from tools.fuzz import run_schedule, sample_schedule
 
-    assert Config().wave_routing is True
     for seed in range(540, 740):
         v = run_schedule(sample_schedule(seed))
-        assert v is None, f"seed {seed}: {v}"
-
-
-@pytest.mark.faults
-def test_fuzz_band_scalar_routing_pinned():
-    """Wave routing drains a whole wave before any handler runs, so
-    the scalar arm's finer per-message interleavings (a new message
-    overtaking older pending ones mid-wave) are a schedule space the
-    default arm can no longer reach — this band stays PINNED to
-    wave_routing=False so the adversarial scheduler keeps exploring
-    it (the schedule key round-trips through repro files)."""
-    from tools.fuzz import run_schedule, sample_schedule
-
-    for seed in range(740, 760):
-        s = sample_schedule(seed)
-        s["wave_routing"] = False
-        v = run_schedule(s)
         assert v is None, f"seed {seed}: {v}"

@@ -1,35 +1,23 @@
-"""Scalar vs columnar EGRESS equivalence (ISSUE 13).
+"""The columnar egress plane (ISSUE 13).
 
-The egress columnarization moved outbound work to wave granularity:
-one coalescer flush hands its whole wave of folded bundles to ONE
-``Authenticator.sign_wire_wave`` pass (payload bodies encode once per
-distinct object through the shared-prefix ``FrameEncodeMemo``, MACs
-batch over the PR-7 precomputed key schedules), single-receiver sends
-ride the same signer, and the protocol plane's pending coin-share
-issues pool in the CryptoHub's coin column — one native
-multi-exponentiation dispatch per staged pool per wave instead of one
-``issue_shares_batch`` per node per drain.  That reshapes WHEN frames
-encode, sign, and coin shares issue — but it must never reshape WHAT
-crosses the wire or what the roster commits.
-``Config.egress_columnar=False`` keeps the per-post scalar egress
-path as a live comparison arm; these tests run the same seeded
-schedule under both arms and require byte-identical committed ledgers
-AND byte-identical wire-frame streams (under deterministically pinned
-entropy/time) on the channel transport, byte-identical signer output
-and committed batches on real gRPC, that the deterministic sign/coin
-counters actually DROP, that the PR-4 semantic coalitions still lie
-per-receiver through the columnar egress arm, and that the whole
-egress path is PYTHONHASHSEED-independent.
+Outbound work runs at wave granularity: one coalescer flush hands its
+whole wave of folded bundles to ONE ``Authenticator.sign_wire_wave``
+pass (payload bodies encode once per distinct object through the
+shared-prefix ``FrameEncodeMemo``, MACs batch over the PR-7
+precomputed key schedules), single-receiver sends ride the same
+signer, and the protocol plane's pending coin-share issues pool in the
+CryptoHub's coin column — one native multi-exponentiation dispatch per
+staged pool per wave.  These tests hold the wave signer byte-identical
+to looping ``sign_wire_many``, the batched coin kernels to their
+per-item maps, and run the PR-4 semantic coalitions, which still lie
+per-receiver through the wave flush.  What a seeded run COMMITS and
+puts on the wire is pinned in tests/test_seeded_pins.py.
 """
 
 from __future__ import annotations
 
-import hashlib
-import os
 import pathlib
-import subprocess
 import sys
-import threading
 
 import pytest
 
@@ -37,86 +25,7 @@ REPO = pathlib.Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO))
 
 from cleisthenes_tpu.config import Config  # noqa: E402
-from cleisthenes_tpu.core.ledger import encode_batch_body  # noqa: E402
 from cleisthenes_tpu.protocol.cluster import SimulatedCluster  # noqa: E402
-
-
-def _channel_run(egress: bool) -> tuple:
-    """(ledger digest, depth, delivery counters, hub counters) for one
-    seeded 4-node channel-transport run under the given egress arm."""
-    cluster = SimulatedCluster(
-        config=Config(
-            n=4, batch_size=8, seed=3031, egress_columnar=egress
-        ),
-        seed=3031,
-        key_seed=17,
-    )
-    for i in range(24):
-        cluster.submit(b"egr-tx-%04d" % i)
-    cluster.run_epochs()
-    depth = cluster.assert_agreement()
-    h = hashlib.sha256()
-    for nid in cluster.ids:
-        for epoch, batch in enumerate(
-            cluster.nodes[nid].committed_batches
-        ):
-            h.update(encode_batch_body(epoch, batch))
-    hub = cluster.nodes[cluster.ids[0]].hub.stats()
-    return h.hexdigest(), depth, cluster.net.delivery_stats(), hub
-
-
-def test_scalar_vs_columnar_identical_ledgers_channel():
-    col = _channel_run(egress=True)
-    sca = _channel_run(egress=False)
-    assert col[1] >= 2 and sca[1] >= 2  # both actually committed
-    assert col[0] == sca[0], (
-        "columnar egress committed different ledger bytes than the "
-        f"scalar arm:\n  columnar: {col}\n  scalar:   {sca}"
-    )
-    # the refactor's entire point: the columnar arm makes FEWER
-    # Authenticator sign passes (one wave call per flush instead of
-    # one per post) and FEWER native coin-issue dispatches (one
-    # pooled share_batch per wave instead of one per node per drain)
-    # for the identical schedule — never more
-    assert col[2]["mac_signs"] < sca[2]["mac_signs"], (col[2], sca[2])
-    assert 2 * col[3]["coin_issue_batches"] <= sca[3]["coin_issue_batches"], (
-        col[3], sca[3],
-    )
-    # both arms issue the identical shares through the same unit
-    assert col[3]["coin_issue_items"] == sca[3]["coin_issue_items"]
-    # payload bodies actually encoded never increase (the memo only
-    # ever dedups; with no cross-receiver sharing the arms tie)
-    assert col[2]["frames_encoded"] <= sca[2]["frames_encoded"]
-    # scalar arm reports zeroed memo keys (schema stability)
-    assert sca[2]["encode_memo_hits"] == 0
-    assert sca[2]["encode_memo_misses"] == 0
-
-
-def test_transport_metrics_surface_egress_counters():
-    """Metrics.snapshot() carries the egress-plane counters on the
-    channel transport (endpoint_stats provider) and the coin-issue
-    tallies in the hub block."""
-    cluster = SimulatedCluster(
-        config=Config(n=4, batch_size=8, seed=6, egress_columnar=True),
-        seed=6,
-        key_seed=3,
-    )
-    for i in range(8):
-        cluster.submit(b"megr-%04d" % i)
-    cluster.run_epochs()
-    snap = cluster.nodes[cluster.ids[0]].metrics.snapshot()
-    transport = snap["transport"]
-    for key in (
-        "frames_encoded",
-        "encode_memo_hits",
-        "encode_memo_misses",
-        "mac_sign_batches",
-    ):
-        assert key in transport, transport
-    assert transport["mac_sign_batches"] > 0
-    assert transport["frames_encoded"] > 0
-    assert snap["hub"]["coin_share_batches"] > 0
-    assert snap["hub"]["coin_share_items"] > 0
 
 
 # ---------------------------------------------------------------------------
@@ -241,195 +150,18 @@ def test_coin_share_batch_matches_scalar_kernels():
 
 
 # ---------------------------------------------------------------------------
-# wire-frame byte equivalence across arms (channel transport)
-# ---------------------------------------------------------------------------
-
-# Runs BOTH egress arms inside one subprocess with entropy and wall
-# clock pinned (constant CP-nonce bytes keep every Chaum-Pedersen
-# proof valid while making it batch-position-independent; a fixed
-# time.time pins the envelope timestamp field), captures every frame
-# at enqueue time via ChannelNetwork.frame_tap, and requires the two
-# frame STREAMS — sender, receiver, and wire bytes, in order — to be
-# byte-identical.  Prints one digest line carrying the deterministic
-# egress counters; two PYTHONHASHSEED values must produce identical
-# lines (hash-order iteration in the wave-signer / coin-pool path
-# would show up as different counters, frame order, or ledger bytes).
-_EGRESS_DRIVER = r"""
-import hashlib
-import secrets
-import time
-
-secrets.token_bytes = lambda n: b"\x07" * n  # constant CP nonces
-time.time = lambda: 1_700_000_000.0  # pinned envelope timestamps
-
-from cleisthenes_tpu.config import Config
-from cleisthenes_tpu.core.ledger import encode_batch_body
-from cleisthenes_tpu.protocol.cluster import SimulatedCluster
-
-
-def run(egress):
-    cluster = SimulatedCluster(
-        config=Config(
-            n=4, batch_size=8, seed=4042, egress_columnar=egress
-        ),
-        seed=4042,
-        key_seed=19,
-    )
-    frames = []
-    cluster.net.frame_tap = lambda s, r, w: frames.append((s, r, w))
-    for i in range(24):
-        cluster.submit(b"egr-hs-%04d" % i)
-    cluster.run_epochs()
-    depth = cluster.assert_agreement()
-    assert depth >= 2, f"want >=2 committed epochs, got {depth}"
-    h = hashlib.sha256()
-    for nid in cluster.ids:
-        for epoch, batch in enumerate(
-            cluster.nodes[nid].committed_batches
-        ):
-            h.update(encode_batch_body(epoch, batch))
-    return frames, h.hexdigest(), cluster.net.delivery_stats(), (
-        cluster.nodes[cluster.ids[0]].hub.stats()
-    )
-
-
-col_frames, col_digest, col_d, col_hub = run(True)
-sca_frames, sca_digest, sca_d, sca_hub = run(False)
-assert col_digest == sca_digest, "ledger bytes diverged across arms"
-assert len(col_frames) == len(sca_frames), (
-    len(col_frames), len(sca_frames),
-)
-for i, (a, b) in enumerate(zip(col_frames, sca_frames)):
-    assert a == b, (
-        f"frame {i} diverged across egress arms: "
-        f"{a[0]}->{a[1]} vs {b[0]}->{b[1]}"
-    )
-fh = hashlib.sha256()
-for s, r, w in col_frames:
-    fh.update(s.encode() + b"|" + r.encode() + b"|" + w)
-print(
-    "EGRESS_DIGEST=%s frames=%d stream=%s signs=%d encoded=%d "
-    "coin_batches=%d coin_items=%d"
-    % (
-        col_digest,
-        len(col_frames),
-        fh.hexdigest(),
-        col_d["mac_signs"],
-        col_d["frames_encoded"],
-        col_hub["coin_issue_batches"],
-        col_hub["coin_issue_items"],
-    )
-)
-"""
-
-
-def _run_egress_driver(hashseed: str) -> str:
-    env = dict(os.environ)
-    env["PYTHONHASHSEED"] = hashseed
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    proc = subprocess.run(
-        [sys.executable, "-c", _EGRESS_DRIVER],
-        cwd=REPO,
-        env=env,
-        capture_output=True,
-        text=True,
-        timeout=300,
-    )
-    assert proc.returncode == 0, (
-        f"PYTHONHASHSEED={hashseed} egress run failed:\n"
-        f"{proc.stdout}\n{proc.stderr}"
-    )
-    for line in proc.stdout.splitlines():
-        if line.startswith("EGRESS_DIGEST="):
-            return line
-    raise AssertionError(f"no egress digest line:\n{proc.stdout}")
-
-
-def test_wire_frames_identical_across_arms_and_hash_seeds():
-    a = _run_egress_driver("1")
-    b = _run_egress_driver("2")
-    assert a == b, (
-        "columnar egress diverged across PYTHONHASHSEED values:\n"
-        f"  {a}\n  {b}\n-> hash-order iteration is leaking into the "
-        "wave-signer / coin-pool path (see staticcheck DET002)"
-    )
-
-
-# ---------------------------------------------------------------------------
-# real gRPC: columnar vs scalar egress over sockets
-# ---------------------------------------------------------------------------
-
-
-def _grpc_epoch0_bodies(egress: bool) -> tuple:
-    """(per-node epoch-0 bodies, one host's metrics snapshot) from a
-    4-node run over real localhost gRPC under the given egress arm."""
-    from cleisthenes_tpu.protocol.honeybadger import setup_keys
-    from cleisthenes_tpu.transport.host import ValidatorHost
-
-    n = 4
-    cfg = Config(n=n, batch_size=8, seed=81, egress_columnar=egress)
-    ids = [f"node{i}" for i in range(n)]
-    keys = setup_keys(cfg, ids, seed=58)
-    hosts = {i: ValidatorHost(cfg, i, ids, keys[i]) for i in ids}
-    try:
-        addrs = {i: h.listen() for i, h in hosts.items()}
-        threads = [
-            threading.Thread(target=h.connect, args=(addrs,))
-            for h in hosts.values()
-        ]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join(timeout=15)
-        for i in range(8):
-            hosts[ids[i % n]].submit(b"grpc-egr-%02d" % i)
-        for h in hosts.values():
-            h.propose()
-        first = {i: h.wait_commit(timeout=60) for i, h in hosts.items()}
-        assert {e for e, _ in first.values()} == {0}
-        snap = hosts[ids[0]].node.metrics.snapshot()
-        return [encode_batch_body(0, b) for _, b in first.values()], snap
-    finally:
-        for h in hosts.values():
-            h.stop()
-
-
-def test_scalar_vs_columnar_identical_ledgers_grpc():
-    """Same roster, same submissions, real sockets: the columnar and
-    scalar egress arms must commit byte-identical epoch-0 batches,
-    and the columnar arm's wave signer must actually engage (sign
-    batches > 0; frame-level byte equality for this path is proven at
-    the signer seam by test_sign_wire_wave_parity_and_memo_sharing,
-    since thread timing makes whole-run frame streams incomparable
-    over real sockets)."""
-    col, col_snap = _grpc_epoch0_bodies(egress=True)
-    sca, _sca_snap = _grpc_epoch0_bodies(egress=False)
-    # within-run agreement is byte-exact on both arms...
-    assert all(b == col[0] for b in col)
-    assert all(b == sca[0] for b in sca)
-    # ...and across the egress-arm boundary too
-    assert col[0] == sca[0], (
-        "columnar vs scalar gRPC runs committed different epoch-0 bytes"
-    )
-    transport = col_snap["transport"]
-    assert transport["mac_sign_batches"] > 0
-    assert transport["frames_encoded"] > 0
-    assert col_snap["hub"]["coin_share_batches"] > 0
-
-
-# ---------------------------------------------------------------------------
-# PR-4 semantic coalitions against the columnar egress arm
+# PR-4 semantic coalitions against the columnar egress plane
 # ---------------------------------------------------------------------------
 
 
 def _drive_coalition(behaviors: dict, n: int, seed: int):
-    """Run a Byzantine coalition on the columnar egress arm; returns
+    """Run a Byzantine coalition; returns
     (agreed honest depth, the network) — assert_agreement = identical
     ledger prefixes."""
     bad = sorted(behaviors)
     cluster = SimulatedCluster(
         n=n,
-        config=Config(n=n, batch_size=8, egress_columnar=True),
+        config=Config(n=n, batch_size=8),
         seed=seed,
         key_seed=27,
         behaviors=behaviors,
@@ -456,7 +188,6 @@ def test_equivocator_coalition_columnar_egress():
     another or fail the MACs wholesale."""
     from cleisthenes_tpu.protocol.byzantine import make_behavior
 
-    assert Config().egress_columnar is True  # the arm under test
     behaviors = {"node003": make_behavior("equivocator", seed=51)}
     depth, net = _drive_coalition(behaviors, n=4, seed=37)
     assert depth >= 1

@@ -103,8 +103,9 @@ BAD_FIXTURES = [
     # increments and counters that never reach snapshot() gate at the
     # declaration line
     "protocol/schema001_bad.py",
-    # the arm registry (ISSUE 14): stale ARM_FLAGS entries, dead arm
-    # flags and wave entry points with no arm-flag gate
+    # the arm registry (ISSUE 14): stale ARM_FLAGS entries (a removed
+    # option still named there), dead arm flags and live flags the
+    # perfgate fingerprint does not key on
     "protocol/arm001_bad.py",
     # the verify-before-dispatch taint walk (ISSUE 14): decoded frames
     # reaching a handler sink with no verify_wire* in between

@@ -212,10 +212,9 @@ def _run_cli(*args, timeout=120):
 
 def test_lone_real_file_scan_has_no_standing_to_convict_absence():
     """Single-file runs of the real registry modules must stay clean:
-    'never incremented' / 'never read' / wave-unreachable are claims
-    about consumers the scan cannot see (self-contained fixtures keep
-    the full rule set — tests/test_staticcheck.py proves they still
-    gate)."""
+    'never incremented' / 'never read' are claims about consumers the
+    scan cannot see (self-contained fixtures keep the full rule set —
+    tests/test_staticcheck.py proves they still gate)."""
     for rel in (
         "cleisthenes_tpu/utils/metrics.py",
         "cleisthenes_tpu/config.py",

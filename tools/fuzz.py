@@ -57,6 +57,16 @@ from cleisthenes_tpu.protocol.cluster import (
 from cleisthenes_tpu.utils.adversary import Coalition
 
 SCHEDULE_VERSION = 1
+# every key sample_schedule writes and _build_cluster / run_schedule
+# read; a schedule carrying any other key is refused by name
+SCHEDULE_KEYS = frozenset(
+    (
+        "version", "seed", "pipeline_depth", "n", "f", "batch_size",
+        "key_seed", "rounds", "txs", "bad", "behaviors", "wire",
+        "timeline", "check_liveness", "wan_profile", "ingress",
+        "lanes", "reduced",
+    )
+)
 
 # wire stages the sampler may enable, with their sampled-argument
 # ranges (kept mild: the budget is f Byzantine nodes, not a dead net)
@@ -327,6 +337,15 @@ def sample_schedule(
 
 
 def _build_cluster(schedule: dict, trace: bool) -> SimulatedCluster:
+    unknown = sorted(set(schedule) - SCHEDULE_KEYS)
+    if unknown:
+        # a repro file may carry a key this build no longer (or does
+        # not yet) understand; replaying it with the key ignored would
+        # claim a schedule this build cannot run
+        raise ValueError(
+            f"unknown schedule key(s) {unknown}: this build runs "
+            f"{sorted(SCHEDULE_KEYS)}"
+        )
     by_node: Dict[str, list] = {}
     for spec in schedule["behaviors"]:
         b = make_behavior(
@@ -358,12 +377,6 @@ def _build_cluster(schedule: dict, trace: bool) -> SimulatedCluster:
         trace=trace,
         attested_log=red,
         reduced_quorum=red,
-        # schedules may pin the routing arm: wave_routing drains a
-        # whole wave before any handler runs, so the scalar arm's
-        # finer per-message interleavings are a schedule space of
-        # their own — a band stays pinned to it (the key round-trips
-        # through repro files like every other schedule field)
-        wave_routing=schedule.get("wave_routing", True),
         # K-deep window (ISSUE 15): depth rides the schedule; the
         # reconfig lead stretches with it where the default would
         # violate Config's lead > depth + decrypt_lag_max bound

@@ -304,26 +304,9 @@ def run_sample(
             # the stage shares) MEAN — runs must never gate against
             # trend records measured under the other mode
             "order_then_settle": bool(cfg.order_then_settle),
-            # the delivery arm changes what the frame/MAC counters
-            # MEAN (scalar: one decode+verify per frame; columnar:
-            # memoized decode, one verify per wave) — same rule
-            "delivery_columnar": bool(cfg.delivery_columnar),
-            # the routing arm changes what handler_dispatches MEANS
-            # (scalar: one per payload; wave: one per kind per wave)
-            # — a mode flip must never gate against the other mode's
-            # trend
-            "wave_routing": bool(cfg.wave_routing),
-            # the egress arm changes what the encode/sign/coin
-            # counters MEAN (scalar: one sign pass per post, one coin
-            # batch per node per drain; columnar: one wave pass per
-            # flush, one pooled coin dispatch) — same rule
-            "egress_columnar": bool(cfg.egress_columnar),
-            # the remaining ARM_FLAGS (config.py): the hub's flush
-            # discipline changes what hub_dispatches MEANS and epoch
-            # pipelining changes what the epoch windows overlap —
-            # every declared arm flag keys the fingerprint
-            # (staticcheck ARM001 cross-checks the set)
-            "hub_wave_flush": bool(cfg.hub_wave_flush),
+            # every declared arm flag (config.ARM_FLAGS) keys the
+            # fingerprint (staticcheck ARM001 cross-checks the set):
+            # epoch pipelining changes what the epoch windows overlap
             "epoch_pipelining": bool(cfg.epoch_pipelining),
             # K-deep pipelined frontiers (ISSUE 15): the depth
             # changes how many epochs share each wave — and with

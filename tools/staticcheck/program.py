@@ -6,9 +6,8 @@ the invariants that actually broke ground in PRs 7-13 are CROSS-MODULE
 contracts: payload kinds must carry encode+parse+pb coverage
 (transport/message.py vs transport/pb_adapter.py), Metrics counters
 must appear in the snapshot schema and the golden /metrics exposition,
-Config arm flags must be perfgate fingerprint keys with a pinned
-scalar arm in the equivalence tests, and every ``*_wave`` entry point
-must sit behind an arm-flag gate.  This module builds the one index
+and Config arm flags must be perfgate fingerprint keys with both
+arms pinned in the equivalence tests.  This module builds the one index
 those registry rules (tools/staticcheck/registry_rules.py) run over.
 
 Role detection is STRUCTURAL, not path-hardcoded, so the fixture
@@ -52,7 +51,6 @@ _PB_TAG_RE = re.compile(r"^_PB_TAG_[A-Z0-9_]+$")
 # stock-decoder interop
 PB_RESERVED_TAGS = frozenset((1, 2, 3, 4))
 
-_WAVE_SUFFIX = "_wave"
 _BOOL_FLAG_PIN_RE = r"\b{flag}\s*=\s*(?:True|False)\b"
 # int-valued arms (Config.lanes): the pin is a literal integer, and
 # the rule wants the DISTINCT values (baseline=1 vs shard-out>1)
@@ -134,10 +132,6 @@ class ProgramIndex:
     counter_incs: Dict[str, int]  # counter attr -> inc() sites seen
     attr_reads: Set[str]  # every Attribute attr loaded anywhere
     kw_names: Set[str]  # every keyword-argument name used anywhere
-    defs: Dict[str, Set[str]]  # function/class name -> defining files
-    refs: Dict[str, Set[str]]  # relpath -> names referenced there
-    flag_reader_files: Set[str]  # files reading any declared arm flag
-    wave_defs: List[Tuple[str, str, int]]  # (name, relpath, line)
     fingerprint_keys: Optional[Set[str]]  # None: no perfgate in sight
     golden_families: Optional[Set[str]]  # None: no golden in sight
     test_flag_pins: Optional[str]  # concatenated tests text, or None
@@ -173,10 +167,6 @@ class ProgramIndex:
                 self.test_flag_pins,
             )
         }
-
-
-def is_wave_entry_name(name: str) -> bool:
-    return name.endswith(_WAVE_SUFFIX) and len(name) > len(_WAVE_SUFFIX)
 
 
 # ---------------------------------------------------------------------------
@@ -440,9 +430,6 @@ def build_index(ctxs, root: pathlib.Path) -> ProgramIndex:
     counter_incs: Dict[str, int] = {}
     attr_reads: Set[str] = set()
     kw_names: Set[str] = set()
-    defs: Dict[str, Set[str]] = {}
-    refs: Dict[str, Set[str]] = {}
-    wave_defs: List[Tuple[str, str, int]] = []
     # (relpath, keys) per file carrying a "fingerprint" dict: the
     # REAL registry (a file named perfgate.py) wins over
     # fingerprint-shaped dict literals in tests/helpers, so a key
@@ -468,35 +455,11 @@ def build_index(ctxs, root: pathlib.Path) -> ProgramIndex:
         if fp is not None:
             fingerprints_by_file.append((ctx.relpath, fp))
 
-        file_refs: Set[str] = set()
         for node in ast.walk(ctx.tree):
             if isinstance(node, ast.Attribute):
                 attr_reads.add(node.attr)
-                file_refs.add(node.attr)
-            elif isinstance(node, ast.Name) and isinstance(
-                node.ctx, ast.Load
-            ):
-                file_refs.add(node.id)
-            elif isinstance(node, ast.Constant) and isinstance(
-                node.value, str
-            ):
-                # getattr(handler, "serve_wave", None)-style dynamic
-                # references count as uses
-                if node.value.isidentifier():
-                    file_refs.add(node.value)
             elif isinstance(node, ast.keyword) and node.arg:
                 kw_names.add(node.arg)
-                file_refs.add(node.arg)
-            elif isinstance(
-                node, (ast.FunctionDef, ast.AsyncFunctionDef, ast.ClassDef)
-            ):
-                defs.setdefault(node.name, set()).add(ctx.relpath)
-                if isinstance(
-                    node, (ast.FunctionDef, ast.AsyncFunctionDef)
-                ) and is_wave_entry_name(node.name):
-                    wave_defs.append(
-                        (node.name, ctx.relpath, node.lineno)
-                    )
             if (
                 isinstance(node, ast.Call)
                 and isinstance(node.func, ast.Attribute)
@@ -505,7 +468,6 @@ def build_index(ctxs, root: pathlib.Path) -> ProgramIndex:
             ):
                 attr = node.func.value.attr
                 counter_incs[attr] = counter_incs.get(attr, 0) + 1
-        refs[ctx.relpath] = file_refs
 
     # -- root augmentation (real registries only; fixture trees carry
     # their own minis under their own root) ----------------------------
@@ -569,26 +531,6 @@ def build_index(ctxs, root: pathlib.Path) -> ProgramIndex:
             except OSError:
                 golden_families = None
 
-    # files that read any declared arm flag (attribute read or keyword
-    # pass-through): the gate seeds for the wave-reachability closure
-    all_flags: Set[str] = set()
-    for c in config_modules:
-        all_flags |= set(c.arm_flags)
-    # (the declarations themselves are AnnAssign targets and string
-    # constants, never Attribute reads, so the config module only
-    # lands here if it genuinely READS a flag)
-    flag_reader_files: Set[str] = set()
-    for ctx in ctxs:
-        for node in ast.walk(ctx.tree):
-            if (
-                isinstance(node, ast.Attribute)
-                and node.attr in all_flags
-            ) or (
-                isinstance(node, ast.keyword) and node.arg in all_flags
-            ):
-                flag_reader_files.add(ctx.relpath)
-                break
-
     return ProgramIndex(
         wire_modules=wire_modules,
         pb_modules=pb_modules,
@@ -598,10 +540,6 @@ def build_index(ctxs, root: pathlib.Path) -> ProgramIndex:
         counter_incs=counter_incs,
         attr_reads=attr_reads,
         kw_names=kw_names,
-        defs=defs,
-        refs=refs,
-        flag_reader_files=flag_reader_files,
-        wave_defs=wave_defs,
         fingerprint_keys=fingerprint_keys,
         golden_families=golden_families,
         test_flag_pins=test_flag_pins,
@@ -609,23 +547,6 @@ def build_index(ctxs, root: pathlib.Path) -> ProgramIndex:
             len(ctxs) == 1 and not is_fixture_path(ctxs[0].relpath)
         ),
     )
-
-
-def gated_closure(index: ProgramIndex) -> Set[str]:
-    """Files reachable from arm-flag readers over the references-a-
-    name-defined-there relation: a gated module that calls into a
-    module hands its arm selection down, so wave entry points defined
-    anywhere in the closure sit behind a Config-flag gate."""
-    gated = set(index.flag_reader_files)
-    work = list(gated)
-    while work:
-        src = work.pop()
-        for name in index.refs.get(src, ()):
-            for target in index.defs.get(name, ()):
-                if target not in gated:
-                    gated.add(target)
-                    work.append(target)
-    return gated
 
 
 __all__ = [
@@ -637,8 +558,6 @@ __all__ = [
     "ProgramIndex",
     "WireModule",
     "build_index",
-    "gated_closure",
     "is_fixture_path",
-    "is_wave_entry_name",
     "parse_golden_families",
 ]

@@ -26,8 +26,6 @@ from tools.staticcheck.core import FileContext, Finding, rule
 from tools.staticcheck.program import (
     PB_RESERVED_TAGS,
     ProgramIndex,
-    gated_closure,
-    is_wave_entry_name,
 )
 
 
@@ -229,17 +227,16 @@ class Schema001MetricsContract:
 
 
 # ---------------------------------------------------------------------------
-# ARM001: arm-flag / wave-entry-point parity
+# ARM001: arm-flag registry parity
 # ---------------------------------------------------------------------------
 #
-# Every columnar seam (PRs 7/9/10/13) keeps its scalar arm live behind
-# a Config flag for byte-equivalence, and perfgate fingerprints must
-# key on the flag so a mode flip never gates against the other mode's
-# trend.  ``ARM_FLAGS`` in config.py is the declared registry (the
-# @guarded_by of the both-arms discipline); this rule cross-checks it
-# against the Config fields, the fingerprint keys, the equivalence
-# tests' explicit pins, and the wave entry points' reachability from
-# flag-reading modules.
+# A seam that keeps a comparison arm live behind a Config flag for
+# byte-equivalence must pin both values in tests, and perfgate
+# fingerprints must key on the flag so a mode flip never gates against
+# the other mode's trend.  ``ARM_FLAGS`` in config.py is the declared
+# registry (the @guarded_by of the both-arms discipline); this rule
+# cross-checks it against the Config fields, the fingerprint keys and
+# the equivalence tests' explicit pins.
 
 @rule
 class Arm001WaveArmParity:
@@ -248,8 +245,7 @@ class Arm001WaveArmParity:
         "every ARM_FLAGS entry must be a bool or int Config field, "
         "read by the package, pinned explicitly in tests (>= 2 "
         "distinct values for int arms), and a perfgate fingerprint "
-        "key; every *_wave entry point must be reachable from an "
-        "arm-flag-reading module (the scalar-arm gate)"
+        "key"
     )
 
     def check_program(
@@ -283,8 +279,8 @@ class Arm001WaveArmParity:
                     yield _program_finding(
                         self.id, c.relpath, line,
                         f"arm flag {flag!r} is never read anywhere "
-                        "in the scanned tree (dead arm; the scalar "
-                        "twin cannot be reachable)",
+                        "in the scanned tree (dead arm; nothing "
+                        "selects on it)",
                         ctx_map,
                     )
                 if (
@@ -317,26 +313,11 @@ class Arm001WaveArmParity:
                     yield _program_finding(
                         self.id, c.relpath, line,
                         f"arm flag {flag!r} is never pinned "
-                        "(flag=True/False) in tests; the scalar "
-                        "byte-equivalence arm has no coverage",
+                        "(flag=True/False) in tests; the "
+                        "byte-equivalence comparison arm has no "
+                        "coverage",
                         ctx_map,
                     )
-        if index.partial_scan:
-            return  # the gating modules live in other files
-        gated = gated_closure(index)
-        for name, relpath, line in index.wave_defs:
-            parts = relpath.split("/")
-            if "protocol" not in parts and "transport" not in parts:
-                continue
-            if relpath not in gated:
-                yield _program_finding(
-                    self.id, relpath, line,
-                    f"wave entry point {name}() is not reachable "
-                    "from any arm-flag-reading module; a wave seam "
-                    "without a Config-flag gate has no live scalar "
-                    "twin to byte-compare against",
-                    ctx_map,
-                )
 
 
 # ---------------------------------------------------------------------------

@@ -313,9 +313,8 @@ class Det003HubColumnarSeam:
 # ``handler.serve_request(...)`` / ``x.handle_message(...)`` call from
 # transport/ code silently erodes that seam back to one Python call
 # chain per payload — the exact regression the router removed.  The
-# sanctioned sites (the scalar byte-equivalence comparison arm behind
-# Config.wave_routing=False, local self-delivery short-circuits, and
-# the non-wave-handler fallbacks) carry allow[DET004] pragmas with
+# sanctioned sites (local self-delivery short-circuits and the
+# non-wave-handler fallbacks) carry allow[DET004] pragmas with
 # justifications.
 
 _DET004_CALLS = frozenset(("serve_request", "handle_message"))
@@ -498,10 +497,9 @@ class Det005RosterVersionAccessor:
 # per-frame ``sign_wire_many(...)`` / ``encode_message(...)`` call
 # from protocol/ code or a transport send path silently erodes that
 # seam back to one envelope encode + sign pass per post — the exact
-# redundancy the wave signer removed.  The sanctioned sites (the
-# scalar byte-equivalence comparison arm behind
-# Config.egress_columnar=False and pre-pool boot traffic) carry
-# allow[DET006] pragmas with justifications; transport/message.py is
+# redundancy the wave signer removed.  The sanctioned sites (pre-pool
+# boot traffic, non-endpoint test rigs) carry allow[DET006] pragmas
+# with justifications; transport/message.py is
 # the codec itself and transport/base.py is the authenticator layer
 # whose job IS the per-frame encode+sign primitives (the hub.py of
 # this seam), so both are exempt.
