@@ -163,7 +163,15 @@ class CommonCoin:
 
     def combine(self, coin_id: bytes, shares: Sequence[DhShare]) -> int:
         """Full 256-bit coin value from >= f+1 verified shares."""
-        val = tpke.combine_shares(shares, self.pub.threshold, self.group)
+        return self.value_of(
+            coin_id,
+            tpke.combine_shares(shares, self.pub.threshold, self.group),
+        )
+
+    def value_of(self, coin_id: bytes, val: int) -> int:
+        """``combine`` from the combined group element on — for
+        callers whose Lagrange combines ran batched (the CryptoHub's
+        combine column)."""
         return int.from_bytes(
             hashlib.sha256(
                 b"coinval|"

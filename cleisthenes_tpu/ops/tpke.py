@@ -33,6 +33,7 @@ import dataclasses
 import functools
 import hashlib
 import hmac
+import operator
 import secrets
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
@@ -47,11 +48,13 @@ from cleisthenes_tpu.ops.modmath import (
     DEFAULT_GROUP,
     G,
     GroupParams,
+    ModEngine,
     P,
     Q,
     get_engine_degraded,
     host_pow,
     host_pow_batch,
+    ints_to_bytes33,
     mod_rows,
     mul_add_mod_rows,
 )
@@ -704,12 +707,118 @@ def _combine_subset(shares, threshold: int) -> Tuple[List[int], List[int]]:
         xs = shares.index[order].tolist()
         ds = be_rows_to_ints(shares.d[order])
     else:
-        use = sorted(shares, key=lambda s: s.index)[:threshold]
+        use = sorted(shares, key=_share_index)[:threshold]
         xs = [s.index for s in use]
         ds = [s.d for s in use]
     if len(set(xs)) != len(xs):
         raise ValueError("duplicate share indices")
     return xs, ds
+
+
+_share_index = operator.attrgetter("index")
+
+# Rows of a combine wave go to the engine this many at a time, and a
+# last chunk of half as many or more is padded up to it: the
+# exponentiation programs compile per row bucket (ModEngine._bucket),
+# and a served wave is anything from one set to a roster's N^2 sets, so
+# unchunked it would meet a new power of two (a compilation of seconds)
+# whenever a round revealed more coins at once than any round before.
+# Chunked at twice the engine's host floor, every chunk the floor
+# leaves to the device has the one shape; smaller tails stay on the
+# host kernel.
+COMBINE_CHUNK_ROWS = 2 * ModEngine.HOST_FLOOR
+
+
+def _pow_chunks(eng, bases: List[int], index_sets: List[tuple], q: int):
+    """``bases ** (the Lagrange coefficients of each index set, in
+    turn)`` as Python ints, plus the number of engine calls made,
+    ``COMBINE_CHUNK_ROWS`` rows at a time: byte columns through
+    ``pow_cols`` where the engine is columnar (the exponents are the
+    cached ``(t, 32)`` rows, so none is converted), the int entry
+    point otherwise."""
+    n = len(bases)
+    step = COMBINE_CHUNK_ROWS
+    if not eng.columnar:
+        exps = [lam for xs in index_sets for lam in _lagrange_cached(xs, q)]
+        terms: List[int] = []
+        for lo in range(0, n, step):
+            terms.extend(
+                eng.pow_batch(bases[lo : lo + step], exps[lo : lo + step])
+            )
+        return terms, -(-n // step)
+    base_b = ints_to_bytes33(bases)
+    exp_b = np.concatenate([_lagrange_exp_rows(xs, q) for xs in index_sets])
+    outs = []
+    for lo in range(0, n, step):
+        b, e = base_b[lo : lo + step], exp_b[lo : lo + step]
+        rows = len(b)
+        if rows < step <= 2 * rows:
+            # 0 ** 0 rows: the tail takes the full chunks' shape
+            pad = step - rows
+            b = np.concatenate([b, np.zeros((pad, 33), dtype=np.uint8)])
+            e = np.concatenate([e, np.zeros((pad, 32), dtype=np.uint8)])
+        outs.append(eng.pow_cols(b, e)[:rows])
+    out = outs[0] if len(outs) == 1 else np.concatenate(outs)
+    return be_rows_to_ints(out[:, ::-1]), len(outs)
+
+
+def combine_share_wave(
+    share_sets: Sequence[Sequence[DhShare]],
+    thresholds: Sequence[int],
+    group: GroupParams = DEFAULT_GROUP,
+    backend: str = "cpu",
+    mesh=None,
+) -> Tuple[List[int], int, int]:
+    """``combine_shares_batch`` with a threshold a set (the rows of
+    one dispatch do not care whose roster they are) and its tally:
+    ``(values, exponentiation dispatches made, sets answered without
+    one)`` — the last being memo hits and sets repeated within the
+    wave (a cluster-shared hub sees validators offer the same
+    subset)."""
+    if not share_sets:
+        return [], 0, 0
+    with trace.span("tpke", "combine_batch", groups=len(share_sets)):
+        eng = get_engine_degraded(backend, mesh, group)
+        p, q = group.p, group.q
+        results: List[Optional[int]] = [None] * len(share_sets)
+        fresh: Dict[tuple, List[int]] = {}  # memo key -> result slots
+        bases: List[int] = []
+        index_sets: List[tuple] = []
+        for si, (shares, threshold) in enumerate(
+            zip(share_sets, thresholds)
+        ):
+            xs, ds = _combine_subset(shares, threshold)
+            key = (group, threshold, tuple(zip(xs, ds)))
+            hit = _COMBINE_MEMO.get(key)
+            if hit is not None:
+                results[si] = hit
+                continue
+            slots = fresh.get(key)
+            if slots is not None:
+                slots.append(si)
+                continue
+            fresh[key] = [si]
+            bases.extend([d % p for d in ds])
+            index_sets.append(tuple(xs))
+        dispatches = 0
+        if fresh:
+            terms, dispatches = _pow_chunks(eng, bases, index_sets, q)
+            off = 0
+            for (key, slots), xs in zip(fresh.items(), index_sets):
+                acc = 1
+                for term in terms[off : off + len(xs)]:
+                    acc = acc * term % p
+                off += len(xs)
+                if len(_COMBINE_MEMO) >= _COMBINE_MEMO_CAP:
+                    _COMBINE_MEMO.clear()
+                _COMBINE_MEMO[key] = acc
+                for si in slots:
+                    results[si] = acc
+        return (  # type: ignore[return-value]
+            results,
+            dispatches,
+            len(share_sets) - len(fresh),
+        )
 
 
 def combine_shares_batch(
@@ -723,38 +832,9 @@ def combine_shares_batch(
     exponentiation dispatch (each set >= threshold verified shares;
     result order matches input order).  Equivalent to mapping
     ``combine_shares``, and shares its memo."""
-    if not share_sets:
-        return []
-    with trace.span("tpke", "combine_batch", groups=len(share_sets)):
-        eng = get_engine_degraded(backend, mesh, group)
-        results: List[Optional[int]] = [None] * len(share_sets)
-        bases_flat: List[int] = []
-        exps_flat: List[int] = []
-        spans: List[tuple] = []  # (set_idx, memo_key, n_terms)
-        for si, shares in enumerate(share_sets):
-            xs, ds = _combine_subset(shares, threshold)
-            key = (group, threshold, tuple(zip(xs, ds)))
-            hit = _COMBINE_MEMO.get(key)
-            if hit is not None:
-                results[si] = hit
-                continue
-            lams = lagrange_coeff_at_zero(xs, group.q)
-            bases_flat.extend(d % group.p for d in ds)
-            exps_flat.extend(lams)
-            spans.append((si, key, threshold))
-        if bases_flat:
-            pows = eng.pow_batch(bases_flat, exps_flat)
-            off = 0
-            for si, key, n_terms in spans:
-                acc = 1
-                for term in pows[off : off + n_terms]:
-                    acc = acc * term % group.p
-                off += n_terms
-                if len(_COMBINE_MEMO) >= _COMBINE_MEMO_CAP:
-                    _COMBINE_MEMO.clear()
-                _COMBINE_MEMO[key] = acc
-                results[si] = acc
-        return results  # type: ignore[return-value]
+    return combine_share_wave(
+        share_sets, [threshold] * len(share_sets), group, backend, mesh
+    )[0]
 
 
 def verify_share_groups(
@@ -875,7 +955,7 @@ def _as_share_list(shares) -> Sequence[DhShare]:
     return shares.to_shares() if isinstance(shares, ShareColumns) else shares
 
 
-@functools.lru_cache(maxsize=256)
+@functools.lru_cache(maxsize=4096)
 def _lagrange_exp_rows(xs: tuple, q: int) -> np.ndarray:
     """The Lagrange coefficients of ``xs`` as the engine's (t, 32)
     big-endian exponent rows (a wave combines the same index set for
@@ -1223,7 +1303,7 @@ class SharePool:
     """
 
     __slots__ = ("threshold", "_pending", "_verified", "_burned",
-                 "_seen", "_lazy", "_n", "_idx_cover")
+                 "_seen", "_lazy", "_n", "_idx_cover", "_opt")
 
     def __init__(self, threshold: int):
         self.threshold = threshold
@@ -1245,6 +1325,10 @@ class SharePool:
         # coverable instead of materializing a whole wave (recomputed
         # exactly when a burn invalidates it)
         self._idx_cover: set = set()
+        # optimistic_subset()'s answer for the pool as it stands (any
+        # add or verdict drops it): the settler asks once to offer the
+        # subset to the batched combine and once more to use it
+        self._opt: Optional[List[DhShare]] = None
 
     def covered(self) -> int:
         return len(self._idx_cover)
@@ -1257,6 +1341,7 @@ class SharePool:
         self._pending[sender] = share
         self._idx_cover.add(share.index)
         self._n += 1
+        self._opt = None
         return True
 
     def add_lazy(
@@ -1270,6 +1355,7 @@ class SharePool:
         self._lazy.append((sender, index, d, e, z))
         self._idx_cover.add(index)
         self._n += 1
+        self._opt = None
         return True
 
     def _materialize(self) -> None:
@@ -1327,6 +1413,7 @@ class SharePool:
         """Record external verification verdicts: valid shares move to
         the verified set, senders of invalid ones burn."""
         burned_any = False
+        self._opt = None
         for sender, good in zip(senders, ok):
             share = self._pending.pop(sender, None)
             if share is None:
@@ -1366,6 +1453,8 @@ class SharePool:
         some selected share was invalid, and the caller falls back to
         the verified path, which burns the culprit.  NOT safe for the
         common coin — its combined value has no independent check."""
+        if self._opt is not None:
+            return self._opt
         self._materialize()
         by_index: Dict[int, DhShare] = {}
         for share in self._verified.values():
@@ -1375,7 +1464,8 @@ class SharePool:
             by_index.setdefault(share.index, share)
         if len(by_index) < self.threshold:
             return None
-        return list(by_index.values())
+        self._opt = list(by_index.values())
+        return self._opt
 
     def try_verified(self, verify_fn) -> Optional[List[DhShare]]:
         """Self-contained threshold check: if >= threshold shares are
@@ -1568,7 +1658,14 @@ class Tpke:
         deterministically for every correct node, since the combined
         KEM value is independent of which valid share subset was used.
         """
-        kem = combine_shares(shares, self.pub.threshold, self.group)
+        return self.open(
+            ct, combine_shares(shares, self.pub.threshold, self.group)
+        )
+
+    def open(self, ct: Ciphertext, kem: int) -> bytes:
+        """``combine`` from the combined KEM value on: tag check, then
+        the plaintext — for callers whose combines ran batched
+        (``combine_shares_batch``, the CryptoHub's combine column)."""
         key = hashlib.sha256(b"kem" + _ibytes(kem, self.group.nbytes)).digest()
         tag = hmac.new(
             key, _ibytes(ct.c1, self.group.nbytes) + ct.c2, hashlib.sha256
@@ -1598,6 +1695,7 @@ __all__ = [
     "verify_and_combine_share_groups",
     "combine_shares",
     "combine_shares_batch",
+    "combine_share_wave",
     "lagrange_coeff_at_zero",
     "hash_to_group",
     "Tpke",

@@ -38,6 +38,7 @@ the instance; epochs are buffered one level up by HoneyBadger).
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, Dict, List, Optional, Set, Tuple
 
 from cleisthenes_tpu.config import Config
@@ -73,6 +74,7 @@ class _Round:
         "aux_sent",
         "coin_share_sent",
         "coin_shares",
+        "coin_combined",
         "coin_value",
         "advanced",
         "rows_pulled",
@@ -86,6 +88,9 @@ class _Round:
         # only ever occupy (and burn) its own slot, never censor an
         # honest node's share or force repeated re-verification
         self.coin_shares = SharePool(coin_threshold)
+        # x^s, Lagrange-combined from the pool's f+1 verified shares
+        # by the hub's combine column; the coin's bit is a hash of it
+        self.coin_combined: Optional[int] = None
         self.coin_value: Optional[bool] = None
         self.advanced = False
         # cursor into the ACS CoinRowStore's row list for this round
@@ -569,16 +574,43 @@ class BBA:
             # forever (liveness break found by round-3 review).
             self.hub.mark_dirty(self)
 
-    def after_crypto_flush(self) -> None:
-        if self.halted:
-            return
-        r = self._rounds.get(self.round)
-        if r is None or r.coin_value is not None:
-            return
-        valid = r.coin_shares.ready()
+    def offer_combines(self, wave) -> None:
+        """Between a flush round's share verdicts and its
+        ``after_crypto_flush`` calls: a round whose pool the verdicts
+        completed offers its f+1 verified shares to the wave's combine
+        column, so every coin the round reveals — all instances, on a
+        shared hub all validators — is ONE exponentiation dispatch."""
+        r = self._unrevealed_round()
+        valid = None if r is None else r.coin_shares.ready()
         if valid is None:
             return
-        r.coin_value = self.coin.toss(self._coin_id(self.round), valid)
+        wave.add_combine(
+            valid,
+            self._coin_threshold,
+            self.coin.group,
+            functools.partial(setattr, r, "coin_combined"),
+        )
+
+    def _unrevealed_round(self) -> Optional["_Round"]:
+        """The current round while its coin is still to reveal."""
+        if self.halted:
+            return None
+        r = self._rounds.get(self.round)
+        if r is None or r.coin_value is not None:
+            return None
+        return r
+
+    def after_crypto_flush(self) -> None:
+        r = self._unrevealed_round()
+        # coin_combined is set iff the pool was ready() when the
+        # round's verdicts were in: nothing verifies a share between
+        # the offer and here
+        if r is None or r.coin_combined is None:
+            return
+        r.coin_value = bool(
+            self.coin.value_of(self._coin_id(self.round), r.coin_combined)
+            & 1
+        )
         if self.trace is not None:
             self.trace.instant(
                 "coin",

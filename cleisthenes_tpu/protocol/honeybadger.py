@@ -28,6 +28,7 @@ deployment model (docs/THRESHOLD_ENCRYPTION-EN.md:33: "SetUp").
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import struct
 from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
@@ -403,6 +404,7 @@ class _EpochState:
         "ciphertexts",
         "dec_shares",
         "decrypted",
+        "dec_kems",
         "opt_failed",
         "opt_short",
         "committed",
@@ -431,6 +433,10 @@ class _EpochState:
         self.dec_shares: Dict[str, SharePool] = {}
         # proposer -> tx list, or None = deterministically excluded
         self.decrypted: Dict[str, Optional[List[bytes]]] = {}
+        # proposer -> the KEM value the hub's combine column made of
+        # its CP-verified shares, until after_crypto_flush opens the
+        # ciphertext with it
+        self.dec_kems: Dict[str, int] = {}
         # proposers whose optimistic (unverified-subset) combine hit a
         # bad tag: their shares take the CP-verified path instead
         self.opt_failed: Set[str] = set()
@@ -2012,8 +2018,7 @@ class HoneyBadger:
         # share issue AFTER the pipelined next-epoch proposal: the
         # share-issue stage must not absorb epoch e+1's encode time
         self._issue_dec_shares(epoch, es)
-        for proposer in list(es.ciphertexts):
-            self._try_decrypt(epoch, es, proposer)
+        self._try_decrypts(epoch, es, list(es.ciphertexts))
         self._maybe_commit(epoch, es)
 
     def _issue_dec_shares(self, epoch: int, es: _EpochState) -> None:
@@ -2249,18 +2254,62 @@ class HoneyBadger:
             return
         self._settler_active = True
         try:
+            # every (epoch, proposer) whose pool can combine, in ONE
+            # dispatch of the hub's combine column (on a shared hub,
+            # with every other validator's); an epoch whose
+            # ciphertexts only parse in the loop below combines there
+            kems = self._take_kems(self.settle_combine_wants())
             for epoch in range(len(self.committed_batches), self.epoch):
                 es = self._epochs.get(epoch)
                 if es is None or not es.ordered:
                     continue
                 if not es.shares_issued:
                     self._issue_dec_shares(epoch, es)
-                for proposer in list(es.ciphertexts):
-                    if proposer not in es.decrypted:
-                        self._try_decrypt(epoch, es, proposer)
+                self._try_decrypts(epoch, es, self._decrypt_ready(es), kems)
             self._maybe_settle()
         finally:
             self._settler_active = False
+
+    def settle_combine_wants(self) -> List[Tuple]:
+        """The settler's ``CryptoHub.take_combines`` rows — ``((epoch,
+        proposer), subset, threshold, group)`` for every ordered,
+        unsettled (epoch, proposer) whose pool holds an optimistic
+        subset — read without changing anything: a shared hub asks
+        every validator a wave fed for them (``note_combine_source``)
+        when the first settler pass of the idle phase takes."""
+        wants: List[Tuple] = []
+        if not self._two_frontier:
+            return wants
+        for epoch in range(len(self.committed_batches), self.epoch):
+            es = self._epochs.get(epoch)
+            if es is None or not es.ordered:
+                continue
+            for proposer in self._decrypt_ready(es):
+                want = self._combine_want(epoch, es, proposer)
+                if want is not None:
+                    wants.append(want)
+        return wants
+
+    @staticmethod
+    def _decrypt_ready(es: _EpochState) -> List[str]:
+        """The proposers ``_try_decrypt`` can act on — agreed, parsed,
+        not decrypted, pool at the threshold — in ``es.ciphertexts``
+        order: what a settler pass visits instead of calling it once a
+        proposer (most idle phases none is)."""
+        pools = es.dec_shares
+        if es.output is None or not pools:
+            return []
+        # pools exist only under a roster view with key material
+        threshold = es.view.keys.tpke_pub.threshold
+        decrypted = es.decrypted
+        ready = []
+        for proposer in es.ciphertexts:
+            if proposer in decrypted:
+                continue
+            pool = pools.get(proposer)
+            if pool is not None and len(pool) >= threshold:
+                ready.append(proposer)
+        return ready
 
     def _maybe_settle(self) -> None:
         """Settle ordered epochs in order at the SETTLED frontier:
@@ -2321,8 +2370,9 @@ class HoneyBadger:
             # shares only POOL on the message path; the settler probes
             # combines and settles at the next idle boundary, so the
             # decrypt work batches per wave instead of per frame
+            self.hub.note_combine_source(self)
             return
-        self._try_decrypt(epoch, es, proposer)
+        self._try_decrypts(epoch, es, (proposer,))
         self._maybe_commit(epoch, es)
 
     def _handle_dec_share_batch(
@@ -2400,21 +2450,88 @@ class HoneyBadger:
                         touched.append(proposer)
                 else:
                     self.metrics.dedup_absorbed.inc()
+        if not probe:
+            # the settler combines at the idle boundary: tell the hub
+            # this validator's pools moved, so the first pass to take
+            # folds them into its dispatch
+            self.hub.note_combine_source(self)
+            return
         if not touched:
             return
-        for proposer in touched:
-            self._try_decrypt(epoch, es, proposer)
+        self._try_decrypts(epoch, es, touched)
         self._maybe_commit(epoch, es)
 
-    def _try_decrypt(
+    def _combine_want(
         self, epoch: int, es: _EpochState, proposer: str
+    ) -> Optional[Tuple]:
+        """The ``CryptoHub.take_combines`` row of one optimistic
+        decrypt — ``((epoch, proposer), subset, threshold, group)`` —
+        or None where ``_try_decrypt`` would not combine (nothing
+        agreed or parsed yet, decrypted already, pool short, or the
+        proposer flagged onto the CP-verified path).  Changes
+        nothing."""
+        if es.output is None or proposer in es.decrypted:
+            return None
+        if proposer not in es.ciphertexts or proposer in es.opt_failed:
+            return None
+        pool = es.dec_shares.get(proposer)
+        if pool is None:  # and no key material, under a foreign roster
+            return None
+        pub = es.view.keys.tpke_pub
+        if len(pool) < pub.threshold:
+            return None
+        subset = pool.optimistic_subset()
+        if subset is None:
+            return None
+        return (epoch, proposer), subset, pub.threshold, pub.group
+
+    def _take_kems(self, wants: List[Tuple]) -> Dict[Tuple, Tuple]:
+        """``{(epoch, proposer): (subset, KEM value)}`` of ``wants``,
+        combined by the hub in one dispatch."""
+        if not wants:
+            return {}
+        values = self.hub.take_combines(self, wants)
+        return {
+            want[0]: (want[1], val) for want, val in zip(wants, values)
+        }
+
+    def _try_decrypts(
+        self,
+        epoch: int,
+        es: _EpochState,
+        proposers: Sequence[str],
+        kems: Optional[Dict[Tuple, Tuple]] = None,
+    ) -> None:
+        """``_try_decrypt`` for each of ``proposers`` in order, their
+        Lagrange combines made first and together (those ``kems``
+        does not hold already)."""
+        if kems is None:
+            kems = {}
+        wants = []
+        for proposer in proposers:
+            if (epoch, proposer) not in kems:
+                want = self._combine_want(epoch, es, proposer)
+                if want is not None:
+                    wants.append(want)
+        kems.update(self._take_kems(wants))
+        for proposer in proposers:
+            self._try_decrypt(epoch, es, proposer, kems)
+
+    def _try_decrypt(
+        self,
+        epoch: int,
+        es: _EpochState,
+        proposer: str,
+        kems: Dict[Tuple, Tuple],
     ) -> None:
         """Threshold reached: optimistic combine first — the ciphertext
         tag authenticates the combined KEM value, so in the honest case
         NO per-share CP verification runs at all (it replaces 2(f+1)
         dual-exponentiations per proposer).  A bad tag means a selected
         share was invalid: flag the proposer onto the CP-verified hub
-        path, which burns the culprit and combines valid shares."""
+        path, which burns the culprit and combines valid shares.
+        ``kems`` holds the combined value of the subset the caller's
+        batch saw; a pool that moved since combines again."""
         if es.output is None or proposer in es.decrypted:
             return
         ct = es.ciphertexts.get(proposer)
@@ -2432,6 +2549,11 @@ class HoneyBadger:
                 es.opt_short.add(proposer)
                 return
             es.opt_short.discard(proposer)
+            hit = kems.get((epoch, proposer))
+            if hit is None or hit[0] != subset:
+                hit = self._take_kems(
+                    [self._combine_want(epoch, es, proposer)]
+                )[(epoch, proposer)]
             try:
                 with trace.span(
                     "settle" if self._two_frontier else "tpke",
@@ -2440,7 +2562,7 @@ class HoneyBadger:
                     epoch=epoch,
                     proposer=proposer,
                 ):
-                    plain = view.tpke.combine(ct, subset)
+                    plain = view.tpke.open(ct, hit[1])
             except ValueError:  # bad tag: an invalid share slipped in
                 es.opt_failed.add(proposer)
                 self.hub.mark_dirty(self)
@@ -2498,11 +2620,15 @@ class HoneyBadger:
             # hazard as BBA._on_coin_verdicts; round-3 review)
             self.hub.mark_dirty(self)
 
-    def after_crypto_flush(self) -> None:
-        for epoch, es in list(self._epochs.items()):
+    def offer_combines(self, wave) -> None:
+        """The CP-verified path's combines (flagged proposers whose
+        pool the round's verdicts completed), into the wave's combine
+        column beside the round's coins."""
+        for es in self._epochs.values():
             if es.output is None or es.committed or not es.view.local:
                 continue
-            for proposer, ct in list(es.ciphertexts.items()):
+            pub = es.view.keys.tpke_pub
+            for proposer in es.ciphertexts:
                 if proposer in es.decrypted:
                     continue
                 pool = es.dec_shares.get(proposer)
@@ -2511,8 +2637,27 @@ class HoneyBadger:
                 valid = pool.ready()
                 if valid is None:
                     continue
+                wave.add_combine(
+                    valid,
+                    pub.threshold,
+                    pub.group,
+                    functools.partial(es.dec_kems.__setitem__, proposer),
+                )
+
+    def after_crypto_flush(self) -> None:
+        for epoch, es in list(self._epochs.items()):
+            if es.output is None or es.committed or not es.view.local:
+                continue
+            for proposer, ct in list(es.ciphertexts.items()):
+                if proposer in es.decrypted:
+                    continue
+                # there iff the pool was ready() when this round's
+                # verdicts were in (offer_combines)
+                kem = es.dec_kems.pop(proposer, None)
+                if kem is None:
+                    continue
                 try:
-                    plain = es.view.tpke.combine(ct, valid)
+                    plain = es.view.tpke.open(ct, kem)
                     es.decrypted[proposer] = deserialize_txs(
                         plain, self._tx_parse_memo
                     )

@@ -15,13 +15,20 @@ service with request coalescing" shape, PAPERS.md 2502.03247): the
 transport's idle callback is the only flush trigger on both
 transports, and one flush drains EVERY dirty client of the wave into
 a handful of wide typed columns — all pending ECHO-branch proofs,
-all ready RS decode-rechecks, all pooled coin/TPKE shares — then
-executes ONE batch call per work kind, in dependency order
-(branches -> decodes -> shares), and fans verdicts back out via the
-client callback protocol.  Branch verdicts can unlock decodes
+all ready RS decode-rechecks, all pooled coin/TPKE shares, and then
+every share set those verdicts made combinable — and executes ONE
+batch call per work kind, in dependency order
+(branches -> decodes -> shares -> combines), fanning results back out
+via the client callback protocol.  Branch verdicts can unlock decodes
 (verified shards complete a staged matrix); the hub re-drains
 verdict-marked clients *within the same wave round* so those decodes
 ride the round's single decode dispatch instead of a follow-on one.
+Share verdicts complete pools: the round's drained clients are asked
+(offer_combines) for the f+1 verified shares of every pool that is
+now ready, and ALL of them — every coin the round reveals, every
+CP-verified decryption, every validator's on a shared hub — are
+Lagrange-combined in one exponentiation dispatch before any client's
+quorum logic runs.
 
 Why pull, not push: the work lives where the protocol state lives, so
 an instance that becomes irrelevant mid-flight (delivered, halted,
@@ -44,8 +51,14 @@ Client protocol (duck-typed; see RBC/BBA/HoneyBadger):
       columns (wave.add_branch / add_decode / add_share); a client
       may be drained more than once per round and must only offer
       each work item once
+  offer_combines(wave: HubWave) -> None        (optional)
+      called on every client drained this round, in drain order, once
+      the round's verdicts are in and before any after_crypto_flush:
+      offer each share set that is now combinable (wave.add_combine);
+      the item callback stores the combined value
   after_crypto_flush() -> None
-      verdicts have been applied via item callbacks; run quorum logic
+      verdicts and combined values have been applied via item
+      callbacks; run quorum logic
 
 Work item shapes (the wave's typed columns):
   branches: add_branch(client, root: bytes32, leaf: bytes,
@@ -66,10 +79,32 @@ Work item shapes (the wave's typed columns):
   shares:   add_share(pub, base: int, context: bytes,
             senders: list[str], shares: list[DhShare],
             cb(senders, verdicts: list[bool]))
+  combines: add_combine(shares: list[DhShare] (>= threshold,
+            index-distinct), threshold: int, group: GroupParams,
+            cb(value: int)) — the first ``threshold`` shares by
+            Shamir index combine to base^s; one
+            tpke.combine_share_wave per flush round over every
+            offered set (thresholds may differ set to set; one wave
+            per GroupParams), a wave of COMBINE_CHUNK_ROWS rows a
+            call, routed host kernel or device by the engine's floor.
+            Counted in combine_batches / combine_items /
+            combine_memo_hits, not in ``dispatches``.
+
+The settler's side of the combine column (it runs before the flush,
+at the head of the idle phase, and needs its values within its pass):
+
+  hub.note_combine_source(owner)
+      at wave time: a message wave pooled decryption shares at owner
+  hub.take_combines(owner, wants) -> list[int]
+      wants: [(meta, shares, threshold, group)]; the first taker of an
+      idle phase combines its wants together with every other noted
+      owner's (owner.settle_combine_wants() -> the same rows), whose
+      values park until their own pass takes them
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -77,6 +112,7 @@ import numpy as np
 from cleisthenes_tpu.ops.backend import BatchCrypto
 from cleisthenes_tpu.ops.coin import share_batch as coin_share_batch
 from cleisthenes_tpu.ops.tpke import (
+    combine_share_wave,
     issue_shares_batch,
     verify_share_groups,
 )
@@ -115,6 +151,10 @@ WAVE_WIDTH_CAP = 1 << 16
 _Memo = BoundedFifoMemo
 
 
+def _park(park: Dict, meta, shares, value: int) -> None:
+    park[meta] = (shares, value)
+
+
 class HubWave:
     """One flush's typed work columns.
 
@@ -126,8 +166,8 @@ class HubWave:
     a wave's N copies of one check to a single slot without hashing
     any content.  Ids are only compared between live objects held by
     this wave (the columns pin them), so reuse-after-GC cannot alias.
-    Decode and share items stay flat lists — their populations are
-    ~N per wave, not ~N^2.
+    Decode, share and combine items stay flat lists — their
+    populations are ~N per wave per node, not ~N^2.
     """
 
     __slots__ = (
@@ -137,6 +177,7 @@ class HubWave:
         "_b_ids",
         "decodes",
         "shares",
+        "combines",
         "clients",
     )
 
@@ -147,6 +188,7 @@ class HubWave:
         self._b_ids: Dict[Tuple, int] = {}
         self.decodes: List[Tuple] = []  # (root, idxs, [shards], cb, n)
         self.shares: List[Tuple] = []  # (pub, base, ctx, senders, shs, cb)
+        self.combines: List[Tuple] = []  # (shares, threshold, group, cb)
         self.clients: List[object] = []  # drained clients, arrival order
 
     def add_branch(
@@ -180,8 +222,13 @@ class HubWave:
     ) -> None:
         self.shares.append((pub, base, context, senders, shares, cb))
 
+    def add_combine(self, shares: list, threshold: int, group, cb) -> None:
+        self.combines.append((shares, threshold, group, cb))
+
     def has_work(self) -> bool:
-        return bool(self.b_items or self.decodes or self.shares)
+        return bool(
+            self.b_items or self.decodes or self.shares or self.combines
+        )
 
     def take_branches(self) -> Tuple[List[Tuple], List[Tuple]]:
         slots, items = self.b_slots, self.b_items
@@ -196,6 +243,10 @@ class HubWave:
 
     def take_shares(self) -> List[Tuple]:
         out, self.shares = self.shares, []
+        return out
+
+    def take_combines(self) -> List[Tuple]:
+        out, self.combines = self.combines, []
         return out
 
 
@@ -307,6 +358,25 @@ class CryptoHub:
         self._dec_lock = new_lock()
         self._dec_pool: List[Tuple] = []  # (owner, meta, item, group)
         self._dec_results: Dict[object, List[Tuple]] = {}
+        # Combine column (ISSUE 32): every Lagrange combine of the
+        # served path — a revealed coin's f+1 verified shares, an
+        # optimistic or CP-verified decryption's — runs here, many sets
+        # to one tpke.combine_share_wave.  combine_batches counts the
+        # exponentiation dispatches that made (items / batches is how
+        # widely the column engages: one set a call before it
+        # existed); combine_memo_hits the sets answered without one.
+        # Like the issue columns' pairs they stay out of
+        # ``dispatches``.
+        self.combine_batches = 0
+        self.combine_items = 0
+        self.combine_memo_hits = 0
+        # The settler's side of the column (take_combines): owners
+        # whose dec-share pools a message wave fed (note_combine_source)
+        # -> asked for their ready sets by the first taker of the idle
+        # phase; owner -> {meta: (shares, value)} parked until the
+        # owner's own settler pass claims them.
+        self._comb_sources: Dict[object, None] = {}
+        self._comb_results: Dict[object, Dict] = {}
         # per-flush total column width (branch+decode+share items) of
         # every flush that carried work, for the bench's
         # wave_width_p50/p95 counters (bounded; see WAVE_WIDTH_CAP)
@@ -427,10 +497,21 @@ class CryptoHub:
                         self._run_shares(wave.take_shares())
                 # executor callbacks may re-mark clients (e.g. a share
                 # burn with parked replacements); quorum logic runs on
-                # every client drained this round, in drain order
+                # every client drained this round, in drain order —
+                # after the round's verdicts have shown which of them
+                # hold a combinable share set, and those sets have
+                # been combined together
                 clients, wave.clients = wave.clients, []
+                order = list(dict.fromkeys(clients))
+                with trace.span("hub", "combines") as csp:
+                    for c in order:
+                        offer = getattr(c, "offer_combines", None)
+                        if offer is not None:
+                            offer(wave)
+                    if wave.combines:
+                        self._run_combines(wave.take_combines(), csp)
                 with trace.span("hub", "callbacks", clients=len(clients)):
-                    for c in dict.fromkeys(clients):
+                    for c in order:
                         c.after_crypto_flush()
         finally:
             self._flushing = False
@@ -693,6 +774,91 @@ class CryptoHub:
         for (item, keys) in zip(items, item_keys):
             item[5](item[3], [local[k] for k in keys])
 
+    # -- combine column ------------------------------------------------------
+
+    def _run_combines(self, items: List[Tuple], sp=None) -> None:
+        """EVERY offered share set Lagrange-combined in one
+        ``tpke.combine_share_wave`` (thresholds may differ from set to
+        set; one wave per GroupParams, which only a re-keyed roster
+        makes more than one), through the engine the other waves use,
+        so its host floor sends a wave of N=16's 6-row sets to the
+        threaded host kernel and a roster's worth of 22-row sets at
+        N=64 to the device.  Item shape: ``(shares, threshold, group,
+        cb(value: int))``."""
+        self.combine_items += len(items)
+        by_group: Dict[object, List[int]] = {}
+        for i, item in enumerate(items):
+            by_group.setdefault(item[2], []).append(i)
+        if sp:
+            sp.note(
+                items=len(items),
+                rows=sum(item[1] for item in items),
+            )
+        for group, idxs in by_group.items():
+            vals, batches, hits = combine_share_wave(
+                [items[i][0] for i in idxs],
+                [items[i][1] for i in idxs],
+                group,
+                backend=self.crypto.engine_backend,
+                mesh=self.crypto.mesh,
+            )
+            self.combine_batches += batches
+            self.combine_memo_hits += hits
+            for i, val in zip(idxs, vals):
+                items[i][3](val)
+
+    def note_combine_source(self, owner) -> None:
+        """A message wave pooled decryption shares at ``owner``: its
+        next settler pass may find sets to combine.  The first
+        ``take_combines`` of the idle phase asks every noted owner
+        (``owner.settle_combine_wants()``), so a shared hub folds the
+        whole roster's optimistic combines into its one dispatch.
+        Idempotent and O(1)."""
+        self._comb_sources[owner] = None
+
+    def take_combines(self, owner, wants: List[Tuple]) -> List[int]:
+        """The combined values of ``owner``'s ``wants`` — ``(meta,
+        shares, threshold, group)`` rows, ``meta`` the owner's own
+        hashable handle — in order, within this call.  A want another
+        taker's dispatch already combined (same meta, same shares) is
+        claimed from the park; otherwise the rest run now, in ONE
+        wave with the ready sets of every other noted owner, whose
+        values park until their own pass asks (a pass whose pool moved
+        in between recombines: the park is keyed by what was
+        combined)."""
+        self._comb_sources.pop(owner, None)
+        parked = self._comb_results.pop(owner, None) or {}
+        values: List[int] = [0] * len(wants)
+        items: List[Tuple] = []
+        for i, (meta, shares, threshold, group) in enumerate(wants):
+            hit = parked.get(meta)
+            if hit is not None and hit[0] == shares:
+                values[i] = hit[1]
+            else:
+                items.append(
+                    (
+                        shares, threshold, group,
+                        functools.partial(values.__setitem__, i),
+                    )
+                )
+        if items:
+            sources, self._comb_sources = self._comb_sources, {}
+            for src in sources:
+                rows = src.settle_combine_wants()
+                if not rows:
+                    continue
+                park = self._comb_results.setdefault(src, {})
+                for meta, shares, threshold, group in rows:
+                    items.append(
+                        (
+                            shares, threshold, group,
+                            functools.partial(_park, park, meta, shares),
+                        )
+                    )
+            with trace.span("hub", "combines") as sp:
+                self._run_combines(items, sp)
+        return values
+
     # -- coin-issue column --------------------------------------------------
 
     def stage_coin_issue(self, owner, meta, item, group) -> None:
@@ -815,6 +981,9 @@ class CryptoHub:
             "coin_issue_items": self.coin_issue_items,
             "dec_issue_batches": self.dec_issue_batches,
             "dec_issue_items": self.dec_issue_items,
+            "combine_batches": self.combine_batches,
+            "combine_items": self.combine_items,
+            "combine_memo_hits": self.combine_memo_hits,
         }
 
 

@@ -244,3 +244,240 @@ class TestHubLiveness:
             hb.start_epoch()
         net.run()
         assert_identical_batches(nodes)
+
+
+class _CoinLike:
+    """A hub client with BBA's shape: a SharePool whose pending shares
+    verify in the share column, whose f+1 verified shares then ride the
+    combine column, and whose callback reads the combined value."""
+
+    def __init__(self, hub, pub, base, context, shares, group):
+        self.hub, self.pub, self.base, self.context = hub, pub, base, context
+        self.group = group
+        self.pool = tpke.SharePool(pub.threshold)
+        for i, sh in enumerate(shares):
+            self.pool.add(f"n{i:03d}", sh)
+        self.combined = None
+        self.value = None
+        self.offered = None
+        hub.mark_dirty(self)
+
+    def drain_pending(self, wave):
+        # every pooled share, not just need_more(): the roster-size
+        # sets then offer more shares than their threshold
+        senders, shs = self.pool.collect_pending()
+        if senders:
+            wave.add_share(
+                self.pub, self.base, self.context, senders, shs,
+                lambda snd, ok: self.pool.apply_verdicts(snd, ok),
+            )
+
+    def offer_combines(self, wave):
+        valid = self.pool.ready()
+        if valid is not None and self.value is None:
+            self.offered = valid
+            wave.add_combine(
+                valid, self.pub.threshold, self.group,
+                lambda val: setattr(self, "combined", val),
+            )
+
+    def after_crypto_flush(self):
+        if self.combined is not None:
+            self.value = self.combined
+
+
+def _coin_share_sets():
+    """Seeded coin share sets at thresholds 2, 6 and 22: (pub, base,
+    context, shares) per set — a threshold-size set each, one with
+    every share of its roster (larger than its threshold), and the
+    t=6 set once more (a repeated set)."""
+    sets = []
+    for n, t, seed in ((4, 2, 41), (16, 6, 42), (64, 22, 43)):
+        pub, secrets = tpke.deal(n, t, seed=seed)
+        coin = CommonCoin(pub)
+        for k, take in enumerate((t, n)):
+            cid = b"combine|%d|%d" % (t, k)
+            _pub, base, context = coin.group_params(cid)
+            shares = [coin.share(s, cid) for s in secrets[:take]]
+            sets.append((pub, base, context, shares))
+    sets.append(sets[2])
+    return sets
+
+
+class TestCombineColumn:
+    @pytest.mark.parametrize("dedup", [True, False])
+    def test_mixed_thresholds_one_dispatch_a_flush_round(self, dedup):
+        """Every ready set of a flush round — thresholds 2, 6 and 22
+        mixed, one set repeated, three larger than their threshold —
+        is combined by ONE exponentiation dispatch, to the values the
+        scalar ``combine_shares`` gives."""
+        hub = CryptoHub(BatchCrypto("cpu", 4, 1, 2), dedup=dedup)
+        sets = _coin_share_sets()
+        clients = [
+            _CoinLike(hub, pub, base, ctx, shares, pub.group)
+            for pub, base, ctx, shares in sets
+        ]
+        tpke._COMBINE_MEMO.clear()
+        hub.flush()
+        stats = hub.stats()
+        assert stats["combine_items"] == len(sets) == 7
+        assert stats["combine_batches"] == 1
+        # the repeated set rides its twin's rows
+        assert stats["combine_memo_hits"] == 1
+        # the combine column stays out of the share-verify dispatches
+        assert stats["dispatches"] == 1
+        tpke._COMBINE_MEMO.clear()
+        assert [len(c.offered) for c in clients] == [2, 4, 6, 16, 22, 64, 6]
+        for c in clients:
+            assert c.value is not None
+            assert c.value == tpke.combine_shares(
+                c.offered, c.pub.threshold, c.group
+            )
+        # a second flush with nothing dirty combines nothing
+        hub.flush()
+        assert hub.stats()["combine_batches"] == 1
+
+    def test_two_rounds_two_dispatches_and_the_memo(self):
+        """One dispatch a flush ROUND: a client whose set completes a
+        round later rides that round's dispatch; a set the memo holds
+        makes none."""
+        hub = CryptoHub(BatchCrypto("cpu", 4, 1, 2), dedup=True)
+        sets = _coin_share_sets()
+        tpke._COMBINE_MEMO.clear()
+        first = [
+            _CoinLike(hub, pub, base, ctx, shares, pub.group)
+            for pub, base, ctx, shares in sets[:3]
+        ]
+        hub.flush()
+        assert hub.stats()["combine_batches"] == 1
+        later = [
+            _CoinLike(hub, pub, base, ctx, shares, pub.group)
+            for pub, base, ctx, shares in sets[3:]
+        ]
+        hub.flush()
+        stats = hub.stats()
+        assert stats["combine_batches"] == 2
+        assert stats["combine_items"] == 7
+        assert stats["combine_memo_hits"] == 1  # sets[6] is sets[2]
+        assert all(c.value is not None for c in first + later)
+        assert later[-1].value == first[2].value
+
+    def test_settler_takes_fold_noted_owners_into_one_dispatch(self):
+        """take_combines: the first taker's dispatch carries every
+        noted owner's ready sets and parks their values; an owner
+        whose set moved since combines again."""
+        hub = CryptoHub(BatchCrypto("cpu", 4, 1, 2), dedup=True)
+        sets = _coin_share_sets()
+        tpke._COMBINE_MEMO.clear()
+
+        class Owner:
+            def __init__(self, rows):
+                self.rows = rows
+
+            def settle_combine_wants(self):
+                return list(self.rows)
+
+        def want(k, meta):
+            pub, _base, _ctx, shares = sets[k]
+            return (meta, shares, pub.threshold, pub.group)
+
+        a = Owner([want(0, "a0"), want(4, "a1")])
+        b = Owner([want(2, "b0")])
+        c = Owner([want(5, "c0")])
+        for o in (a, b, c):
+            hub.note_combine_source(o)
+        got_a = hub.take_combines(a, a.rows)
+        assert hub.stats()["combine_batches"] == 1
+        assert hub.stats()["combine_items"] == 4
+        # b's pass finds its value parked: no dispatch
+        got_b = hub.take_combines(b, b.rows)
+        assert hub.stats()["combine_batches"] == 1
+        # c's pool moved between the fold and its own pass
+        moved = [want(1, "c0")]
+        got_c = hub.take_combines(c, moved)
+        assert hub.stats()["combine_batches"] == 2
+        tpke._COMBINE_MEMO.clear()
+        for got, rows in ((got_a, a.rows), (got_b, b.rows), (got_c, moved)):
+            for val, (_m, shares, t, group) in zip(got, rows):
+                assert val == tpke.combine_shares(shares, t, group)
+
+    def test_forged_dec_share_fails_one_proposer_in_a_batched_pass(self):
+        """The settler combines a pass's proposers in one batch and
+        tag-checks each result alone: a forged share for ONE proposer
+        sends that proposer alone to ``opt_failed`` (and the
+        CP-verified path), and the epoch settles the bytes an honest
+        run settles."""
+        import hashlib
+
+        from cleisthenes_tpu.core.ledger import encode_batch_body
+        from cleisthenes_tpu.ops.tpke import DhShare
+        from cleisthenes_tpu.protocol.cluster import SimulatedCluster
+        from cleisthenes_tpu.protocol.honeybadger import HoneyBadger
+
+        def run(forge: bool):
+            cluster = SimulatedCluster(
+                config=Config(n=4, batch_size=8, seed=515),
+                seed=515,
+                key_seed=17,
+            )
+            bad = cluster.ids[0]  # Shamir index 1: in every subset
+            victim = cluster.ids[2]
+            hb_bad = cluster.nodes[bad]
+            hub = hb_bad.hub
+            if forge:
+                real_take = hub.take_dec_issues
+
+                def forged_take(owner):
+                    rows = real_take(owner)
+                    if owner is hb_bad:
+                        rows = [
+                            (
+                                meta,
+                                DhShare(s.index, 12345, s.e, s.z)
+                                if meta == (0, victim)
+                                else s,
+                            )
+                            for meta, s in rows
+                        ]
+                    return rows
+
+                hub.take_dec_issues = forged_take
+            failed = {}
+            real_try = HoneyBadger._try_decrypt
+
+            def spy(self, epoch, es, proposer, kems):
+                real_try(self, epoch, es, proposer, kems)
+                if es.opt_failed:
+                    failed.setdefault(
+                        (self.node_id, epoch), set()
+                    ).update(es.opt_failed)
+
+            HoneyBadger._try_decrypt = spy
+            try:
+                for i in range(16):
+                    cluster.submit(b"settle-pin-%04d" % i)
+                cluster.run_epochs()
+            finally:
+                HoneyBadger._try_decrypt = real_try
+            cluster.assert_agreement()
+            h = hashlib.sha256()
+            for nid in cluster.ids:
+                for epoch, batch in enumerate(
+                    cluster.nodes[nid].committed_batches
+                ):
+                    h.update(encode_batch_body(epoch, batch))
+            return h.hexdigest(), failed, hub.stats(), victim
+
+        honest_digest, none_failed, honest_stats, _v = run(forge=False)
+        assert not none_failed
+        digest, failed, stats, victim = run(forge=True)
+        assert digest == honest_digest
+        # every validator's epoch-0 pass met the forged share, and it
+        # cost the victim's ciphertext alone its optimistic combine
+        assert set(failed) == {(nid, 0) for nid in (
+            "node000", "node001", "node002", "node003"
+        )}
+        assert all(props == {victim} for props in failed.values())
+        # batched: many sets a dispatch, the verified path's included
+        assert stats["combine_items"] > 2 * stats["combine_batches"]
+        assert stats["combine_items"] > honest_stats["combine_items"]

@@ -567,3 +567,79 @@ class TestShareColumns:
         assert v == [[True] * self.N] * 2
         assert tpke.share_tally()["shares_materialized"] == self.N
         assert vals == tpke.verify_and_combine_share_groups(groups, self.T)[1]
+
+
+class TestCombineWaveChunks:
+    """``combine_share_wave`` feeds the engine ``COMBINE_CHUNK_ROWS``
+    rows at a time and pads a last chunk of half as many or more up to
+    it, so a device-bound chunk has one shape whatever the wave."""
+
+    @staticmethod
+    def _sets(count, seed):
+        pub, shares = tpke.deal(n=7, threshold=3, seed=seed)
+        sets = []
+        for i in range(count):
+            ctx = b"chunk|%d" % i
+            base = tpke.hash_to_group(ctx)
+            out = tpke.issue_shares_batch(
+                [(s, base, ctx, pub.verification_keys[s.index - 1])
+                 for s in shares]
+            )
+            # every validator its own first-arrived subset
+            sets.append(out[i % 4 : i % 4 + 3 + i % 2])
+        return sets
+
+    @pytest.mark.parametrize(
+        "backend,count,calls",
+        [
+            ("cpu", 5, 1),  # 15 rows: one chunk, padded to 16
+            ("cpu", 11, 3),  # 33 rows: 16 + 16 + a 1-row tail as it is
+            ("tpu", 5, 1),
+            ("tpu", 11, 3),
+        ],
+    )
+    def test_chunked_wave_matches_scalar(
+        self, monkeypatch, backend, count, calls
+    ):
+        monkeypatch.setattr(tpke, "COMBINE_CHUNK_ROWS", 16)
+        # the device programs, at toy size: the floors pinned off
+        monkeypatch.setattr(mm.ModEngine, "host_delegation", False)
+        sets = self._sets(count, seed=60 + count)
+        shapes = []
+        real = mm.ModEngine.pow_cols
+
+        def spy(eng, base_b, exp_b):
+            shapes.append(len(base_b))
+            return real(eng, base_b, exp_b)
+
+        monkeypatch.setattr(mm.ModEngine, "pow_cols", spy)
+        tpke._COMBINE_MEMO.clear()
+        vals, dispatches, hits = tpke.combine_share_wave(
+            sets + sets[:2], [3] * (count + 2), backend=backend
+        )
+        assert dispatches == calls == len(shapes)
+        assert hits == 2  # the two repeated sets ride their twins' rows
+        assert shapes == ([16] if count == 5 else [16, 16, 1])
+        tpke._COMBINE_MEMO.clear()
+        assert vals == [tpke.combine_shares(s, 3) for s in sets + sets[:2]]
+        # and the memo is seeded for the scalar path
+        assert len(tpke._COMBINE_MEMO) == count
+
+    def test_wide_group_takes_the_int_entry_point(self):
+        pub, shares = tpke.deal(n=4, threshold=2, seed=71, group=mm.GROUP384)
+        base = tpke.hash_to_group(b"wide", mm.GROUP384)
+        out = tpke.issue_shares_batch(
+            [(s, base, b"w", pub.verification_keys[s.index - 1])
+             for s in shares],
+            group=mm.GROUP384,
+        )
+        tpke._COMBINE_MEMO.clear()
+        vals, dispatches, hits = tpke.combine_share_wave(
+            [out[:2], out[1:3]], [2, 2], mm.GROUP384
+        )
+        assert (dispatches, hits) == (1, 0)
+        tpke._COMBINE_MEMO.clear()
+        assert vals == [
+            tpke.combine_shares(s, 2, mm.GROUP384)
+            for s in (out[:2], out[1:3])
+        ]
