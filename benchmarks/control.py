@@ -3,8 +3,9 @@
     python3 benchmarks/control.py --workload <cell> --seeds 3 --seconds 6
 
 Not part of the benchmark's runs.  One process: for each seed one sound
-run, then one run under each fault of benchmarks/faults.py (the timed
-path broken underneath, one stated guarantee each).  Every sound run
+run, then one run under each fault that benchmarks/faults.py has for
+the cell (the timed path broken underneath, one stated guarantee each;
+the log's faults only where the configuration has a log).  Every sound run
 has to read ``correct`` true and every broken one false; the numbers
 compared are printed beside their limits, and they are the readings
 PERF.md sets the limits from.  Exits 0 only if all of that held.
@@ -31,17 +32,16 @@ def main(argv=None) -> int:
     ap.add_argument("--drain-limit", type=float, default=15.0)
     args = ap.parse_args(argv)
 
-    from benchmarks import executors, run, spec
-    from benchmarks.faults import FAULTS
+    from benchmarks import executors, faults, run, spec
 
     # a broken commit step never drains: do not wait a minute for it
     executors.DRAIN_LIMIT_S = args.drain_limit
-    kind = spec.load_cell(args.workload).config["executor"]
+    broken = sorted(faults.for_cell(spec.load_cell(args.workload)).items())
     ok = True
     try:
         for i in range(args.seeds):
             seed = args.first_seed + i
-            for name, fault in [("sound", None)] + sorted(FAULTS[kind].items()):
+            for name, fault in [("sound", None)] + broken:
                 result = run.run_cell(
                     args.workload, seed, args.seconds, False, fault=fault
                 )

@@ -14,14 +14,24 @@ From the program these take only the system under test and its
 counters.  Clocks, stamps, spans and what is handed to the reference
 are the harness's own.  A configuration's file picks the executor by
 name; a traffic file picks the loop.
+
+A served configuration whose ``cluster.wal_dir`` is not null runs with
+its validators' write-ahead logs on (``LogDir``): a fresh directory a
+run under the checkout, what a crash would leave of each log handed to
+the reference, the directory removed on every way out.
 """
 
 from __future__ import annotations
 
 import contextlib
+import os
+import pathlib
+import shutil
 import time
 from typing import Callable, Dict, List, Optional, Sequence
 
+from benchmarks.meters import SyncMeter
+from benchmarks.spec import SpecError
 from benchmarks.traffic import KIND_WARM, Arrival, TxSource
 
 DRAIN_LIMIT_S = 60.0  # an answer may come a minute late, not never
@@ -119,6 +129,104 @@ def _config(cell_config: Dict, seed: Optional[int]):
     return Config(**fields)
 
 
+def _process_gone(pid: int) -> bool:
+    try:
+        os.kill(pid, 0)
+    except ProcessLookupError:
+        return True
+    except PermissionError:
+        pass  # it runs, as somebody else
+    return False
+
+
+def fresh_log_dir(parent: pathlib.Path, cell: str, seed: int) -> pathlib.Path:
+    """``parent/<cell>.<seed>.<pid>``, made empty.  A log that finds a
+    file recovers from it, so a run that met the last run's logs would
+    start at that run's last epoch with a ledger of transactions nobody
+    submitted.  What runs whose process is gone left here (a killed run
+    removes nothing) goes on the way."""
+    parent.mkdir(parents=True, exist_ok=True)
+    for old in parent.iterdir():
+        pid = old.name.rpartition(".")[2]
+        if pid.isdigit() and _process_gone(int(pid)):
+            shutil.rmtree(old, ignore_errors=True)
+    mine = parent / f"{cell}.{seed}.{os.getpid()}"
+    shutil.rmtree(mine, ignore_errors=True)
+    mine.mkdir()
+    return mine
+
+
+class LogDir:
+    """One run's write-ahead logs, and what a crash would leave of them
+    at the durability the configuration's file states:
+
+    ``durable_after: "flush"``  the file's size as a second look sees
+        it while the validator's own handle is open: what the operating
+        system holds, which is what a killed process leaves;
+    ``durable_after: "fsync"``  its size at its last ``os.fsync`` /
+        ``os.fdatasync`` (meters.SyncMeter): what a loss of power
+        leaves.
+    """
+
+    LEVELS = ("flush", "fsync")
+
+    def __init__(self, cell, seed: int) -> None:
+        cfg = cell.config
+        self.level = cfg.get("durable_after")
+        if self.level not in self.LEVELS:
+            raise SpecError(
+                f"a configuration with a wal_dir states durable_after as one "
+                f"of {self.LEVELS}, not {self.level!r}"
+            )
+        self.replicas = int(cfg["durable_replicas"])
+        self._parent = (cell.root / cfg["cluster"]["wal_dir"]).resolve()
+        if not self._parent.is_relative_to(cell.root.resolve()):
+            raise SpecError(f"wal_dir {self._parent} leaves the checkout")
+        self.path = fresh_log_dir(self._parent, cell.name, seed)
+        self.syncs = SyncMeter()
+        self.syncs.install()
+
+    def _log(self, node_id: str) -> str:
+        # SimulatedCluster._make_wal's naming
+        return str(self.path / f"{node_id}.log")
+
+    def written_bytes(self, ids: Sequence[str]) -> int:
+        return sum(os.stat(self._log(nid)).st_size for nid in ids)
+
+    def held_bytes(self, ids: Sequence[str]) -> List[int]:
+        """Of each log, the prefix a crash at this moment would leave."""
+        if self.level == "fsync":
+            return [self.syncs.synced_bytes(self._log(nid)) for nid in ids]
+        return [os.stat(self._log(nid)).st_size for nid in ids]
+
+    def observe(self, ids: Sequence[str],
+                held_at_settle: Sequence[Sequence[int]]) -> Dict:
+        """Plain data for the reference; taken before the cluster is
+        stopped, since a graceful close flushes and a kill does not.
+        ``held_at_settle[e]`` is ``held_bytes`` as it read when the
+        harness stamped epoch e settled: what an acknowledged settle
+        could count on, whatever reached the file afterwards."""
+        logs = {}
+        for i, (nid, held) in enumerate(zip(ids, self.held_bytes(ids))):
+            logs[nid] = {
+                "path": self._log(nid),
+                "held_bytes": held,
+                "held_at_settle": [row[i] for row in held_at_settle],
+            }
+        return {
+            "durable_after": self.level,
+            "durable_replicas": self.replicas,
+            "syncs": self.syncs.count,
+            "logs": logs,
+        }
+
+    def remove(self) -> None:
+        self.syncs.remove()
+        shutil.rmtree(self.path, ignore_errors=True)
+        with contextlib.suppress(OSError):
+            self._parent.rmdir()  # unless another run's logs are there
+
+
 class Served:
     """SimulatedCluster behind its ingress planes."""
 
@@ -137,9 +245,18 @@ class Served:
         # configuration's file fixes one (``cluster.key_seed``)
         kwargs = {"seed": seed, "key_seed": seed}
         kwargs.update(cell.config.get("cluster", {}))
-        self.cluster = SimulatedCluster(
-            config=self.cfg, auto_propose=False, **kwargs
-        )
+        self.cluster = None
+        self.wal: Optional[LogDir] = None
+        if kwargs.get("wal_dir") is not None:
+            self.wal = LogDir(cell, seed)
+            kwargs["wal_dir"] = str(self.wal.path)
+        try:
+            self.cluster = SimulatedCluster(
+                config=self.cfg, auto_propose=False, **kwargs
+            )
+        except BaseException:
+            self.close()
+            raise
         self.ids: List[str] = list(self.cluster.ids)
         self._nodes = [self.cluster.nodes[nid] for nid in self.ids]
         self._ingress = [self.cluster.ingress(nid) for nid in self.ids]
@@ -155,6 +272,11 @@ class Served:
         self.timed: List[bytes] = []
         self.timed_ok: List[bool] = []
         self.rounds = 0
+        # (start, end, bytes of log written so far) of every round
+        self.round_log: List[tuple] = []
+        # per settled epoch, what a crash would have left of each log
+        # at the moment the epoch was stamped settled
+        self.held_at_settle: List[List[int]] = []
         self.clock = RoundClock(meter)
 
     # -- driving -------------------------------------------------------
@@ -171,8 +293,11 @@ class Served:
         now = time.perf_counter()
         while len(self.t_ordered) < ordered:
             self.t_ordered.append(now)
-        while len(self.t_settled) < settled:
-            self.t_settled.append(now)
+        if len(self.t_settled) < settled:
+            held = [] if self.wal is None else self.wal.held_bytes(self.ids)
+            while len(self.t_settled) < settled:
+                self.t_settled.append(now)
+                self.held_at_settle.append(held)
 
     def _submit(self, a: Arrival, due: Optional[float]) -> None:
         node = a.nonce % len(self.ids)
@@ -191,6 +316,7 @@ class Served:
     def _round(self, between: Callable[[], None]) -> None:
         spans = self.spans
         net = self.cluster.net
+        start = time.perf_counter()
         with self.clock.timed():
             with spans("start_epoch"):
                 for hb in self._nodes:
@@ -209,6 +335,10 @@ class Served:
                 if not stepped and net.pending_count() == 0:
                     break
         self.rounds += 1
+        self.round_log.append((
+            start, time.perf_counter(),
+            None if self.wal is None else self.wal.written_bytes(self.ids),
+        ))
 
     def _quiet(self) -> bool:
         ordered, settled = self._frontiers()
@@ -322,7 +452,7 @@ class Served:
         from cleisthenes_tpu.ops import placement
 
         hb0 = self._nodes[0]
-        return {
+        out = {
             "hub": dict(hb0.hub.stats()),
             "delivery": dict(self.cluster.net.delivery_stats()),
             "placement": placement.snapshot(),
@@ -331,6 +461,9 @@ class Served:
             "epochs": len(self.t_settled),
             "rounds": self.rounds,
         }
+        if self.wal is not None:
+            out["wal_bytes"] = self.wal.written_bytes(self.ids)
+        return out
 
     def observe(self) -> Dict:
         """Plain data for the reference: nothing of the program's
@@ -352,13 +485,23 @@ class Served:
                 for nid, hb in zip(self.ids, self._nodes)
             },
             "batch_size": max(self.cfg.batch_size, self.cfg.n),
+            "wal": None if self.wal is None else self.wal.observe(
+                self.ids, self.held_at_settle
+            ),
         }
 
     def close(self) -> None:
-        self.cluster.stop()
-        self.cluster = None
-        self._nodes = []
-        self._ingress = []
+        """Stops the cluster and takes the logs away; run.py calls it
+        once the comparison has read them, and again on every other way
+        out of a run."""
+        if self.cluster is not None:
+            self.cluster.stop()
+            self.cluster = None
+            self._nodes = []
+            self._ingress = []
+        if self.wal is not None:
+            self.wal.remove()
+            self.wal = None
 
 
 class Lockstep:
@@ -483,5 +626,5 @@ class Lockstep:
 
 EXECUTORS = {"served": Served, "lockstep": Lockstep}
 
-__all__ = ["Served", "Lockstep", "Spans", "EXECUTORS", "RoundClock",
-           "drain_limit_s"]
+__all__ = ["Served", "Lockstep", "LogDir", "Spans", "EXECUTORS", "RoundClock",
+           "drain_limit_s", "fresh_log_dir"]

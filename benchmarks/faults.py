@@ -14,10 +14,33 @@ exchange between chips to leave out):
   coin_flipped      (lockstep) the common coin's bit is inverted: the
                     control for the BBA + coin layer, whose outcome
                     the committed batch alone does not show
+
+and, where the configuration has a write-ahead log (``for_cell``), its
+durability broken underneath one validator:
+
+  wal_skipped       every second batch record is not written
+  wal_unflushed     records reach the file two appends late, from a
+                    buffer of the process's own: the later flush,
+                    which only the prefix a crash would leave can show.
+                    (Leaving out ``flush()`` alone loses nothing at
+                    these sizes: Python's buffered writer hands a
+                    record larger than its 8 KiB buffer straight to
+                    the operating system; PERF.md, PR 33.)
+  wal_altered       one transaction's last bit is flipped before the
+                    record is framed, so that its CRC holds
+  wal_behind        the appends are taken off the commit path: records
+                    wait in a buffer of the process's own and reach the
+                    file when the validator is next idle (its next
+                    ``start_epoch``, and before the harness's last look
+                    at the files).  After the drain the logs hold every
+                    record, whole and in order; only the prefix held
+                    when each epoch was stamped settled shows that the
+                    settle was acknowledged before it was durable
 """
 
 from __future__ import annotations
 
+import collections
 import copy
 from typing import Callable, Dict
 
@@ -68,6 +91,86 @@ def _served_half_batch(executor) -> None:
             return txs[: len(txs) // 2]
 
         hb._create_batch = half
+
+
+# -- served, with a write-ahead log -------------------------------------------
+
+
+def _wal_skipped(executor) -> None:
+    log = executor._nodes[1].batch_log
+    orig = log.append
+    state = {"calls": 0}
+
+    def append(epoch, batch):
+        state["calls"] += 1
+        if state["calls"] % 2:
+            orig(epoch, batch)
+
+    log.append = append
+
+
+def _wal_unflushed(executor) -> None:
+    log = executor._nodes[1].batch_log
+    orig = log._append_record_locked
+    behind = collections.deque()
+
+    def append_record(rec):
+        # the last two records of a drained run hold its last epoch's
+        # batch record, with or without a checkpoint after it
+        behind.append(rec)
+        if len(behind) > 2:
+            orig(behind.popleft())
+
+    log._append_record_locked = append_record
+
+
+def _wal_altered(executor) -> None:
+    log = executor._nodes[1].batch_log
+    orig = log.append
+
+    def append(epoch, batch):
+        # the ledger keeps the batch as it was settled
+        batch = copy.deepcopy(batch)
+        for txs in batch.contributions.values():
+            if txs:
+                txs[0] = _flip(txs[0])
+                break
+        orig(epoch, batch)
+
+    log.append = append
+
+
+def _wal_behind(executor) -> None:
+    hb = executor._nodes[1]
+    log = hb.batch_log
+    write = log._append_record_locked
+    behind = collections.deque()
+    log._append_record_locked = behind.append
+
+    def catch_up() -> None:
+        while behind:
+            write(behind.popleft())
+
+    start_epoch, observe = hb.start_epoch, executor.observe
+
+    def idle_then_start():
+        catch_up()
+        return start_epoch()
+
+    def idle_then_observe():
+        catch_up()
+        return observe()
+
+    hb.start_epoch = idle_then_start
+    executor.observe = idle_then_observe
+
+
+WAL_FAULTS: Dict[str, Callable] = {
+    "wal_skipped": _wal_skipped,
+    "wal_unflushed": _wal_unflushed,
+    "wal_altered": _wal_altered,
+    "wal_behind": _wal_behind,
+}
 
 
 # -- lockstep -----------------------------------------------------------------
@@ -126,4 +229,14 @@ FAULTS: Dict[str, Dict[str, Callable]] = {
     },
 }
 
-__all__ = ["FAULTS"]
+
+def for_cell(cell) -> Dict[str, Callable]:
+    """The faults this cell can have: its executor's, and the log's
+    where its configuration has one (elsewhere they break nothing)."""
+    faults = dict(FAULTS[cell.config["executor"]])
+    if cell.config.get("cluster", {}).get("wal_dir") is not None:
+        faults.update(WAL_FAULTS)
+    return faults
+
+
+__all__ = ["FAULTS", "WAL_FAULTS", "for_cell"]

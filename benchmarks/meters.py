@@ -1,12 +1,15 @@
 """Compilation and dispatch meters, copied from chip_smoke.py
 (CompileMeter, dispatch_round_trip_us) so that the yardstick lives
-where a later PR cannot change it."""
+where a later PR cannot change it; and SyncMeter, the harness's own
+reading of what reached the disk."""
 
 from __future__ import annotations
 
 import functools
+import os
 import statistics
 import time
+from typing import Dict, Tuple
 
 import numpy as np
 
@@ -38,6 +41,46 @@ class CompileMeter:
     def _on_event(self, event: str, **_kw) -> None:
         if event == _CACHE_HIT_EVENT:
             self.cache_hits += 1
+
+
+class SyncMeter:
+    """What the last ``os.fsync`` / ``os.fdatasync`` of each file left
+    on the disk: between ``install()`` and ``remove()`` the two calls
+    are wrapped, and every one that returns is counted and the file's
+    ``st_size`` at that moment kept by (``st_dev``, ``st_ino``).  A
+    loss of power leaves of a file what it held at its last sync, so
+    ``synced_bytes(path)`` is the prefix a ``durable_after: fsync``
+    configuration may count on; a file never synced reads 0.  It takes
+    nothing from the program."""
+
+    _CALLS = ("fsync", "fdatasync")
+
+    def __init__(self) -> None:
+        self.count = 0
+        self._sizes: Dict[Tuple[int, int], int] = {}
+        self._orig: Dict[str, object] = {}
+
+    def install(self) -> None:
+        for name in self._CALLS:
+            orig = getattr(os, name)
+            self._orig[name] = orig
+            setattr(os, name, functools.partial(self._sync, orig))
+
+    def remove(self) -> None:
+        for name, orig in self._orig.items():
+            setattr(os, name, orig)
+        self._orig = {}
+
+    def _sync(self, orig, fd) -> None:
+        orig(fd)
+        # a file object is as good as a descriptor to os.fsync
+        st = os.fstat(fd if isinstance(fd, int) else fd.fileno())
+        self.count += 1
+        self._sizes[(st.st_dev, st.st_ino)] = st.st_size
+
+    def synced_bytes(self, path: str) -> int:
+        st = os.stat(path)
+        return self._sizes.get((st.st_dev, st.st_ino), 0)
 
 
 @functools.cache
@@ -72,4 +115,5 @@ def dispatch_round_trip_us(reps: int = 20) -> float:
     return statistics.median(probe_round_trip() for _ in range(reps)) * 1e6
 
 
-__all__ = ["CompileMeter", "probe_round_trip", "dispatch_round_trip_us"]
+__all__ = ["CompileMeter", "SyncMeter", "probe_round_trip",
+           "dispatch_round_trip_us"]

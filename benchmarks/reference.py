@@ -22,6 +22,28 @@ the reference is the set of answers that the guarantees allow):
   unsettled   ordered epochs not yet settled at drain, worst validator
   oversize    batches with more transactions than the batch size
 
+A configuration with a write-ahead log states how durable a settled
+batch is.  The harness hands over each validator's log and the length
+of the prefix a crash would leave at that level (what the operating
+system holds, or what the last sync left); the reference parses the
+prefix itself and holds it to the ledgers (``compare_wal``):
+
+  wal_short      OK-acked and settled transactions read back from the
+                 prefix of fewer validators' logs than the
+                 configuration's ``durable_replicas``: the guarantee as
+                 a client would state it
+  wal_missing    (validator, settled epoch) pairs with no whole batch
+                 record of that epoch in the prefix
+  wal_wrong      batch records that are not, proposer for proposer and
+                 byte for byte, the batch that validator settled in
+                 that epoch; records of epochs it never settled; epochs
+                 out of order or twice
+  wal_unordered  settled epochs whose ordered record is absent from
+                 the prefix or follows their batch record; compared
+                 where the configuration states that order
+  wal_torn       bytes of the prefix after its last whole record (a
+                 drained run leaves none)
+
 Lockstep path (benign synchronous schedule, so the ledger is fully
 determined and the reference predicts it):
 
@@ -39,7 +61,9 @@ determined and the reference predicts it):
 from __future__ import annotations
 
 import hashlib
-from typing import Dict, List, Sequence, Tuple
+import struct
+import zlib
+from typing import Dict, List, Optional, Sequence, Tuple
 
 Numbers = Dict[str, Tuple[float, float]]  # name -> (value, limit)
 
@@ -116,6 +140,185 @@ def settled_epochs(obs: Dict) -> Dict[bytes, int]:
             for tx in txs:
                 out.setdefault(tx, epoch)
     return out
+
+
+# -- the write-ahead logs ---------------------------------------------------
+
+# core/ledger.py's docstring is the format's specification: records of
+# ``magic | u32 len | body | u32 crc32(body)``, all big-endian
+WAL_BATCH = b"CLOG"  # u64 epoch | u32 proposers | per proposer, sorted
+#                      (u32 | id | u32 txs | per tx (u32 | bytes))
+WAL_ORDERED = b"COrd"  # u64 epoch | u32 proposers | per proposer, sorted
+#                        (u32 | id | u32 | ciphertext)
+# framed alike and skipped by their length: the dedup-set checkpoint,
+# the roster switch, and the lane-tagged twins of the three
+WAL_SKIPPED = (b"CCKP", b"RCFG", b"LCLG", b"LCKP", b"LOrd")
+
+WalRecord = Tuple[bytes, Optional[int], Optional[Dict[str, List[bytes]]], int]
+
+
+def _wal_body(magic: bytes, body: bytes) -> Tuple[int, Dict[str, List[bytes]]]:
+    """(epoch, {proposer: [tx]}) of a batch record's body, or (epoch,
+    {proposer: [ciphertext]}) of an ordered record's."""
+    epoch, proposers = struct.unpack_from(">QI", body, 0)
+    off = 12
+    out: Dict[str, List[bytes]] = {}
+    for _ in range(proposers):
+        (width,) = struct.unpack_from(">I", body, off)
+        off += 4
+        proposer = body[off:off + width].decode("utf-8")
+        off += width
+        count = 1
+        if magic == WAL_BATCH:
+            (count,) = struct.unpack_from(">I", body, off)
+            off += 4
+        items = []
+        for _ in range(count):
+            (width,) = struct.unpack_from(">I", body, off)
+            off += 4
+            items.append(body[off:off + width])
+            off += width
+        out[proposer] = items
+    if off != len(body):
+        raise ValueError("the body's lengths do not add up to its own")
+    return epoch, out
+
+
+def wal_records(data: bytes) -> Tuple[List[WalRecord], int]:
+    """The whole records at the head of ``data`` as (magic, epoch,
+    contributions, the offset at which the record ends), epoch and
+    contributions None for a kind that is skipped; and the offset at
+    which the last of them ends.  It stops, as a validator that
+    recovers from the file does, at the first record with an unknown
+    magic, a length past the end, a CRC that does not match or a body
+    that does not parse: what follows is a torn tail."""
+    records: List[WalRecord] = []
+    off = 0
+    while off + 8 <= len(data):
+        magic = data[off:off + 4]
+        (width,) = struct.unpack_from(">I", data, off + 4)
+        end = off + 8 + width + 4
+        if end > len(data):
+            break
+        body = data[off + 8:end - 4]
+        if zlib.crc32(body) != struct.unpack_from(">I", data, end - 4)[0]:
+            break
+        if magic in (WAL_BATCH, WAL_ORDERED):
+            try:
+                epoch, contributions = _wal_body(magic, body)
+            except (ValueError, struct.error):  # UnicodeDecodeError is one
+                break
+            records.append((magic, epoch, contributions, end))
+        elif magic in WAL_SKIPPED:
+            records.append((magic, None, None, end))
+        else:
+            break
+        off = end
+    return records, off
+
+
+def compare_wal(obs: Dict) -> Numbers:
+    """``obs``: as ``compare_served`` takes it, and ``wal``:
+    durable_replicas, logs {node_id: {path, held_bytes,
+    held_at_settle}}.  Every log is read here, ``held_bytes`` of it: the
+    prefix a crash after the drain would leave.  ``held_at_settle[e]``
+    is the prefix a crash would have left at the moment epoch e was
+    stamped settled, and a record counts for its epoch only if it ends
+    inside that: a settle that is acknowledged before its records are
+    held is not durable, whatever reaches the file once the system is
+    idle.  (An epoch with no stamp, one a validator settled ahead of
+    the slowest, is judged by ``held_bytes``.)
+
+      wal_short      transactions OK-acked and settled whose batch
+                     record was held in time in fewer than
+                     ``durable_replicas`` logs
+      wal_missing    (validator, settled epoch) with no whole CLOG
+                     record in ``held_bytes``
+      wal_late       (validator, settled epoch) whose CLOG or COrd
+                     record is in ``held_bytes`` and ends beyond the
+                     prefix held when the epoch was stamped settled
+      wal_wrong      CLOG records that are not, proposer for proposer
+                     and byte for byte, the batch that validator settled
+                     for that epoch, or name a proposer their epoch's
+                     COrd record does not; of epochs never settled, out
+                     of order or twice; COrd records that differ from
+                     the one another validator holds for the epoch
+                     (``core/ledger.py``: "honest nodes' ordered logs
+                     are byte-identical")
+      wal_unordered  settled epochs whose COrd record is absent from
+                     ``held_bytes`` or follows its CLOG record
+      wal_torn       bytes of ``held_bytes`` after its last whole record
+    """
+    wal = obs["wal"]
+    ids = list(obs["node_ids"])
+    admitted = {tx for tx, _node, ok in obs["submissions"] if ok}
+    copies = {
+        tx: 0
+        for contributions in obs["ledgers"][ids[0]]
+        for txs in contributions.values() for tx in txs if tx in admitted
+    }
+    missing = late = wrong = unordered = torn = 0
+    agreed: Dict[int, Dict[str, List[bytes]]] = {}  # epoch -> its COrd body
+    for nid in ids:
+        log = wal["logs"][nid]
+        with open(log["path"], "rb") as fh:
+            held = fh.read(log["held_bytes"])
+        records, end = wal_records(held)
+        torn += len(held) - end
+        ledger = obs["ledgers"][nid]
+        stamped = log["held_at_settle"]
+
+        def in_time(epoch: int, ends_at: int) -> bool:
+            return epoch >= len(stamped) or ends_at <= stamped[epoch]
+
+        # epoch -> (place, end) of its first record of the kind
+        batch_at: Dict[int, Tuple[int, int]] = {}
+        ordered_at: Dict[int, Tuple[int, int]] = {}
+        ordered: Dict[int, Dict[str, List[bytes]]] = {}
+        read_back = set()
+        newest = -1
+        for place, (magic, epoch, contributions, ends_at) in enumerate(records):
+            if magic == WAL_ORDERED:
+                if agreed.setdefault(epoch, contributions) != contributions:
+                    wrong += 1
+                ordered_at.setdefault(epoch, (place, ends_at))
+                ordered.setdefault(epoch, contributions)
+            elif magic == WAL_BATCH:
+                # an epoch with no COrd record is wal_unordered's to count
+                may_propose = ordered.get(epoch, contributions)
+                if (epoch <= newest or epoch >= len(ledger)
+                        or contributions != ledger[epoch]
+                        or any(p not in may_propose for p in contributions)):
+                    wrong += 1
+                newest = max(newest, epoch)
+                if epoch not in batch_at:
+                    batch_at[epoch] = (place, ends_at)
+                    if in_time(epoch, ends_at):
+                        for txs in contributions.values():
+                            read_back.update(txs)
+        for epoch in range(len(ledger)):
+            batch, first = batch_at.get(epoch), ordered_at.get(epoch)
+            if batch is None:
+                missing += 1
+            if first is None or (batch is not None and first[0] > batch[0]):
+                unordered += 1
+            if any(
+                at is not None and not in_time(epoch, at[1])
+                for at in (batch, first)
+            ):
+                late += 1
+        for tx in read_back:
+            if tx in copies:
+                copies[tx] += 1
+    short = sum(1 for n in copies.values() if n < wal["durable_replicas"])
+    return {
+        "wal_short": (short, 0),
+        "wal_missing": (missing, 0),
+        "wal_late": (late, 0),
+        "wal_wrong": (wrong, 0),
+        "wal_unordered": (unordered, 0),
+        "wal_torn": (torn, 0),
+    }
 
 
 # -- lockstep ---------------------------------------------------------------
@@ -231,5 +434,6 @@ def verdict(numbers: Numbers) -> bool:
     return all(value <= limit for value, limit in numbers.values())
 
 
-__all__ = ["compare_served", "compare_lockstep", "settled_epochs",
-           "predict_batch", "CoinReference", "verdict", "Numbers"]
+__all__ = ["compare_served", "compare_wal", "wal_records", "compare_lockstep",
+           "settled_epochs", "predict_batch", "CoinReference", "verdict",
+           "Numbers"]

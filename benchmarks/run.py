@@ -11,6 +11,9 @@ memory, the counters, and last the comparison with the plain reference
 to stdout on lines that start ``[bench]``; the last line of stdout is
 the one JSON object of the result.  The numbers compared, each beside
 its limit, are the last lines of stderr and the last key of the result.
+A configuration with a write-ahead log keeps its logs in a directory of
+this run's own under ``.bench_wal/`` in the checkout; they are read
+back as part of the comparison and removed on every way out.
 
 ``--trace 0`` reports the cell's end-to-end metrics, ``--trace 1`` its
 per-layer metrics: the profiler runs over the window's last whole loop
@@ -26,11 +29,13 @@ import time
 _T_PROCESS = time.perf_counter()  # set-up starts here, before the imports
 
 import argparse  # noqa: E402
+import contextlib  # noqa: E402
 import gc  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import pathlib  # noqa: E402
 import shutil  # noqa: E402
+import signal  # noqa: E402
 import sys  # noqa: E402
 from typing import Callable, Dict, List, Optional  # noqa: E402
 
@@ -228,6 +233,7 @@ END_TO_END: Dict[str, Callable[[Dict], float]] = {
     "settled_tx_per_s": _settled_tx_per_s,
     "settle_p50_ms": lambda r: _latency_pctl(r, "t_settled", 0.50),
     "settle_p90_ms": lambda r: _latency_pctl(r, "t_settled", 0.90),
+    "settle_p99_ms": lambda r: _latency_pctl(r, "t_settled", 0.99),
     "order_p50_ms": lambda r: _latency_pctl(r, "t_ordered", 0.50),
     "setup_s": lambda r: r["setup_s"],
 }
@@ -248,8 +254,26 @@ def run_cell(
 ) -> Dict:
     """Set-up, window, drain, comparison; returns the result object.
     ``fault`` (tests and benchmarks/control.py only) is called with the
-    warmed-up executor and breaks the timed path under it."""
+    warmed-up executor and breaks the timed path under it.  Whichever
+    way a run ends, its executor is closed: a logged configuration's
+    logs are hundreds of megabytes that a checkout must not keep."""
     t_process = time.perf_counter() if t_process is None else t_process
+    with contextlib.ExitStack() as on_exit:
+        return _run_cell(
+            workload, seed, seconds, trace, root, fault, t_process, on_exit
+        )
+
+
+def _run_cell(
+    workload: str,
+    seed: int,
+    seconds: float,
+    trace: bool,
+    root: pathlib.Path,
+    fault: Optional[Callable],
+    t_process: float,
+    on_exit: contextlib.ExitStack,
+) -> Dict:
     from benchmarks import spec
 
     cell = spec.load_cell(workload, root)
@@ -284,6 +308,7 @@ def run_cell(
     spans = Spans()
     placement.reset()
     executor = EXECUTORS[cell.config["executor"]](cell, seed, spans, meter)
+    on_exit.callback(executor.close)
     t_d = time.perf_counter()
     loop = cell.traffic["loop"]
     schedule = None
@@ -330,6 +355,7 @@ def run_cell(
     t0, t_end = ends["t0"], ends["t_end"]
     run: Dict = {
         "cell": workload,
+        "config": cell.config,
         "executor": executor.kind,
         "loop": loop,
         "seconds": seconds,
@@ -343,6 +369,14 @@ def run_cell(
     }
     if executor.kind == "served":
         obs = executor.observe()
+        # the logs are read while they are there; close() removes them
+        durable = {}
+        if obs["wal"] is not None:
+            held = sum(log["held_bytes"] for log in obs["wal"]["logs"].values())
+            say(f"write-ahead logs: {after['wal_bytes']} bytes written, {held} "
+                f"held after the last {obs['wal']['durable_after']}, "
+                f"{obs['wal']['syncs']} syncs")
+            durable = reference.compare_wal(obs)
         executor.close()
         settled_in = reference.settled_epochs(obs)
         t_settled = executor.t_settled
@@ -355,6 +389,7 @@ def run_cell(
             due=executor.due,
             late_s=executor.late,
             submit_s=executor.submit_s,
+            rounds=executor.round_log,
         )
         ledger = obs["ledgers"][obs["node_ids"][0]]
         in_window = [
@@ -371,7 +406,7 @@ def run_cell(
             for tx, ok in zip(executor.timed, executor.timed_ok)
             if not ok or tx not in settled_in
         )
-        numbers = reference.compare_served(obs)
+        numbers = dict(reference.compare_served(obs), **durable)
     else:
         first = ends["first_epoch"]
         obs = executor.observe(first)
@@ -423,9 +458,12 @@ def run_cell(
             "device_ops": reduced["device_ops"],
             "idle_gaps": reduced["idle_gaps"],
         }
+    late = sorted(run.get("late_s") or ())
     say(f"window {t_end - t0:.3f} s, {run['epochs_in_window']} epochs, "
         f"{run['settled_in_window']} transactions settled; "
-        f"{after['compiles'] - before['compiles']} compilations in the window")
+        f"{after['compiles'] - before['compiles']} compilations in the window"
+        + (f"; generator late p95 {percentile(late, 0.95) * 1e3:.1f} ms"
+           if late and loop == "open" else ""))
     result["compared"] = {
         name: {"value": value, "limit": limit}
         for name, (value, limit) in numbers.items()
@@ -440,6 +478,8 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     args = ap.parse_args(argv)
+    # a run that is told to end leaves as one that raises does
+    signal.signal(signal.SIGTERM, lambda *_: sys.exit(128 + signal.SIGTERM))
     try:
         result = run_cell(
             args.workload, args.seed, args.seconds, bool(args.trace),

@@ -32,6 +32,7 @@ class Cell:
     traffic: Dict  # the cell's traffic file, parsed
     end_to_end: List[Dict]  # the metrics this cell reports, --trace 0
     per_layer: List[Dict]  # and --trace 1
+    root: pathlib.Path = ROOT  # the checkout: what a run writes goes under it
 
 
 def _read_json(path: pathlib.Path) -> Dict:
@@ -78,6 +79,7 @@ def load_cell(workload: str, root: pathlib.Path = ROOT) -> Cell:
         traffic=traffic,
         end_to_end=e2e,
         per_layer=layer,
+        root=root,
     )
 
 
