@@ -196,9 +196,16 @@ class XlaMerkle(MerkleBackend):
     # jobs stay on host, batch waves run on device.  An N=16 live
     # epoch's whole merkle load therefore stays native, while the
     # N>=128 crypto-plane waves (16k+ items) take the device path.
-    # Both values are carried over from an earlier attachment of the
-    # chip and are UNMEASURED on a local one (ops.placement counts
-    # which side each batch took; PERF.md holds the dispatch cost).
+    # The floors count items and are blind to their width: at
+    # 250-byte transactions (N=16, B=16,384) a wave is 256 proofs of
+    # 43 KB leaves, 11 MB of SHA-256, and stays on the host because
+    # 256 < 8,192.  Measured there on one v5e chip (PERF.md section 6,
+    # PR 34), keeping it on the host is right: 8.5 ms on the native
+    # hasher against 72 ms through verify_branches, whose scan walks a
+    # leaf's 679 blocks one after another whatever the batch.  Both
+    # values are carried over from an earlier attachment of the chip
+    # and no crossover in items was searched for (ops.placement counts
+    # which side each batch took, in items and in bytes).
     HOST_FLOOR_VERIFY = 8192
     HOST_FLOOR_BUILD_LEAVES = 16384
 
@@ -237,11 +244,13 @@ class XlaMerkle(MerkleBackend):
         if b < self.HOST_FLOOR_VERIFY:
             # also covers the base-class single-tree build(): a
             # 16-leaf tree would be ~5 per-level device dispatches
-            with placement.batch("sha256.hash_batch", False, b), trace.span(
-                "ops", "host"
-            ):
+            with placement.batch(
+                "sha256.hash_batch", False, b, nbytes=msgs.nbytes
+            ), trace.span("ops", "host"):
                 return self._host._hash_batch(msgs)
-        with placement.batch("sha256.hash_batch", True, b, self._mesh):
+        with placement.batch(
+            "sha256.hash_batch", True, b, self._mesh, nbytes=msgs.nbytes
+        ):
             with trace.span("ops", "pack"):
                 bucket = self._bucket(b)
                 if bucket != b:
@@ -260,11 +269,12 @@ class XlaMerkle(MerkleBackend):
         b, n, _ = shards.shape
         if b * n < self.HOST_FLOOR_BUILD_LEAVES:
             with placement.batch(
-                "merkle.build_forest", False, b * n
+                "merkle.build_forest", False, b * n, nbytes=shards.nbytes
             ), trace.span("ops", "host"):
                 return self._host.build_batch(shards)
         with placement.batch(
-            "merkle.build_forest", True, b * n, self._mesh
+            "merkle.build_forest", True, b * n, self._mesh,
+            nbytes=shards.nbytes,
         ):
             with trace.span("ops", "pack"):
                 bucket = self._bucket(b)
@@ -301,9 +311,11 @@ class XlaMerkle(MerkleBackend):
         from cleisthenes_tpu.ops.sha256_xla import verify_branches
 
         b = leaves.shape[0]
+        # the bytes hashed: each proof's leaf and its path of siblings
+        nbytes = leaves.nbytes + branches.nbytes
         if b < self.HOST_FLOOR_VERIFY:
             with placement.batch(
-                "merkle.verify_branches", False, b
+                "merkle.verify_branches", False, b, nbytes=nbytes
             ), trace.span("ops", "host"):
                 return self._host.verify_batch(
                     roots, leaves, branches, indices
@@ -317,7 +329,7 @@ class XlaMerkle(MerkleBackend):
             return np.concatenate([a, reps])
 
         with placement.batch(
-            "merkle.verify_branches", True, b, self._mesh
+            "merkle.verify_branches", True, b, self._mesh, nbytes=nbytes
         ):
             with trace.span("ops", "pack"):
                 columns = (

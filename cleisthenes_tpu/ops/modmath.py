@@ -1150,6 +1150,11 @@ class ModEngine:
             self._family = (
                 f"modexp_{self._layout.limb_bits}x{self._layout.nlimbs}"
             )
+            # bytes of one (value, exponent) pair as the programs take
+            # them: what an int entry point reports to the tally
+            self._pair_bytes = (
+                33 + 32 if self._fits256 else 2 * self._layout.val_bytes
+            )
             if self._fits256:
                 m_limbs, m_prime, r_limbs, r2_limbs = _spec256(group)
             else:
@@ -1427,11 +1432,13 @@ class ModEngine:
                     ).append((bi, off, width))
         for g_bucket, chunks in by_bucket.items():
             heights = [blocks[bi][1].shape[0] for bi, _o, _w in chunks]
+            items = sum(h * w for h, (_bi, _o, w) in zip(heights, chunks))
             with placement.batch(
                 self._family + ".comb",
                 True,
-                sum(h * w for h, (_bi, _o, w) in zip(heights, chunks)),
+                items,
                 self.mesh,
+                nbytes=33 * sum(heights) + 32 * items,  # bases, exponents
             ):
                 with trace.span("ops", "pack"):
                     # under a mesh the base rows shard flat: a row's
@@ -1533,11 +1540,13 @@ class ModEngine:
         host = self._host_floor(len(bases))
         if host is not None:
             with placement.batch(
-                f"{self._family}.{host_op}", False, len(bases)
+                f"{self._family}.{host_op}", False, len(bases),
+                nbytes=len(bases) * self._pair_bytes,
             ), trace.span("ops", "host"):
                 return host.pow_batch(bases, exps)
         with placement.batch(
-            self._family + ".pow", True, len(bases), self.mesh
+            self._family + ".pow", True, len(bases), self.mesh,
+            nbytes=len(bases) * self._pair_bytes,
         ):
             if self._wide is not None:
                 lay = self._layout
@@ -1585,11 +1594,13 @@ class ModEngine:
         host = self._host_floor(len(base_b))
         if host is not None:
             with placement.batch(
-                f"{self._family}.{host_op}", False, len(base_b)
+                f"{self._family}.{host_op}", False, len(base_b),
+                nbytes=base_b.nbytes + exp_b.nbytes,
             ), trace.span("ops", "host"):
                 return host.pow_cols(base_b, exp_b)
         with placement.batch(
-            self._family + ".pow", True, len(base_b), self.mesh
+            self._family + ".pow", True, len(base_b), self.mesh,
+            nbytes=base_b.nbytes + exp_b.nbytes,
         ):
             return self._pow_device(base_b, exp_b)
 
@@ -1638,11 +1649,13 @@ class ModEngine:
         host = self._host_floor(len(u1))
         if host is not None:
             with placement.batch(
-                self._family + ".dual_pow", False, len(u1)
+                self._family + ".dual_pow", False, len(u1),
+                nbytes=2 * len(u1) * self._pair_bytes,
             ), trace.span("ops", "host"):
                 return host.dual_pow_batch(u1, e1, u2, e2)
         with placement.batch(
-            self._family + ".dual_pow", True, len(u1), self.mesh
+            self._family + ".dual_pow", True, len(u1), self.mesh,
+            nbytes=2 * len(u1) * self._pair_bytes,
         ):
             if self._wide is not None:
                 lay = self._layout
@@ -1708,13 +1721,15 @@ class ModEngine:
                 )
             )
         host = self._host_floor(len(u1_b))
+        nbytes = u1_b.nbytes + e1_b.nbytes + u2_b.nbytes + e2_b.nbytes
         if host is not None:
             with placement.batch(
-                self._family + ".dual_pow", False, len(u1_b)
+                self._family + ".dual_pow", False, len(u1_b), nbytes=nbytes
             ), trace.span("ops", "host"):
                 return host.dual_pow_cols(u1_b, e1_b, u2_b, e2_b)
         with placement.batch(
-            self._family + ".dual_pow", True, len(u1_b), self.mesh
+            self._family + ".dual_pow", True, len(u1_b), self.mesh,
+            nbytes=nbytes,
         ):
             return self._dual_pow_device(u1_b, e1_b, u2_b, e2_b)
 

@@ -93,15 +93,15 @@ class Xla16ErasureCoder(ErasureCoder):
     # -- single-instance ops (tiny: host path keeps dispatch count
     # down, same policy as the 8-bit XLA coder's host floor) ----------
     def encode(self, data: np.ndarray) -> np.ndarray:
-        with placement.batch("rs_gf65536.encode", False, 1), trace.span(
-            "ops", "host"
-        ):
+        with placement.batch(
+            "rs_gf65536.encode", False, 1, nbytes=np.asarray(data).nbytes
+        ), trace.span("ops", "host"):
             return self._cpu.encode(data)
 
     def _decode_impl(self, indices: tuple, shards: np.ndarray) -> np.ndarray:
-        with placement.batch("rs_gf65536.decode", False, 1), trace.span(
-            "ops", "host"
-        ):
+        with placement.batch(
+            "rs_gf65536.decode", False, 1, nbytes=np.asarray(shards).nbytes
+        ), trace.span("ops", "host"):
             return self._cpu._decode_impl(indices, shards)
 
     # -- batched ops: one lifted matmul for all instances -------------
@@ -115,7 +115,9 @@ class Xla16ErasureCoder(ErasureCoder):
         assert k == self.k
         if self.n == self.k:
             return data.copy()
-        with placement.batch("rs_gf65536.encode_batch", True, b):
+        with placement.batch(
+            "rs_gf65536.encode_batch", True, b, nbytes=data.nbytes
+        ):
             with trace.span("ops", "pack"):
                 syms = data.view("<u2").reshape(b, k, L // 2)
             with trace.span("ops", "device", program="encode_kernel_batch"):
@@ -146,7 +148,9 @@ class Xla16ErasureCoder(ErasureCoder):
             self._normalize_indices(pat)
             if pat == tuple(range(self.k)):
                 return shards.copy()
-            with placement.batch("rs_gf65536.decode_batch", True, b):
+            with placement.batch(
+                "rs_gf65536.decode_batch", True, b, nbytes=shards.nbytes
+            ):
                 with trace.span("ops", "pack"):
                     g = self._g_decode(pat)
                     syms = shards.view("<u2").reshape(b, k, L // 2)

@@ -97,12 +97,20 @@ def _decode_recheck_kernel(g_dec, g_enc, shards):
 class XlaErasureCoder(ErasureCoder):
     # A single instance's encode/decode below this byte count (and a
     # batch below four times it) runs on the host numpy path: the
-    # single-shot ops (one proposer's VAL encode) are exactly the
-    # small case.  The value is carried over from an earlier
-    # attachment of the chip and is UNMEASURED on a local one
-    # (ops.placement counts which side each batch took; PERF.md holds
-    # the dispatch cost).  It holds under a mesh as without one: the
-    # floors read the bytes, not the layout.
+    # single-shot ops (one proposer's VAL encode at 64-byte
+    # transactions) are exactly the small case.  Measured above it on
+    # one v5e chip, at the (6, 43,392) matrices of 250-byte
+    # transactions at N=16, B=16,384 (PERF.md section 6, PR 34; call
+    # with transfer and fetch / the native host kernel): one encode
+    # 2.2 / 5.2 ms; the fused decode-recheck of 8 matrices 72 / 94 ms
+    # and of 256 matrices 209 / 3,237 ms, nearly all of the 72 ms the
+    # 679 sequential SHA-256 blocks of a 43 KB leaf (the three-step
+    # path, RS on the device and the forest on the host, takes 16 ms
+    # for the same 8).  The floor's value itself is carried over from
+    # an earlier attachment of the chip and no crossover was searched
+    # for below it (ops.placement counts which side each batch took,
+    # in items and in bytes).  It holds under a mesh as without one:
+    # the floors read the bytes, not the layout.
     HOST_FLOOR_BYTES = 1 << 16
 
     def __init__(self, n: int, k: int, mesh=None):
@@ -144,13 +152,13 @@ class XlaErasureCoder(ErasureCoder):
         if self.n == self.k:
             return data.copy()
         if data.nbytes < self.HOST_FLOOR_BYTES:
-            with placement.batch("rs_gf256.encode", False, 1), trace.span(
-                "ops", "host"
-            ):
+            with placement.batch(
+                "rs_gf256.encode", False, 1, nbytes=data.nbytes
+            ), trace.span("ops", "host"):
                 return self._host.encode(data)
-        with placement.batch("rs_gf256.encode", True, 1), trace.span(
-            "ops", "device", program="_encode_kernel"
-        ):
+        with placement.batch(
+            "rs_gf256.encode", True, 1, nbytes=data.nbytes
+        ), trace.span("ops", "device", program="_encode_kernel"):
             return np.asarray(_encode_kernel(self._g_enc, jnp.asarray(data)))
 
     def _decode_bits_impl(self, indices: tuple) -> jnp.ndarray:
@@ -159,11 +167,13 @@ class XlaErasureCoder(ErasureCoder):
 
     def _decode_impl(self, indices: tuple, shards: np.ndarray) -> np.ndarray:
         if shards.nbytes < self.HOST_FLOOR_BYTES:
-            with placement.batch("rs_gf256.decode", False, 1), trace.span(
-                "ops", "host"
-            ):
+            with placement.batch(
+                "rs_gf256.decode", False, 1, nbytes=shards.nbytes
+            ), trace.span("ops", "host"):
                 return self._host._decode_impl(indices, shards)
-        with placement.batch("rs_gf256.decode", True, 1):
+        with placement.batch(
+            "rs_gf256.decode", True, 1, nbytes=shards.nbytes
+        ):
             with trace.span("ops", "pack"):
                 g = self._decode_bits(indices)
             with trace.span("ops", "device", program="_decode_kernel"):
@@ -176,11 +186,12 @@ class XlaErasureCoder(ErasureCoder):
             return data.copy()
         if data.nbytes < 4 * self.HOST_FLOOR_BYTES:
             with placement.batch(
-                "rs_gf256.encode_batch", False, len(data)
+                "rs_gf256.encode_batch", False, len(data), nbytes=data.nbytes
             ), trace.span("ops", "host"):
                 return self._host.encode_batch(data)
         with placement.batch(
-            "rs_gf256.encode_batch", True, len(data), self._mesh
+            "rs_gf256.encode_batch", True, len(data), self._mesh,
+            nbytes=data.nbytes,
         ), trace.span("ops", "device", program="_encode_kernel_batch"):
             if self._mesh is None:
                 return np.asarray(
@@ -207,13 +218,17 @@ class XlaErasureCoder(ErasureCoder):
             # (and span) each step; this row counts the fusion's
             # refusals, and its span is empty
             with placement.batch(
-                "rs_gf256.decode_recheck", False, len(shards)
+                "rs_gf256.decode_recheck", False, len(shards),
+                nbytes=shards.nbytes,
             ):
                 return None
         patterns = [self._normalize_indices(ix) for ix in indices]
         if len(set(patterns)) != 1:
             return None
-        with placement.batch("rs_gf256.decode_recheck", True, len(shards)):
+        with placement.batch(
+            "rs_gf256.decode_recheck", True, len(shards),
+            nbytes=shards.nbytes,
+        ):
             with trace.span("ops", "pack"):
                 g = self._decode_bits(patterns[0])
                 b = shards.shape[0]
@@ -238,11 +253,13 @@ class XlaErasureCoder(ErasureCoder):
         shards = np.ascontiguousarray(shards, dtype=np.uint8)
         if shards.nbytes < 4 * self.HOST_FLOOR_BYTES:
             with placement.batch(
-                "rs_gf256.decode_batch", False, len(shards)
+                "rs_gf256.decode_batch", False, len(shards),
+                nbytes=shards.nbytes,
             ), trace.span("ops", "host"):
                 return self._host.decode_batch(indices, shards)
         with placement.batch(
-            "rs_gf256.decode_batch", True, len(shards), self._mesh
+            "rs_gf256.decode_batch", True, len(shards), self._mesh,
+            nbytes=shards.nbytes,
         ):
             with trace.span("ops", "pack"):
                 patterns = [self._normalize_indices(ix) for ix in indices]

@@ -181,6 +181,9 @@ class ChannelNetwork:
         # live on ChannelEndpoint for Metrics.snapshot)
         self.frames_decoded = 0
         self.mac_verify_calls = 0
+        # payload-body bytes of the frames _verify_frame decoded one at
+        # a time; the wave pass's are the decode memo's ``nbytes``
+        self._frame_bytes_decoded = 0
         # Egress plane: each flush's whole wave of folded bundles
         # arrives in ONE post_wave call, signs through the sender
         # endpoint's sign_wire_wave (payload bodies encode once per
@@ -261,14 +264,20 @@ class ChannelNetwork:
         seeded schedule): payload decodes executed, Authenticator
         verify invocations, and the shared-prefix memo's hit/miss
         tallies — the numbers bench.py's protocol sections and
-        tools/perfgate.py gate on."""
+        tools/perfgate.py gate on.  ``bytes_decoded`` /
+        ``bytes_encoded`` are the payload-body bytes behind
+        ``frames_decoded`` / ``frames_encoded``: what the codec really
+        parsed and built, memo hits left out (a signer that ignores
+        the encode memo adds nothing to ``bytes_encoded``)."""
         memo = self._decode_memo
-        ehits = emisses = 0
+        ehits = emisses = ebytes = 0
         for ep in self._endpoints.values():
             ehits += ep.encode_memo.hits
             emisses += ep.encode_memo.misses
+            ebytes += ep.encode_memo.nbytes
         return {
             "frames_decoded": self.frames_decoded,
+            "bytes_decoded": memo.nbytes + self._frame_bytes_decoded,
             "mac_verifies": self.mac_verify_calls,
             "decode_memo_hits": memo.hits,
             "decode_memo_misses": memo.misses,
@@ -276,6 +285,7 @@ class ChannelNetwork:
             # Authenticator sign invocations, and the per-endpoint
             # encode memos' pooled hit/miss tallies
             "frames_encoded": self.frames_encoded,
+            "bytes_encoded": ebytes,
             "mac_signs": self.mac_sign_calls,
             "encode_memo_hits": ehits,
             "encode_memo_misses": emisses,
@@ -697,6 +707,11 @@ class ChannelNetwork:
             return None
         ep.frames_decoded += 1
         self.frames_decoded += 1
+        # the body is the prefix less its envelope: magic, version,
+        # kind, the length-prefixed sender, the timestamp, the length
+        self._frame_bytes_decoded += len(signing_prefix) - (
+            6 + 4 + len(msg.sender_id.encode("utf-8")) + 8 + 4
+        )
         ep.mac_verify_batches += 1
         self.mac_verify_calls += 1
         if not ep.auth.verify_wire(msg, signing_prefix):

@@ -1067,14 +1067,16 @@ class FrameEncodeMemo(BoundedFifoMemo):
     insertion first, never clear-all).  ``hits``/``misses`` feed the
     transport egress metrics (``encode_memo_hit_rate`` in the bench
     sections); a miss is a payload body actually encoded — the
-    ``frames_encoded`` counter's unit."""
+    ``frames_encoded`` counter's unit — and ``nbytes`` sums those
+    bodies' lengths (``bytes_encoded``)."""
 
-    __slots__ = ("hits", "misses")
+    __slots__ = ("hits", "misses", "nbytes")
 
     def __init__(self, cap: int = 4096):
         super().__init__(cap)
         self.hits = 0
         self.misses = 0
+        self.nbytes = 0
 
 
 def encode_payload_shared(
@@ -1088,6 +1090,7 @@ def encode_payload_shared(
         return ent[1], ent[2]
     memo.misses += 1
     kind, body = _encode_payload(p)
+    memo.nbytes += len(body)
     memo.put(key, (p, kind, body))
     return kind, body
 
@@ -1173,15 +1176,17 @@ class FrameDecodeMemo(BoundedFifoMemo):
     clear-all: a hot wave sitting at the cap loses one stale entry
     per fresh one instead of periodically re-decoding its whole
     working set.  ``hits``/``misses`` feed the transport metrics
-    (decode_memo_hit_rate in the bench sections).
+    (decode_memo_hit_rate in the bench sections); ``nbytes`` sums the
+    payload bodies parsed on misses (``bytes_decoded``).
     """
 
-    __slots__ = ("hits", "misses")
+    __slots__ = ("hits", "misses", "nbytes")
 
     def __init__(self, cap: int = 4096):
         super().__init__(cap)
         self.hits = 0
         self.misses = 0
+        self.nbytes = 0
 
 
 def decode_frame_shared(
@@ -1248,6 +1253,7 @@ def decode_frame_shared(
     ent = memo.map.get(digest)
     if ent is None:
         memo.misses += 1
+        memo.nbytes += body_len
         sender = bytes(view[sender_off : sender_off + sender_len]).decode(
             "utf-8"
         )

@@ -454,13 +454,18 @@ def test_seam_spans_under_a_mesh_and_none_without(
     assert _seam_spans(FakeAnnotation.log) == []
     assert not {"ops/shard", "ops/gather"} & set(trace.totals())
     # one device's tally is what it was before the mesh columns
-    row = dict(device_calls=1, host_calls=0, host_items=0,
-               mesh_calls=0, mesh_items=0)
+    # and the bytes are those of the arrays handed in: 9 rows of two
+    # (33-byte value, 32-byte exponent) pairs; (8, 4, 64) data and
+    # shards; the (8, 8, 64) shard set hashed
+    row = dict(device_calls=1, host_calls=0, host_items=0, host_bytes=0,
+               mesh_calls=0, mesh_items=0, mesh_bytes=0)
     assert placement.snapshot() == {
-        "merkle.build_forest": dict(row, device_items=64),
-        "modexp_12x22.dual_pow": dict(row, device_items=9),
-        "rs_gf256.decode_batch": dict(row, device_items=8),
-        "rs_gf256.encode_batch": dict(row, device_items=8),
+        "merkle.build_forest": dict(row, device_items=64, device_bytes=4096),
+        "modexp_12x22.dual_pow": dict(
+            row, device_items=9, device_bytes=9 * 2 * 65
+        ),
+        "rs_gf256.decode_batch": dict(row, device_items=8, device_bytes=2048),
+        "rs_gf256.encode_batch": dict(row, device_items=8, device_bytes=2048),
     }
 
     FakeAnnotation.log = []
