@@ -390,18 +390,24 @@ class ACS:
 
     def handle_ready_batch(self, sender: str, p) -> None:
         """One sender's READYs fanned across instances
-        (ReadyBatchPayload): membership, delivered-instance filtering,
-        dedup and per-(root, instance) counting all run vectorized in
-        the EchoBank; only threshold crossings reach RBC logic."""
-        self.echo_bank.batch_ready(sender, p.proposers, p.roots)
+        (ReadyBatchPayload), as a wave of one row: membership,
+        delivered-instance filtering, dedup and per-(root, instance)
+        counting all run vectorized in the EchoBank; only threshold
+        crossings reach RBC logic."""
+        self.echo_bank.wave_ready(((sender, p.proposers, p.roots),))
 
     def handle_echo_batch(self, sender: str, p) -> None:
         """One sender's ECHOes fanned across instances
-        (EchoBatchPayload): the membership + delivered + dedup gates
-        hoist into the EchoBank's vectorized row filters; surviving
-        items park per instance via RBC's claim logic."""
-        self.echo_bank.batch_echo(
-            sender, p.shard_index, p.proposers, p.roots, p.branches, p.shards
+        (EchoBatchPayload), as a wave of one row: filters, precheck
+        and claims run vectorized in the EchoBank and the survivors
+        park as one frame."""
+        self.echo_bank.wave_echo(
+            (
+                (
+                    sender, p.shard_index, p.proposers, p.roots,
+                    p.branches, p.shards,
+                ),
+            )
         )
 
     # -- wave-routed ingest columns (protocol.router.WaveRouter) -----------
@@ -440,21 +446,17 @@ class ACS:
     def handle_echo_wave(self, items) -> None:
         """One delivery wave's ECHOes across ALL senders: each row is
         one sender's fan-out (columnar batch, or a width-1 scalar
-        ECHO) and runs the EchoBank's vectorized membership/delivered/
-        dedup filters — one handler dispatch instead of one per
-        payload."""
-        batch_echo = self.echo_bank.batch_echo
-        for sender, shard_index, proposers, roots, branches, shards in items:
-            batch_echo(
-                sender, shard_index, proposers, roots, branches, shards
-            )
+        ECHO), and the whole column is ONE vectorized pass through
+        the EchoBank (EchoBank.wave_echo, the twin of
+        handle_vote_wave -> VoteBank.wave_vote): senders x instances
+        wide, one parked frame a row."""
+        self.echo_bank.wave_echo(items)
 
     def handle_ready_wave(self, items) -> None:
         """One delivery wave's READYs across ALL senders (row shape as
-        in handle_echo_wave)."""
-        batch_ready = self.echo_bank.batch_ready
-        for sender, proposers, roots in items:
-            batch_ready(sender, proposers, roots)
+        in handle_echo_wave): one vectorized pass
+        (EchoBank.wave_ready)."""
+        self.echo_bank.wave_ready(items)
 
     def handle_coin_wave(self, items) -> None:
         """One delivery wave's coin shares across ALL senders: each

@@ -48,9 +48,9 @@ Client protocol (duck-typed; see RBC/BBA/HoneyBadger):
       round drains only dirty clients
   drain_pending(wave: HubWave) -> None
       move pending work out of client state into the wave's typed
-      columns (wave.add_branch / add_decode / add_share); a client
-      may be drained more than once per round and must only offer
-      each work item once
+      columns (wave.add_branch_frame / add_branch / add_decode /
+      add_share); a client may be drained more than once per round
+      and must only offer each work item once
   offer_combines(wave: HubWave) -> None        (optional)
       called on every client drained this round, in drain order, once
       the round's verdicts are in and before any after_crypto_flush:
@@ -61,16 +61,26 @@ Client protocol (duck-typed; see RBC/BBA/HoneyBadger):
       callbacks; run quorum logic
 
 Work item shapes (the wave's typed columns):
-  branches: add_branch(client, root: bytes32, leaf: bytes,
-            branch: tuple[bytes32,...], index: int, ctx) — verdicts
-            deliver in bulk via client.on_branch_verdicts(ctxs, oks),
-            one call per client per dispatch (a per-item closure was
-            ~5% of an N=64 epoch).  Duplicate work across clients
-            dedups AT APPEND TIME by object identity (dedup mode):
-            an in-proc cluster's N receivers share one decoded
-            payload's root/leaf/branch objects, so the content-key
-            memo is consulted once per distinct check, not once per
-            (check, receiver).
+  branches: add_branch_frame(frame) — one sender's surviving ECHO
+            items of one payload, WHOLE (protocol.echobank.EchoFrame:
+            the payload's roots / branches / shards tuples by
+            reference, the kept positions, the shard index); verdicts
+            return to the frame's bank as
+            bank.on_branch_verdicts(frames, oks) — one boolean array a
+            frame, one call a bank a dispatch — and then each instance
+            noted at drain time (wave.note_branch_client) gets
+            after_branch_verdicts(), in drain order.  Duplicate work
+            across receivers dedups AT APPEND TIME by object identity
+            (dedup mode): an in-proc cluster's N receivers share one
+            decoded payload's tuples, so ONE probe a frame (the ids of
+            the tuples and the shard index) finds the payload's slots,
+            and the content-key memo is consulted once per distinct
+            check, not once per (check, receiver).
+            add_branch(client, root: bytes32, leaf: bytes,
+            branch: tuple[bytes32,...], index: int, ctx) is the
+            per-item entry of the same column (verdicts in bulk via
+            client.on_branch_verdicts(ctxs, oks)); its slots dedup by
+            the ids of the item's own objects, as a width-1 frame's do.
   decodes:  add_decode(root: bytes32, idxs: tuple[int,...],
             shards: list[bytes] (k branch-verified shards, idxs
             order), cb(data: Optional[ndarray])) — decode + re-encode
@@ -158,23 +168,33 @@ def _park(park: Dict, meta, shares, value: int) -> None:
 class HubWave:
     """One flush's typed work columns.
 
-    Branch items are slotted: each append lands a (client, ctx, slot)
-    row, where ``slot`` indexes the unique-work list.  In dedup mode
-    (cluster-shared hub) uniqueness is established at APPEND time by
-    object identity — the in-proc transport's payload memo hands every
-    receiver the same root/leaf/branch objects, so id-keying collapses
-    a wave's N copies of one check to a single slot without hashing
-    any content.  Ids are only compared between live objects held by
-    this wave (the columns pin them), so reuse-after-GC cannot alias.
-    Decode, share and combine items stay flat lists — their
-    populations are ~N per wave per node, not ~N^2.
+    Branch work is slotted: ``b_slots`` is the unique-work list, a
+    frame lands as (frame, slot array) and a per-item append as a
+    (client, ctx, slot) row.  In dedup mode (cluster-shared hub)
+    uniqueness is established at APPEND time by object identity — the
+    in-proc transport's payload memo hands every receiver the same
+    payload tuples, so one probe a frame (the ids of its roots /
+    branches / shards tuples and the shard index) finds the slot of
+    every position the payload already has, and a wave's N copies of
+    one check collapse to a single slot without hashing any content.
+    A width-1 frame is keyed like a per-item append, by the ids of the
+    item's own root / leaf / branch objects: the router wraps a scalar
+    ECHO's fields in fresh tuples a delivery.  Ids are only compared
+    between live objects held by this wave (the columns pin them), so
+    reuse-after-GC cannot alias.  Decode, share and combine items stay
+    flat lists — their populations are ~N per wave per node, not ~N^2.
     """
 
     __slots__ = (
         "dedup",
         "b_slots",
         "b_items",
+        "b_frames",
+        "b_frame_slots",
+        "b_frame_items",
+        "b_clients",
         "_b_ids",
+        "_f_ids",
         "decodes",
         "shares",
         "combines",
@@ -185,7 +205,15 @@ class HubWave:
         self.dedup = dedup
         self.b_slots: List[Tuple] = []  # unique (root, leaf, branch, idx)
         self.b_items: List[Tuple] = []  # (client, ctx, slot)
+        self.b_frames: List[object] = []  # EchoFrame, arrival order
+        self.b_frame_slots: List[np.ndarray] = []  # slot of each kept item
+        self.b_frame_items = 0
+        # instances whose parked items a frame carries, in drain order
+        self.b_clients: List[object] = []
         self._b_ids: Dict[Tuple, int] = {}
+        # (id(roots), id(branches), id(shards), index) -> slot of each
+        # payload position (-1 = none yet)
+        self._f_ids: Dict[Tuple, np.ndarray] = {}
         self.decodes: List[Tuple] = []  # (root, idxs, [shards], cb, n)
         self.shares: List[Tuple] = []  # (pub, base, ctx, senders, shs, cb)
         self.combines: List[Tuple] = []  # (shares, threshold, group, cb)
@@ -208,6 +236,53 @@ class HubWave:
             slots.append((root, leaf, branch, index))
         self.b_items.append((client, ctx, slot))
 
+    def add_branch_frame(self, frame) -> None:
+        """One parked ECHO frame, whole: its kept items' slots are
+        found (dedup mode) by one identity probe of the payload, made
+        for the positions no receiver has offered yet, or (a hub a
+        node) are the items themselves."""
+        pos = frame.pos
+        if pos.size == 0:
+            return
+        slots = self.b_slots
+        roots, branches, shards = frame.roots, frame.branches, frame.shards
+        index = frame.shard_index
+        if not self.dedup:
+            base = len(slots)
+            slots.extend(
+                [(roots[k], shards[k], branches[k], index) for k in pos.tolist()]
+            )
+            fslots = np.arange(base, base + pos.size)
+        elif len(roots) == 1:
+            key = (id(roots[0]), id(shards[0]), id(branches[0]), index)
+            slot = self._b_ids.get(key)
+            if slot is None:
+                slot = self._b_ids[key] = len(slots)
+                slots.append((roots[0], shards[0], branches[0], index))
+            fslots = np.full(1, slot, dtype=np.int64)
+        else:
+            key = (id(roots), id(branches), id(shards), index)
+            known = self._f_ids.get(key)
+            if known is None:
+                known = self._f_ids[key] = np.full(
+                    len(roots), -1, dtype=np.int64
+                )
+            fslots = known[pos]
+            if fslots.min() < 0:
+                for k in pos[fslots < 0].tolist():
+                    known[k] = len(slots)
+                    slots.append((roots[k], shards[k], branches[k], index))
+                fslots = known[pos]
+        self.b_frames.append(frame)
+        self.b_frame_slots.append(fslots)
+        self.b_frame_items += pos.size
+
+    def note_branch_client(self, client) -> None:
+        """``client`` (an RBC instance) had parked items in the frames
+        just offered: its ``after_branch_verdicts()`` runs once the
+        verdicts have landed on its bank, in the order noted."""
+        self.b_clients.append(client)
+
     def add_decode(
         self, root: bytes, idxs: tuple, shards: list, cb, n=None
     ) -> None:
@@ -227,15 +302,29 @@ class HubWave:
 
     def has_work(self) -> bool:
         return bool(
-            self.b_items or self.decodes or self.shares or self.combines
+            self.b_items
+            or self.b_frames
+            or self.decodes
+            or self.shares
+            or self.combines
         )
 
-    def take_branches(self) -> Tuple[List[Tuple], List[Tuple]]:
-        slots, items = self.b_slots, self.b_items
-        self.b_slots, self.b_items = [], []
-        if self._b_ids:
-            self._b_ids = {}
-        return slots, items
+    def branch_items(self) -> int:
+        return len(self.b_items) + self.b_frame_items
+
+    def take_branches(self) -> Tuple[List, List, List, List, List]:
+        """(slots, per-item rows, frames, frame slot arrays, noted
+        clients) — and an empty column."""
+        out = (
+            self.b_slots, self.b_items, self.b_frames,
+            self.b_frame_slots, self.b_clients,
+        )
+        self.b_slots, self.b_items, self.b_frames = [], [], []
+        self.b_frame_slots, self.b_clients = [], []
+        self.b_frame_items = 0
+        self._b_ids = {}
+        self._f_ids = {}
+        return out
 
     def take_decodes(self) -> List[Tuple]:
         out, self.decodes = self.decodes, []
@@ -317,6 +406,10 @@ class CryptoHub:
         # observability (utils.metrics reads these)
         self.flushes = 0
         self.branch_items = 0
+        # frames the branch column took whole, and the distinct checks
+        # (slots) their items and the per-item appends came to
+        self.branch_frames = 0
+        self.branch_slots = 0
         self.decode_items = 0
         self.share_items = 0
         self.dispatches = 0
@@ -476,9 +569,10 @@ class CryptoHub:
                 if not wave.has_work():
                     break
                 rounds += 1
-                if wave.b_items:
+                if wave.b_items or wave.b_frames:
                     with trace.span(
-                        "hub", "branches", items=len(wave.b_items)
+                        "hub", "branches", items=wave.branch_items(),
+                        frames=len(wave.b_frames), slots=len(wave.b_slots),
                     ):
                         self._run_branches(*wave.take_branches())
                     if self._dirty:
@@ -534,16 +628,27 @@ class CryptoHub:
     # -- executors ---------------------------------------------------------
 
     def _run_branches(
-        self, slots: List[Tuple], items: List[Tuple]
+        self,
+        slots: List[Tuple],
+        items: List[Tuple],
+        frames: List = (),
+        frame_slots: List = (),
+        clients: List = (),
     ) -> None:
         """Branch proofs grouped by (depth, leaf length) — one
         merkle.verify_batch per group (trees of one roster share a
         depth, so this is ~one group per wave).  Content-key memo
         lookups run per unique SLOT (the wave already id-deduped the
-        N-receiver copies), and verdicts deliver in BULK per client
-        (``on_branch_verdicts(ctxs, oks)``): a wave's N^2 echoes cost
-        one call per instance, not one closure each."""
-        self.branch_items += len(items)
+        N-receiver copies), and verdicts deliver in BULK: a frame's as
+        one boolean array to its bank (``on_branch_verdicts(frames,
+        oks)``, one call a bank), per-item appends' per client
+        (``on_branch_verdicts(ctxs, oks)``); then every noted instance
+        runs ``after_branch_verdicts()``, in drain order."""
+        self.branch_items += len(items) + sum(
+            fs.size for fs in frame_slots
+        )
+        self.branch_frames += len(frames)
+        self.branch_slots += len(slots)
         verdicts: List[bool] = [False] * len(slots)
         if self.dedup:
             memo = self._branch_memo.map
@@ -582,6 +687,19 @@ class CryptoHub:
             ent[2].append(verdicts[slot])
         for client, ctxs, oks in by_client.values():
             client.on_branch_verdicts(ctxs, oks)
+        if frames:
+            good = np.asarray(verdicts, dtype=bool)
+            by_bank: Dict[int, Tuple[object, List, List]] = {}
+            for frame, fslots in zip(frames, frame_slots):
+                ent = by_bank.get(id(frame.bank))
+                if ent is None:
+                    ent = by_bank[id(frame.bank)] = (frame.bank, [], [])
+                ent[1].append(frame)
+                ent[2].append(good[fslots])
+            for bank, bank_frames, oks in by_bank.values():
+                bank.on_branch_verdicts(bank_frames, oks)
+        for client in clients:
+            client.after_branch_verdicts()
 
     def _verify_branch_groups(
         self, items: List[Tuple], deliver: Callable
@@ -975,6 +1093,8 @@ class CryptoHub:
             "flushes": self.flushes,
             "dispatches": self.dispatches,
             "branch_items": self.branch_items,
+            "branch_frames": self.branch_frames,
+            "branch_slots": self.branch_slots,
             "decode_items": self.decode_items,
             "share_items": self.share_items,
             "coin_issue_batches": self.coin_issue_batches,

@@ -18,32 +18,38 @@ per its own spec (reference docs/RBC-EN.md:28-45):
             READY(h) + N-2f verified shards -> decode and deliver
             (docs/RBC-EN.md:41-42).
 
-Crypto never runs on the message path: inbound ECHO proofs park in a
-pending pool (one slot per sender) and the decode+root-recheck parks
-as a request; the shared ``protocol.hub.CryptoHub`` pulls all pending
-work — across every concurrent RBC instance of the epoch — into
-batched device dispatches when some instance's quorum threshold makes
-results necessary (SURVEY.md §7 hard part 3's per-epoch accumulation
-buffers; the reference's N^2-branch-hash cost model is
+Crypto never runs on the message path: inbound ECHO proofs park in
+the roster-wide ``protocol.echobank.EchoBank`` as frame records (one a
+sender's payload) and the decode+root-recheck parks as a request; the
+shared ``protocol.hub.CryptoHub`` pulls all pending work — across
+every concurrent RBC instance of the epoch — into batched device
+dispatches when some instance's quorum threshold makes results
+necessary (SURVEY.md §7 hard part 3's per-epoch accumulation buffers;
+the reference's N^2-branch-hash cost model is
 docs/HONEYBADGER-EN.md:96).  Only the single VAL proof is verified
 inline: our own ECHO must go out immediately and nothing else would
 trigger a flush that early.
+
+A delivery wave's ECHOes and READYs do not pass through this class an
+item at a time: ``EchoBank.wave_echo`` / ``wave_ready`` take the whole
+wave as columns, and RBC hears only of threshold crossings
+(``_send_ready``, ``_maybe_deliver``, the flush request) and, after a
+verdict pass that verified an echo of its instance,
+``after_branch_verdicts``.  ``handle_echo_fast`` / ``_echo_item`` are
+the per-payload entries (VAL-adjacent ECHOes on a host, rows that
+repeat an instance, unit tests); they write the same bank arrays.
 """
 
 from __future__ import annotations
 
-from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Set
 
 from cleisthenes_tpu.config import Config
 from cleisthenes_tpu.ops.backend import BatchCrypto
 from cleisthenes_tpu.ops.payload import join_payload, split_payload
+from cleisthenes_tpu.protocol.echobank import MAX_SHARD_BYTES, EchoBank
 from cleisthenes_tpu.transport.message import RbcPayload, RbcType
 from cleisthenes_tpu.utils import trace
-
-# Per-root shard length sanity cap (a Byzantine proposer must not make
-# honest nodes buffer huge shards; envelopes are separately capped by
-# transport.message.MAX_FIELD_BYTES).
-MAX_SHARD_BYTES = 16 * 1024 * 1024
 
 # id-keyed branch-shape memo: entries hold the branch TUPLE (a few
 # hundred bytes — pinning the id against recycling, same discipline as
@@ -107,8 +113,6 @@ class RBC:
         # standalone use (unit tests) gets a private single-instance
         # bank — the same arrays, width 1.
         if bank is None:
-            from cleisthenes_tpu.protocol.echobank import EchoBank
-
             bank = EchoBank(
                 member_ids, config.f, inst_ids=[proposer], metrics=metrics,
                 quorum_large=config.quorum_large,
@@ -127,8 +131,9 @@ class RBC:
         self.hub.register((owner if scope is None else scope, epoch), self)
         # flight recorder (None = tracing off; utils/trace.py)
         self.trace = trace
-        # owner-node metrics (None in standalone unit tests): only the
-        # duplicate-vote absorption counter is touched here
+        # owner-node metrics (None in standalone unit tests): the
+        # duplicate-vote absorption counter and the per-payload echo
+        # counter (echo_items_scalar) are touched here
         self.metrics = metrics
 
         # hook set by ACS: fn(proposer_id, value_bytes)
@@ -145,17 +150,10 @@ class RBC:
         # slot is claimed at arrival; a sender whose proof later fails
         # verification has burned its one vote.
         # depth of the padded tree the proposer must have built
-        # (precomputed: _precheck runs once per delivered ECHO)
-        p = 1
-        self._depth = 0
-        while p < self.n:
-            p <<= 1
-            self._depth += 1
-        # root -> set of verified ECHO senders
-        self._echo_senders: Dict[bytes, Set[str]] = {}
-        # root -> shard_index -> shard bytes (branch-verified)
-        self._shards: Dict[bytes, Dict[int, bytes]] = {}
-        self._shard_len: Dict[bytes, int] = {}
+        self._depth = bank.depth
+        # verified-echo counts, verified shards and the verified shard
+        # length of each root live in the bank too, per
+        # (root, instance).
         # roots whose decode+recheck is wanted (ready/echo quorum hit)
         self._decode_req: Set[bytes] = set()
         self._bad_roots: Set[bytes] = set()  # failed interpolation recheck
@@ -259,12 +257,12 @@ class RBC:
         if not ok:
             return False
         # Shards of one root must agree on length (RS needs a matrix).
-        # _shard_len only ever holds BRANCH-VERIFIED lengths (set in
-        # _handle_val after _check_proof and in _make_echo_cb), so an
-        # unverified Byzantine ECHO cannot poison the expectation and
-        # wedge honest traffic (ADVICE.md round-2 high finding).
-        want_len = self._shard_len.get(root)
-        if want_len is not None and len(shard) != want_len:
+        # The bank only ever holds BRANCH-VERIFIED lengths (set in
+        # _handle_val after _check_proof and by the verdict pass), so
+        # an unverified Byzantine ECHO cannot poison the expectation
+        # and wedge honest traffic (ADVICE.md round-2 high finding).
+        want_len = self.bank.verified_len(self.index, root)
+        if want_len and len(shard) != want_len:
             return False
         return True
 
@@ -292,7 +290,9 @@ class RBC:
         if not self._check_proof(payload):
             return
         # verified: this length is now the root's authoritative one
-        self._shard_len.setdefault(payload.root_hash, len(payload.shard))
+        self.bank.set_verified_len(
+            self.index, payload.root_hash, len(payload.shard)
+        )
         self._echo_sent = True
         if self.trace is not None:
             self.trace.instant(
@@ -328,10 +328,9 @@ class RBC:
         shard_index: int,
     ) -> None:
         """docs/RBC-EN.md:35-39 (reference rbc/rbc.go:60-62) — the
-        field-level scalar entry; the columnar EchoBatchPayload path
-        runs the same claim through EchoBank.batch_echo, which hoists
-        the dedup/delivered/membership filters into vectorized row
-        operations and calls ``_echo_item`` per surviving item."""
+        field-level scalar entry; a delivery wave's ECHOes run the
+        same filters, precheck and claim as one vectorized pass in
+        ``EchoBank.wave_echo`` and never come here."""
         bank = self.bank
         si = bank.sidx.get(sender)
         if si is None:
@@ -351,18 +350,18 @@ class RBC:
         shard: bytes,
         shard_index: int,
     ) -> None:
-        """Claim + park one deduped ECHO (the per-item protocol logic
-        under both delivery paths).  The branch proof is NOT verified
-        here: the proof parks in the bank's contiguous pending slot
-        and verifies in the hub's next batched dispatch — triggered
-        below the moment this root could reach its N-f quorum."""
+        """Claim + park one deduped ECHO (the per-payload twin of
+        ``EchoBank.wave_echo``'s pass).  The branch proof is NOT
+        verified here: it parks in the bank as a frame of width 1 and
+        verifies in the hub's next batched dispatch — triggered below
+        the moment this root could reach its N-f quorum."""
+        if self.metrics is not None:
+            self.metrics.echo_items_scalar.inc()
         if not self._precheck_fields(root, branch, shard, shard_index):
             return
-        bank = self.bank
         # slot claimed; burns if the proof later fails verification
-        pot = bank.echo_claim(self.index, si, root)
-        bank.pending[self.index].append(
-            (root, sender, shard, shard_index, branch)
+        pot = self.bank.echo_park(
+            self.index, si, shard_index, root, branch, shard
         )
         self.hub.mark_dirty(self)
         if (
@@ -450,7 +449,7 @@ class RBC:
             # decode (or the shard verifications feeding it) is still
             # pending: stage the request and flush if work exists
             self._request_decode(root)
-            if root in self._decode_req or self.bank.pending[self.index]:
+            if root in self._decode_req or self.bank.has_parked[self.index]:
                 self.hub.request_flush()
             if self.delivered:
                 return  # the flush's quorum pass delivered already
@@ -466,10 +465,8 @@ class RBC:
                 proposer=self.proposer,
                 bytes=len(value),
             )
-        # free per-root buffers; the instance is terminal now — the
-        # bank's sentinel row drops every later vote vectorized
-        self._shards.clear()
-        self._echo_senders.clear()
+        # the instance is terminal now — the bank's sentinel row drops
+        # every later vote vectorized
         self._decode_req.clear()
         self.bank.deactivate(self.index)
         if self.on_deliver is not None:
@@ -479,28 +476,17 @@ class RBC:
 
     def drain_pending(self, wave) -> None:
         """Move pending crypto work into the wave's typed columns
-        (protocol.hub.HubWave): every parked ECHO proof as a branch
-        item, every staged decode whose matrix is complete as a decode
-        item (shard BYTES in index order — the hub builds each unique
-        matrix once instead of one np.stack per client)."""
-        pend = self.bank.pending[self.index]
-        if self.delivered or not (pend or self._decode_req):
+        (protocol.hub.HubWave): the bank's parked ECHO frames into the
+        branch column (whole, by the first instance drained), every
+        staged decode whose matrix is complete as a decode item (shard
+        BYTES in index order — the hub builds each unique matrix once
+        instead of one np.stack per client)."""
+        bank = self.bank
+        parked = bank.has_parked[self.index]
+        if self.delivered or not (parked or self._decode_req):
             return  # fast path: the hub may drain a client twice/round
-        # pending ECHO proofs -> batched branch verification: the
-        # bank's contiguous arrival-order slot pops WHOLESALE into the
-        # wave's branch columns (no per-root dict walk)
-        if pend:
-            self.bank.pending[self.index] = []
-            add = wave.add_branch
-            for root, sender, shard, sidx, branch in pend:
-                add(
-                    self,
-                    root,
-                    shard,
-                    branch,
-                    sidx,
-                    (root, sender, shard, sidx),
-                )
+        if parked:
+            bank.drain_parked(wave, self)
         # staged decode requests with enough verified shards; sorted:
         # _decode_req is a set of 32-byte roots, and its hash order
         # (PYTHONHASHSEED-dependent) would otherwise decide decode
@@ -509,63 +495,35 @@ class RBC:
             if root in self._decoded or root in self._bad_roots:
                 self._decode_req.discard(root)
                 continue
-            shards_map = self._shards.get(root, {})
-            if len(shards_map) < self.k:
+            got = bank.decode_shards(self.index, root, self.k)
+            if got is None:
                 continue  # stays staged until shards verify
             self._decode_req.discard(root)
-            idxs = tuple(sorted(shards_map)[: self.k])
             wave.add_decode(
-                root,
-                idxs,
-                [shards_map[i] for i in idxs],
-                self._make_decode_cb(root),
-                n=self.n,
+                root, got[0], got[1], self._make_decode_cb(root), n=self.n
             )
 
-    def on_branch_verdicts(self, ctxs, oks) -> None:
-        """Bulk ECHO-branch verdicts from the hub (one call per flush
-        instead of a per-echo closure — at N=64 the closures alone
-        were ~1.8 s of an epoch).  ctx = (root, sender, shard, sidx).
-
-        A root crossing its N-f echo quorum here stages its decode
-        request IMMEDIATELY (not in after_crypto_flush): the hub
-        re-drains verdict-marked clients before running the round's
-        decode column, so the decode rides THIS wave's single decode
-        dispatch instead of a follow-on round's."""
-        if self.delivered:
+    def after_branch_verdicts(self) -> None:
+        """The hub's branch verdicts have landed on the bank's arrays
+        (``EchoBank.on_branch_verdicts``); called once a drained
+        instance, in drain order.  A root that crossed its N-f echo
+        quorum stages its decode request IMMEDIATELY (not in
+        after_crypto_flush): the hub re-drains verdict-marked clients
+        before running the round's decode column, so the decode rides
+        THIS wave's single decode dispatch instead of a follow-on
+        round's."""
+        bank = self.bank
+        if not bank.verdict_touched[self.index]:
             return
-        shard_len = self._shard_len
-        echo_senders = self._echo_senders
-        shards = self._shards
-        re_mark = False
-        for (root, sender, shard, sidx), ok in zip(ctxs, oks):
-            if not ok:
-                # invalid: the sender's one slot stays burned, but the
-                # claim leaves the bank's quorum POTENTIAL — otherwise
-                # f parked forgeries would push pot past n-f forever
-                # and every later honest echo would request a flush
-                self.bank.echo_drop(self.index, root)
-                continue
-            # length authority comes only from verified shards; a
-            # verified shard conflicting with the established length
-            # is a Byzantine proposer mixing lengths under one tree —
-            # drop it, RS needs a rectangular matrix
-            want = shard_len.setdefault(root, len(shard))
-            if len(shard) != want:
-                self.bank.echo_drop(self.index, root)
-                continue
-            echo_senders.setdefault(root, set()).add(sender)
-            shards.setdefault(root, {})[sidx] = shard
-            re_mark = True
-        if not re_mark:
+        bank.verdict_touched[self.index] = False
+        if self.delivered:
             return
         # stage any echo-quorum decode now (same guards as
         # after_crypto_flush; _request_decode dedups staged roots)
         if self._ready_root is None:
-            quorum = self.n - self.f
-            for root, senders in echo_senders.items():
-                if len(senders) >= quorum and root not in self._bad_roots:
-                    self._request_decode(root)
+            root = bank.echo_quorum_root(self.index)
+            if root is not None and root not in self._bad_roots:
+                self._request_decode(root)
         # a staged decode may just have reached k shards — stay on
         # the hub's dirty list so this wave round (or the next)
         # collects it (no decode staged -> nothing new to offer)
@@ -591,22 +549,14 @@ class RBC:
         if self.delivered:
             return
         # N-f verified ECHOs -> stage decode (READY follows a
-        # successful root recheck, docs/RBC-EN.md:35-39)
-        for root, senders in list(self._echo_senders.items()):
-            if (
-                len(senders) >= self.n - self.f
-                and self._ready_root is None
-                and root not in self._bad_roots
-            ):
+        # successful root recheck, docs/RBC-EN.md:35-39); one root at
+        # most has them (EchoBank.quorum_row)
+        if self._ready_root is None:
+            root = self.bank.echo_quorum_root(self.index)
+            if root is not None and root not in self._bad_roots:
                 self._request_decode(root)
                 if root in self._decoded:
                     self._send_ready(root)
-        for root in list(self._decoded):
-            if (
-                self._ready_root is None
-                and len(self._echo_senders.get(root, ())) >= self.n - self.f
-            ):
-                self._send_ready(root)
         for root in self.bank.ready_roots(self.index):
             if self.delivered:
                 break

@@ -213,6 +213,15 @@ class Metrics:
         # VISIBLE — before it, absorption happened silently across a
         # dozen private sets
         self.dedup_absorbed = Counter()
+        # received ECHO items past the membership / delivered / dedup
+        # filters, by the entry that claimed them: the EchoBank's
+        # vectorized wave pass, or RBC's per-payload _echo_item.
+        # wave / (wave + scalar) is the wave path's engagement share —
+        # 100% in honest routed traffic (rows that repeat an instance
+        # and a host's width-1 serve_request ECHOes are the scalar
+        # rest).  DETERMINISTIC for a seeded schedule.
+        self.echo_items_wave = Counter()
+        self.echo_items_scalar = Counter()
         # two-frontier commit (Config.order_then_settle): epochs whose
         # ciphertext ordering committed (the ordered frontier's tally;
         # settlement lands in epochs_committed as before)
@@ -474,6 +483,12 @@ class Metrics:
         if self._roster_version is not None:
             reconfig["roster_version"] = int(self._roster_version())
         out["reconfig"] = reconfig
+        # receipt-bank block (same schema rule): how a node's received
+        # ECHO items were claimed
+        out["banks"] = {
+            "echo_items_wave": self.echo_items_wave.value,
+            "echo_items_scalar": self.echo_items_scalar.value,
+        }
         # wave-routing block: ALWAYS present with every key, zeroed on
         # bare nodes (the PR-9 schema-stability rule
         # — scrapers and the timeseries sampler must never see a key

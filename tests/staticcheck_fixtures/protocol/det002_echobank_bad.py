@@ -3,8 +3,8 @@ delivery-plane bank keeps receipt state in arrays and insertion-
 ordered dicts precisely so no set order ever reaches protocol
 decisions — a hand-rolled bank that iterates its sender/root SETS in
 hash order must still gate.  Every tagged line is the exact shape the
-real protocol.echobank avoids (its registry is a dict, its pending
-slots are lists)."""
+real protocol.echobank avoids (its registry is a dict, its parked
+frames a list in arrival order)."""
 
 
 class BadEchoBank:
@@ -14,7 +14,7 @@ class BadEchoBank:
         # receipt state as sets — the pre-bank dict-of-dicts shape
         self.echo_senders = set()
         self.ready_roots: set = set()
-        self.pending = {}
+        self.parked = {}
 
     def drain_slots(self, wave):
         # hash-order drain: wave column order would differ across

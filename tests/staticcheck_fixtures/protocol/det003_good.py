@@ -40,25 +40,24 @@ class WaveClient:
 
 
 class BankClient:
-    """The EchoBank discipline (ISSUE 9): pending proofs park in a
-    contiguous per-instance bank slot and pop WHOLESALE into the hub
-    wave — no inline verify anywhere on the receive path."""
+    """The EchoBank discipline (ISSUE 9, 35): pending proofs park in
+    the bank as frame records, in arrival order, and cross WHOLE into
+    the hub wave's branch column — no inline verify anywhere on the
+    receive path."""
 
     def __init__(self, hub, bank, index):
         self.hub = hub
         self.bank = bank
         self.index = index
 
-    def echo_item(self, root, sender, shard, shard_index, branch):
-        self.bank.pending[self.index].append(
-            (root, sender, shard, shard_index, branch)
-        )
+    def echo_item(self, frame):
+        self.bank.parked.append(frame)
+        self.bank.has_parked[self.index] = True
         self.hub.mark_dirty(self)
 
     def drain_pending(self, wave):
-        pend = self.bank.pending[self.index]
-        self.bank.pending[self.index] = []
-        for root, sender, shard, sidx, branch in pend:
-            wave.add_branch(
-                self, root, shard, branch, sidx, (root, sender)
-            )
+        parked, self.bank.parked = self.bank.parked, []
+        for frame in parked:
+            wave.add_branch_frame(frame)
+        self.bank.has_parked[self.index] = False
+        wave.note_branch_client(self)

@@ -203,6 +203,59 @@ class TestHubWaveIdDedup:
         assert len(wave2.b_slots) == 2
 
 
+    def test_frames_dedup_by_payload_and_width_one_by_item(self):
+        """A frame record lands whole: receivers sharing one decoded
+        payload's tuples share its slots by ONE probe a frame, each
+        position made a slot once, whichever receiver first keeps it;
+        a width-1 frame (the router wraps a scalar ECHO's fields in
+        fresh tuples a delivery) dedups like a per-item append, by the
+        item's own objects."""
+        import numpy as np
+
+        from cleisthenes_tpu.protocol.echobank import EchoFrame
+
+        roots = tuple(bytes([i]) * 32 for i in range(4))
+        branches = tuple((bytes([9]) * 32,) for _ in range(4))
+        shards = tuple(b"shard%d" % i for i in range(4))
+
+        def frame(bank, pos, r=roots, b=branches, s=shards):
+            pos = np.asarray(pos, dtype=np.int64)
+            return EchoFrame(
+                bank, 0, 1, pos, pos, pos, pos, r, b, s
+            )
+
+        wave = HubWave(dedup=True)
+        wave.add_branch_frame(frame("a", [0, 1, 2, 3]))
+        wave.add_branch_frame(frame("b", [3, 1]))
+        wave.add_branch_frame(frame("c", []))  # nothing kept: no frame
+        assert len(wave.b_slots) == 4 and len(wave.b_frames) == 2
+        assert wave.b_frame_slots[1].tolist() == [3, 1]
+        assert wave.branch_items() == 6
+        # a receiver that kept only part of a payload first
+        wave = HubWave(dedup=True)
+        wave.add_branch_frame(frame("a", [2]))
+        wave.add_branch_frame(frame("b", [0, 2]))
+        assert wave.b_frame_slots[1].tolist() == [1, 0]
+        assert [s[1] for s in wave.b_slots] == [b"shard2", b"shard0"]
+        # width 1: fresh wrappers, the same objects inside
+        wave = HubWave(dedup=True)
+        for bank in ("a", "b"):
+            wave.add_branch_frame(
+                frame(bank, [0], (roots[0],), (branches[0],), (shards[0],))
+            )
+        wave.add_branch(object(), roots[0], shards[0], branches[0], 1, None)
+        assert len(wave.b_slots) == 1
+        # a hub a node: every kept item is its own slot
+        wave = HubWave(dedup=False)
+        wave.add_branch_frame(frame("a", [0, 1]))
+        wave.add_branch_frame(frame("b", [0, 1]))
+        assert len(wave.b_slots) == 4
+        assert wave.b_frame_slots[1].tolist() == [2, 3]
+        slots, items, frames, fslots, clients = wave.take_branches()
+        assert (len(slots), items, len(frames), clients) == (4, [], 2, [])
+        assert not wave.has_work()
+
+
 class TestHubLiveness:
     def test_poisoned_share_burn_and_recovery(self):
         """A Byzantine dec-share burns through the batched path and the
