@@ -19,16 +19,26 @@ A served configuration whose ``cluster.wal_dir`` is not null runs with
 its validators' write-ahead logs on (``LogDir``): a fresh directory a
 run under the checkout, what a crash would leave of each log handed to
 the reference, the directory removed on every way out.
+
+A served cell whose traffic file holds ``faults`` (``fault_schedule``)
+has validators killed and restarted inside its window, under the open
+loop: ``Served`` applies each event at the first delivery-wave boundary
+at or after its time, keeps who is up and who is in service, routes the
+clients by the traffic file's model, and hands the reference what it
+needs to hold the deployment to its guarantees across the outage.
 """
 
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import os
 import pathlib
 import shutil
 import time
 from typing import Callable, Dict, List, Optional, Sequence
+
+import numpy as np
 
 from benchmarks.meters import SyncMeter
 from benchmarks.spec import SpecError
@@ -47,6 +57,9 @@ WARMUP_FILLS = (1.0, 1.0, 0.97, 0.94, 0.91)
 LOCKSTEP_WARMUP_MAX = 12
 LOCKSTEP_WARMUP_CLEAN = 2
 COMB_FILLER = (8, 512)  # groups, exponents a group
+# when the clients of a restarted validator go back to it: once it is in
+# service again (a health-checked balancer), or once its port is open
+CLIENTS_RETURN = ("in_service", "at_restart")
 
 
 def _no_tick(_now: float) -> None:
@@ -58,6 +71,65 @@ def drain_limit_s(longest_round_s: float) -> float:
     longest round where that is more.  Rounds are never cut, so the
     limit only decides whether another one starts."""
     return max(DRAIN_LIMIT_S, DRAIN_ROUNDS * longest_round_s)
+
+
+@dataclasses.dataclass(frozen=True)
+class FaultEvent:
+    at: float  # share of the window
+    kind: str  # "kill" or "restart"
+    members: tuple  # indices into the roster's ids, ascending
+
+
+@dataclasses.dataclass(frozen=True)
+class FaultSchedule:
+    events: tuple  # of FaultEvent, by time
+    clients_return: str
+
+
+def fault_schedule(traffic: Dict, ids: Sequence[str],
+                   tolerated: int) -> Optional[FaultSchedule]:
+    """The traffic file's ``faults`` (None where it has none): a list of
+    ``{"at": <share of the window>, "kill" | "restart": [<ids>]}``,
+    held to what a run can apply: the open loop, known validators, a
+    restart only of one that is down, never more than ``tolerated`` (the
+    roster's f) down at once."""
+    if "faults" not in traffic:
+        return None
+    if traffic.get("loop") != "open":
+        raise SpecError("a fault schedule needs the open loop: requests "
+                        "have to come on a schedule while validators are down")
+    clients_return = traffic.get("clients_return", CLIENTS_RETURN[0])
+    if clients_return not in CLIENTS_RETURN:
+        raise SpecError(f"clients_return is one of {CLIENTS_RETURN}, "
+                        f"not {clients_return!r}")
+    index = {nid: i for i, nid in enumerate(ids)}
+    events, down, last = [], set(), 0.0
+    for row in traffic["faults"]:
+        row = row if isinstance(row, dict) else {}
+        kinds = [k for k in ("kill", "restart") if k in row]
+        at = row.get("at")
+        if (len(kinds) != 1 or set(row) != {"at", kinds[0]}
+                or not isinstance(at, (int, float)) or not last <= at <= 1.0):
+            raise SpecError(f"a fault is {{at: a share of the window, kill or "
+                            f"restart: [ids]}}, in order of time; not {row!r}")
+        kind, names = kinds[0], row[kinds[0]]
+        if (not isinstance(names, list) or not names
+                or len(set(names)) != len(names)
+                or any(n not in index for n in names)):
+            raise SpecError(f"fault {row!r} names validators the roster lacks, "
+                            f"none, or one twice")
+        members = tuple(sorted(index[n] for n in names))
+        if kind == "kill" and down & set(members):
+            raise SpecError(f"fault {row!r} kills a validator that is down")
+        if kind == "restart" and not set(members) <= down:
+            raise SpecError(f"fault {row!r} restarts a validator that is up")
+        down = down | set(members) if kind == "kill" else down - set(members)
+        if len(down) > tolerated:
+            raise SpecError(f"fault {row!r} leaves {len(down)} validators down "
+                            f"where the roster tolerates {tolerated}")
+        events.append(FaultEvent(float(at), kind, members))
+        last = at
+    return FaultSchedule(tuple(events), clients_return)
 
 
 class RoundClock:
@@ -80,26 +152,43 @@ class RoundClock:
 
 
 def warm_shapes(crypto, group, shapes: Dict) -> None:
-    """Run each exponentiation program the cell can meet once, at the
-    sizes the configuration's file lists (``warm_shapes``: the share
-    and coin waves' sizes move with the BBA round count, each size
-    bucket is a program, and the rare ones would otherwise be met
-    first inside a window).  Through the engine's own entry points."""
-    from cleisthenes_tpu.ops import modmath
+    """Run each program the cell can meet and warm-up's rounds do not,
+    once, at the sizes the configuration's file lists (``warm_shapes``),
+    through the program's own entry points.
 
-    eng = modmath.get_engine(crypto.engine_backend, crypto.mesh, group)
-    # a grouped call is split by group size, so a small shape rides
-    # with a filler of COMB_FILLER that lifts the call over the
-    # comb's host floor
-    filler = [(group.g, [3] * COMB_FILLER[1])] * COMB_FILLER[0]
-    for groups, exps in shapes.get("comb", ()):
-        call = [(group.g, [3] * exps)] * groups
-        if exps != COMB_FILLER[1]:
-            call = call + filler
-        eng.pow_batch_grouped(call)
-    for rows in shapes.get("dual_pow", ()):
-        eng.dual_pow_batch(
-            [group.g] * rows, [3] * rows, [group.g] * rows, [5] * rows
+    ``comb`` / ``dual_pow``: the exponentiation programs (the share and
+    coin waves' sizes move with the BBA round count, each size bucket is
+    a program, and the rare ones would otherwise be met first inside a
+    window).  ``rs_mixed``: [matrices, shard length] of the RS decode
+    column's three-step path, which a wave takes whose matrices were
+    gathered from different senders (a validator that came level inside
+    an epoch holds other ECHOes than its peers); the program does not
+    bucket that path's batch axis, so every batch size is a set of
+    programs (PERF.md section 7)."""
+    if shapes.get("comb") or shapes.get("dual_pow"):
+        from cleisthenes_tpu.ops import modmath
+
+        eng = modmath.get_engine(crypto.engine_backend, crypto.mesh, group)
+        # a grouped call is split by group size, so a small shape rides
+        # with a filler of COMB_FILLER that lifts the call over the
+        # comb's host floor
+        filler = [(group.g, [3] * COMB_FILLER[1])] * COMB_FILLER[0]
+        for groups, exps in shapes.get("comb", ()):
+            call = [(group.g, [3] * exps)] * groups
+            if exps != COMB_FILLER[1]:
+                call = call + filler
+            eng.pow_batch_grouped(call)
+        for rows in shapes.get("dual_pow", ()):
+            eng.dual_pow_batch(
+                [group.g] * rows, [3] * rows, [group.g] * rows, [5] * rows
+            )
+    for matrices, length in shapes.get("rs_mixed", ()):
+        # the first k shards everywhere but in the last matrix: two
+        # erasure patterns, so the fused program refuses the wave
+        indices = np.tile(np.arange(crypto.k), (matrices, 1))
+        indices[-1] += 1
+        crypto.decode_recheck_batch(
+            indices, np.zeros((matrices, crypto.k, length), dtype=np.uint8)
         )
 
 
@@ -200,12 +289,21 @@ class LogDir:
         return [os.stat(self._log(nid)).st_size for nid in ids]
 
     def observe(self, ids: Sequence[str],
-                held_at_settle: Sequence[Sequence[int]]) -> Dict:
+                held_at_settle: Sequence[Sequence[int]],
+                served_at_settle: Sequence[Sequence[bool]] = (),
+                adopted: Optional[Dict[str, List]] = None) -> Dict:
         """Plain data for the reference; taken before the cluster is
         stopped, since a graceful close flushes and a kill does not.
         ``held_at_settle[e]`` is ``held_bytes`` as it read when the
         harness stamped epoch e settled: what an acknowledged settle
-        could count on, whatever reached the file afterwards."""
+        could count on, whatever reached the file afterwards.  Under a
+        fault schedule, ``served_at_settle[e]`` says which validators
+        were in service at that stamp (a log is held to the stamps of
+        its validator's time in service), and ``adopted[nid]`` the
+        [from, to) epochs a restarted validator may have taken over
+        from its peers: from the frontier its log left it at, to the
+        last epoch its peers had ordered or in flight (``pipeline_depth``
+        from their ordered frontier on) when it came level."""
         logs = {}
         for i, (nid, held) in enumerate(zip(ids, self.held_bytes(ids))):
             logs[nid] = {
@@ -213,6 +311,11 @@ class LogDir:
                 "held_bytes": held,
                 "held_at_settle": [row[i] for row in held_at_settle],
             }
+            if adopted is not None:
+                logs[nid]["served_at_settle"] = [
+                    row[i] for row in served_at_settle
+                ]
+                logs[nid]["adopted"] = adopted.get(nid, [])
         return {
             "durable_after": self.level,
             "durable_replicas": self.replicas,
@@ -261,6 +364,37 @@ class Served:
         self._nodes = [self.cluster.nodes[nid] for nid in self.ids]
         self._ingress = [self.cluster.ingress(nid) for nid in self.ids]
         self._ok = int(IngressStatus.OK)
+        try:
+            self.faults = fault_schedule(cell.traffic, self.ids, self.cfg.f)
+            if self.wal is None and self.faults is not None and any(
+                ev.kind == "restart" for ev in self.faults.events
+            ):
+                raise SpecError("a restart comes back from the validator's "
+                                "log: the configuration needs a wal_dir")
+        except BaseException:
+            self.close()
+            raise
+        # who is up (not killed, or restarted), who is in service (up and
+        # level with the others), and whom the clients reach; without a
+        # fault schedule all three are everybody, throughout
+        everybody = range(len(self.ids))
+        self._up = set(everybody)
+        self._serving = set(everybody)
+        self._reach = [True] * len(self.ids)
+        self._answering = list(everybody)  # the indices ``_reach`` holds true
+        self._up_nodes = self._serving_nodes = self._nodes
+        self._next_fault = 0
+        self._wave = 0  # delivery waves of the round that is running
+        self._evicted_gone = 0  # by mempools that died with their process
+        # what the fault schedule did, for the result and the reference
+        self.fault_log: List[Dict] = []  # one row an event, as applied
+        self.outages: List[Dict] = []  # one row a validator and kill
+        self._outage: Dict[int, Dict] = {}  # of those down or catching up
+        self.resubmitted = 0
+        # of the window's submissions under a schedule: the arrival and
+        # the validators (a bit each) whose OK it has
+        self._arrivals: List[Arrival] = []
+        self._acked_by: List[int] = []
         self.t_ordered: List[float] = []
         self.t_settled: List[float] = []
         # (tx, node_id, ok) of every submission, warm-up and drain too
@@ -274,21 +408,25 @@ class Served:
         self.rounds = 0
         # (start, end, bytes of log written so far) of every round
         self.round_log: List[tuple] = []
+        self.round_waves: List[int] = []  # delivery waves of every round
         # per settled epoch, what a crash would have left of each log
         # at the moment the epoch was stamped settled
         self.held_at_settle: List[List[int]] = []
+        # ... and, under a fault schedule, who was in service then
+        self.served_at_settle: List[tuple] = []
         self.clock = RoundClock(meter)
 
     # -- driving -------------------------------------------------------
 
     def _frontiers(self) -> tuple:
-        ordered = min(hb.merged_ordered_frontier for hb in self._nodes)
-        settled = min(hb.merged_settled_frontier for hb in self._nodes)
+        serving = self._serving_nodes
+        ordered = min(hb.merged_ordered_frontier for hb in serving)
+        settled = min(hb.merged_settled_frontier for hb in serving)
         return ordered, settled
 
     def _stamp(self) -> None:
-        """An epoch is ordered, or settled, once EVERY validator's
-        frontier has crossed it."""
+        """An epoch is ordered, or settled, once EVERY validator in
+        service has crossed it."""
         ordered, settled = self._frontiers()
         now = time.perf_counter()
         while len(self.t_ordered) < ordered:
@@ -298,9 +436,17 @@ class Served:
             while len(self.t_settled) < settled:
                 self.t_settled.append(now)
                 self.held_at_settle.append(held)
+            if self.faults is not None:
+                served = tuple(i in self._serving for i in range(len(self.ids)))
+                while len(self.served_at_settle) < settled:
+                    self.served_at_settle.append(served)
+        if len(self._serving) < len(self._up):
+            self._back_in_service(now)
 
     def _submit(self, a: Arrival, due: Optional[float]) -> None:
         node = a.nonce % len(self.ids)
+        if not self._reach[node]:
+            node = self._fail_over(a.nonce)
         t1 = time.perf_counter()
         ack = self._ingress[node].submit(a.client, a.nonce, a.fee, a.tx)
         t2 = time.perf_counter()
@@ -312,6 +458,9 @@ class Served:
             self.submit_s.append(t2 - t1)
             self.timed.append(a.tx)
             self.timed_ok.append(ok)
+            if self.faults is not None:
+                self._arrivals.append(a)
+                self._acked_by.append(ok << node)
 
     def _round(self, between: Callable[[], None]) -> None:
         spans = self.spans
@@ -319,8 +468,9 @@ class Served:
         start = time.perf_counter()
         with self.clock.timed():
             with spans("start_epoch"):
-                for hb in self._nodes:
+                for hb in self._up_nodes:
                     hb.start_epoch()
+            self._wave = 0
             while True:
                 with spans("step"):
                     stepped = net.step()
@@ -330,19 +480,26 @@ class Served:
                     # pass if that produced traffic
                     with spans("idle_phase"):
                         net.idle_phase()
+                self._wave += 1
                 self._stamp()
                 between()
                 if not stepped and net.pending_count() == 0:
                     break
         self.rounds += 1
+        self.round_waves.append(self._wave)
+        self._wave = 0  # between rounds: before the next one's first wave
         self.round_log.append((
             start, time.perf_counter(),
             None if self.wal is None else self.wal.written_bytes(self.ids),
         ))
 
     def _quiet(self) -> bool:
+        """Nothing waits to be proposed, every ordered epoch is settled,
+        and no restarted validator is still catching up."""
         ordered, settled = self._frontiers()
-        return self.cluster.pending() == 0 and ordered == settled
+        pending = sum(hb.pending_tx_count() for hb in self._up_nodes)
+        return (pending == 0 and ordered == settled
+                and len(self._serving) == len(self._up))
 
     def _drain(self) -> None:
         start = time.perf_counter()
@@ -350,6 +507,168 @@ class Served:
             time.perf_counter() - start < drain_limit_s(self.clock.longest_s)
         ):
             self._round(lambda: None)
+
+    # -- the fault schedule -----------------------------------------------
+
+    def _membership_changed(self) -> None:
+        self._up_nodes = [self._nodes[i] for i in sorted(self._up)]
+        self._serving_nodes = [self._nodes[i] for i in sorted(self._serving)]
+        back = (self._serving if self.faults.clients_return == "in_service"
+                else self._up)
+        self._reach = [self._clients_reach(i, back) for i in range(len(self.ids))]
+        self._answering = [i for i, ok in enumerate(self._reach) if ok]
+
+    def _clients_reach(self, i: int, back: set) -> bool:
+        """Does a client's connection to validator ``i`` get through?"""
+        return i in back
+
+    def _fail_over(self, nonce: int) -> int:
+        """Whom the client of a validator that refuses the connection
+        tries instead, at once (no timeout is modelled): one of its own
+        choosing, so that the refused transactions spread evenly over
+        the validators that answer.  (The next address in id order would
+        send the refused 5/16 of the cell's load to one validator: a hot
+        spot of the client's making, PERF.md section 6.)"""
+        answer = self._answering
+        return answer[(nonce // len(self.ids)) % len(answer)]
+
+    def _apply_faults(self, now: float, seconds: float) -> None:
+        """Every event whose time has come, in order; ``_round`` calls
+        this between delivery waves, so an event as a rule falls inside
+        an epoch with frames in flight (an idle system takes it at
+        once)."""
+        events = self.faults.events
+        while (self._next_fault < len(events)
+               and events[self._next_fault].at * seconds <= now):
+            ev = events[self._next_fault]
+            self._next_fault += 1
+            row = {
+                "kind": ev.kind,
+                "nodes": [self.ids[i] for i in ev.members],
+                "due_s": ev.at * seconds,
+                "t": time.perf_counter(),
+                "round": self.rounds,
+                "wave": self._wave,
+                "epochs_ordered": len(self.t_ordered),
+                "epochs_settled": len(self.t_settled),
+                "submissions": len(self.submissions),
+            }
+            self.fault_log.append(row)
+            if ev.kind == "kill":
+                self._kill(ev.members)
+                row["resubmitted"] = self._resubmit()
+            else:
+                self._restart(ev.members, row)
+            row["took_s"] = time.perf_counter() - row["t"]
+
+    def _kill(self, members: Sequence[int]) -> None:
+        for i in members:
+            nid = self.ids[i]
+            self.cluster.crash(nid)  # its frames in flight die with it
+            self._up.discard(i)
+            self._serving.discard(i)
+            self._outage[i] = {
+                "node": nid,
+                "t_kill": time.perf_counter(),
+                "ordered_at_kill": self._nodes[i].merged_ordered_frontier,
+                "settled_at_kill": self._nodes[i].merged_settled_frontier,
+            }
+            self.outages.append(self._outage[i])
+        self._membership_changed()
+
+    def _resubmit(self) -> int:
+        """The clients of the validators that just went: a mempool is
+        memory, so what only validators now out of reach have
+        acknowledged, and no epoch stamped so far has settled, is the
+        client's to send again (it saw its connection drop).  At once,
+        round robin over the validators that answer.  It stays one
+        attempt, timed from its first due time."""
+        gone = sum(1 << i for i, ok in enumerate(self._reach) if not ok)
+        waiting = {
+            self.timed[k]: k
+            for k, by in enumerate(self._acked_by) if by and not by & ~gone
+        }
+        ledger = self._serving_nodes[0].merged_batches
+        for epoch in range(len(self.t_settled)):
+            for txs in ledger[epoch].contributions.values():
+                for tx in txs:
+                    waiting.pop(tx, None)
+        answer = self._answering
+        for turn, k in enumerate(sorted(waiting.values())):
+            a, node = self._arrivals[k], answer[turn % len(answer)]
+            ack = self._ingress[node].submit(a.client, a.nonce, a.fee, a.tx)
+            ok = int(ack.status) == self._ok
+            self.submissions.append((a.tx, self.ids[node], ok))
+            self._acked_by[k] |= ok << node
+        self.resubmitted += len(waiting)
+        return len(waiting)
+
+    def _restart(self, members: Sequence[int], event: Dict) -> None:
+        """Each validator's process comes back from its log, in id
+        order, and asks its peers for what it missed, as
+        ``ValidatorHost.listen`` does for a node whose log left it past
+        epoch 0 (transport/host.py).  The harness takes the new
+        ``HoneyBadger`` and the new ingress plane in place of the old."""
+        for i in members:
+            nid = self.ids[i]
+            self._evicted_gone += self._nodes[i].mempool.evicted
+            t1 = time.perf_counter()
+            hb = self.cluster.restart_node(nid)
+            t2 = time.perf_counter()
+            self._nodes[i] = hb
+            self._ingress[i] = self.cluster.ingress(nid)
+            hb.request_catchup()
+            self._up.add(i)
+            self._outage[i].update(
+                t_restart_event=event["t"],
+                round_restart=event["round"],
+                t_restart=t1,
+                replay_s=t2 - t1,
+                settled_at_restart=hb.merged_settled_frontier,
+                ordered_epochs_at_restart=len(self.t_ordered),
+            )
+        self._membership_changed()
+
+    def _back_in_service(self, now: float) -> None:
+        """A restarted validator is in service again from the moment its
+        settled frontier equals the highest of those in service."""
+        top = max(hb.merged_settled_frontier for hb in self._serving_nodes)
+        back = [
+            i for i in sorted(self._up - self._serving)
+            if self._nodes[i].merged_settled_frontier >= top
+        ]
+        # the epochs its peers have ordered, or have in flight, at this
+        # moment started without it: it may yet adopt those
+        adopted_to = self.cfg.pipeline_depth + max(
+            hb.merged_ordered_frontier for hb in self._serving_nodes
+        )
+        for i in back:
+            self._serving.add(i)
+            self._outage.pop(i).update(
+                t_in_service=now,
+                settled_in_service=self._nodes[i].merged_settled_frontier,
+                adopted_to=adopted_to,
+                round_in_service=self.rounds,
+                submissions_in_service=len(self.submissions),
+            )
+        if back:
+            self._membership_changed()
+
+    def fault_report(self) -> Optional[Dict]:
+        """What the schedule did, as plain data for the result line's
+        metrics: the events as applied, each outage, the waves of every
+        round; None without a schedule."""
+        if self.faults is None:
+            return None
+        return {
+            "events": self.fault_log,
+            "outages": self.outages,
+            "resubmitted": self.resubmitted,
+            "round_waves": self.round_waves,
+            "never_back": sorted(
+                self.ids[i] for i in self._up - self._serving
+            ),
+        }
 
     def warm_up(self) -> None:
         """The listed shapes, if any, then full rounds."""
@@ -390,6 +709,8 @@ class Served:
                     while nxt < count and due[nxt] <= now:
                         self._submit(arrivals[nxt], t0 + due[nxt])
                         nxt += 1
+            if self.faults is not None:
+                self._apply_faults(now, seconds)
 
         while True:
             pump()
@@ -405,6 +726,10 @@ class Served:
                 continue
             self._round(pump)
         t_end = time.perf_counter()
+        # the fallback: where one round ran from before the stretch the
+        # trace starts in to the window's end, no boundary fell inside
+        # it, and the trace starts here, over the drain's rounds
+        tick(t_end - t0)
         with spans("drain"):
             self._drain()
         return {"t0": t0, "t_end": t_end}
@@ -468,14 +793,16 @@ class Served:
     def observe(self) -> Dict:
         """Plain data for the reference: nothing of the program's
         objects but the transactions' bytes and the ledgers' shape."""
-        return {
+        obs = {
             "node_ids": list(self.ids),
             "submissions": self.submissions,
             "ledgers": {
                 nid: [b.contributions for b in hb.merged_batches]
                 for nid, hb in zip(self.ids, self._nodes)
             },
-            "evicted": sum(hb.mempool.evicted for hb in self._nodes),
+            "evicted": self._evicted_gone + sum(
+                hb.mempool.evicted for hb in self._nodes
+            ),
             "ordered": {
                 nid: hb.merged_ordered_frontier
                 for nid, hb in zip(self.ids, self._nodes)
@@ -485,10 +812,30 @@ class Served:
                 for nid, hb in zip(self.ids, self._nodes)
             },
             "batch_size": max(self.cfg.batch_size, self.cfg.n),
-            "wal": None if self.wal is None else self.wal.observe(
-                self.ids, self.held_at_settle
-            ),
+            "wal": None,
         }
+        adopted = None
+        if self.faults is not None:
+            obs["faults"] = {
+                "pipeline_depth": self.cfg.pipeline_depth,
+                "outages": self.outages,
+                "down_at_rest": [
+                    nid for i, nid in enumerate(self.ids) if i not in self._up
+                ],
+            }
+            # one that is never back in service is still adopting
+            adopted = {
+                nid: [[o["settled_at_restart"],
+                       o.get("adopted_to", len(self.t_settled))]
+                      for o in self.outages
+                      if o["node"] == nid and "settled_at_restart" in o]
+                for nid in self.ids
+            }
+        if self.wal is not None:
+            obs["wal"] = self.wal.observe(
+                self.ids, self.held_at_settle, self.served_at_settle, adopted
+            )
+        return obs
 
     def close(self) -> None:
         """Stops the cluster and takes the logs away; run.py calls it
@@ -627,4 +974,5 @@ class Lockstep:
 EXECUTORS = {"served": Served, "lockstep": Lockstep}
 
 __all__ = ["Served", "Lockstep", "LogDir", "Spans", "EXECUTORS", "RoundClock",
-           "drain_limit_s", "fresh_log_dir"]
+           "drain_limit_s", "fresh_log_dir", "fault_schedule", "FaultSchedule",
+           "FaultEvent", "CLIENTS_RETURN"]

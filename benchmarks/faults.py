@@ -36,6 +36,20 @@ durability broken underneath one validator:
                     record, whole and in order; only the prefix held
                     when each epoch was stamped settled shows that the
                     settle was acknowledged before it was durable
+
+and, where the cell's traffic has a fault schedule, the outage itself
+broken (the first validator the schedule kills is the victim):
+
+  resubmit_left_out  the clients' resubmission at the kill is skipped:
+                     what only the killed validators had admitted and
+                     not yet seen settled is gone (``lost``)
+  restart_behind     the victim's log is cut back by its last whole
+                     batch records before it restarts, and the new
+                     process never asks its peers for what it missed:
+                     it stays behind for good (``forked``)
+  kill_not_taken     the victim is left connected: its peers and its
+                     clients go on reaching it and it goes on proposing
+                     while the books say it is down (``killed_proposed``)
 """
 
 from __future__ import annotations
@@ -165,6 +179,77 @@ def _wal_behind(executor) -> None:
     executor.observe = idle_then_observe
 
 
+# -- served, with a fault schedule --------------------------------------------
+
+CUT_BACK_BATCHES = 2  # batch records taken off the victim's log
+
+
+def _victim(executor) -> int:
+    return executor.faults.events[0].members[0]
+
+
+def _resubmit_left_out(executor) -> None:
+    executor._resubmit = lambda: 0
+
+
+def _restart_behind(executor) -> None:
+    from benchmarks import reference
+
+    cluster = executor.cluster
+    victim = executor.ids[_victim(executor)]
+    restart_node = cluster.restart_node
+
+    def restart_behind(nid):
+        if nid == victim:
+            path = executor.wal._log(nid)
+            with open(path, "rb") as fh:
+                records, _end = reference.wal_records(fh.read())
+            batches = [i for i, rec in enumerate(records)
+                       if rec[0] == reference.WAL_BATCH]
+            keep = batches[-CUT_BACK_BATCHES] if len(
+                batches) >= CUT_BACK_BATCHES else 0
+            cluster.nodes[nid].batch_log.close()
+            with open(path, "r+b") as fh:
+                # ... and whatever precedes that batch record's epoch
+                # in the file stays: a whole prefix, as a crash leaves
+                fh.truncate(records[keep - 1][3] if keep else 0)
+        hb = restart_node(nid)
+        if nid == victim:
+            hb.request_catchup = lambda: None
+            hb._request_catchup = lambda force=False: None
+        return hb
+
+    cluster.restart_node = restart_behind
+
+
+def _kill_not_taken(executor) -> None:
+    victim = _victim(executor)
+    cluster = executor.cluster
+    crash, reach = cluster.crash, executor._clients_reach
+    changed = executor._membership_changed
+
+    def crash_but_one(nid):
+        if nid != executor.ids[victim]:
+            crash(nid)
+
+    def membership_changed():
+        changed()
+        if victim not in executor._up:
+            # the process lives: it is driven as the others are
+            executor._up_nodes = executor._up_nodes + [executor._nodes[victim]]
+
+    cluster.crash = crash_but_one
+    executor._clients_reach = lambda i, back: i == victim or reach(i, back)
+    executor._membership_changed = membership_changed
+
+
+OUTAGE_FAULTS: Dict[str, Callable] = {
+    "resubmit_left_out": _resubmit_left_out,
+    "restart_behind": _restart_behind,
+    "kill_not_taken": _kill_not_taken,
+}
+
+
 WAL_FAULTS: Dict[str, Callable] = {
     "wal_skipped": _wal_skipped,
     "wal_unflushed": _wal_unflushed,
@@ -231,12 +316,19 @@ FAULTS: Dict[str, Dict[str, Callable]] = {
 
 
 def for_cell(cell) -> Dict[str, Callable]:
-    """The faults this cell can have: its executor's, and the log's
-    where its configuration has one (elsewhere they break nothing)."""
+    """The faults this cell can have: its executor's, the log's where
+    its configuration has one, and the outage's where its traffic has a
+    fault schedule (``restart_behind`` where that restarts somebody);
+    elsewhere they break nothing."""
     faults = dict(FAULTS[cell.config["executor"]])
     if cell.config.get("cluster", {}).get("wal_dir") is not None:
         faults.update(WAL_FAULTS)
+    schedule = cell.traffic.get("faults")
+    if schedule:
+        faults.update(OUTAGE_FAULTS)
+        if not any("restart" in row for row in schedule):
+            del faults["restart_behind"]
     return faults
 
 
-__all__ = ["FAULTS", "WAL_FAULTS", "for_cell"]
+__all__ = ["FAULTS", "WAL_FAULTS", "OUTAGE_FAULTS", "for_cell"]

@@ -9,7 +9,7 @@ import functools
 import os
 import statistics
 import time
-from typing import Dict, Tuple
+from typing import Dict, List, Tuple
 
 import numpy as np
 
@@ -20,9 +20,11 @@ _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 
 class CompileMeter:
-    """Counts XLA compilations and the seconds they took.  With a warm
-    persistent cache a compilation is a cache read: same count, far
-    fewer seconds, and ``cache_hits`` says how many were reads."""
+    """Counts XLA compilations and the seconds they took, and keeps
+    each one's program name (the event's ``fun_name``) in order, so
+    that a run which compiles inside its window can say what.  With a
+    warm persistent cache a compilation is a cache read: same count,
+    far fewer seconds, and ``cache_hits`` says how many were reads."""
 
     def __init__(self) -> None:
         import jax.monitoring
@@ -30,13 +32,15 @@ class CompileMeter:
         self.count = 0
         self.seconds = 0.0
         self.cache_hits = 0
+        self.names: List[str] = []
         jax.monitoring.register_event_duration_secs_listener(self._on_secs)
         jax.monitoring.register_event_listener(self._on_event)
 
-    def _on_secs(self, event: str, duration: float, **_kw) -> None:
+    def _on_secs(self, event: str, duration: float, **kw) -> None:
         if event == _COMPILE_EVENT:
             self.count += 1
             self.seconds += duration
+            self.names.append(str(kw.get("fun_name", "?")))
 
     def _on_event(self, event: str, **_kw) -> None:
         if event == _CACHE_HIT_EVENT:

@@ -16,11 +16,28 @@ the reference is the set of answers that the guarantees allow):
   duplicated  transactions settled more than once
   foreign     settled transactions that nobody submitted
   misplaced   settled in the contribution of another validator than
-              the one that admitted them
+              those that admitted them (a transaction's homes are every
+              validator that acknowledged it OK: one, unless its client
+              had to send it again after its validator was killed)
   forked      (validator, epoch) batches whose bytes differ from the
               first validator's
   unsettled   ordered epochs not yet settled at drain, worst validator
   oversize    batches with more transactions than the batch size
+
+Under a fault schedule (validators killed inside the window, and as a
+rule restarted from their logs) the same numbers hold across the
+outage: a transaction that was sent again counts once; ``forked`` and
+``unsettled`` are over every validator at rest, the restarted ones
+included, so one that never catches up, or catches up to something
+else, is ``forked`` (only a validator the schedule leaves down is left
+out).  And one number more:
+
+  killed_proposed  contributions of a killed validator in epochs that
+                   were stamped ordered while it was down, beyond the
+                   ``pipeline_depth`` epochs from its own ordered
+                   frontier on that it may have had in flight when it
+                   was killed: a dead validator that goes on proposing
+                   means the kill did not take
 
 A configuration with a write-ahead log states how durable a settled
 batch is.  The harness hands over each validator's log and the length
@@ -79,15 +96,32 @@ def _flatten(contributions: Dict[str, List[bytes]]) -> List[bytes]:
     return out
 
 
+def _at_rest(obs: Dict) -> List[str]:
+    """The validators that are up once the system is at rest: all,
+    but for those a fault schedule killed and did not restart."""
+    down = (obs.get("faults") or {}).get("down_at_rest", ())
+    return [nid for nid in obs["node_ids"] if nid not in down]
+
+
+def witness(obs: Dict) -> str:
+    """The validator whose ledger the others' are compared with, and
+    the latencies read against: the first that is up at rest."""
+    return _at_rest(obs)[0]
+
+
 def compare_served(obs: Dict) -> Numbers:
     """``obs``: node_ids, submissions [(tx, node_id, acked_ok)],
     ledgers {node_id: [ {proposer: [tx]} per epoch ]}, evicted,
-    ordered / settled {node_id: frontier}, batch_size."""
-    ids = list(obs["node_ids"])
-    admitted_at: Dict[bytes, str] = {}
+    ordered / settled {node_id: frontier}, batch_size; under a fault
+    schedule also faults: pipeline_depth, down_at_rest [node_id],
+    outages [{node, ordered_at_kill, ordered_epochs_at_restart (the
+    epochs stamped ordered when it was restarted; absent if it never
+    was)}]."""
+    ids = _at_rest(obs)
+    admitted_at: Dict[bytes, Tuple[str, ...]] = {}
     for tx, node_id, ok in obs["submissions"]:
         if ok:
-            admitted_at[tx] = node_id
+            admitted_at[tx] = admitted_at.get(tx, ()) + (node_id,)
     first = obs["ledgers"][ids[0]]
     settled_in: Dict[bytes, int] = {}
     duplicated = foreign = misplaced = oversize = 0
@@ -100,10 +134,10 @@ def compare_served(obs: Dict) -> Numbers:
                     duplicated += 1
                     continue
                 settled_in[tx] = epoch
-                home = admitted_at.get(tx)
-                if home is None:
+                homes = admitted_at.get(tx)
+                if homes is None:
                     foreign += 1
-                elif home != proposer:
+                elif proposer not in homes:
                     misplaced += 1
         if size > obs["batch_size"]:
             oversize += 1
@@ -120,7 +154,7 @@ def compare_served(obs: Dict) -> Numbers:
     unsettled = max(
         max(0, obs["ordered"][nid] - obs["settled"][nid]) for nid in ids
     )
-    return {
+    numbers = {
         "lost": (lost, 0),
         "duplicated": (duplicated, 0),
         "foreign": (foreign, 0),
@@ -129,13 +163,25 @@ def compare_served(obs: Dict) -> Numbers:
         "unsettled": (unsettled, 0),
         "oversize": (oversize, 0),
     }
+    faults = obs.get("faults")
+    if faults is not None:
+        killed_proposed = 0
+        for outage in faults["outages"]:
+            lo = outage["ordered_at_kill"] + faults["pipeline_depth"]
+            hi = outage.get("ordered_epochs_at_restart", len(first))
+            killed_proposed += sum(
+                1 for contributions in first[lo:hi]
+                if contributions.get(outage["node"])
+            )
+        numbers["killed_proposed"] = (killed_proposed, 0)
+    return numbers
 
 
 def settled_epochs(obs: Dict) -> Dict[bytes, int]:
     """tx -> the epoch the first validator settled it in (first
     occurrence): what the latencies are read against."""
     out: Dict[bytes, int] = {}
-    for epoch, contributions in enumerate(obs["ledgers"][obs["node_ids"][0]]):
+    for epoch, contributions in enumerate(obs["ledgers"][witness(obs)]):
         for txs in contributions.values():
             for tx in txs:
                 out.setdefault(tx, epoch)
@@ -229,6 +275,24 @@ def compare_wal(obs: Dict) -> Numbers:
     idle.  (An epoch with no stamp, one a validator settled ahead of
     the slowest, is judged by ``held_bytes``.)
 
+    Under a fault schedule a log also has ``served_at_settle`` (was its
+    validator in service when epoch e was stamped settled?) and
+    ``adopted`` ([from, to) epochs a restarted validator may have taken
+    over from its peers: from where its log left it to the last epoch
+    they had ordered or in flight when it came level).  A log is held to a settle stamp
+    (``wal_short``, ``wal_late``) only where its validator was in
+    service then: a validator that is down acknowledges nothing.  At
+    rest every log is held in full (``wal_missing``, ``wal_wrong``,
+    ``wal_torn``): a restarted validator's log has every epoch it
+    settled, byte for byte the agreed batch.  For an adopted epoch
+    ``core/ledger.py`` promises the CLOG record ("appended on every
+    commit, local or adopted via catch-up") and no COrd record of the
+    validator's own (the ordering was never run there): so
+    ``wal_unordered`` asks of an adopted epoch only that a COrd record,
+    where there is one (the epoch was ordered before the kill and
+    settled after the restart, or its ordering was adopted ahead of its
+    plaintext), comes before its CLOG record.
+
       wal_short      transactions OK-acked and settled whose batch
                      record was held in time in fewer than
                      ``durable_replicas`` logs
@@ -254,7 +318,7 @@ def compare_wal(obs: Dict) -> Numbers:
     admitted = {tx for tx, _node, ok in obs["submissions"] if ok}
     copies = {
         tx: 0
-        for contributions in obs["ledgers"][ids[0]]
+        for contributions in obs["ledgers"][witness(obs)]
         for txs in contributions.values() for tx in txs if tx in admitted
     }
     missing = late = wrong = unordered = torn = 0
@@ -267,9 +331,14 @@ def compare_wal(obs: Dict) -> Numbers:
         torn += len(held) - end
         ledger = obs["ledgers"][nid]
         stamped = log["held_at_settle"]
+        served = log.get("served_at_settle")
+        adopted = log.get("adopted", ())
 
         def in_time(epoch: int, ends_at: int) -> bool:
             return epoch >= len(stamped) or ends_at <= stamped[epoch]
+
+        def held_to_stamp(epoch: int) -> bool:
+            return served is None or epoch >= len(served) or served[epoch]
 
         # epoch -> (place, end) of its first record of the kind
         batch_at: Dict[int, Tuple[int, int]] = {}
@@ -293,16 +362,19 @@ def compare_wal(obs: Dict) -> Numbers:
                 newest = max(newest, epoch)
                 if epoch not in batch_at:
                     batch_at[epoch] = (place, ends_at)
-                    if in_time(epoch, ends_at):
+                    if held_to_stamp(epoch) and in_time(epoch, ends_at):
                         for txs in contributions.values():
                             read_back.update(txs)
         for epoch in range(len(ledger)):
             batch, first = batch_at.get(epoch), ordered_at.get(epoch)
             if batch is None:
                 missing += 1
-            if first is None or (batch is not None and first[0] > batch[0]):
+            if first is None:
+                if not any(lo <= epoch < hi for lo, hi in adopted):
+                    unordered += 1
+            elif batch is not None and first[0] > batch[0]:
                 unordered += 1
-            if any(
+            if held_to_stamp(epoch) and any(
                 at is not None and not in_time(epoch, at[1])
                 for at in (batch, first)
             ):
@@ -435,5 +507,5 @@ def verdict(numbers: Numbers) -> bool:
 
 
 __all__ = ["compare_served", "compare_wal", "wal_records", "compare_lockstep",
-           "settled_epochs", "predict_batch", "CoinReference", "verdict",
-           "Numbers"]
+           "settled_epochs", "witness", "predict_batch", "CoinReference",
+           "verdict", "Numbers"]

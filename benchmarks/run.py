@@ -13,13 +13,20 @@ the one JSON object of the result.  The numbers compared, each beside
 its limit, are the last lines of stderr and the last key of the result.
 A configuration with a write-ahead log keeps its logs in a directory of
 this run's own under ``.bench_wal/`` in the checkout; they are read
-back as part of the comparison and removed on every way out.
+back as part of the comparison and removed on every way out.  A cell
+whose traffic file holds a fault schedule has validators killed and
+restarted from those logs inside the window (benchmarks/executors.py),
+and says what the schedule did on ``[bench] fault`` lines.
 
 ``--trace 0`` reports the cell's end-to-end metrics, ``--trace 1`` its
 per-layer metrics: the profiler runs over the window's last whole loop
 iterations (rounds, epochs), from the first boundary within TRACE_SECONDS
 (2.5), or within the longest iteration timed so far where that is more, of
-the window's nominal end; the counters cover the whole of the window.
+the window's nominal end; in a cell with a fault schedule, of its last
+event, so that the restart, the round that catches up, what the clients
+send after it and the drain are what is traced (an open loop with no
+boundary from there to its end starts the trace as the window closes,
+over the drain); the counters cover the whole of the window.
 """
 
 from __future__ import annotations
@@ -43,6 +50,8 @@ HERE = pathlib.Path(__file__).resolve().parent
 ROOT = HERE.parent
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
+
+from benchmarks.latency import latencies_ms, percentile  # noqa: E402
 
 TRACE_SECONDS = 2.5
 TRACE_DIR = ROOT / ".bench_trace"
@@ -91,14 +100,6 @@ def memory_peak_bytes(chips: int) -> int:
     return peak
 
 
-def percentile(sorted_vals: List[float], q: float) -> float:
-    """Nearest rank: the smallest value with at least q of the sample
-    at or below it."""
-    if not sorted_vals:
-        return math.inf
-    return sorted_vals[max(0, math.ceil(q * len(sorted_vals)) - 1)]
-
-
 def trace_start_s(seconds: float, longest_round_s: float) -> float:
     """From when on, in seconds of the window, a loop boundary starts
     the trace: TRACE_SECONDS before the nominal end, or one longest
@@ -107,12 +108,24 @@ def trace_start_s(seconds: float, longest_round_s: float) -> float:
     return max(0.0, seconds - max(TRACE_SECONDS, longest_round_s))
 
 
+def trace_anchor_s(seconds: float, faults) -> float:
+    """The moment of the window (``seconds`` long) that a traced run
+    has to see, which ``trace_start_s`` then starts before: the nominal
+    end; under a fault schedule (``executors.FaultSchedule``) its last
+    event, which is what the cell exists for and falls seconds before
+    the end."""
+    if faults is None or not faults.events:
+        return seconds
+    return faults.events[-1].at * seconds
+
+
 class Tracer:
     """The profiler over the window's last whole loop iterations.
     ``tick`` is called at every loop boundary; the trace starts at the
-    first one at or after ``trace_start_s`` and ends with the window.
-    The longest iteration is what warm-up timed (``warm_round_s``) and
-    what the ticks of this window lie apart."""
+    first one at or after ``trace_start_s`` of ``seconds`` (the window's
+    length, or ``trace_anchor_s``) and ends with the window.  The
+    longest iteration is what warm-up timed (``warm_round_s``) and what
+    the ticks of this window lie apart."""
 
     def __init__(self, on: bool, seconds: float, spans, name: str,
                  counters: Callable[[], Dict],
@@ -207,21 +220,10 @@ class Tracer:
 
 def _latency_pctl(run: Dict, stamps: str, q: float) -> float:
     """Percentile of due time -> the stamp of the epoch that settled
-    the transaction, over EVERY transaction due in the window; one that
-    was refused or never settled is beyond every percentile."""
+    the transaction, over EVERY transaction due in the window."""
     cache = run.setdefault("_latencies_ms", {})
     if stamps not in cache:
-        settled_in = run["settled_in"]
-        at = run[stamps]
-        out = []
-        for tx, due, ok in zip(run["timed"], run["due"], run["timed_ok"]):
-            epoch = settled_in.get(tx) if ok else None
-            if epoch is None or epoch >= len(at):
-                out.append(math.inf)
-            else:
-                out.append((at[epoch] - due) * 1e3)
-        out.sort()
-        cache[stamps] = out
+        cache[stamps] = latencies_ms(run, stamps)
     return percentile(cache[stamps], q)
 
 
@@ -237,6 +239,47 @@ END_TO_END: Dict[str, Callable[[Dict], float]] = {
     "order_p50_ms": lambda r: _latency_pctl(r, "t_ordered", 0.50),
     "setup_s": lambda r: r["setup_s"],
 }
+
+
+def _say_faults(faults: Dict, t0: float, t_settled: List[float],
+                rounds: List[tuple]) -> None:
+    """The fault schedule as it was applied, on ``[bench]`` lines."""
+    for ev in faults["events"]:
+        near = range(max(0, ev["round"] - 2), min(len(rounds), ev["round"] + 5))
+        say(f"fault: rounds {near[0]}..{near[-1]} around the {ev['kind']}, "
+            "seconds (delivery waves): " + ", ".join(
+                f"{rounds[k][1] - rounds[k][0]:.2f} ({faults['round_waves'][k]})"
+                for k in near
+            ))
+    for ev in faults["events"]:
+        nodes = ev["nodes"]
+        line = (
+            f"fault: {ev['kind']} {nodes[0]}..{nodes[-1]} ({len(nodes)}) at "
+            f"{ev['t'] - t0:.3f} s (due {ev['due_s']:.3f}), "
+            + (f"in round {ev['round']} after delivery wave {ev['wave']} of its "
+               f"{faults['round_waves'][ev['round']]}" if ev["wave"] else
+               f"between rounds, before round {ev['round']}")
+            + f", {ev['epochs_ordered']} epochs "
+            f"stamped ordered and {ev['epochs_settled']} settled (warm-up's among "
+            f"them); took {ev['took_s'] * 1e3:.1f} ms"
+        )
+        if ev["kind"] == "kill":
+            line += f", {ev['resubmitted']} transactions resubmitted"
+        say(line)
+    for o in faults["outages"]:
+        if "t_restart" not in o:
+            continue
+        nid = o["node"]
+        stamped = sum(1 for t in t_settled if o["t_kill"] < t <= o["t_restart"])
+        back = (
+            f"in service {o['t_in_service'] - o['t_restart']:.3f} s later at "
+            f"epoch {o['settled_in_service']}, in round {o['round_in_service']}"
+            if "t_in_service" in o else "never back in service"
+        )
+        say(f"fault: {nid} killed at epoch {o['settled_at_kill']}, {stamped} "
+            f"epochs stamped settled by the others while it was down, replayed "
+            f"{o['settled_at_restart']} epochs from its log in "
+            f"{o['replay_s'] * 1e3:.1f} ms, {back}")
 
 
 # -- one run ------------------------------------------------------------------
@@ -325,9 +368,12 @@ def _run_cell(
         fault(executor)
     gc.collect()
     gc.freeze()  # set-up's garbage is not collected inside the window
-    tracer = Tracer(trace, seconds, spans, workload, executor.counters,
-                    executor.clock.longest_s)
+    tracer = Tracer(
+        trace, trace_anchor_s(seconds, getattr(executor, "faults", None)),
+        spans, workload, executor.counters, executor.clock.longest_s,
+    )
     before = executor.counters()
+    compile_before = (meter.seconds, meter.cache_hits)
     setup_s = time.perf_counter() - t_process
     say(
         f"set-up {setup_s:.2f} s: imports and gate {t_b - t_process:.2f}, "
@@ -350,6 +396,8 @@ def _run_cell(
     reduced = tracer.finish()
     peak = memory_peak_bytes(cell.chips)
     after = executor.counters()
+    compile_s, cache_hits = (meter.seconds - compile_before[0],
+                             meter.cache_hits - compile_before[1])
 
     # ---- what the window did, as plain data ----
     t0, t_end = ends["t0"], ends["t_end"]
@@ -378,6 +426,7 @@ def _run_cell(
                 f"{obs['wal']['syncs']} syncs")
             durable = reference.compare_wal(obs)
         executor.close()
+        faults = executor.fault_report()
         settled_in = reference.settled_epochs(obs)
         t_settled = executor.t_settled
         run.update(
@@ -391,7 +440,10 @@ def _run_cell(
             submit_s=executor.submit_s,
             rounds=executor.round_log,
         )
-        ledger = obs["ledgers"][obs["node_ids"][0]]
+        if faults is not None:
+            run["faults"] = faults
+            _say_faults(faults, t0, t_settled, executor.round_log)
+        ledger = obs["ledgers"][reference.witness(obs)]
         in_window = [
             e for e, at in enumerate(t_settled) if t0 < at <= t_end
         ]
@@ -405,7 +457,7 @@ def _run_cell(
             1
             for tx, ok in zip(executor.timed, executor.timed_ok)
             if not ok or tx not in settled_in
-        )
+        ) + len((faults or {}).get("never_back", ()))
         numbers = dict(reference.compare_served(obs), **durable)
     else:
         first = ends["first_epoch"]
@@ -459,9 +511,12 @@ def _run_cell(
             "idle_gaps": reduced["idle_gaps"],
         }
     late = sorted(run.get("late_s") or ())
+    compiled = meter.names[before["compiles"]:after["compiles"]]
     say(f"window {t_end - t0:.3f} s, {run['epochs_in_window']} epochs, "
         f"{run['settled_in_window']} transactions settled; "
-        f"{after['compiles'] - before['compiles']} compilations in the window"
+        f"{len(compiled)} compilations in the window"
+        + (f" ({', '.join(compiled)}; {compile_s:.2f} s, {cache_hits} "
+           f"persistent-cache hits)" if compiled else "")
         + (f"; generator late p95 {percentile(late, 0.95) * 1e3:.1f} ms"
            if late and loop == "open" else ""))
     result["compared"] = {
