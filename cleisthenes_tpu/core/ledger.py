@@ -385,15 +385,28 @@ class BatchLog:
             yield end, magic, body
             off = end
 
+    def _read_records(self) -> List[Tuple[int, bytes, bytes]]:
+        """The log as a restart reads it: every validated record
+        (``_scan``'s tuples), under one ``ledger/replay`` span a read
+        (recovery at construction, then one per ``replay*`` the owning
+        node asks for)."""
+        with span("ledger", "replay", recorder=self.trace) as sp:
+            with open(self.path, "rb") as fh:
+                data = fh.read()
+            records = list(self._scan(data))
+            sp.note(
+                records=len(records),
+                bytes=records[-1][0] if records else 0,
+            )
+        return records
+
     def _recover_locked(self) -> None:
         """Scan the log, truncating any torn tail (construction-time:
         the instance is not shared yet)."""
         if not os.path.exists(self.path):
             return
-        with open(self.path, "rb") as fh:
-            data = fh.read()
         good_end = 0
-        for end, magic, body in self._scan(data):
+        for end, magic, body in self._read_records():
             if magic == _MAGIC:
                 self._last_epoch, _ = _decode_body(body)
             elif magic == _MAGIC_ORD:
@@ -418,7 +431,7 @@ class BatchLog:
                 )
             # RCFG records are consumed via replay_reconfigs()
             good_end = end
-        if good_end < len(data):  # torn/corrupt tail: drop it
+        if good_end < os.path.getsize(self.path):  # torn tail: drop it
             with open(self.path, "r+b") as fh:
                 fh.truncate(good_end)
 
@@ -510,18 +523,14 @@ class BatchLog:
         reconfig records, oldest first — recovery's cross-check that
         the ceremony re-derived from the replayed batches matches what
         the crashed process had durably switched to."""
-        with open(self.path, "rb") as fh:
-            data = fh.read()
-        for _end, magic, body in self._scan(data):
+        for _end, magic, body in self._read_records():
             if magic == _MAGIC_RCFG:
                 yield decode_reconfig_body(body)
 
     def replay(self) -> Iterator[Tuple[int, Batch]]:
         """All committed (epoch, batch) records, oldest first
         (checkpoint records are skipped — see ``last_checkpoint``)."""
-        with open(self.path, "rb") as fh:
-            data = fh.read()
-        for _end, magic, body in self._scan(data):
+        for _end, magic, body in self._read_records():
             if magic == _MAGIC:
                 yield _decode_body(body)
 
@@ -529,9 +538,7 @@ class BatchLog:
         """All ciphertext-ordered (epoch, COrd body) records, oldest
         first.  A restart settles ordered-ahead epochs (COrd with no
         matching CLOG yet) from here — the ordering is never re-run."""
-        with open(self.path, "rb") as fh:
-            data = fh.read()
-        for _end, magic, body in self._scan(data):
+        for _end, magic, body in self._read_records():
             if magic == _MAGIC_ORD:
                 (epoch,) = struct.unpack_from(">Q", body, 0)
                 yield epoch, body
@@ -657,18 +664,14 @@ class _LaneLog:
         )
 
     def replay(self) -> Iterator[Tuple[int, Batch]]:
-        with open(self._log.path, "rb") as fh:
-            data = fh.read()
-        for _end, magic, body in self._log._scan(data):
+        for _end, magic, body in self._log._read_records():
             if magic == _MAGIC_LANE:
                 lane, inner = _split_lane_body(body)
                 if lane == self.lane:
                     yield _decode_body(inner)
 
     def replay_ordered(self) -> Iterator[Tuple[int, bytes]]:
-        with open(self._log.path, "rb") as fh:
-            data = fh.read()
-        for _end, magic, body in self._log._scan(data):
+        for _end, magic, body in self._log._read_records():
             if magic == _MAGIC_LANE_ORD:
                 lane, inner = _split_lane_body(body)
                 if lane == self.lane:

@@ -24,6 +24,11 @@ SEMANTIC adversary seam: ``behaviors={node_id: Behavior}`` mounts
 protocol-level malicious behaviors (protocol.byzantine — equivocation,
 split voting, share forgery...) on chosen nodes, composable with the
 wire-level filters on the same run.
+
+``requires=[names]`` is a deployment's file saying what it relies on
+across a restart beyond the protocol's own guarantees (``HOLDS``): a
+program that lacks one refuses to build the cluster, where running it
+would lose what the file promises to keep.  It changes no behavior.
 """
 
 from __future__ import annotations
@@ -42,6 +47,17 @@ from cleisthenes_tpu.protocol.hub import CryptoHub
 from cleisthenes_tpu.transport.base import HmacAuthenticator
 from cleisthenes_tpu.transport.broadcast import ChannelBroadcaster
 from cleisthenes_tpu.transport.channel import ChannelNetwork
+
+
+# What this program holds across a restart, by the names a deployment's
+# file may ask for (``SimulatedCluster(requires=...)``).
+HOLDS = frozenset({
+    # a proposal in flight when its epoch is adopted through CATCHUP goes
+    # back on the queue less what the adopted batch settled, so a
+    # restarted validator keeps what it acknowledged while it was not
+    # yet level (HoneyBadger._requeue_own)
+    "requeue_at_adoption",
+})
 
 
 def run_until_drained(
@@ -115,7 +131,14 @@ class SimulatedCluster:
         behaviors: Optional[Dict[str, object]] = None,
         wal_dir: Optional[str] = None,
         wan_profile: Optional[object] = None,
+        requires: Sequence[str] = (),
     ) -> None:
+        missing = sorted(set(requires) - HOLDS)
+        if missing:
+            raise ValueError(
+                f"this program does not hold {missing}; it holds "
+                f"{sorted(HOLDS)}"
+            )
         if config is not None:
             if n != 4 and n != config.n:  # both given and conflicting
                 raise ValueError(
@@ -736,4 +759,4 @@ class SimulatedCluster:
         self.net.fault_filter = f
 
 
-__all__ = ["SimulatedCluster", "run_until_drained"]
+__all__ = ["HOLDS", "SimulatedCluster", "run_until_drained"]

@@ -884,6 +884,7 @@ class HoneyBadger:
                     self._remember_committed(set(seen))
             for epoch, batch in batch_log.replay():
                 self.committed_batches.append(batch)
+                self.metrics.catchup_replayed_records.inc()
                 if epoch > ckpt_epoch:
                     self._remember_committed(set(batch.tx_list()))
                 # re-derive the reconfig plane (RECONFIG + dealing txs
@@ -915,6 +916,7 @@ class HoneyBadger:
                 self._epochs[oepoch] = es
                 self._ordered_bodies[oepoch] = body
                 self.epoch = oepoch + 1
+                self.metrics.catchup_replayed_records.inc()
         if batch_log is not None:
             # leave replay mode: cross-check the re-derived roster
             # schedule against the WAL's RCFG records, re-deal if a
@@ -2736,9 +2738,11 @@ class HoneyBadger:
         if not force and self._last_catchup_request == frontier:
             return  # one broadcast per frontier (re-fired as we adopt)
         self._last_catchup_request = frontier
-        if self.trace is not None:
-            self.trace.instant("catchup", "request", from_epoch=frontier)
-        self.out.broadcast(CatchupReqPayload(from_epoch=frontier))
+        self.metrics.catchup_requests_sent.inc()
+        with trace.span(
+            "catchup", "request", recorder=self.trace, from_epoch=frontier
+        ):
+            self.out.broadcast(CatchupReqPayload(from_epoch=frontier))
 
     def _handle_catchup_req(
         self, sender: str, p: CatchupReqPayload
@@ -2794,24 +2798,26 @@ class HoneyBadger:
         self._catchup_floor[sender] = max(
             self._catchup_floor.get(sender, 0), end, ord_end
         )
-        if self.trace is not None:
-            self.trace.instant(
-                "catchup",
-                "serve",
-                from_epoch=start,
-                epochs=max(0, end - start),
-                ordered=len(serve_ord),
-            )
-        # one response per missed epoch; the coalescing broadcaster
-        # bundles the run into a single envelope for the requester
-        self._send_clog_range(sender, start, end)
-        for epoch in serve_ord:
-            self.out.send_to(
-                sender,
-                CatchupOrdPayload(
-                    epoch=epoch, body=self._ordered_bodies[epoch]
-                ),
-            )
+        self.metrics.catchup_responses_served.inc()
+        with trace.span(
+            "catchup",
+            "serve",
+            recorder=self.trace,
+            from_epoch=start,
+            epochs=max(end, ord_end) - start,
+            bodies=end - start,
+            ordered=len(serve_ord),
+        ):
+            # one response per missed epoch; the coalescing broadcaster
+            # bundles the run into a single envelope for the requester
+            self._send_clog_range(sender, start, end)
+            for epoch in serve_ord:
+                self.out.send_to(
+                    sender,
+                    CatchupOrdPayload(
+                        epoch=epoch, body=self._ordered_bodies[epoch]
+                    ),
+                )
         if serve_ord:
             # part of the window went out as ciphertext orderings
             # only: owe the requester those epochs' plaintext, pushed
@@ -2851,14 +2857,15 @@ class HoneyBadger:
                 if nxt >= limit:
                     del self._catchup_plain_owed[sender]
                 continue
-            if self.trace is not None:
-                self.trace.instant(
-                    "catchup",
-                    "serve_settled",
-                    from_epoch=nxt,
-                    epochs=end - nxt,
-                )
-            self._send_clog_range(sender, nxt, end)
+            self.metrics.catchup_responses_served.inc()
+            with trace.span(
+                "catchup",
+                "serve_settled",
+                recorder=self.trace,
+                from_epoch=nxt,
+                epochs=end - nxt,
+            ):
+                self._send_clog_range(sender, nxt, end)
             if end >= limit:
                 del self._catchup_plain_owed[sender]
             else:
@@ -2870,16 +2877,21 @@ class HoneyBadger:
         """One CatchupResp per committed epoch in [start, end) — the
         serve loop shared by direct catch-up answers and the
         owed-plaintext push."""
+        self.metrics.catchup_bodies_served.inc(end - start)
         for epoch in range(start, end):
-            self.out.send_to(
-                sender,
-                CatchupRespPayload(
-                    epoch=epoch,
-                    body=encode_batch_body(
-                        epoch, self.committed_batches[epoch]
+            # one span a batch body (on the profiler's timeline only):
+            # its calls are the bodies served, where totals() keeps no
+            # argument
+            with trace.span("catchup", "serve_body"):
+                self.out.send_to(
+                    sender,
+                    CatchupRespPayload(
+                        epoch=epoch,
+                        body=encode_batch_body(
+                            epoch, self.committed_batches[epoch]
+                        ),
                     ),
-                ),
-            )
+                )
 
     def peer_reconnected(self, member_id: str) -> None:
         """Transport event: our link to ``member_id`` was just
@@ -2977,27 +2989,35 @@ class HoneyBadger:
         """Commit a batch learned via CATCHUP instead of running the
         (long-gone) epoch ourselves."""
         self.log.info("adopted catch-up batch", epoch=epoch, txs=len(batch))
-        if self.trace is not None:
-            self.trace.instant(
-                "catchup", "adopt", epoch=epoch, txs=len(batch)
-            )
-        self.committed_batches.append(batch)
-        seen = set(batch.tx_list())
-        self._remember_committed(seen)
-        self.metrics.epoch_committed(epoch, len(batch))
-        if self.batch_log is not None:
-            self.batch_log.append(epoch, batch)
-            self._maybe_log_checkpoint(epoch)
-        self._epochs.pop(epoch, None)  # any partial local state is moot
-        self.hub.drop_scope((self.node_id, epoch))
-        self._catchup_tallies.pop(epoch, None)
-        # adopted batches feed the reconfig plane exactly like local
-        # settlements: a crashed/partitioned node learns a ceremony
-        # happened from the log it catches up on
-        self._reconfig.on_batch_settled(epoch, batch)
-        self._maybe_teardown_retired()
-        self._serve_owed_plaintext()
-        self._notify_commit(epoch, batch)
+        with trace.span(
+            "catchup", "adopt", recorder=self.trace,
+            epoch=epoch, txs=len(batch),
+        ) as sp:
+            self.committed_batches.append(batch)
+            seen = set(batch.tx_list())
+            self._remember_committed(seen)
+            self.metrics.epoch_committed(epoch, len(batch))
+            self.metrics.catchup_bodies_adopted.inc()
+            if self.batch_log is not None:
+                self.batch_log.append(epoch, batch)
+                self._maybe_log_checkpoint(epoch)
+            # any partial local state is moot, but not what it took out
+            # of the queue: a validator that proposed into the epoch
+            # (a restarted one that is not level yet does) acknowledged
+            # those transactions at ingress
+            es = self._epochs.pop(epoch, None)
+            requeued = 0 if es is None else self._requeue_own(es, seen)
+            self.metrics.catchup_requeued_tx.inc(requeued)
+            sp.note(requeued=requeued)
+            self.hub.drop_scope((self.node_id, epoch))
+            self._catchup_tallies.pop(epoch, None)
+            # adopted batches feed the reconfig plane exactly like local
+            # settlements: a crashed/partitioned node learns a ceremony
+            # happened from the log it catches up on
+            self._reconfig.on_batch_settled(epoch, batch)
+            self._maybe_teardown_retired()
+            self._serve_owed_plaintext()
+            self._notify_commit(epoch, batch)
         if self._two_frontier and epoch < self.epoch:
             # plaintext for an epoch we had already ORDERED (restart
             # with an ordered-ahead window, or a settle stall peers
@@ -3070,17 +3090,22 @@ class HoneyBadger:
         boundary; the plaintext typically completes via the share
         exchange or CLOG catch-up once peers settle."""
         self.log.info("adopted catch-up ordering", epoch=epoch)
-        if self.trace is not None:
-            self.trace.instant("catchup", "adopt_ordered", epoch=epoch)
-        es = self._epochs.get(epoch)
-        if es is None:
-            es = _EpochState(None, self.roster_for(epoch))
-            es.proposed = True
-            self._epochs[epoch] = es
-        if es.output is None:
-            es.output = output
-        self._record_ordered(epoch, es, body)
-        self._catchup_ord_tallies.pop(epoch, None)
+        # a state we proposed into is kept, my_txs and all: the settler
+        # (_commit_batch) or the plaintext's adoption re-queues what
+        # the batch leaves out, so nothing is re-queued here
+        with trace.span(
+            "catchup", "adopt_ordered", recorder=self.trace,
+            epoch=epoch, requeued=0,
+        ):
+            es = self._epochs.get(epoch)
+            if es is None:
+                es = _EpochState(None, self.roster_for(epoch))
+                es.proposed = True
+                self._epochs[epoch] = es
+            if es.output is None:
+                es.output = output
+            self._record_ordered(epoch, es, body)
+            self._catchup_ord_tallies.pop(epoch, None)
         self._advance_epoch()
 
     def _maybe_log_checkpoint(self, epoch: int) -> None:
@@ -3151,11 +3176,7 @@ class HoneyBadger:
         if self.batch_log is not None:
             self.batch_log.append(epoch, batch)
         self.log.debug("committed", epoch=epoch, txs=len(batch))
-        # re-queue our own txs that did not make it into the set
-        if es.proposed:
-            for tx in es.my_txs:
-                if tx not in seen:
-                    self.que.push(tx)
+        self._requeue_own(es, seen)
         # remember what committed so duplicate local submissions are
         # dropped lazily at poll time (bounded memory)
         self._remember_committed(seen)
@@ -3169,6 +3190,18 @@ class HoneyBadger:
         self._maybe_teardown_retired()
         self._notify_commit(epoch, batch)
         self._serve_owed_plaintext()
+
+    def _requeue_own(self, es: _EpochState, seen: Set[bytes]) -> int:
+        """Our own proposal's txs that did not make it into the epoch's
+        set go back on the queue, in proposal order: at a local commit,
+        and where a state we proposed into is dropped for a batch
+        adopted through CATCHUP.  Returns how many."""
+        if not es.proposed:
+            return 0
+        back = [tx for tx in es.my_txs if tx not in seen]
+        for tx in back:
+            self.que.push(tx)
+        return len(back)
 
     def _prune_epoch_states(self) -> None:
         """Drop epoch state that is BOTH outside the demux window

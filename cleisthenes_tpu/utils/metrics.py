@@ -222,6 +222,20 @@ class Metrics:
         # rest).  DETERMINISTIC for a seeded schedule.
         self.echo_items_wave = Counter()
         self.echo_items_scalar = Counter()
+        # crash recovery (protocol/honeybadger.py, CATCHUP and the
+        # restore from the log): requests broadcast; responses served
+        # (one a window answered or pushed) and the batch bodies in
+        # them; batches adopted from f+1 peers instead of run; own
+        # transactions put back on the queue when a state this node
+        # had proposed into was dropped for an adopted batch; records
+        # taken from the log at construction.  DETERMINISTIC for a
+        # seeded schedule.
+        self.catchup_requests_sent = Counter()
+        self.catchup_responses_served = Counter()
+        self.catchup_bodies_served = Counter()
+        self.catchup_bodies_adopted = Counter()
+        self.catchup_requeued_tx = Counter()
+        self.catchup_replayed_records = Counter()
         # two-frontier commit (Config.order_then_settle): epochs whose
         # ciphertext ordering committed (the ordered frontier's tally;
         # settlement lands in epochs_committed as before)
@@ -488,6 +502,16 @@ class Metrics:
         out["banks"] = {
             "echo_items_wave": self.echo_items_wave.value,
             "echo_items_scalar": self.echo_items_scalar.value,
+        }
+        # crash-recovery block (same schema rule): zeroed on a node
+        # that never restarted, asked or was asked
+        out["catchup"] = {
+            "requests_sent": self.catchup_requests_sent.value,
+            "responses_served": self.catchup_responses_served.value,
+            "bodies_served": self.catchup_bodies_served.value,
+            "bodies_adopted": self.catchup_bodies_adopted.value,
+            "requeued_tx": self.catchup_requeued_tx.value,
+            "replayed_records": self.catchup_replayed_records.value,
         }
         # wave-routing block: ALWAYS present with every key, zeroed on
         # bare nodes (the PR-9 schema-stability rule
