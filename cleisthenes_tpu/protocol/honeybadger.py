@@ -97,13 +97,21 @@ COMMITTED_MEMORY_EPOCHS = 64
 # against a Byzantine peer spraying far-future epochs)
 CATCHUP_MAX_EPOCHS = 32
 CATCHUP_WINDOW = 128
-# serving-side amplification guard: a sender whose from_epoch does not
-# advance past the window already served it gets this many repeat
-# serves, re-armed on every local epoch advance (an 8-byte CatchupReq
+# serving-side amplification guard: a sender that asks again at (or
+# behind) the from_epoch it asked last, inside the window already
+# served it, gets this many repeat serves of the whole window,
+# re-armed on every local epoch advance (an 8-byte CatchupReq
 # otherwise buys CATCHUP_MAX_EPOCHS full batch bodies — a free 32x
 # bandwidth/CPU amplifier for a Byzantine member looping requests).
-# Counted, not clocked: seeded deterministic runs replay exactly.
+# A from_epoch that ADVANCED inside the served window is a requester
+# adopting what it was sent: it draws nothing and buys only the
+# epochs past the window.  So a body goes to a requester once, plus
+# at most this many times a re-arm.  Counted, not clocked: seeded
+# deterministic runs replay exactly.
 CATCHUP_REPEAT_BUDGET = 2
+# payloads _send_clog_range keeps built, FIFO: two serving windows a
+# validator, whatever epochs a requester asks for
+CATCHUP_BODY_MEMO_EPOCHS = 2 * CATCHUP_MAX_EPOCHS
 # a laggard whose CatchupReq (or its responses) was lost re-broadcasts
 # after every this-many further sightings of far-ahead traffic — a
 # deterministic, traffic-driven retry (no timers in the protocol plane)
@@ -865,6 +873,12 @@ class HoneyBadger:
         # on its final window).  One entry per sender, one window per
         # settlement advance: no amplification beyond a normal serve.
         self._catchup_parked: Dict[str, int] = {}
+        # epoch -> the CatchupRespPayload served for it: filled at the
+        # first serve (never at commit), so every requester is handed
+        # the same object and the transport's FrameEncodeMemo shares
+        # its encode.  committed_batches is append-only, so an entry
+        # never goes stale
+        self._catchup_body_memo = _Memo(CATCHUP_BODY_MEMO_EPOCHS)
         # durable committed-batch log (core.ledger.BatchLog): restore
         # the committed history + epoch counter + dup-filter on restart
         self.batch_log = batch_log
@@ -2752,16 +2766,19 @@ class HoneyBadger:
         # legitimate catch-up correspondent during the transition
         if not self._reconfig.known_member(sender):
             return
+        if sender == self.node_id:
+            return  # our own broadcast, looped back: we hold what we hold
         start = p.from_epoch
         # remembered even when unservable: if the link to the sender
         # heals later, peer_reconnected re-serves from here
+        prev = self._catchup_last_req.get(sender)
         self._catchup_last_req[sender] = start
-        end = min(len(self.committed_batches), start + CATCHUP_MAX_EPOCHS)
+        settled = len(self.committed_batches)
+        end = min(settled, start + CATCHUP_MAX_EPOCHS)
         # two-frontier mode: epochs we ORDERED but have not settled yet
         # have no plaintext to serve, but their agreed ciphertext
         # ordering (COrd body) still lets the requester advance its
         # ordered frontier and rejoin the live epochs
-        ord_start = max(start, len(self.committed_batches))
         ord_end = (
             min(self.epoch, start + CATCHUP_MAX_EPOCHS)
             if self._two_frontier
@@ -2769,48 +2786,63 @@ class HoneyBadger:
         )
         serve_ord = [
             e
-            for e in range(ord_start, ord_end)
+            for e in range(max(start, settled), ord_end)
             if e in self._ordered_bodies
         ]
         if not (0 <= start < end) and not serve_ord:
-            if 0 <= start and start >= len(self.committed_batches):
+            if 0 <= start and start >= settled:
                 # asked at (or past) our own frontier: park it and
                 # re-serve when settlement advances past the ask
                 self._catchup_parked[sender] = start
             return  # nothing committed there (yet) that we can serve
         self._catchup_parked.pop(sender, None)
         end = max(end, start)  # plaintext range may be empty
-        # amplification guard: a legitimately catching-up node's
-        # from_epoch strictly advances past each window we served it;
-        # a request that does NOT advance (replayed frame, Byzantine
-        # request loop, or an honest retry after lost responses) draws
-        # from a small repeat budget re-armed on every local epoch
-        # advance and on link heal — counted, not clocked, so seeded
-        # deterministic runs replay exactly, yet an 8-byte request no
-        # longer buys unlimited 32-batch responses
-        if start < self._catchup_floor.get(sender, 0):
-            budget = self._catchup_repeats.get(
-                sender, CATCHUP_REPEAT_BUDGET
-            )
-            if budget <= 0:
-                return
-            self._catchup_repeats[sender] = budget - 1
-        self._catchup_floor[sender] = max(
-            self._catchup_floor.get(sender, 0), end, ord_end
-        )
-        self.metrics.catchup_responses_served.inc()
+        # amplification guard, read from the request itself against
+        # the two numbers kept a sender.  A from_epoch at or past the
+        # floor is a requester that adopted all we served it: the next
+        # window, unconditionally.  One that ADVANCED since its last
+        # request but lies inside what we served is a requester
+        # adopting what is still in flight to it: it buys what is past
+        # the floor (usually nothing, or the epochs settled since) and
+        # draws no budget.  One that did NOT advance (an honest retry
+        # after lost responses, a replayed frame, a Byzantine loop)
+        # buys the whole window again from a small repeat budget
+        # re-armed on every local epoch advance and on link heal —
+        # counted, not clocked, so seeded deterministic runs replay
+        # exactly, and a body goes to a requester once plus at most
+        # CATCHUP_REPEAT_BUDGET times a re-arm
+        floor = self._catchup_floor.get(sender, 0)
+        send_from = start
+        if start < floor:
+            if prev is not None and prev < start:
+                send_from = floor
+            else:
+                budget = self._catchup_repeats.get(
+                    sender, CATCHUP_REPEAT_BUDGET
+                )
+                if budget <= 0:
+                    return
+                self._catchup_repeats[sender] = budget - 1
+        self._catchup_floor[sender] = max(floor, end, ord_end)
+        skipped = max(0, min(end, send_from) - start)
+        self.metrics.catchup_bodies_in_flight_skipped.inc(skipped)
+        serve_ord = [e for e in serve_ord if e >= send_from]
+        bodies = max(0, end - send_from)
+        if bodies or serve_ord:
+            self.metrics.catchup_responses_served.inc()
         with trace.span(
             "catchup",
             "serve",
             recorder=self.trace,
             from_epoch=start,
             epochs=max(end, ord_end) - start,
-            bodies=end - start,
+            bodies=bodies,
+            skipped=skipped,
             ordered=len(serve_ord),
         ):
             # one response per missed epoch; the coalescing broadcaster
             # bundles the run into a single envelope for the requester
-            self._send_clog_range(sender, start, end)
+            self._send_clog_range(sender, send_from, end)
             for epoch in serve_ord:
                 self.out.send_to(
                     sender,
@@ -2876,22 +2908,31 @@ class HoneyBadger:
     ) -> None:
         """One CatchupResp per committed epoch in [start, end) — the
         serve loop shared by direct catch-up answers and the
-        owed-plaintext push."""
+        owed-plaintext push.  An epoch's payload is built at its first
+        serve and handed to every later requester as the same object
+        (``_catchup_body_memo``)."""
+        if end <= start:
+            return
         self.metrics.catchup_bodies_served.inc(end - start)
+        memo = self._catchup_body_memo
         for epoch in range(start, end):
             # one span a batch body (on the profiler's timeline only):
             # its calls are the bodies served, where totals() keeps no
             # argument
             with trace.span("catchup", "serve_body"):
-                self.out.send_to(
-                    sender,
-                    CatchupRespPayload(
+                payload = memo.map.get(epoch)
+                if payload is None:
+                    self.metrics.catchup_body_memo_misses.inc()
+                    payload = CatchupRespPayload(
                         epoch=epoch,
                         body=encode_batch_body(
                             epoch, self.committed_batches[epoch]
                         ),
-                    ),
-                )
+                    )
+                    memo.put(epoch, payload)
+                else:
+                    self.metrics.catchup_body_memo_hits.inc()
+                self.out.send_to(sender, payload)
 
     def peer_reconnected(self, member_id: str) -> None:
         """Transport event: our link to ``member_id`` was just

@@ -228,11 +228,17 @@ class Metrics:
         # them; batches adopted from f+1 peers instead of run; own
         # transactions put back on the queue when a state this node
         # had proposed into was dropped for an adopted batch; records
-        # taken from the log at construction.  DETERMINISTIC for a
-        # seeded schedule.
+        # taken from the log at construction; bodies an advancing
+        # request asked for again while they were in flight to it, and
+        # so not sent again; serves of a body that found its payload
+        # built (hits) or built it (misses: one encode_batch_body).
+        # DETERMINISTIC for a seeded schedule.
         self.catchup_requests_sent = Counter()
         self.catchup_responses_served = Counter()
         self.catchup_bodies_served = Counter()
+        self.catchup_bodies_in_flight_skipped = Counter()
+        self.catchup_body_memo_hits = Counter()
+        self.catchup_body_memo_misses = Counter()
         self.catchup_bodies_adopted = Counter()
         self.catchup_requeued_tx = Counter()
         self.catchup_replayed_records = Counter()
@@ -509,6 +515,11 @@ class Metrics:
             "requests_sent": self.catchup_requests_sent.value,
             "responses_served": self.catchup_responses_served.value,
             "bodies_served": self.catchup_bodies_served.value,
+            "bodies_in_flight_skipped": (
+                self.catchup_bodies_in_flight_skipped.value
+            ),
+            "body_memo_hits": self.catchup_body_memo_hits.value,
+            "body_memo_misses": self.catchup_body_memo_misses.value,
             "bodies_adopted": self.catchup_bodies_adopted.value,
             "requeued_tx": self.catchup_requeued_tx.value,
             "replayed_records": self.catchup_replayed_records.value,

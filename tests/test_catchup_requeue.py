@@ -372,6 +372,9 @@ def test_catchup_block_is_zeroed_on_a_node_that_never_restarted():
         "requests_sent": 0,
         "responses_served": 0,
         "bodies_served": 0,
+        "bodies_in_flight_skipped": 0,
+        "body_memo_hits": 0,
+        "body_memo_misses": 0,
         "bodies_adopted": 0,
         "requeued_tx": 0,
         "replayed_records": 0,
