@@ -59,6 +59,7 @@ Event tuple shape (storage; `to_chrome` renders the JSON form):
 from __future__ import annotations
 
 import collections
+import gc
 import sys
 import threading
 import time
@@ -105,6 +106,8 @@ CATEGORIES = frozenset(
         # coin_wave, decrypt, commit
         "hb",  # one HoneyBadger turn: on_idle > coin_drain, settler,
         # pipeline, deferred, dec_drain; start_epoch
+        "gc",  # Python's cyclic collector: one gen0 / gen1 / gen2 span
+        # a collection, under the span whose allocation set it off
     )
 )
 
@@ -220,6 +223,16 @@ _session_on = _session_unbound  # is a JAX profiler session running?
 _local = threading.local()  # .stack: this thread's open spans
 _totals_lock = threading.Lock()
 _totals: Dict[str, List[float]] = {}  # "cat/name" -> [calls, total_s, self_s]
+# The collector's rows, a generation each, kept apart from _totals and its
+# lock: a collection can start inside that lock, on the thread holding it
+# (the row list a new key allocates).  Each row is one tuple, replaced
+# whole, so a reader sees it before or after a collection, never between.
+_GC_KEYS = ("gc/gen0", "gc/gen1", "gc/gen2")
+_GC_EMPTY = ((0, 0.0, 0.0),) * len(_GC_KEYS)
+_gc_rows: List[Tuple[int, float, float]] = list(_GC_EMPTY)
+_gc_open: Optional["_Collection"] = None  # the collection in progress
+_gc_hooked = False  # _on_collection is in gc.callbacks
+_hook_lock = threading.Lock()
 
 
 class _Off:
@@ -270,13 +283,18 @@ class _Span:
                 **self._late, **args
             }
 
+    # A span is on its thread's stack exactly from its first clock read
+    # to its last: whatever allocates outside that stretch (the
+    # annotation, the row) can set off a collection, which must then
+    # count as the parent's child, inside the parent's wall.
+
     def __enter__(self):
         if self._ann is not None:
+            self._ann.__enter__()
             stack = getattr(_local, "stack", None)
             if stack is None:
                 stack = _local.stack = []
             stack.append(self)
-            self._ann.__enter__()
         self._t0 = _clock()
         return self
 
@@ -284,9 +302,6 @@ class _Span:
         dur = _clock() - self._t0
         ann = self._ann
         if ann is not None:
-            if self._late is not None:
-                ann.set_metadata(**self._late)
-            ann.__exit__(*exc)
             stack = _local.stack
             if stack[-1] is self:
                 stack.pop()
@@ -294,19 +309,82 @@ class _Span:
                 stack.remove(self)
             if stack:
                 stack[-1]._children_s += dur
+            if self._late is not None:
+                ann.set_metadata(**self._late)
+            ann.__exit__(*exc)
             if _session_on():  # a span the session's end cut is left out
-                with _totals_lock:
-                    row = _totals.get(self._key)
-                    if row is None:
-                        row = _totals[self._key] = [0, 0.0, 0.0]
-                    row[0] += 1
-                    row[1] += dur
-                    row[2] += dur - self._children_s
+                self._tally(dur)
         if self._recorder is not None:
             self._recorder._record(
                 self._cat, self._name, self._t0, dur, self._args
             )
         return False
+
+    def _tally(self, dur: float) -> None:
+        with _totals_lock:
+            row = _totals.get(self._key)
+            if row is None:
+                row = _totals[self._key] = [0, 0.0, 0.0]
+            row[0] += 1
+            row[1] += dur
+            row[2] += dur - self._children_s
+
+
+class _Collection(_Span):
+    """One run of the cyclic collector: a ``gc/gen<n>`` span on the
+    collecting thread's stack, so its time leaves the self time of the
+    span it interrupted.  Its row lives in ``_gc_rows``, outside
+    ``_totals_lock``."""
+
+    __slots__ = ("_gen",)
+
+    def __init__(self, generation: int) -> None:
+        super().__init__("gc", f"gen{generation}", None, {}, session=True)
+        self._gen = generation
+
+    def _tally(self, dur: float) -> None:
+        calls, total, own = _gc_rows[self._gen]
+        _gc_rows[self._gen] = (
+            calls + 1, total + dur, own + dur - self._children_s
+        )
+
+
+def _on_collection(phase: str, info: dict) -> None:
+    """The ``gc.callbacks`` entry: while a session runs, a collection is
+    a span from its "start" to its "stop"; the first collection that
+    finds no session takes the hook out.  The collector does not run
+    again until this returns, so one collection is open at a time."""
+    global _gc_open
+    if phase == "start":
+        if not _session_on():
+            _unhook_collector()
+            return
+        _gc_open = _Collection(info["generation"])
+        _gc_open.__enter__()
+    elif _gc_open is not None:
+        sp, _gc_open = _gc_open, None
+        sp.note(collected=info["collected"],
+                uncollectable=info["uncollectable"])
+        sp.__exit__(None, None, None)
+
+
+def _hook_collector() -> None:
+    """Puts the collector on the timeline; ``span`` calls this on its
+    session branch only, so a run without a session never hooks it."""
+    global _gc_hooked
+    with _hook_lock:
+        if not _gc_hooked:
+            gc.callbacks.append(_on_collection)
+            _gc_hooked = True
+
+
+def _unhook_collector() -> None:
+    global _gc_hooked
+    _gc_hooked = False
+    try:
+        gc.callbacks.remove(_on_collection)
+    except ValueError:  # another thread's collection took it out first
+        pass
 
 
 def span(cat: str, name: str, recorder: Optional[TraceRecorder] = None, **args):
@@ -315,27 +393,34 @@ def span(cat: str, name: str, recorder: Optional[TraceRecorder] = None, **args):
     ``recorder`` is given) it is a TraceAnnotation ``cat/name`` on the
     profiler's timeline, a row of ``totals()`` and, with a recorder,
     the ring tuple ``complete()`` appends; ``sp.note(**args)`` adds
-    what is known only at the end.  Off it is one shared no-op."""
+    what is known only at the end.  Off it is one shared no-op.  The
+    first span of a session also puts the collector on the timeline."""
     session = _session_on()
     if recorder is None and not session:
         return _OFF
+    if session and not _gc_hooked:
+        _hook_collector()
     return _Span(cat, name, recorder, args, session)
 
 
 def totals() -> Dict[str, Dict[str, float]]:
     """{"cat/name": {"calls", "total_s", "self_s"}} of every span that
-    began and ended inside a profiler session since ``reset_totals()``.
-    Self times of one thread's spans partition that thread's wall."""
+    began and ended inside a profiler session since ``reset_totals()``,
+    the collector's ``gc/gen<n>`` among them once one has run.  Self
+    times of one thread's spans partition that thread's wall."""
+    rows = {key: row for key, row in zip(_GC_KEYS, _gc_rows) if row[0]}
     with _totals_lock:
-        return {
-            key: {"calls": row[0], "total_s": row[1], "self_s": row[2]}
-            for key, row in sorted(_totals.items())
-        }
+        rows.update((key, tuple(row)) for key, row in _totals.items())
+    return {
+        key: {"calls": row[0], "total_s": row[1], "self_s": row[2]}
+        for key, row in sorted(rows.items())
+    }
 
 
 def reset_totals() -> None:
     with _totals_lock:
         _totals.clear()
+    _gc_rows[:] = _GC_EMPTY
 
 
 # ---------------------------------------------------------------------------

@@ -52,11 +52,15 @@ class FakeAnnotation:
 
 @pytest.fixture
 def session(monkeypatch):
-    """A profiler session as the span entry point observes one."""
+    """A profiler session as the span entry point observes one.  The
+    collector stays off the timeline: a collection these tests did not
+    force would add a span to the exact logs and clocks below
+    (tests/test_trace_gc.py lets it in)."""
     state = {"on": True}
     FakeAnnotation.log = []
     monkeypatch.setattr(trace, "_session_on", lambda: state["on"])
     monkeypatch.setattr(trace, "_Annotation", FakeAnnotation)
+    monkeypatch.setattr(trace, "_hook_collector", lambda: None)
     trace.reset_totals()
     placement.reset()
     yield state
@@ -179,7 +183,7 @@ def test_totals_fill_only_inside_a_session_and_reset(session):
 
 
 def test_new_categories_are_known_to_the_validator():
-    assert {"ops", "lockstep", "hb"} <= trace.CATEGORIES
+    assert {"ops", "lockstep", "hb", "gc"} <= trace.CATEGORIES
     assert not hasattr(trace.TraceRecorder, "span")
 
 
@@ -491,6 +495,8 @@ def test_lockstep_epoch_in_a_real_profile(tmp_path, monkeypatch):
 
     # toy batches sit under every floor: pin them to the XLA kernels
     monkeypatch.setattr(ModEngine, "host_delegation", False)
+    # a collection between the epoch's spans would lie outside the epoch
+    monkeypatch.setattr(trace, "_hook_collector", lambda: None)
     monkeypatch.setattr(XlaMerkle, "HOST_FLOOR_VERIFY", 0)
     monkeypatch.setattr(XlaMerkle, "HOST_FLOOR_BUILD_LEAVES", 0)
     monkeypatch.setattr(XlaErasureCoder, "HOST_FLOOR_BYTES", 0)
