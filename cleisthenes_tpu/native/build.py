@@ -128,12 +128,21 @@ def _configure_modpow(lib: ctypes.CDLL) -> None:
 def _configure_sha256(lib: ctypes.CDLL) -> None:
     lib.sha256_rows.argtypes = [
         ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64,
-        ctypes.c_void_p, ctypes.c_void_p,
+        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int,
     ]
+    lib.sha256_rows.restype = ctypes.c_int
     lib.sha256_rows_fixed.argtypes = [
         ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64,
-        ctypes.c_int64, ctypes.c_void_p,
+        ctypes.c_int64, ctypes.c_void_p, ctypes.c_int,
     ]
+    lib.sha256_rows_fixed.restype = ctypes.c_int
+    lib.sha256_path.argtypes = []
+    lib.sha256_path.restype = ctypes.c_int
+    lib.sha256_resolve.argtypes = [ctypes.c_int]
+    lib.sha256_resolve.restype = ctypes.c_int
+    lib.sha256_thread_floor_blocks.argtypes = []
+    lib.sha256_thread_floor_blocks.restype = ctypes.c_int64
+    lib.sha256_selftest.argtypes = []
     lib.sha256_selftest.restype = ctypes.c_int
     rc = lib.sha256_selftest()
     if rc != 0:

@@ -3,46 +3,88 @@
 // The protocol's hot host loops hash hundreds of thousands of small
 // fixed-layout transcripts per lockstep epoch (Chaum-Pedersen
 // challenges in ops/tpke.py, Merkle leaf/node digests in
-// ops/merkle.py's host path).  Per-message hashlib calls spend more
-// time in Python call overhead than in compression; this kernel takes
-// the whole wave as one padded row-matrix and returns all digests in
-// a single crossing.  Implemented from FIPS 180-4 (same spec as
+// ops/merkle.py's host path) and one 38-byte counter row per 32 bytes
+// of every threshold-encrypted payload (ops/tpke.py's keystream).
+// Per-message hashlib calls spend more time in Python call overhead
+// than in compression; this kernel takes the whole wave as one padded
+// row-matrix and returns all digests in a single crossing.  The
+// portable path is implemented from FIPS 180-4 (same spec as
 // ops/sha256_xla.py, which is the device-side twin).
 //
 // Layout: msgs is (m, stride) row-major uint8, row i holds lens[i]
 // message bytes (rest ignored); out is (m, 32).
 
+#include <cmath>
 #include <cstdint>
 #include <cstring>
 
 #include <dlfcn.h>
 
 #include <initializer_list>
+#include <system_error>
+#include <thread>
+#include <vector>
 
 namespace {
 
-// OpenSSL's SHA256 one-shot (hardware SHA-NI where the CPU has it,
-// ~2x this file's portable loop).  Resolved at first use via dlopen
-// so the build needs no -dev headers; the portable path below is the
+// How a row is hashed; the numbers are what sha256_path() returns.
+//  2: OpenSSL's SHA256_Init/Update/Final on a context on the stack;
+//  1: OpenSSL's one-shot SHA256(), which in OpenSSL 3 fetches the
+//     digest and sets up a context on every call (~6x the streaming
+//     calls' cost on a one-block row);
+//  0: this file's portable loop.
+// OpenSSL (hardware SHA-NI where the CPU has it) is resolved via
+// dlopen so the build needs no -dev headers; the portable path is the
 // always-available fallback and the selftest cross-checks them.
-typedef unsigned char* (*openssl_sha256_fn)(const unsigned char*,
-                                            size_t, unsigned char*);
+enum Path { kBuiltin = 0, kOneShot = 1, kStreaming = 2 };
 
-openssl_sha256_fn resolve_openssl() {
-    static openssl_sha256_fn fn = nullptr;
-    static bool tried = false;
-    if (!tried) {
-        tried = true;
-        for (const char* name :
-             {"libcrypto.so.3", "libcrypto.so.1.1", "libcrypto.so"}) {
-            if (void* h = dlopen(name, RTLD_LAZY | RTLD_GLOBAL)) {
-                fn = reinterpret_cast<openssl_sha256_fn>(
-                    dlsym(h, "SHA256"));
-                if (fn) break;
-            }
+typedef unsigned char* (*oneshot_fn)(const unsigned char*, size_t,
+                                     unsigned char*);
+typedef int (*init_fn)(void*);
+typedef int (*update_fn)(void*, const void*, size_t);
+typedef int (*final_fn)(unsigned char*, void*);
+
+struct Hasher {
+    Path path = kBuiltin;
+    oneshot_fn oneshot = nullptr;
+    init_fn init = nullptr;
+    update_fn update = nullptr;
+    final_fn final = nullptr;
+};
+
+// OpenSSL's SHA256_CTX is 112 bytes (eight state words, a 64-bit bit
+// count, a 64-byte block and two ints); the context lives in this
+// aligned buffer of more than twice that.
+constexpr size_t kCtxBytes = 256;
+
+// The best path whose symbols resolve, looking no higher than `cap`:
+// a lower cap is how the tests see a libcrypto without the streaming
+// calls, or without libcrypto at all.
+Hasher resolve(int cap) {
+    Hasher h;
+    if (cap < kOneShot) return h;
+    for (const char* name :
+         {"libcrypto.so.3", "libcrypto.so.1.1", "libcrypto.so"}) {
+        void* lib = dlopen(name, RTLD_LAZY | RTLD_GLOBAL);
+        if (!lib) continue;
+        h.oneshot = reinterpret_cast<oneshot_fn>(dlsym(lib, "SHA256"));
+        if (!h.oneshot) continue;
+        h.path = kOneShot;
+        if (cap >= kStreaming) {
+            h.init = reinterpret_cast<init_fn>(dlsym(lib, "SHA256_Init"));
+            h.update =
+                reinterpret_cast<update_fn>(dlsym(lib, "SHA256_Update"));
+            h.final = reinterpret_cast<final_fn>(dlsym(lib, "SHA256_Final"));
+            if (h.init && h.update && h.final) h.path = kStreaming;
         }
+        break;
     }
-    return fn;
+    return h;
+}
+
+Hasher& hasher() {
+    static Hasher h = resolve(kStreaming);
+    return h;
 }
 
 inline uint32_t rotr(uint32_t x, int n) {
@@ -120,34 +162,132 @@ void sha256_one(const uint8_t* msg, int64_t len, uint8_t out[32]) {
     }
 }
 
+// The rows of one call: row i is msgs + i * stride, lens[i] bytes long,
+// or `len` bytes where lens is null.
+struct Rows {
+    const uint8_t* msgs;
+    int64_t m;
+    int64_t stride;
+    const int32_t* lens;
+    int64_t len;
+    uint8_t* out;
+
+    int64_t len_of(int64_t i) const { return lens ? lens[i] : len; }
+};
+
+void hash_range(const Hasher& h, const Rows& r, int64_t lo, int64_t hi) {
+    switch (h.path) {
+        case kStreaming: {
+            alignas(64) unsigned char ctx[kCtxBytes];
+            for (int64_t i = lo; i < hi; i++) {
+                h.init(ctx);
+                h.update(ctx, r.msgs + i * r.stride, size_t(r.len_of(i)));
+                h.final(r.out + i * 32, ctx);
+            }
+            return;
+        }
+        case kOneShot:
+            for (int64_t i = lo; i < hi; i++)
+                h.oneshot(r.msgs + i * r.stride, size_t(r.len_of(i)),
+                          r.out + i * 32);
+            return;
+        case kBuiltin:
+            for (int64_t i = lo; i < hi; i++)
+                sha256_one(r.msgs + i * r.stride, r.len_of(i),
+                           r.out + i * 32);
+            return;
+    }
+}
+
+// Rows are independent, so a large call splits them over threads
+// (ctypes releases the GIL for the whole call).  Work is counted in
+// 64-byte compression blocks, not rows: a 38-byte keystream row is one
+// block and a 43 KB Merkle leaf 679.  On the chip's host starting and
+// joining a thread costs 75-200 us, what one thread hashes ~4,096
+// blocks in at 42-60 ns a block (PERF.md, section 6, PR 41).  W blocks
+// on T threads then take about W/T + (T-1)*S block-times, least at
+// T = sqrt(W/S): one thread below 4*S blocks, the thread floor, and
+// more as the square root of the work above it.
+constexpr int64_t kThreadStartBlocks = 4096;
+constexpr int64_t kThreadFloorBlocks = 4 * kThreadStartBlocks;
+constexpr int kMaxThreads = 16;
+
+int64_t blocks_of(const Rows& r) {
+    if (!r.lens) return r.m * ((r.len + 72) / 64);
+    int64_t blocks = 0;
+    for (int64_t i = 0; i < r.m; i++) blocks += (int64_t(r.lens[i]) + 72) / 64;
+    return blocks;
+}
+
+int auto_threads(const Rows& r) {
+    int64_t blocks = blocks_of(r);
+    if (blocks < kThreadFloorBlocks) return 1;
+    int64_t threads = int64_t(std::sqrt(double(blocks) / kThreadStartBlocks));
+    unsigned hw = std::thread::hardware_concurrency();
+    int64_t cap = hw ? (hw < kMaxThreads ? hw : kMaxThreads) : 1;
+    return int(threads < cap ? threads : cap);
+}
+
+// Hash the rows on `threads` threads (0: as many as the work calls
+// for); returns the number that ran.
+int hash_rows(const Rows& r, int threads) {
+    const Hasher h = hasher();
+    if (threads <= 0) threads = auto_threads(r);
+    if (threads > r.m) threads = int(r.m);
+    if (threads <= 1) {
+        hash_range(h, r, 0, r.m);
+        return 1;
+    }
+    int64_t chunk = (r.m + threads - 1) / threads;
+    std::vector<std::thread> pool;
+    pool.reserve(threads - 1);
+    int ran = 1;
+    int64_t lo = chunk;  // the calling thread takes the first chunk
+    for (; lo < r.m; lo += chunk) {
+        int64_t hi = lo + chunk < r.m ? lo + chunk : r.m;
+        try {
+            pool.emplace_back([&h, &r, lo, hi] { hash_range(h, r, lo, hi); });
+        } catch (const std::system_error&) {
+            break;  // no thread to be had: the caller hashes the rest
+        }
+        ran++;
+    }
+    hash_range(h, r, 0, chunk < r.m ? chunk : r.m);
+    if (lo < r.m) hash_range(h, r, lo, r.m);
+    for (auto& th : pool) th.join();
+    return ran;
+}
+
 }  // namespace
 
 extern "C" {
 
 // msgs: (m, stride) row-major; lens: per-row byte counts (lens[i] <=
-// stride); out: (m, 32).
-void sha256_rows(const uint8_t* msgs, int64_t m, int64_t stride,
-                 const int32_t* lens, uint8_t* out) {
-    if (openssl_sha256_fn fn = resolve_openssl()) {
-        for (int64_t i = 0; i < m; i++)
-            fn(msgs + i * stride, size_t(lens[i]), out + i * 32);
-        return;
-    }
-    for (int64_t i = 0; i < m; i++)
-        sha256_one(msgs + i * stride, lens[i], out + i * 32);
+// stride); out: (m, 32); threads: 0 to choose from the work (above),
+// else that many.  Returns the number of threads that hashed.
+int sha256_rows(const uint8_t* msgs, int64_t m, int64_t stride,
+                const int32_t* lens, uint8_t* out, int threads) {
+    return hash_rows(Rows{msgs, m, stride, lens, 0, out}, threads);
 }
 
 // Equal-length fast path (no lens array needed).
-void sha256_rows_fixed(const uint8_t* msgs, int64_t m, int64_t len,
-                       int64_t stride, uint8_t* out) {
-    if (openssl_sha256_fn fn = resolve_openssl()) {
-        for (int64_t i = 0; i < m; i++)
-            fn(msgs + i * stride, size_t(len), out + i * 32);
-        return;
-    }
-    for (int64_t i = 0; i < m; i++)
-        sha256_one(msgs + i * stride, len, out + i * 32);
+int sha256_rows_fixed(const uint8_t* msgs, int64_t m, int64_t len,
+                      int64_t stride, uint8_t* out, int threads) {
+    return hash_rows(Rows{msgs, m, stride, nullptr, len, out}, threads);
 }
+
+// The path rows are hashed by (Path above).
+int sha256_path() { return hasher().path; }
+
+// Resolve again, taking no path above `cap`; returns the path taken.
+// Not safe while another thread hashes.
+int sha256_resolve(int cap) {
+    hasher() = resolve(cap);
+    return hasher().path;
+}
+
+// The fewest 64-byte blocks a call splits over threads at.
+int64_t sha256_thread_floor_blocks() { return kThreadFloorBlocks; }
 
 int sha256_selftest() {
     // FIPS 180-4 vectors: "abc" and the empty string
@@ -165,23 +305,32 @@ int sha256_selftest() {
     uint8_t got[32];
     sha256_one(abc, 3, got);
     if (std::memcmp(got, want_abc, 32) != 0) return 1;
-    if (openssl_sha256_fn fn = resolve_openssl()) {
-        // the dispatched path must agree with the spec path
-        uint8_t got2[32];
-        fn(abc, 3, got2);
-        if (std::memcmp(got2, want_abc, 32) != 0) return 4;
-    }
     sha256_one(abc, 0, got);
     if (std::memcmp(got, want_empty, 32) != 0) return 2;
     // a >64-byte message exercises the two-block tail path
-    uint8_t longmsg[100];
-    for (int i = 0; i < 100; i++) longmsg[i] = uint8_t(i);
+    uint8_t longmsg[200];
+    for (int i = 0; i < 200; i++) longmsg[i] = uint8_t(i);
     sha256_one(longmsg, 100, got);
     // spot value computed with hashlib:
     // sha256(bytes(range(100))).hexdigest()[:8] == "bce0aff1"
     if (!(got[0] == 0xbc && got[1] == 0xe0 && got[2] == 0xaf &&
           got[3] == 0xf1))
         return 3;
+    // the dispatched path, on one thread and split over two, must agree
+    // with the spec path row for row: lengths 0..199 over one matrix
+    int32_t lens[200];
+    uint8_t want[200][32], rows[200][32];
+    for (int i = 0; i < 200; i++) {
+        lens[i] = i;
+        sha256_one(longmsg, i, want[i]);
+    }
+    for (int threads : {1, 2}) {
+        std::memset(rows, 0, sizeof(rows));
+        sha256_rows(longmsg, 200, 0, lens, &rows[0][0], threads);
+        if (std::memcmp(rows, want, sizeof(want)) != 0) return 4;
+    }
+    sha256_rows_fixed(abc, 1, 3, 3, got, 1);
+    if (std::memcmp(got, want_abc, 32) != 0) return 5;
     return 0;
 }
 

@@ -13,6 +13,11 @@ columns are the device's own output bytes; ``ints_to_be_rows`` /
 ``be_rows_to_ints`` are the crossing for what is a Python int (the
 list entry points' values, the combine's memo keys and Lagrange
 terms).
+
+Each row is hashed by OpenSSL's streaming SHA-256 where libcrypto has
+it, and a call with enough 64-byte blocks is split over threads inside
+the kernel; ``hash_tally`` says what the calls since its last reset
+hashed and by which path.
 """
 
 from __future__ import annotations
@@ -21,11 +26,46 @@ import functools
 import hashlib
 import io
 import itertools
-from typing import List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Union
 
 import numpy as np
 
 from cleisthenes_tpu.native.build import load_sha256
+
+
+# The kernel's paths, by the number native/sha256rows.cpp's
+# ``sha256_path()`` returns; ``hashlib`` is this module's own loop,
+# taken where the native library did not build or load.
+PATHS = ("builtin", "openssl_oneshot", "openssl_streaming")
+
+# What ``sha256_rows`` hashed since the last reset: calls, rows, 64-byte
+# compression blocks (padding included), calls the kernel split over
+# threads, and the path the last call took.  Read by tests and
+# tools/hashbench.py.
+_TALLY_FIELDS = ("calls", "rows", "blocks", "threaded_calls")
+_tally: Dict[str, Union[int, str]] = dict.fromkeys(_TALLY_FIELDS, 0)
+_tally["path"] = ""
+
+
+def hash_tally() -> Dict[str, Union[int, str]]:
+    """{calls, rows, blocks, threaded_calls, path} since the last
+    ``reset_hash_tally``; ``path`` is one of ``PATHS`` or ``hashlib``
+    ("" before the first call)."""
+    return dict(_tally)
+
+
+def reset_hash_tally() -> None:
+    for key in _TALLY_FIELDS:
+        _tally[key] = 0
+    _tally["path"] = ""
+
+
+def _count(m: int, blocks: int, threads: int, path: str) -> None:
+    _tally["calls"] += 1
+    _tally["rows"] += m
+    _tally["blocks"] += blocks
+    _tally["threaded_calls"] += threads > 1
+    _tally["path"] = path
 
 
 def sha256_rows(
@@ -52,18 +92,25 @@ def sha256_rows(
             # out-of-range length would read past the row (and the
             # fallback would silently truncate — reject in both)
             raise ValueError("lens values must be in [0, stride]")
+    # SHA-256 pads a message of L bytes to (L + 72) // 64 blocks
+    if lens32 is None:
+        blocks = m * ((stride + 72) // 64)
+    else:
+        blocks = int(((lens32.astype(np.int64) + 72) // 64).sum())
     lib = load_sha256()
     if lib is not None:
         if lens32 is None:
-            lib.sha256_rows_fixed(
-                rows.ctypes.data, m, stride, stride, out.ctypes.data
+            threads = lib.sha256_rows_fixed(
+                rows.ctypes.data, m, stride, stride, out.ctypes.data, 0
             )
         else:
-            lib.sha256_rows(
+            threads = lib.sha256_rows(
                 rows.ctypes.data, m, stride, lens32.ctypes.data,
-                out.ctypes.data,
+                out.ctypes.data, 0,
             )
+        _count(m, blocks, threads, PATHS[lib.sha256_path()])
         return out
+    _count(m, blocks, 1, "hashlib")
     # degraded path: identical digests, one hashlib call per row
     if lens32 is None:
         for i in range(m):
@@ -109,7 +156,10 @@ def be_rows_to_ints(rows: np.ndarray) -> List[int]:
 
 
 __all__ = [
+    "PATHS",
     "sha256_rows",
+    "hash_tally",
+    "reset_hash_tally",
     "ints_to_be_rows",
     "be_rows_to_ints",
 ]
