@@ -459,20 +459,6 @@ def measure_spmd(
 # Wide-group modexp: the XLA limb families past 256 bits
 # ---------------------------------------------------------------------------
 
-# RFC 3526 MODP group 14 (2048-bit safe prime)
-_MODP14 = int(
-    "FFFFFFFFFFFFFFFFC90FDAA22168C234C4C6628B80DC1CD129024E088A67CC74"
-    "020BBEA63B139B22514A08798E3404DDEF9519B3CD3A431B302B0A6DF25F1437"
-    "4FE1356D6D51C245E485B576625E7EC6F44C42E9A637ED6B0BFF5CB6F406B7ED"
-    "EE386BFB5A899FA5AE9F24117C4B1FE649286651ECE45B3DC2007CB8A163BF05"
-    "98DA48361C55D39A69163FA8FD24CF5F83655D23DCA3AD961C62F356208552BB"
-    "9ED529077096966D670C354E4ABC9804F1746C08CA18217C32905E462E36CE3B"
-    "E39E772C180E86039B2783A2EC07A28FB5C55DF06F4C52C9DE2BCBF695581718"
-    "3995497CEA956AE515D2261898FA051015728E5A8AACAA68FFFFFFFFFFFFFFFF",
-    16,
-)
-
-
 # RFC 2409 First Oakley Group (768-bit safe prime) — sized for the
 # (11, 72) limb family, so all three wide families get a measured
 # device-vs-host number (WIDE_FLOORS provenance)
@@ -486,8 +472,8 @@ _OAKLEY1 = int(
 
 def measure_modexp_wide() -> dict:
     """exps/s of the wide XLA limb families (384/768/2048-bit groups)
-    vs the host comparator — python pow here, since the native
-    Montgomery kernel is 256-bit-only."""
+    vs python pow (the 2048-bit group's native host kernel against its
+    device programs: tools/modexpbench.py)."""
     from cleisthenes_tpu.ops import modmath as mm
 
     rng = np.random.default_rng(29)
@@ -495,7 +481,7 @@ def measure_modexp_wide() -> dict:
     for label, p, batch in (
         ("384", mm.P384, 2048),  # the packaged 384-bit group's prime
         ("768", _OAKLEY1, 512),  # (11,72) family
-        ("2048", _MODP14, 128),
+        ("2048", mm.MODP14, 128),  # RFC 3526 MODP group 14
     ):
         group = mm.GroupParams(p=p, q=(p - 1) // 2, g=4)
         # uncached engine (get_engine's per-group cache would leak the

@@ -75,6 +75,12 @@ class Config:
       crypto_backend: 'cpu' (numpy reference), 'cpp' (native compiled
         GF kernels) or 'tpu' (batched JAX/XLA kernels) — the
         BatchCrypto/ErasureCoder seam from BASELINE.json.
+      crypto_group: the group threshold encryption and the common coin
+        run in, by name (ops.modmath.GROUPS): 'default' (256-bit,
+        research grade) or 'modp14' (RFC 3526's 2048-bit MODP group 14,
+        112-bit security by NIST SP 800-57 Part 1, Table 2).  The
+        dealer (protocol.honeybadger.setup_keys) deals in it; every
+        validator of a roster names the same one.
       dial_timeout_s: client dial timeout (reference comm.go:107-109).
       dial_retry_base_s / dial_retry_max_s: redial policy for the
         self-healing gRPC transport — capped exponential backoff with
@@ -162,6 +168,7 @@ class Config:
     f: Optional[int] = None
     batch_size: int = 256
     crypto_backend: str = "cpu"
+    crypto_group: str = "default"
     dial_timeout_s: float = DEFAULT_DIAL_TIMEOUT_S
     dial_retry_base_s: float = DEFAULT_DIAL_RETRY_BASE_S
     dial_retry_max_s: float = DEFAULT_DIAL_RETRY_MAX_S
@@ -336,6 +343,9 @@ class Config:
             )
         if self.crypto_backend not in ("cpu", "cpp", "tpu"):
             raise ValueError(f"unknown crypto_backend {self.crypto_backend!r}")
+        from cleisthenes_tpu.ops.modmath import group_named
+
+        group_named(self.crypto_group)  # raises on an unknown name
         if self.trace_buffer <= 0:
             raise ValueError(
                 f"trace_buffer={self.trace_buffer} must be > 0"

@@ -149,6 +149,24 @@ def _configure_sha256(lib: ctypes.CDLL) -> None:
         raise RuntimeError(f"sha256rows selftest failed: {rc}")
 
 
+def _configure_modpow_wide(lib: ctypes.CDLL) -> None:
+    rows = [ctypes.c_int64, ctypes.c_void_p, ctypes.c_int64,
+            ctypes.c_void_p, ctypes.c_int64, ctypes.c_int]
+    lib.modpow_wide_batch.argtypes = [ctypes.c_void_p] * 2 + rows
+    lib.modpow_wide_batch.restype = ctypes.c_int
+    lib.dualpow_wide_batch.argtypes = [ctypes.c_void_p] * 4 + rows
+    lib.dualpow_wide_batch.restype = ctypes.c_int
+    lib.modpow_wide_available.argtypes = []
+    lib.modpow_wide_available.restype = ctypes.c_int
+    lib.modpow_wide_selftest.argtypes = []
+    lib.modpow_wide_selftest.restype = ctypes.c_int
+    rc = lib.modpow_wide_selftest()
+    if rc != 0:
+        raise RuntimeError(f"modpow_wide selftest failed: {rc}")
+    if not lib.modpow_wide_available():
+        raise RuntimeError("modpow_wide: libcrypto's BN calls not found")
+
+
 def load_sha256() -> Optional[ctypes.CDLL]:
     """The batched SHA-256 library, or None (no toolchain)."""
     return _load("sha256rows", _configure_sha256)
@@ -164,8 +182,20 @@ def load_modpow() -> Optional[ctypes.CDLL]:
     return _load("modpow256", _configure_modpow)
 
 
+def load_modpow_wide() -> Optional[ctypes.CDLL]:
+    """The OpenSSL-backed modexp library of every width, or None (no
+    toolchain, or no libcrypto)."""
+    return _load("modpow_wide", _configure_modpow_wide)
+
+
 def native_available() -> bool:
     return load_gf256() is not None
 
 
-__all__ = ["load_gf256", "load_modpow", "load_sha256", "native_available"]
+__all__ = [
+    "load_gf256",
+    "load_modpow",
+    "load_modpow_wide",
+    "load_sha256",
+    "native_available",
+]

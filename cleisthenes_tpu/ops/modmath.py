@@ -27,10 +27,14 @@ of them plus carries per limb, staying under 2^30 — so carries can be
 native int32 on the VPU with zero emulation.  Montgomery radix
 R = 2^264.
 
-The group: Z_p* with a fixed 256-bit safe prime p = 2q+1, operating in
-the order-q quadratic-residue subgroup generated by g=4.  (A research-
-grade parameter choice: the seam lets a curve or larger prime drop in
-later without touching callers.)
+The default group: Z_p* with a fixed 256-bit safe prime p = 2q+1,
+operating in the order-q quadratic-residue subgroup generated by g=4 —
+a research-grade parameter choice, since a discrete log in a 256-bit
+prime field is within reach.  A deployment that needs production
+strength names RFC 3526's 2048-bit MODP-14 group instead
+(``Config.crypto_group = "modp14"``, ``GROUP14``: 112-bit security),
+which runs in the (11, 192) limb family on the device and through
+OpenSSL (native/modpow_wide.cpp) on the host.
 """
 
 from __future__ import annotations
@@ -44,7 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from cleisthenes_tpu.ops import placement
+from cleisthenes_tpu.ops import placement, widepow
 from cleisthenes_tpu.ops.hashrows import be_rows_to_ints, ints_to_be_rows
 from cleisthenes_tpu.parallel.mesh import host_array
 from cleisthenes_tpu.utils import trace
@@ -220,6 +224,10 @@ _WIDE_LAYOUTS = (
     LimbLayout(11, 192),  # <= 2112 bits, 264-byte values
 )
 LAYOUT256 = LimbLayout(LIMB_BITS, NLIMBS)
+# The families whose ladders run as Pallas kernels on a TPU: the
+# 256-bit one and MODP-14's 2112-bit one.  (12,32) and (11,72) stay
+# plain XLA, the form their WIDE_FLOORS were carried over against.
+_KERNEL_LAYOUTS = (LAYOUT256, LimbLayout(11, 192))
 
 
 def layout_for_group(group: GroupParams) -> Optional[LimbLayout]:
@@ -270,6 +278,38 @@ P384 = int(
     16,
 )
 GROUP384 = GroupParams(p=P384, q=(P384 - 1) // 2, g=4)
+
+# RFC 3526 section 3: the 2048-bit MODP group (id 14), the smallest
+# finite-field group NIST SP 800-57 Part 1 (Table 2) rates at 112-bit
+# security.  p = 2q + 1 is a safe prime; the RFC's generator g = 2 is
+# a quadratic residue, since p = 7 (mod 8), so it generates the order-q
+# subgroup the threshold schemes work in.  The (11, 192) limb family
+# hosts it on the device, native/modpow_wide.cpp on the host.
+MODP14 = int(
+    "FFFFFFFFFFFFFFFFC90FDAA22168C234C4C6628B80DC1CD129024E088A67CC74"
+    "020BBEA63B139B22514A08798E3404DDEF9519B3CD3A431B302B0A6DF25F1437"
+    "4FE1356D6D51C245E485B576625E7EC6F44C42E9A637ED6B0BFF5CB6F406B7ED"
+    "EE386BFB5A899FA5AE9F24117C4B1FE649286651ECE45B3DC2007CB8A163BF05"
+    "98DA48361C55D39A69163FA8FD24CF5F83655D23DCA3AD961C62F356208552BB"
+    "9ED529077096966D670C354E4ABC9804F1746C08CA18217C32905E462E36CE3B"
+    "E39E772C180E86039B2783A2EC07A28FB5C55DF06F4C52C9DE2BCBF695581718"
+    "3995497CEA956AE515D2261898FA051015728E5A8AACAA68FFFFFFFFFFFFFFFF",
+    16,
+)
+GROUP14 = GroupParams(p=MODP14, q=(MODP14 - 1) // 2, g=2)
+
+# The groups a deployment names by Config.crypto_group
+GROUPS = {"default": DEFAULT_GROUP, "modp14": GROUP14}
+
+
+def group_named(name: str) -> GroupParams:
+    """The group ``Config.crypto_group`` names."""
+    try:
+        return GROUPS[name]
+    except KeyError:
+        raise ValueError(
+            f"unknown crypto_group {name!r}; one of {tuple(GROUPS)}"
+        ) from None
 
 
 def _int_to_limbs_w(x: int, lay: LimbLayout) -> np.ndarray:
@@ -343,15 +383,17 @@ _TILE_ROWS = 8
 # the limb loop: unrolled, the 72- and 192-limb products would be
 # 20-150 thousand operations, and the 32-limb one, ~4,400, took the
 # CPU 36-41 s to compile in each of tests/test_groups.py's 384-bit
-# tests where the loop takes 2; no cell runs a wide group.
+# tests where the loop takes 2.
 _STRAIGHT_LINE_MAX_LIMBS = 24
 
 
-def _make_mont_mul(limb_bits: int, nlimbs: int):
+def _make_mont_mul(limb_bits: int, nlimbs: int, kernel: bool = False):
     """Build the lazy-carry CIOS Montgomery product for one limb
     layout.  The 256-bit layout and every wide layout share THIS one
     builder; the form (straight-line or looped over limbs) follows
-    from ``nlimbs`` at trace time.
+    from ``nlimbs`` at trace time.  ``kernel``: the product of a
+    ladder that runs as a Pallas kernel on a TPU (_KERNEL_LAYOUTS),
+    which there takes the loop's kernel form.
 
     ``mont_mul(a, b, m_col, m_prime)``: a, b (nlimbs, ...) int32 with
     canonical limbs (< 2^limb_bits); m_col the modulus limbs,
@@ -376,10 +418,7 @@ def _make_mont_mul(limb_bits: int, nlimbs: int):
             axis=0,
         )
 
-    def straight_line(a, b, m_col, m_prime):
-        t = jnp.zeros_like(a)
-        for i in range(nlimbs):
-            t = limb_step(t, a[i], b, m_col, m_prime)
+    def canonical(t, m_col):
         # one carry propagation -> canonical limbs, of t and of t - p
         # side by side (the result is < 2p, so one of the two is it):
         # chains over planes, stacked once.  A limb of t - p may be
@@ -398,13 +437,14 @@ def _make_mont_mul(limb_bits: int, nlimbs: int):
             (less[-1] < 0)[None], jnp.stack(kept), jnp.stack(less)
         )
 
-    def looped(a, b, m_col, m_prime):
-        def outer(i, t):
-            ai = jax.lax.dynamic_index_in_dim(a, i, axis=0, keepdims=False)
-            return limb_step(t, ai, b, m_col, m_prime)
+    def straight_line(a, b, m_col, m_prime):
+        t = jnp.zeros_like(a)
+        for i in range(nlimbs):
+            t = limb_step(t, a[i], b, m_col, m_prime)
+        return canonical(t, m_col)
 
-        t = jax.lax.fori_loop(0, nlimbs, outer, jnp.zeros_like(a), unroll=2)
-
+    def carried(t, m_col):
+        # ``canonical`` as loops over the limbs, with dynamic updates
         def carry_pass(j, t):
             c = t[j] >> limb_bits
             return t.at[j].set(t[j] & mask).at[j + 1].add(c)
@@ -423,11 +463,39 @@ def _make_mont_mul(limb_bits: int, nlimbs: int):
         )
         return jnp.where((borrow == 0)[None], d, t)
 
+    def looped(a, b, m_col, m_prime):
+        def outer(i, t):
+            ai = jax.lax.dynamic_index_in_dim(a, i, axis=0, keepdims=False)
+            return limb_step(t, ai, b, m_col, m_prime)
+
+        t = jax.lax.fori_loop(0, nlimbs, outer, jnp.zeros_like(a), unroll=2)
+        return carried(t, m_col)
+
+    def rotated(a, b, m_col, m_prime):
+        # ``looped`` as a kernel body has it: a's limbs rotate through
+        # limb 0, since a kernel has no dynamic slice of a value, and
+        # the carry is unrolled (a few operations a limb, against the
+        # loop's few hundred; in plain XLA, over 192 limbs, it took the
+        # CPU ~30 s to compile)
+        def outer(_, state):
+            t, a = state
+            t = limb_step(t, a[0], b, m_col, m_prime)
+            return t, jnp.concatenate([a[1:], a[:1]], axis=0)
+
+        t, _ = jax.lax.fori_loop(0, nlimbs, outer, (jnp.zeros_like(a), a))
+        return canonical(t, m_col)
+
+    def in_kernel(a, b, m_col, m_prime):
+        # _run_planes makes the same choice at the same trace
+        return (rotated if _on_tpu() else looped)(a, b, m_col, m_prime)
+
+    if nlimbs <= _STRAIGHT_LINE_MAX_LIMBS:
+        form = straight_line
+    else:
+        form = in_kernel if kernel else looped
     # jitted: a program traces the product once a shape, however many
     # times its text holds it (the kernels all share one block shape)
-    return jax.jit(
-        straight_line if nlimbs <= _STRAIGHT_LINE_MAX_LIMBS else looped
-    )
+    return jax.jit(form)
 
 
 _mont_mul = _make_mont_mul(LIMB_BITS, NLIMBS)
@@ -558,11 +626,11 @@ def _from_montgomery(mul, acc):
     return mul(acc, one)
 
 
-def _make_ladders(lay: LimbLayout):
+def _make_ladders(lay: LimbLayout, kernel: bool = False):
     """(pow ladder, dual-pow ladder, bytes of an exponent word) of one
-    limb layout."""
+    limb layout; ``kernel`` as ``_make_mont_mul`` takes it."""
     nl = lay.nlimbs
-    mul_raw = _make_mont_mul(lay.limb_bits, nl)
+    mul_raw = _make_mont_mul(lay.limb_bits, nl, kernel)
     # exponents cross the seam as 32 bytes in the 256-bit layout, as
     # val_bytes in a wide one
     exp_bytes = EXP_BITS // 8 if lay == LAYOUT256 else lay.val_bytes
@@ -798,10 +866,11 @@ def _make_programs(lay: LimbLayout):
     layout: unpack, to-Montgomery, exponentiate, from-Montgomery,
     repack in ONE program (one dispatch, one transfer each way).  The
     group constants are traced arguments, so one compiled program
-    serves every group the layout fits.  The 256-bit layout's ladders
-    run as the Pallas kernel on a TPU; the wide families stay plain
-    XLA everywhere."""
-    pow_ladder, dual_ladder, word_bytes = _make_ladders(lay)
+    serves every group the layout fits.  The ladders of
+    _KERNEL_LAYOUTS run as Pallas kernels on a TPU; the other wide
+    families' stay plain XLA everywhere."""
+    kernel = lay in _KERNEL_LAYOUTS
+    pow_ladder, dual_ladder, word_bytes = _make_ladders(lay, kernel)
     is256 = lay == LAYOUT256
 
     def values(b):  # (B, val_bytes) uint8 LE -> limb planes
@@ -821,7 +890,7 @@ def _make_programs(lay: LimbLayout):
         return _from_planes(out, b).T
 
     def run(ladder, consts, planes):
-        if is256:
+        if kernel:
             return _run_planes(ladder, consts, *planes)
         return ladder(consts, *planes)
 
@@ -837,12 +906,13 @@ def _make_programs(lay: LimbLayout):
         out = run(dual_ladder, _group_consts(*group), planes)
         return packed(out, len(u1_b))
 
-    # the 256-bit programs' names are what the profiler's modules are
-    # read by
-    prefix = "_" if is256 else ""
+    # the profiler's modules (jit_<name>) are read by these names: the
+    # 256-bit programs' by benchmarks/layer_metrics/modexp256_roofline.py,
+    # which counts no wide program
+    suffix = "fused" if is256 else "wide"
     return (
-        _jit_program(pow_program, 2, prefix + "pow_fused"),
-        _jit_program(dual_pow_program, 4, prefix + "dual_pow_fused"),
+        _jit_program(pow_program, 2, "_pow_" + suffix),
+        _jit_program(dual_pow_program, 4, "_dual_pow_" + suffix),
     )
 
 
@@ -1006,7 +1076,8 @@ _pow_fused_grouped = _jit_program(_comb_program, 2, "_pow_fused_grouped")
 @functools.cache
 def _wide_kernels(lay: LimbLayout):
     """The same two programs for one wide limb family (the looped
-    product from 72 limbs up), compiled once per (layout, shape)."""
+    product), compiled once per (layout, shape): the profiler's
+    modules ``jit__pow_wide`` and ``jit__dual_pow_wide``."""
     return _make_programs(lay)
 
 
@@ -1112,7 +1183,8 @@ class ModEngine:
     384-bit BLS12-381-class field runs ON DEVICE, no cpu detour).
     ``'cpu'`` uses the native C++ Montgomery kernel
     (native/modpow256.cpp) when the toolchain builds it, else python
-    pow() — which also serves arbitrarily large moduli.
+    pow(); a group wider than 256 bits takes OpenSSL's ladders
+    (``ops.widepow``, native/modpow_wide.cpp), else python pow().
     With a ``parallel.mesh.CryptoMesh`` a batch the floors leave to
     the device shards over every mesh device flat (each
     exponentiation is sequential internally, independent across the
@@ -1197,11 +1269,20 @@ class ModEngine:
     # stay on host then.  Carried over, unmeasured, as above.
     HOST_FLOOR_NO_NATIVE = 16
     # Per-family floors for the wide layouts (a width class must not
-    # silently run on a path that loses to the host).  Carried over
-    # and unmeasured on a local attachment like HOST_FLOOR; the host
-    # comparator at these widths is python pow().  None means always
-    # delegate to the host: the (11,192) 2112-bit family was never
-    # seen to win a dispatch.
+    # silently run on a path that loses to the host).  None means always
+    # delegate to the host.  (12,32) and (11,72): carried over and
+    # unmeasured on a local attachment like HOST_FLOOR, no cell runs
+    # them.  (11,192), the MODP-14 group's: measured against the OpenSSL
+    # host kernel (native/modpow_wide.cpp) by tools/modexpbench.py on a
+    # v5e chip and its 13-core host (PERF.md section 6), ms a
+    # call, pow / dual_pow, device (Pallas kernel, 1,024 rows a tile)
+    # against host (13 threads):
+    #       8 rows   575 / 578   against    4.1 /    4.8
+    #   1,024 rows   577 / 580   against  181   /  203
+    #   4,096 rows 2,303 / 2,313 against  728   /  836
+    #  16,384 rows 9,216 / 9,264 against 3,159  / 3,317
+    # 136 us a 192-limb product a tile: the device loses by 3.2 times or
+    # more at every size, so no batch of the family goes to it.
     WIDE_FLOORS = {
         LimbLayout(12, 32): 256,
         LimbLayout(11, 72): 256,
@@ -1536,6 +1617,8 @@ class ModEngine:
                         _ints_to_bytes32le(exps),
                     )
                 )
+            if not self._fits256:
+                return widepow.pow_rows(bases, exps, gp)
             return [pow(b, e, gp) for b, e in zip(bases, exps)]
         host = self._host_floor(len(bases))
         if host is not None:
@@ -1642,6 +1725,8 @@ class ModEngine:
                         _ints_to_bytes32le(e2),
                     )
                 )
+            if not self._fits256:
+                return widepow.dual_pow_rows(u1, e1, u2, e2, gp)
             return [
                 pow(a, x, gp) * pow(b, y, gp) % gp
                 for a, x, b, y in zip(u1, e1, u2, e2)

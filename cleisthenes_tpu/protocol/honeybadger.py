@@ -46,6 +46,7 @@ from cleisthenes_tpu.protocol.hub import _Memo
 from cleisthenes_tpu.ops import tpke as tpke_mod
 from cleisthenes_tpu.ops.backend import BatchCrypto, get_backend
 from cleisthenes_tpu.ops.coin import CommonCoin
+from cleisthenes_tpu.ops.modmath import group_named
 from cleisthenes_tpu.ops.tpke import (
     Ciphertext,
     DhShare,
@@ -280,11 +281,14 @@ def setup_keys(
     OS CSPRNG.  A seed makes the whole key set reproducible — for
     tests and benchmarks ONLY: a seeded deployment's MAC and shares
     are computable by anyone who knows the seed.
+
+    The keys are dealt in ``group``, or where none is passed in the
+    group ``config.crypto_group`` names.
     """
     members = sorted(member_ids)
     if len(members) != config.n:
         raise ValueError(f"roster size {len(members)} != n={config.n}")
-    group = group or tpke_mod.DEFAULT_GROUP
+    group = group or group_named(config.crypto_group)
     tpke_pub, tpke_shares = tpke_mod.deal(
         config.n, config.decryption_threshold, seed=seed, group=group
     )

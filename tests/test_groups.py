@@ -11,9 +11,10 @@ Encrypt / DecShare / batched CP verify / Decrypt
 - a second 256-bit safe prime, through BOTH engines (the native C++
   Montgomery kernel and the XLA limb kernel: one compiled program
   serves every <=256-bit group, constants ride in as traced arrays);
-- the 2048-bit RFC 3526 MODP-14 safe prime, CPU-only, proving the
-  limb-free python path and every byte-width in the CP transcripts
-  generalize past the 256-bit layout.
+- the 2048-bit RFC 3526 MODP-14 safe prime (``modmath.GROUP14``), on
+  the host engine (OpenSSL's ladders, native/modpow_wide.cpp) and the
+  (11, 192) XLA family, proving every byte-width in the CP transcripts
+  generalizes past the 256-bit layout.
 """
 
 import pytest
@@ -27,19 +28,9 @@ from cleisthenes_tpu.ops.modmath import DEFAULT_GROUP, GroupParams, get_engine
 P2 = 0x93A40B764F1F5026ADA7C38AA3EF4EE81E01E89F9FE80837B1E370913DA99F13
 GROUP2 = GroupParams(p=P2, q=(P2 - 1) // 2, g=4)
 
-# RFC 3526 group 14: 2048-bit MODP safe prime (well-known constant).
-MODP14 = int(
-    "FFFFFFFFFFFFFFFFC90FDAA22168C234C4C6628B80DC1CD129024E088A67CC74"
-    "020BBEA63B139B22514A08798E3404DDEF9519B3CD3A431B302B0A6DF25F1437"
-    "4FE1356D6D51C245E485B576625E7EC6F44C42E9A637ED6B0BFF5CB6F406B7ED"
-    "EE386BFB5A899FA5AE9F24117C4B1FE649286651ECE45B3DC2007CB8A163BF05"
-    "98DA48361C55D39A69163FA8FD24CF5F83655D23DCA3AD961C62F356208552BB"
-    "9ED529077096966D670C354E4ABC9804F1746C08CA18217C32905E462E36CE3B"
-    "E39E772C180E86039B2783A2EC07A28FB5C55DF06F4C52C9DE2BCBF695581718"
-    "3995497CEA956AE515D2261898FA051015728E5A8AACAA68FFFFFFFFFFFFFFFF",
-    16,
-)
-GROUP14 = GroupParams(p=MODP14, q=(MODP14 - 1) // 2, g=4)
+# RFC 3526 group 14: the 2048-bit MODP safe prime, g = 2 (the
+# package's constant; tests/test_modp14.py checks it against the RFC)
+from cleisthenes_tpu.ops.modmath import GROUP14  # noqa: E402
 
 N, F = 7, 2
 
@@ -172,10 +163,10 @@ def test_2048bit_modp14_xla_engine_matches_pow(
 
 
 def test_wide_floors_route_by_measured_crossover(jax_cpu_devices):
-    """Engine defaults route per limb family by WIDE_FLOORS (values
-    carried over from an earlier attachment of the chip, unmeasured on
-    the present one): 384-bit goes to the device at >= 256 exps;
-    2048-bit must ALWAYS delegate to the host."""
+    """Engine defaults route per limb family by WIDE_FLOORS: 384-bit
+    goes to the device at >= 256 exps (carried over, unmeasured);
+    2048-bit ALWAYS delegates to the host, whose OpenSSL kernel beat
+    the device at every batch size measured on a v5e chip."""
     eng384 = get_engine("tpu", group=GROUP384)
     assert eng384._host_floor(255) is not None  # below floor -> host
     assert eng384._host_floor(256) is None  # above -> device

@@ -71,3 +71,33 @@ def test_comb_kernels_match_the_host_kernel(interpreted_kernels, rows, width):
     exps[0, 0], exps[-1, -1] = 0, 255
     got = dev._comb_device([(bases, exps)])[0]
     assert (got == host.pow_grouped_cols([(bases, exps)])[0]).all()
+
+
+@pytest.mark.parametrize("op", ["pow", "dual_pow"])
+def test_wide_ladder_kernels_match_pow(interpreted_kernels, monkeypatch, op):
+    """A wide family's ladders as kernels: the limb loop with a's limbs
+    rotating through limb 0 and the carry unrolled (the kernel's form;
+    plain XLA keeps its looped carry).  Only the 2112-bit family runs
+    it; the 384-bit (12, 32) one, the smallest that keeps the loop, is
+    put among the kernel families here, where the interpreter would
+    take minutes over 192 limbs."""
+    monkeypatch.setattr(
+        mm, "_KERNEL_LAYOUTS", mm._KERNEL_LAYOUTS + (mm.LimbLayout(12, 32),)
+    )
+    mm._wide_kernels.cache_clear()
+    grp = mm.GROUP384
+    dev = mm.ModEngine("tpu", group=grp)
+    rnd = random.Random(384)
+    u = [0, 1, grp.p - 1] + [rnd.randrange(grp.p) for _ in range(5)]
+    e = [0, grp.q - 1] + [rnd.randrange(grp.q) for _ in range(6)]
+    if op == "pow":
+        got = dev.pow_batch(u, e)
+        mm._wide_kernels.cache_clear()
+        assert got == [pow(x, y, grp.p) for x, y in zip(u, e)]
+        return
+    got = dev.dual_pow_batch(u, e, u[::-1], e[::-1])
+    mm._wide_kernels.cache_clear()
+    assert got == [
+        pow(a, x, grp.p) * pow(b, y, grp.p) % grp.p
+        for a, x, b, y in zip(u, e, u[::-1], e[::-1])
+    ]
