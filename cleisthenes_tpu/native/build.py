@@ -156,6 +156,11 @@ def _configure_modpow_wide(lib: ctypes.CDLL) -> None:
     lib.modpow_wide_batch.restype = ctypes.c_int
     lib.dualpow_wide_batch.argtypes = [ctypes.c_void_p] * 4 + rows
     lib.dualpow_wide_batch.restype = ctypes.c_int
+    lib.kronecker_wide_batch.argtypes = [
+        ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p,
+        ctypes.c_void_p, ctypes.c_int64,
+    ]
+    lib.kronecker_wide_batch.restype = ctypes.c_int
     lib.modpow_wide_available.argtypes = []
     lib.modpow_wide_available.restype = ctypes.c_int
     lib.modpow_wide_selftest.argtypes = []

@@ -24,6 +24,11 @@
 // results and the modulus are `width` bytes (the modulus's own byte
 // width), exponents `exp_width` bytes.  Rows are independent, so a
 // batch splits over threads (ctypes releases the GIL for the call).
+//
+// Beside the ladders, BN_kronecker: the symbol (x/p) a row, at gcd
+// speed, for the subgroup check of a safe-prime group (ops/tpke.py
+// is_group_element: Euler's criterion).  It is resolved on its own,
+// so a libcrypto without it still serves the exponentiations.
 
 #include <sched.h>
 
@@ -56,6 +61,7 @@ typedef int (*exp2_fn)(void*, const void*, const void*, const void*,
                        const void*, const void*, void*, void*);
 typedef int (*mod_mul_fn)(void*, const void*, const void*, const void*,
                           void*);
+typedef int (*kronecker_fn)(const void*, const void*, void*);
 
 struct Bn {
     bool ok = false;
@@ -70,6 +76,7 @@ struct Bn {
     exp_consttime_fn exp_consttime = nullptr;
     exp2_fn exp2 = nullptr;
     mod_mul_fn mod_mul = nullptr;
+    kronecker_fn kronecker = nullptr;  // optional: null where missing
 };
 
 template <typename T>
@@ -95,7 +102,10 @@ Bn resolve() {
                bind(lib, "BN_mod_exp_mont_consttime", b.exp_consttime) &&
                bind(lib, "BN_mod_exp2_mont", b.exp2) &&
                bind(lib, "BN_mod_mul", b.mod_mul);
-        if (b.ok) break;
+        if (b.ok) {
+            bind(lib, "BN_kronecker", b.kronecker);
+            break;
+        }
     }
     return b;
 }
@@ -278,6 +288,31 @@ int dualpow_wide_batch(const uint8_t* u1, const uint8_t* e1,
     return run(mod, Rows{u1, e1, u2, e2, exp_width, width, out, m}, threads);
 }
 
+// out[i] = the Kronecker symbol (row i / mod), -1, 0 or 1, of m
+// big-endian `width`-byte rows, on the calling thread (a round's
+// subgroup checks are a few hundred rows of ~0.2 ms); returns 1, or
+// -1 where libcrypto or its BN_kronecker is missing or failed.
+int kronecker_wide_batch(const uint8_t* rows, int64_t width,
+                         const uint8_t* mod, int8_t* out, int64_t m) {
+    const Bn& b = bn();
+    if (!b.ok || !b.kronecker) return -1;
+    if (m == 0) return 1;
+    void* ctx = b.ctx_new();
+    void* n = b.bin2bn(mod, int(width), nullptr);
+    void* x = b.bn_new();
+    bool ok = ctx && n && x;
+    for (int64_t i = 0; ok && i < m; i++) {
+        ok = b.bin2bn(rows + i * width, int(width), x) != nullptr;
+        int k = ok ? b.kronecker(x, n, ctx) : -2;
+        ok = k >= -1 && k <= 1;  // -2: OpenSSL's error
+        out[i] = int8_t(k);
+    }
+    for (void* v : {n, x})
+        if (v) b.bn_free(v);
+    if (ctx) b.ctx_free(ctx);
+    return ok ? 1 : -1;
+}
+
 // 1 where libcrypto's BN calls resolved.
 int modpow_wide_available() { return bn().ok ? 1 : 0; }
 
@@ -305,6 +340,15 @@ int modpow_wide_selftest() {
             return 3;
         if (std::memcmp(got, want_dual, 12) != 0) return 4;
     }
+    if (!bn().kronecker) return 0;  // the callers take pow's path
+    // (x / 1000003) for x = 0, 1, 2, 4, 1000002: 1000003 is prime and
+    // 3 mod 8, so 2 is a non-residue and -1 too
+    const uint8_t xs[15] = {0, 0, 0, 0, 0, 1, 0, 0, 2,
+                            0, 0, 4, 0x0f, 0x42, 0x42};
+    const int8_t want_k[5] = {0, 1, -1, 1, -1};
+    int8_t sym[5] = {9, 9, 9, 9, 9};
+    if (kronecker_wide_batch(xs, 3, mod, sym, 5) != 1) return 5;
+    if (std::memcmp(sym, want_k, 5) != 0) return 6;
     return 0;
 }
 

@@ -75,6 +75,7 @@ from cleisthenes_tpu.ops.modmath import (
 from cleisthenes_tpu.ops.tpke import (
     ThresholdPublicKey,
     ThresholdSecretShare,
+    group_members,
 )
 
 
@@ -317,8 +318,6 @@ def _commit_eval_exps(
 def validate_commitments(
     commitment_sets: Sequence[Sequence[int]],
     group: GroupParams = DEFAULT_GROUP,
-    backend: str = "cpu",
-    mesh=None,
     threshold: Optional[int] = None,
 ) -> List[bool]:
     """Shape + subgroup membership for whole commitment vectors.
@@ -338,24 +337,18 @@ def validate_commitments(
     honest verifier downstream (an empty vector is vacuously
     "all-member", and a t' != t vector desynchronizes the flattened
     exponent batches of verify/finalize)."""
-    gp = group
-    eng = get_engine_degraded(backend, mesh, gp)
     flat: List[int] = []
     spans: List[int] = []
     for commits in commitment_sets:
-        flat.extend(c % gp.p for c in commits)
+        flat.extend(c % group.p for c in commits)
         spans.append(len(commits))
-    pows = eng.pow_batch(flat, [gp.q] * len(flat))
+    member = group_members(flat, group)
     out: List[bool] = []
     off = 0
-    for (commits, span) in zip(commitment_sets, spans):
+    for span in spans:
         ok = span > 0 and (threshold is None or span == threshold)
-        ok = ok and all(
-            1 < (c % gp.p) and pows[off + i] == 1
-            for i, c in enumerate(commits)
-        )
+        out.append(ok and all(member[off : off + span]))
         off += span
-        out.append(ok)
     return out
 
 
@@ -514,8 +507,6 @@ def run_dkg(
     commit_ok = validate_commitments(
         [ped[i] for i in range(1, n + 1)],
         group=group,
-        backend=backend,
-        mesh=mesh,
         threshold=threshold,
     )
     bad_commits = {
@@ -606,8 +597,6 @@ def run_dkg(
     feld_ok = validate_commitments(
         [feld[i] for i in p2_checked],
         group=group,
-        backend=backend,
-        mesh=mesh,
         threshold=threshold,
     )
     # consistency vs the phase-one shares every receiver holds

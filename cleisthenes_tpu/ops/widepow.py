@@ -17,7 +17,7 @@ native/modpow256.cpp.
 
 from __future__ import annotations
 
-from typing import Dict, List, Sequence, Union
+from typing import Dict, List, Optional, Sequence, Union
 
 import numpy as np
 
@@ -117,6 +117,25 @@ def dual_pow_rows(
     )
 
 
+def kronecker_rows(xs: Sequence[int], modulus: int) -> Optional[List[int]]:
+    """[(x / modulus)], each -1, 0 or 1, for 0 <= x < modulus (odd);
+    None where the kernel does not build or libcrypto lacks
+    BN_kronecker.  Not counted in the tally: that counts
+    exponentiations."""
+    lib = load_modpow_wide() if modulus % 2 else None
+    if lib is None:
+        return None
+    width = (modulus.bit_length() + 7) // 8
+    rows = ints_to_be_rows(xs, width)
+    mod = np.frombuffer(modulus.to_bytes(width, "big"), dtype=np.uint8)
+    out = np.empty(len(xs), dtype=np.int8)
+    if lib.kronecker_wide_batch(
+        rows.ctypes.data, width, mod.ctypes.data, out.ctypes.data, len(xs)
+    ) < 1:
+        return None
+    return out.tolist()
+
+
 def _run(call, cols, ew, modulus, width, m, threads) -> List[int]:
     mod = np.frombuffer(modulus.to_bytes(width, "big"), dtype=np.uint8)
     out = np.empty((m, width), dtype=np.uint8)
@@ -134,6 +153,7 @@ __all__ = [
     "PATHS",
     "pow_rows",
     "dual_pow_rows",
+    "kronecker_rows",
     "modpow_wide_tally",
     "reset_modpow_wide_tally",
 ]

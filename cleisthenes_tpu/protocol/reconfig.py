@@ -436,24 +436,6 @@ def _pvss_engine(group: GroupParams):
     return get_engine_degraded("cpu", None, group)
 
 
-def _jacobi(a: int, n: int) -> int:
-    """Jacobi symbol (a/n), n odd positive.  For the safe-prime groups
-    here (p = 2q + 1) membership in the order-q QR subgroup is exactly
-    (a/p) == 1 — a gcd-speed screen, vs a full modexp per element."""
-    a %= n
-    result = 1
-    while a:
-        while a % 2 == 0:
-            a //= 2
-            if n % 8 in (3, 5):
-                result = -result
-        a, n = n, a
-        if a % 4 == 3 and n % 4 == 3:
-            result = -result
-        a %= n
-    return result if n == 1 else 0
-
-
 def _pvss_scalar(seed: bytes, k: int, q: int) -> int:
     """Deterministic scalar expansion: 512 hash bits mod q (bias
     negligible at these widths); index m doubles as the DLEQ witness
@@ -600,10 +582,8 @@ def pvss_verify_dealing(
             cipher, c, z = _pvss_parse_section(blob, kind, group)
             if not (0 <= c < q and 0 <= z < q):
                 return False
-            for v in cipher:
-                # subgroup screen: QR test at gcd speed
-                if not (0 < v < p) or _jacobi(v, p) != 1:
-                    return False
+            if not all(tpke_mod.group_members(cipher, group)):
+                return False
             # X_j = prod C_i^{j^i}, Horner in the (small) exponent j
             x_j = commits[-1]
             for cm in reversed(commits[:-1]):
@@ -1152,7 +1132,6 @@ class ReconfigManager:
         ok = validate_commitments(
             [dealing.tpke_commits, dealing.coin_commits],
             group=hb.group,
-            backend="cpu",
             threshold=t_new,
         )
         if not all(ok):

@@ -13,7 +13,13 @@ For RFC 3526's MODP group 14 (``ops.modmath.GROUP14``), times:
 - the host kernel (native/modpow_wide.cpp, OpenSSL's ladders) as the
   program calls it, the kernel choosing its threads, at the same sizes,
   and at 1, 2, 4, 8 and 13 threads at 8, 64 and 1,024 rows;
-- Python's ``pow()``, on 8 rows.
+- Python's ``pow()``, on 8 rows;
+- the subgroup check (``tpke.group_members``) in the 256-bit default
+  group, GROUP384 and MODP-14, in us a row by path: ``kronecker``
+  (OpenSSL's symbol, the path a safe-prime group takes) and ``pow``
+  (x^q through ``host_pow_batch``, the fallback), one row a call as
+  ``deserialize_ciphertext`` calls it and 256 rows in one call; both
+  paths' answers must equal Python's ``pow(x, q, p) == 1``.
 
 Bases, exponents (full width mod q, as Shamir shares are) and a few
 edge rows (0, 1, p - 1; exponents 0 and q - 1) are drawn from a seed;
@@ -44,6 +50,7 @@ from benchmarks import reference_modexp as ref
 THREADS = (1, 2, 4, 8, 13)
 THREAD_SIZES = (8, 64, 1024)
 DISTINCT = 128
+CHECK_ROWS = 256
 
 
 def _inputs(seed: int):
@@ -74,6 +81,43 @@ def _time(call: Callable, repeats: int) -> Dict:
         "best_ms": round(min(out) * 1e3, 3),
         "median_ms": round(statistics.median(out) * 1e3, 3),
     }
+
+
+def group_checks(repeats: int) -> Dict:
+    """{width: {path: {one_row_us, batch_us}}}: us a row of the
+    subgroup check, one row a call and ``CHECK_ROWS`` in one call."""
+    from cleisthenes_tpu.ops import modmath as mm
+    from cleisthenes_tpu.ops import tpke
+
+    out: Dict = {}
+    for group in (mm.DEFAULT_GROUP, mm.GROUP384, mm.GROUP14):
+        p, q = group.p, group.q
+        rnd = random.Random(20261019)
+        xs = [pow(rnd.randrange(2, p - 1), 2, p) for _ in range(CHECK_ROWS // 2)]
+        xs += [p - x for x in xs]  # the non-residues (p = 3 mod 4)
+        want = [pow(x, q, p) == 1 for x in xs]
+        paths = {
+            "kronecker": lambda rows: tpke.group_members(rows, group),
+            "pow": lambda rows: [
+                v == 1 for v in mm.host_pow_batch(rows, [q] * len(rows), group)
+            ],
+        }
+        row = out.setdefault(str(p.bit_length()), {})
+        for path, call in paths.items():
+            tpke.reset_group_check_tally()
+            if call(xs) != want:
+                raise SystemExit(f"group check {path} at {p.bit_length()} "
+                                 "bits differs from pow(x, q, p) == 1")
+            if path == "kronecker" and tpke.group_check_tally()["pow"]:
+                raise SystemExit("group check took the pow path in a "
+                                 "safe-prime group")
+            one = _time(lambda: [call([x]) for x in xs[:64]], repeats)
+            batch = _time(lambda: call(xs), repeats)
+            row[path] = {
+                "one_row_us": round(one["best_ms"] * 1e3 / 64, 2),
+                "batch_us": round(batch["best_ms"] * 1e3 / len(xs), 2),
+            }
+    return out
 
 
 def measure(
@@ -113,7 +157,7 @@ def measure(
         "affinity": len(os.sched_getaffinity(0)),
         "group": "RFC 3526 MODP-14, 2048-bit p, exponents mod q",
         "host": {}, "host_threads": {}, "python_ms_per_row": None,
-        "device": {}, "crossover": {},
+        "device": {}, "crossover": {}, "group_check_us": {},
     }
     host = mm.ModEngine("cpu", group=mm.GROUP14)
     for rows in sizes:
@@ -140,6 +184,7 @@ def measure(
     t0 = time.perf_counter()
     ref.pow_rows(u1[:8], e1[:8])
     report["python_ms_per_row"] = round((time.perf_counter() - t0) * 1e3 / 8, 3)
+    report["group_check_us"] = group_checks(repeats)
     if host_only:
         return report
 
